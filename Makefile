@@ -168,9 +168,12 @@ bench-all:
 bench-full:
 	$(PY) bench.py --full
 
-# build the native codecs explicitly (they also build lazily on import)
+# build the native codecs explicitly (they also build lazily on first use).
+# Same build() as the lazy path, run as a script so jax is not imported:
+# it records the sources' sha256 beside the .so, which is how the loader
+# decides staleness (content, not mtime)
 native:
-	g++ -O2 -std=c++17 -shared -fPIC -o native/libjylis_native.so native/*.cpp
+	$(PY) jylis_tpu/native/__init__.py
 
 run:
 	$(PY) -m jylis_tpu
@@ -212,7 +215,8 @@ smoke3:
 	$(PY) scripts/smoke3.py --spawn
 
 clean:
-	rm -f native/libjylis_native.so jylis_tpu/native/libjylis_native.so \
+	rm -f native/libjylis_native.so native/libjylis_native.so.srchash \
+	  jylis_tpu/native/libjylis_native.so \
 	  native/libjylis_native_san.so native/libjylis_native_tsan.so
 	rm -rf build dist
 	find . -name __pycache__ -type d -exec rm -rf {} +

@@ -21,8 +21,24 @@ Layering (mirrors SURVEY.md section 1, re-designed for JAX/XLA):
 counters, docs/_docs/types/*.md), so x64 mode is enabled at import.
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+# Persistent compile cache, set here because every entry point (the node,
+# lanes, bench.py, the smokes, the tests) imports this package first.
+# JAX_COMPILATION_CACHE_DIR, when set, places the cache and jax reads it
+# itself; otherwise a FIXED directory beside the package (gitignored),
+# never a temp/pid/time-derived one — a cache that moves never hits.
+# Threshold 0: most serving kernels compile in under jax's default 1 s
+# floor, and a boot pays for all of them (database.warmup).
+COMPILE_CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 __version__ = "0.5.0"

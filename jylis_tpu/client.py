@@ -180,7 +180,7 @@ class ClusterClient:
     subset of the cluster; discovery fills in awareness of the rest).
     ``region`` biases routing: endpoints whose node advertises the same
     region are preferred — "nearest replica" by the operator's own
-    region taxonomy, no latency probing. All operations are
+    region naming, no latency probing. All operations are
     synchronous and retry internally; connection-level failures mark
     the endpoint dead for a short window and fail over to the next
     preferred endpoint, recording the client-observed MTTR.
@@ -369,7 +369,7 @@ class ClusterClient:
 
     def execute(self, *args):
         """Route by command class (admission.py's classifier, the same
-        taxonomy the server sheds by): read-shaped commands go through
+        classes the server sheds by): read-shaped commands go through
         read(), everything else through write()."""
         from .admission import READ as _READ
         from .admission import classify

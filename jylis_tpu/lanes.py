@@ -74,6 +74,12 @@ MANIFEST_NAME = "lanes.json"
 RESTART_BACKOFF_S = 0.5
 RESTART_BACKOFF_CAP_S = 10.0
 
+# a lane that cannot EVER serve under this configuration (main.py: more
+# than one lane on an accelerator platform) exits with this code
+# (sysexits EX_CONFIG); the supervisor stops the node instead of
+# respawning into the same wall
+LANE_FATAL_EXIT = 78
+
 
 def lane_of(key: bytes, n_lanes: int) -> int:
     """The lane whose keyspace slice ``key`` hashes into — stable
@@ -280,6 +286,7 @@ class Supervisor:
             os.environ.get(LANE_FAILPOINTS_ENV, "")
         )
         self._shutdown = False
+        self.fatal_rc = 0  # a lane's LANE_FATAL_EXIT, the node's exit code
         self._manifest_lock = asyncio.Lock()
         self.done = asyncio.Event()
 
@@ -427,6 +434,14 @@ class Supervisor:
     async def _lane_died(self, lane_id: int) -> None:
         proc = self.procs[lane_id]
         rc = proc.returncode if proc is not None else None
+        if rc == LANE_FATAL_EXIT:
+            self.log.err() and self.log.e(
+                f"lane {lane_id} refused to serve (rc {rc}, its log line "
+                "above says why); stopping the node"
+            )
+            self.fatal_rc = rc
+            self._on_signal()
+            return
         if rc == 86 and lane_id in self._lane_failpoints:
             # faults.CRASH_EXIT_CODE: the lane died to ITS injected
             # failpoint. Env arming re-reads at import, so respawning
@@ -473,7 +488,10 @@ class Supervisor:
 
 
 async def run_supervisor(config, argv: list[str] | None) -> None:
-    await Supervisor(config, argv).run()
+    sup = Supervisor(config, argv)
+    await sup.run()
+    if sup.fatal_rc:
+        sys.exit(sup.fatal_rc)
 
 
 # ---- aggregated Prometheus endpoint ----------------------------------------

@@ -12,6 +12,9 @@ import asyncio
 import os
 import signal
 import sys
+import time
+
+import jax
 
 from . import faults
 from . import persist
@@ -125,6 +128,12 @@ class Dispose:
                 self._log.info() and self._log.i(
                     f"merge metrics: {self._database.metrics.report()}"
                 )
+                self._log.info() and self._log.i(
+                    f"device state: {device_state_summary(self._database)}"
+                )
+                self._log.info() and self._log.i(
+                    f"device memory: {device_memory_summary()}"
+                )
             metrics.stop_profiling()
         finally:
             if self._journal is not None:
@@ -164,6 +173,28 @@ async def run(argv: list[str] | None = None) -> None:
             config, sys.argv[1:] if argv is None else argv
         )
         return
+    if config.lane_id is not None and config.lanes > 1:
+        # one process per chip: lanes are processes, and an accelerator
+        # belongs to the first process that initialises it — a sibling
+        # lane would crash or hang at backend init and be respawned
+        # forever. Only the lane can see the platform (a supervisor that
+        # looked would itself hold the chip), so it reports with an exit
+        # code the supervisor treats as fatal (lanes.LANE_FATAL_EXIT).
+        try:
+            platform = jax.default_backend()
+        except RuntimeError as e:
+            # backend init refused: on an accelerator host the sibling
+            # lane holds the chip (or its lockfile) — the same wall
+            platform = f"unavailable ({str(e).splitlines()[0][:120]}…)"
+        if platform != "cpu":
+            from . import lanes as lanes_mod
+
+            config.log.err() and config.log.e(
+                f"--lanes {config.lanes} on platform {platform}: lanes are "
+                "processes and cannot share an accelerator; run --lanes 1 "
+                "(one process per chip)"
+            )
+            sys.exit(lanes_mod.LANE_FATAL_EXIT)
     if config.failpoints:
         # flag arming lands on top of any JYLIS_FAILPOINTS env arming
         # (faults.py parses the env at import); same spec syntax
@@ -178,7 +209,16 @@ async def run(argv: list[str] | None = None) -> None:
     else:
         identity = config.addr.hash64()
     system = System(config)
+    t_boot = time.perf_counter()
+    jax.devices()  # backend init (seconds on a TPU), timed apart from ...
+    t_warm = time.perf_counter()
     database_mod.warmup()  # compile serving kernels before going live
+    # ... compile (or compile-cache load) seconds: the part of boot the
+    # persistent cache (jylis_tpu/__init__.py) exists to remove
+    config.log.info() and config.log.i(
+        f"warmup: backend up in {t_warm - t_boot:.2f}s, "
+        f"serving kernels ready in {time.perf_counter() - t_warm:.2f}s"
+    )
     # (warmup's throwaway Database records its compile-time drains into
     # its OWN registry, so the serving registry starts clean by
     # construction — the old process-global clear() is gone with the
@@ -350,6 +390,15 @@ async def run(argv: list[str] | None = None) -> None:
     from . import __version__
 
     log.info() and log.i(f"jylis-tpu version: {__version__}")
+    log.info() and log.i(f"device: {device_summary()}")
+    if database.native_engine is not None:
+        log.info() and log.i("serving engine: native")
+    else:
+        # lib() returned None: no toolchain, or the g++ build failed (its
+        # stderr is above). Correct but several times slower — say so.
+        log.warn() and log.w(
+            "serving engine: python tables (native library unavailable)"
+        )
     log.info() and log.i(f"cluster address: {config.addr}")
     if lane_id is not None:
         log.info() and log.i(f"serving lane {lane_id}/{config.lanes}")
@@ -369,6 +418,46 @@ async def run(argv: list[str] | None = None) -> None:
             lane_tick_task.cancel()
         if metrics_http is not None:
             await metrics_http.dispose()
+
+
+def device_summary() -> str:
+    """What the drains run on, as jax reports it: the boot log's device
+    line (chip_smoke.py reads it back — nothing else tells an operator
+    that a node came up on the CPU backend instead of the chip)."""
+    from .parallel import serving_mesh
+
+    devs = jax.devices()
+    mesh = serving_mesh()
+    shape = "none" if mesh is None else "x".join(
+        f"{k}={v}" for k, v in mesh.shape.items()
+    )
+    return (
+        f"platform={devs[0].platform} kind={devs[0].device_kind!r} "
+        f"count={len(devs)} mesh={shape}"
+    )
+
+
+def device_state_summary(database) -> str:
+    """Every device-backed keyspace's widest plane and how many devices
+    EVERY plane of it is split over — on a multi-chip host the proof
+    that the mesh path shards (not: everything on the first device)."""
+    return "; ".join(
+        f"{name} {'x'.join(map(str, shape))} over {n} device(s)"
+        for name, shape, n in database.device_layout()
+    ) or "none"
+
+
+def device_memory_summary() -> str:
+    """Per-device live and peak HBM bytes where the backend reports them
+    (XLA:CPU reports nothing)."""
+    parts = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        parts.append(
+            f"dev{d.id} in_use={stats.get('bytes_in_use', 'unreported')} "
+            f"peak={stats.get('peak_bytes_in_use', 'unreported')}"
+        )
+    return "; ".join(parts)
 
 
 def _dump_trace(database, log) -> None:

@@ -490,6 +490,20 @@ class Database:
             out.append((mgr.name, batch))
         return out
 
+    def device_layout(self) -> list[tuple[str, tuple, int]]:
+        """(type, widest plane's shape, devices every plane is split
+        over) per repo that keeps plane state on the device — the
+        shutdown log's `device state` line (main.py)."""
+        out = []
+        for mgr in self._map.values():
+            state = getattr(mgr.repo, "_state", None)
+            planes = [p for p in state or () if p is not None]
+            if planes:
+                widest = max(planes, key=lambda p: p.size)
+                spread = min(len(p.sharding.device_set) for p in planes)
+                out.append((mgr.name, tuple(widest.shape), spread))
+        return out
+
     def clean_shutdown(self) -> None:
         """Single-threaded shutdown (tests / direct drivers); the serving
         stack uses clean_shutdown_async, which serialises with in-flight
@@ -527,7 +541,8 @@ def warmup() -> None:
     """Pre-compile every serving-path device kernel at the default bucket
     shapes by driving a throwaway Database through one command of each
     kind. Without this, the FIRST client read after a write blocks the
-    event loop for the XLA compile (seconds on a remote TPU) — long enough
+    event loop for the XLA compile (seconds per kernel when the persistent
+    compile cache, jylis_tpu/__init__.py, is cold) — long enough
     for peers to hit the 10-tick idle eviction and drop our connections,
     opening fire-and-forget delta-loss windows. jit caches are per-process,
     so the throwaway instance warms the real repos' kernels."""

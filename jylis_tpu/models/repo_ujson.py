@@ -32,7 +32,10 @@ Delta wire shape: the UJSON object itself (entries + causal context).
 
 from __future__ import annotations
 
+import time
+
 from ..ops.ujson_host import UJSON
+from ..utils.metrics import resolve_registry
 from .base import ParseError, need
 from .help import RepoHelp
 
@@ -412,7 +415,17 @@ class RepoUJSON:
         """Promote keys as needed and fold their pending deltas into the
         resident rows — ONE device dispatch for every key in the drain.
         Returns the groups that must fall back to the host loop (seqs
-        beyond the u64/32 device layouts)."""
+        beyond the u64/32 device layouts). The one UJSON path that
+        dispatches to the device, so it is what `UJSON drains` counts
+        (keys = keys folded; host-loop folds are not drains)."""
+        t0 = time.perf_counter()
+        fallback = self._fold_resident(groups)
+        reg = resolve_registry(self)
+        if reg.enabled:
+            reg.note_drain("UJSON", len(groups), time.perf_counter() - t0)
+        return fallback
+
+    def _fold_resident(self, groups: dict[bytes, list[UJSON]]):
         store = self._store()
         fallback: dict[bytes, list[UJSON]] = {}
 

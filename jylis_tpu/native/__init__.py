@@ -3,18 +3,27 @@
 The reference's hot codecs are compiled Pony (SURVEY.md §2: pony-resp's
 CommandParser, the framing/serialise codec); their rebuild equivalents are
 C++ under native/, built into ``libjylis_native.so`` by `make native` (or
-lazily here on first import when a toolchain is available — the build is
-two translation units and takes well under a second).
+lazily here on first use when a toolchain is available — a few seconds).
+Both go through ``build()`` below, which records the sha256 of the sources
+beside the binary: staleness is decided by CONTENT, because a copied tree
+(an image layer, a chip-run sandbox, a checkout) keeps no meaningful
+mtimes and must never serve from a binary that does not match native/.
 
 ``lib()`` returns the loaded CDLL or None; callers must keep working
 without it (the Python implementations are the semantic oracles).
+
+This file imports only the standard library, so `make native` runs it as
+a script (``python jylis_tpu/native/__init__.py``) without importing the
+package — and with it jax.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC_DIR = os.path.join(_REPO_ROOT, "native")
@@ -29,43 +38,93 @@ _SO_PATH = (
     or os.path.join(_SRC_DIR, "libjylis_native.so")
 )
 
+# the in-checkout build output: the only binary this module ever (re)builds.
+# An explicit JYLIS_NATIVE_SO or a wheel-bundled .so is taken as given.
+_BUILT_SO = os.path.join(_SRC_DIR, "libjylis_native.so")
+_HASH_SUFFIX = ".srchash"
+
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _build() -> bool:
+def _sources() -> list[str]:
+    """native/*.cpp and the headers they include, sorted."""
     try:
-        sources = [
-            os.path.join(_SRC_DIR, f)
-            for f in sorted(os.listdir(_SRC_DIR))
-            if f.endswith(".cpp")
-        ]
+        names = sorted(os.listdir(_SRC_DIR))
     except OSError:  # no source checkout (installed wheel / image)
+        return []
+    return [
+        os.path.join(_SRC_DIR, f) for f in names if f.endswith((".cpp", ".h"))
+    ]
+
+
+def source_hash() -> str:
+    """sha256 over the names and contents of the native sources."""
+    h = hashlib.sha256()
+    for path in _sources():
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    return h.hexdigest()
+
+
+def built_hash() -> str | None:
+    """The source hash recorded beside the in-checkout binary, or None."""
+    try:
+        with open(_BUILT_SO + _HASH_SUFFIX, encoding="ascii") as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def build() -> bool:
+    """Compile native/*.cpp into the in-checkout .so and record the source
+    hash beside it. Both files land by rename, so a concurrent loader
+    (lanes, parallel tests) sees the old pair or the new one."""
+    units = [p for p in _sources() if p.endswith(".cpp")]
+    if not units:
         return False
-    if not sources:
-        return False
+    want = source_hash()
+    tmp = f"{_BUILT_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _SO_PATH]
-            + sources,
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp] + units,
             check=True,
             capture_output=True,
-            timeout=120,
+            timeout=300,
         )
+        os.replace(tmp, _BUILT_SO)
+        with open(tmp, "w", encoding="ascii") as f:
+            f.write(want + "\n")
+        os.replace(tmp, _BUILT_SO + _HASH_SUFFIX)
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        print(
+            f"jylis_tpu.native: build failed ({e!r}) "
+            f"{detail.decode(errors='replace')[-2000:]}",
+            file=sys.stderr,
+        )
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
         return False
+
+
+def loads_checkout_build() -> bool:
+    """Will lib() load (and keep fresh) the in-checkout build, rather
+    than a binary handed to it (JYLIS_NATIVE_SO, a wheel's)?"""
+    return _SO_PATH == _BUILT_SO
 
 
 def _stale() -> bool:
-    if not os.path.isdir(_SRC_DIR):
-        return False  # prebuilt .so without sources is never stale
-    so_mtime = os.path.getmtime(_SO_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_SRC_DIR, f)) > so_mtime
-        for f in os.listdir(_SRC_DIR)
-        if f.endswith(".cpp")
-    )
+    """Does the binary we are about to load mismatch native/'s sources?
+    Only the in-checkout build is ever judged (and rebuilt)."""
+    if not loads_checkout_build() or not _sources():
+        return False  # prebuilt .so (env / wheel / no sources): as given
+    return built_hash() != source_hash()
 
 
 def _declare_codec(cdll: ctypes.CDLL) -> None:
@@ -149,7 +208,7 @@ def lib() -> ctypes.CDLL | None:
     _tried = True
     try:
         if not os.path.exists(_SO_PATH) or _stale():
-            if not _build():
+            if not loads_checkout_build() or not build():
                 return None
         cdll = ctypes.CDLL(_SO_PATH)
         cdll.resp_scan.restype = ctypes.c_int32
@@ -179,3 +238,7 @@ def lib() -> ctypes.CDLL | None:
     except OSError:
         _lib = None
     return _lib
+
+
+if __name__ == "__main__":  # `make native`
+    sys.exit(0 if build() else 1)

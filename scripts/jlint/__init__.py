@@ -164,7 +164,7 @@ RULES = {
     "JL1002": (None, "undeclared (role, state, msg) fall-through or silent ignore in a cluster protocol handler"),
     "JL1003": (None, "protocol manifest drift, missing, or undescribed (--write-manifest regenerates)"),
     "JL1101": (None, "native command grammar/bounds diverge from the Python oracle (arity, u64 args, transport limits, thresholds)"),
-    "JL1102": (None, "native RESP reply shape or error taxonomy diverges from the Python oracle"),
+    "JL1102": (None, "native RESP reply shape or error classes diverge from the Python oracle"),
     "JL1103": (None, "semantics manifest drift/stale/placeholder, uncovered native command, or stale generated fuzz harness"),
 }
 

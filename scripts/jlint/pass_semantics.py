@@ -4,7 +4,7 @@ Pass 3 proved both serving paths dispatch the same command *names*;
 nothing checked that they agree on what those commands *mean*. This
 pass extracts, for every natively-served command, the full argument
 grammar (arity, strict/optional u64 args, validation predicates), the
-RESP reply shapes, the error taxonomy, and the defer predicates — from
+RESP reply shapes, the error classes, and the defer predicates — from
 ``native/serve_engine.cpp`` via the ``cpp_ast`` front-end (tokenizer +
 recursive descent over the disciplined subset native/ uses, no
 libclang) — and the same facts from the Python oracle's
@@ -25,7 +25,7 @@ Reply shapes use one canonical vocabulary on both sides: ``"+OK"``,
 
 JL1101 fires on an unjustified grammar/bounds divergence (arity, u64
 args, optional args, transport limits, thresholds); JL1102 on an
-unjustified reply-shape or error-taxonomy divergence; JL1103 on
+unjustified reply-shape or error-class divergence; JL1103 on
 manifest drift, a stale ``justified`` entry, a placeholder note, a
 natively-served command (per pass 3) the manifest does not cover, or a
 stale generated fuzz harness (``tests/test_semantic_fuzz.py`` — see

@@ -638,3 +638,44 @@ def test_concurrent_manifest_writes_serialise(tmp_path, monkeypatch):
         assert [lane["id"] for lane in manifest["lanes"]] == [0, 1]
 
     asyncio.run(main())
+
+
+def test_supervisor_treats_a_lane_fatal_exit_as_fatal(tmp_path, monkeypatch):
+    """One process per chip: a lane that finds an accelerator under
+    --lanes N>1 exits LANE_FATAL_EXIT (main.py), and the supervisor must
+    stop the node with that code at once — not respawn the lane into the
+    same wall forever while staying up and exiting 0 on SIGTERM."""
+
+    async def main():
+        import sys
+        import time as _time
+
+        cfg = Config()
+        cfg.port = "0"
+        cfg.addr = Address("127.0.0.1", "9999", "supnode")
+        cfg.lanes = 2
+        cfg.data_dir = str(tmp_path)
+        cfg.log = Log.create_none()
+        sup = lanes_mod.Supervisor(cfg, ["--port", "0", "--lanes", "2"])
+        spawned: list[int] = []
+
+        def child_argv(self, lane_id):
+            spawned.append(lane_id)
+            # lane 0 refuses the way main.py does; lane 1 would serve on
+            code = (
+                f"import sys; sys.exit({lanes_mod.LANE_FATAL_EXIT})"
+                if lane_id == 0
+                else "import time; time.sleep(60)"
+            )
+            return [sys.executable, "-c", code]
+
+        monkeypatch.setattr(lanes_mod.Supervisor, "_child_argv", child_argv)
+        t0 = _time.monotonic()
+        await asyncio.wait_for(sup.run(), 30)
+        assert _time.monotonic() - t0 < 15
+        assert sup.fatal_rc == lanes_mod.LANE_FATAL_EXIT
+        assert spawned == [0, 1]  # no respawn of the refusing lane
+        assert all(p.poll() is not None for p in sup.procs)  # sibling stopped
+
+    asyncio.run(main())
+
