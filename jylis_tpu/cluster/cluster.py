@@ -665,6 +665,9 @@ class Cluster:
         self._reg = _metrics.resolve_registry(database)
         self._h_rtt = self._reg.hist("cluster.rtt")
         self._h_lag = self._reg.hist("cluster.converge_lag")
+        # cluster.decode (obs/span.py): one frame's bytes to a message
+        # object — CRC check and codec
+        self._s_decode = self._reg.seam("cluster.decode")
         # peer identity (str address) -> push→apply lag EWMA in ms; a
         # digest match folds in as a zero-lag sample (the peer is
         # provably converged at that wall instant)
@@ -1245,6 +1248,7 @@ class Cluster:
                     raw = await faults.async_point("cluster.decode", raw)
                     if raw is None:
                         continue
+                    t_dec = self._s_decode.begin()
                     checked = check_frame(raw)
                     if checked is None:
                         self._log.err() and self._log.e(
@@ -1254,6 +1258,7 @@ class Cluster:
                         return
                     origin_ms, body = checked
                     if not conn.established:
+                        self._s_decode.end(t_dec)  # the signature frame
                         if not self._handshake(conn, body, active):
                             return
                         frames.set_max_frame(1 << 30)  # authenticated peer
@@ -1266,6 +1271,7 @@ class Cluster:
                         self._log.err() and self._log.e(f"cluster codec error: {e}")
                         self._drop(conn, Drop.CODEC)
                         return
+                    self._s_decode.end(t_dec)
                     if active:
                         await self._active_msg(
                             conn, msg, origin_ms, nbytes=len(body)

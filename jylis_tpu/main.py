@@ -19,10 +19,11 @@ import jax
 from . import faults
 from . import persist
 from . import journal as journal_mod
-from .utils import metrics
 from .cluster import Cluster
 from .models import database as database_mod
 from .models.database import Database
+from .obs import loop as loop_mod
+from .obs import span
 from .server.server import Server
 from .system import System
 from .utils.config import config_from_cli
@@ -128,13 +129,21 @@ class Dispose:
                 self._log.info() and self._log.i(
                     f"merge metrics: {self._database.metrics.report()}"
                 )
+                busy = self._database.metrics.hist("loop.busy")
+                self._log.info() and self._log.i(
+                    f"event loop: {busy.count} iterations, busy "
+                    f"{busy.total:.1f}s, cpu "
+                    f"{self._database.metrics.loop_cpu_s():.1f}s"
+                )
                 self._log.info() and self._log.i(
                     f"device state: {device_state_summary(self._database)}"
                 )
                 self._log.info() and self._log.i(
                     f"device memory: {device_memory_summary()}"
                 )
-            metrics.stop_profiling()
+            # a trace window left open (SYSTEM PROFILE START) is written
+            # now; stop_trace blocks on the file, so off the loop
+            await asyncio.to_thread(span.stop_window)
         finally:
             if self._journal is not None:
                 # close() joins the writer thread and fsyncs — blocking
@@ -226,6 +235,9 @@ async def run(argv: list[str] | None = None) -> None:
     # jlint: blocking-ok — pre-serving boot; warmup above already built
     # and memoised the native lib, so this resolves from cache
     database = Database(identity=identity, system_repo=system.repo)
+    # the loop's own time (loop.busy, jylis_loop_cpu_seconds_total)
+    # records into this node's registry from here on (obs/loop.py)
+    loop_mod.attach(database.metrics)
     # session-guarantee + admission-control knobs (docs/sessions.md)
     database.session_wait_ms = config.session_wait_ms
     database.set_admission_cap(config.admission_cap)
@@ -554,7 +566,9 @@ async def _snapshot_loop(
 
 def main(argv: list[str] | None = None) -> None:
     try:
-        asyncio.run(run(argv))
+        # the node's loop is a selector loop with a timing selector
+        # (obs/loop.py); a lane worker enters here too
+        asyncio.run(run(argv), loop_factory=loop_mod.new_event_loop)
     except KeyboardInterrupt:
         pass
 

@@ -35,6 +35,8 @@ SYNC_FANOUT = 256
 def sync_bucket(key: bytes) -> int:
     """The digest-tree leaf a key belongs to (stable across replicas)."""
     return hashlib.sha256(key).digest()[0]
+from . import repo_system
+from .base import ParseError
 from .manager import RepoManager
 from .repo_bcount import RepoBCOUNT
 from .repo_counters import RepoGCOUNT, RepoPNCOUNT
@@ -113,9 +115,9 @@ class Database:
             # so drain counters/histograms land per-Database
             repo.metrics = self.metrics
             mgr = RepoManager(
-                repo.name, repo, repo.help, served=self._served_py
+                repo.name, repo, repo.help, served=self._served_py,
+                registry=self.metrics,  # BUSY refusals, the lock/flush spans
             )
-            mgr.registry = self.metrics  # admission BUSY refusal counts
             self._map[repo.name.encode()] = mgr
 
         # incremental sync digest (round-5 verdict item 2): per data type,
@@ -439,6 +441,19 @@ class Database:
             # from any Redis client.
             digest = await self.sync_digest_async()
             resp.string(digest.hex().encode())
+            return
+        if len(cmd) > 1 and cmd[0] == b"SYSTEM" and cmd[1] == b"PROFILE":
+            # the device-trace window (obs/span.py): starting and, more
+            # so, stopping the profiler blocks for as long as the trace
+            # takes to collect and write — a worker thread's business
+            try:
+                seconds = repo_system.parse_profile(cmd[1:])
+            except ParseError:
+                respond_help(resp, self.system.help.render(cmd[1:]))
+                return
+            repo_system.reply_profile(
+                resp, await asyncio.to_thread(repo_system.run_profile, seconds)
+            )
             return
         mgr = self._map.get(cmd[0]) if cmd else None
         if mgr is None:

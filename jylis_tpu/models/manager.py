@@ -35,6 +35,8 @@ from __future__ import annotations
 import asyncio
 import time
 
+from ..obs import span
+from ..utils.metrics import DEFAULT as _DEFAULT_REGISTRY
 from .base import ParseError
 from .help import respond_help
 
@@ -65,7 +67,13 @@ SHUTDOWN_ERR = "SHUTDOWN (server is shutting down, rejecting all requests)"
 
 class RepoManager:
     def __init__(
-        self, name: str, repo, help_obj, clock=time.monotonic, served=None
+        self,
+        name: str,
+        repo,
+        help_obj,
+        clock=time.monotonic,
+        served=None,
+        registry=None,
     ):
         self.name = name
         self.repo = repo
@@ -85,7 +93,15 @@ class RepoManager:
         # command class, never the node. 0 = off (default). The
         # registry counts refusals (SERVING busy_refusals).
         self.admission_cap = 0
-        self.registry = None
+        # the owning Database's registry; a standalone manager records
+        # into the process DEFAULT (utils/metrics.resolve_registry's
+        # policy). The host-time spans of this repo's lock and flush
+        # (obs/span.py; exact starts and ends in docs/observability.md):
+        self.registry = reg = registry or _DEFAULT_REGISTRY
+        self._s_wait_serve = reg.seam("lock.wait_serve")
+        self._s_wait_cluster = reg.seam("lock.wait_cluster")
+        self._s_apply = reg.seam("cluster.apply")
+        self._s_flush = reg.seam("repo.flush")
         self._inflight = 0
         # delta write-ahead journal (journal/journal.py), attached via
         # Database.set_journal: every flushed batch is handed to the
@@ -139,9 +155,8 @@ class RepoManager:
             # only lock-queued commands count as inflight (the inline
             # fast path above never queues), so the cap binds exactly
             # when this class is backed up behind its own drains
-            if self.registry is not None:
-                self.registry.note_serving("busy_refusals")
-                self.registry.trace_event("serving", "busy", "", self.name)
+            self.registry.note_serving("busy_refusals")
+            self.registry.trace_event("serving", "busy", "", self.name)
             resp.err(
                 f"BUSY ({self.name} admission cap {self.admission_cap} "
                 "reached; this command class is backed up — retry)"
@@ -149,7 +164,11 @@ class RepoManager:
             return
         self._inflight += 1
         try:
+            # lock.wait_serve: wanting the repo lock to holding it —
+            # queueing behind a drain or a cluster apply, not service
+            t_wait = self._s_wait_serve.begin()
             async with self._lock:
+                self._s_wait_serve.end(t_wait)
                 if self._shutdown:
                     # shutdown won the lock race while we queued behind a
                     # drain: the final flush already ran — accepting now
@@ -179,12 +198,19 @@ class RepoManager:
     CONVERGE_SLICE = 256
 
     async def converge_async(self, batch) -> None:
+        t_wait = self._s_wait_cluster.begin()
         async with self._lock:
+            self._s_wait_cluster.end(t_wait)
             if self._shutdown:
                 return  # fire-and-forget: late deltas re-deliver elsewhere
             batch = list(batch)
+            meta = {"keys": len(batch)} if span.armed() else None
             for i in range(0, len(batch), self.CONVERGE_SLICE):
+                # cluster.apply: the fold of one slice into the pending
+                # dicts — no lock wait, no yield, not the drain below
+                t_fold = self._s_apply.begin(None, meta)
                 self.converge_deltas(batch[i : i + self.CONVERGE_SLICE])
+                self._s_apply.end(t_fold)
                 if i + self.CONVERGE_SLICE < len(batch):
                     await asyncio.sleep(0)  # let pings/pongs interleave
             # threshold drains run AFTER buffering, in a worker thread —
@@ -195,7 +221,9 @@ class RepoManager:
                 await asyncio.to_thread(self.repo.drain)
 
     async def flush_async(self, fn) -> None:
+        t_wait = self._s_wait_cluster.begin()
         async with self._lock:
+            self._s_wait_cluster.end(t_wait)
             # repos with banked native-queue work drain it in a worker
             # thread first (it can touch the device); the loop-side delta
             # flush then sees fully-applied state
@@ -232,14 +260,20 @@ class RepoManager:
             self._last_proactive = now
 
     def _flush(self) -> None:
-        # unconditional, like the reference's proactive path (:81)
+        """One delta flush, unconditional like the reference's proactive
+        path (:81). repo.flush: the export of the dirty rows and `_emit`
+        — journal hand-off, write heat, the sink's broadcast encode —
+        as ONE span of the caller's (the loop's) time."""
+        meta = {"keys": self.repo.deltas_size()} if span.armed() else None
+        t_flush = self._s_flush.begin(None, meta)
         self._emit(self.repo.flush_deltas())
+        self._s_flush.end(t_flush)
 
     def flush_deltas(self, fn) -> None:
         """Heartbeat entry point: registers the sink, drains if non-empty."""
         self._deltas_fn = fn
         if self.repo.deltas_size() > 0:
-            self._emit(self.repo.flush_deltas())
+            self._flush()
 
     def _emit(self, batch) -> None:
         """Every flushed batch leaves through here: journal first (a
@@ -249,7 +283,7 @@ class RepoManager:
         the journal's writer thread, off the serving path."""
         if self.journal is not None:
             self.journal.append(self.name, batch)
-        if self.registry is not None and self.registry.enabled and batch:
+        if self.registry.enabled and batch:
             # per-digest-tree-bucket write heat: count each flushed key
             # against its sync_bucket (the SAME sha256(key)[0] the
             # anti-entropy digest tree shards by, database.py), so

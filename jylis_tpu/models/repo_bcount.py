@@ -32,7 +32,7 @@ Delta wire shape: the five-component full view
 from __future__ import annotations
 
 from ..ops.bcount import BCount
-from ..utils.metrics import timed_drain
+from ..utils.metrics import FINISH, drain_phase, timed_drain
 from .base import ParseError, need, parse_u64
 from .help import RepoHelp
 
@@ -171,6 +171,7 @@ class RepoBCOUNT:
 
     @timed_drain("BCOUNT", lambda self: len(self._pending))
     def drain(self) -> None:
+        drain_phase(self, FINISH)  # a host fold, as RepoMAP.drain
         pending, self._pending = self._pending, []
         for key, delta in pending:
             self._for(key).converge(BCount.from_wire(delta))

@@ -42,7 +42,7 @@ from ..parallel import (
 )
 from .base import ParseError, bucket, need, pad_rows, parse_u64, U64_MAX
 from .counter_table import NativeTable, PyTable
-from ..utils.metrics import timed_drain
+from ..utils.metrics import DEVICE, FINISH, drain_phase, timed_drain
 from .help import RepoHelp
 
 GCOUNT_HELP = RepoHelp("GCOUNT", {"GET": "key", "INC": "key value"})
@@ -311,11 +311,13 @@ class RepoGCOUNT(_CounterRepo):
                 self._n_shards,
                 self._key_cap // self._n_shards,
             )
+            drain_phase(self, DEVICE)
             hi, lo, sums = drain_sharded_g(
                 self._mesh, self._state.hi, self._state.lo, lr, d_hi, d_lo
             )
             self._state = gcount.GCountState(hi, lo)
             sums = np.asarray(sums)
+            drain_phase(self, FINISH)
             live = [(int(g), sums[j]) for j, g in enumerate(slots) if g >= 0]
             self._finish_drain([r for r, _ in live], [v for _, v in live])
         elif len(rows) * DENSE_FRACTION >= self._key_cap:
@@ -324,8 +326,10 @@ class RepoGCOUNT(_CounterRepo):
                 for col, v in pending.get(row, {}).items():
                     dense[row, col] = v
             d_hi, d_lo = planes.split64_np(dense)
+            drain_phase(self, DEVICE)
             self._state, sums = _drain_g_dense(self._state, d_hi, d_lo)
             sums = np.asarray(sums)
+            drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[row] for row in rows])
         else:
             b = bucket(len(rows))
@@ -336,8 +340,10 @@ class RepoGCOUNT(_CounterRepo):
                 for col, v in pending.get(row, {}).items():
                     deltas[i, col] = v
             d_hi, d_lo = planes.split64_np(deltas)
+            drain_phase(self, DEVICE)
             self._state, sums = _drain_g(self._state, ki, d_hi, d_lo)
             sums = np.asarray(sums)
+            drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[i] for i in range(len(rows))])
 
     def flush_deltas(self):
@@ -445,11 +451,13 @@ class RepoPNCOUNT(_CounterRepo):
                 self._n_shards,
                 self._key_cap // self._n_shards,
             )
+            drain_phase(self, DEVICE)
             p_hi, p_lo, n_hi, n_lo, sums = drain_sharded_pn(
                 self._mesh, *self._state, lr, d_hi, d_lo
             )
             self._state = pncount.PNCountState(p_hi, p_lo, n_hi, n_lo)
             sums = np.asarray(sums).view(np.uint64)
+            drain_phase(self, FINISH)
             live = [(int(g), sums[j]) for j, g in enumerate(slots) if g >= 0]
             self._finish_drain([r for r, _ in live], [v for _, v in live])
         elif len(rows) * DENSE_FRACTION >= self._key_cap:
@@ -462,10 +470,12 @@ class RepoPNCOUNT(_CounterRepo):
                     dn[row, col] = v
             dp_hi, dp_lo = planes.split64_np(dp)
             dn_hi, dn_lo = planes.split64_np(dn)
+            drain_phase(self, DEVICE)
             self._state, sums = _drain_pn_dense(
                 self._state, dp_hi, dp_lo, dn_hi, dn_lo
             )
             sums = np.asarray(sums).view(np.uint64)
+            drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[row] for row in rows])
         else:
             b = bucket(len(rows))
@@ -480,10 +490,12 @@ class RepoPNCOUNT(_CounterRepo):
                     dn[i, col] = v
             dp_hi, dp_lo = planes.split64_np(dp)
             dn_hi, dn_lo = planes.split64_np(dn)
+            drain_phase(self, DEVICE)
             self._state, sums = _drain_pn(
                 self._state, ki, dp_hi, dp_lo, dn_hi, dn_lo
             )
             sums = np.asarray(sums).view(np.uint64)
+            drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[i] for i in range(len(rows))])
 
     def flush_deltas(self):

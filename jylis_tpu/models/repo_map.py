@@ -28,7 +28,7 @@ divergent FIELDS.
 from __future__ import annotations
 
 from ..ops.compose import REGISTRY, pack_field, unpack_field
-from ..utils.metrics import timed_drain
+from ..utils.metrics import FINISH, drain_phase, timed_drain
 from .base import ParseError, need
 from .help import RepoHelp
 from .map_table import PyMapTable
@@ -145,6 +145,9 @@ class RepoMAP:
 
     @timed_drain("MAP", lambda self: len(self._tbl.pending))
     def drain(self) -> None:
+        # a host fold: nothing to assemble for a device, the whole
+        # drain is results going into the host table
+        drain_phase(self, FINISH)
         self._tbl.fold_pending()
 
     def deltas_size(self) -> int:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import time
 
+from ..obs import span
 from ..ops import hostref
 from .base import ParseError, need, parse_opt_count
 from .help import LeafHelp
@@ -28,6 +29,8 @@ SYSTEM_HELP = LeafHelp(
     "  SYSTEM OBSERVE\n"
     "  SYSTEM TRACE [count]\n"
     "  SYSTEM TRACE SPANS\n"
+    "  SYSTEM PROFILE START [seconds]\n"
+    "  SYSTEM PROFILE STOP\n"
     "  SYSTEM DIGEST [TYPES]\n"
     "  SYSTEM TOPOLOGY\n"
     "  SYSTEM VERSION"
@@ -36,6 +39,55 @@ SYSTEM_HELP = LeafHelp(
 
 def _now_millis() -> int:
     return time.time_ns() // 1_000_000
+
+
+def parse_profile(args: list[bytes]) -> float | None:
+    """``PROFILE START [seconds]`` -> the window's length (default and
+    cap `span.WINDOW_MAX_S`), ``PROFILE STOP`` -> None; anything else is
+    a ParseError (help)."""
+    verb = need(args, 1)
+    if verb == b"STOP" and len(args) == 2:
+        return None
+    if verb != b"START" or len(args) > 3:
+        raise ParseError()
+    if len(args) == 2:
+        return span.WINDOW_MAX_S
+    try:
+        seconds = float(args[2])
+    except ValueError:
+        raise ParseError() from None
+    if not 0 < seconds:  # NaN too
+        raise ParseError()
+    return min(seconds, span.WINDOW_MAX_S)
+
+
+def run_profile(seconds: float | None) -> tuple[bool, str]:
+    """Open (``seconds``) or close (None) the device-trace window
+    (obs/span.py): (ok, reply text). Blocks on the profiler — off the
+    event loop on the serving path (Database.apply_async)."""
+    if seconds is None:
+        directory = span.stop_window()
+        if directory is None:
+            return False, "NOPROFILE (no trace window is open)"
+        return True, directory
+    directory = span.profile_dir()
+    if not directory:
+        return False, (
+            f"NOPROFILE ({span.PROFILE_DIR_ENV} is not set: the node was "
+            "not started with a directory to write traces into)"
+        )
+    try:
+        return True, span.start_window(directory, seconds)
+    except RuntimeError as e:
+        return False, f"NOPROFILE ({e})"
+
+
+def reply_profile(resp, result: tuple[bool, str]) -> None:
+    ok, text = result
+    if ok:
+        resp.string(text.encode())
+    else:
+        resp.err(text)
 
 
 class RepoSYSTEM:
@@ -108,7 +160,7 @@ class RepoSYSTEM:
             # value" line per counter, flat and greppable from any Redis
             # client. "cmds" counts commands served on BOTH paths
             # (native engine + Python); drains/keys/device_ms cover the
-            # device merge path
+            # merge path (device_ms is host time inside drain())
             from ..utils.metrics import metric_lines
 
             lines = metric_lines(
@@ -224,6 +276,13 @@ class RepoSYSTEM:
 
             for entry in entries:
                 resp.string(TraceRing.format(entry))
+            return False
+        if op == b"PROFILE":
+            # the device-trace window; this is the single-threaded
+            # path (direct drives) — the serving path's SYSTEM PROFILE
+            # is intercepted by Database.apply_async, which runs the
+            # blocking profiler calls off the loop
+            reply_profile(resp, run_profile(parse_profile(args)))
             return False
         if op == b"DIGEST":
             # single-threaded path only (warmup/tests/direct drives):

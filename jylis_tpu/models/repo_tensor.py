@@ -46,7 +46,7 @@ from ..ops.tensor_host import (
 from .base import ParseError, bucket, need, pad_rows, parse_u64
 from .help import RepoHelp
 from .tensor_table import PyTensorTable
-from ..utils.metrics import timed_drain
+from ..utils.metrics import DEVICE, FINISH, drain_phase, timed_drain
 
 TENSOR_HELP = RepoHelp(
     "TENSOR",
@@ -322,7 +322,10 @@ class RepoTENSOR:
                     d_rid[i, :dim] = np.frombuffer(w.rid, "<u4")
         ts_hi = (d_ts >> np.uint64(32)).astype(np.uint32)
         ts_lo = d_ts.astype(np.uint32)
+        # nothing is read back: the device phase is the dispatch alone
+        drain_phase(self, DEVICE)
         self._state = _drain(self._state, ki, d_val, ts_hi, ts_lo, d_rid)
+        drain_phase(self, FINISH)
         self._tbl.fold_pend()
 
     def _grow_to_fit(self, max_dim: int) -> None:

@@ -45,7 +45,13 @@ from .tlog_table import (
     PyTlogTable,
     ROW_DRAIN_THRESHOLD,
 )
-from ..utils.metrics import timed_drain
+from ..utils.metrics import (
+    ASSEMBLE,
+    DEVICE,
+    FINISH,
+    drain_phase,
+    timed_drain,
+)
 from .help import RepoHelp
 
 # interner compaction: once the table holds this many more ids than live
@@ -508,6 +514,7 @@ class RepoTLOG:
                 counts = np.full(tb, tlog.TRIM_NOOP, np.int64)
                 if trim is not None:
                     trim_ki[0], counts[0] = trim
+                drain_phase(self, DEVICE)
                 new_state, ovf, lens, cuts = _drain_dense(
                     self._state, d_ts, d_vid, d_cut, trim_ki, counts
                 )
@@ -515,6 +522,7 @@ class RepoTLOG:
                 # entries reach into the tail columns the delta writes
                 # through, including rows with no pending delta
                 if bool(np.asarray(ovf).any()):
+                    drain_phase(self, ASSEMBLE)  # the retry builds anew
                     self._len_cap *= 2
                     self._state = tlog.grow(
                         self._state, self._key_cap, self._len_cap
@@ -523,6 +531,7 @@ class RepoTLOG:
                 self._state = new_state
                 lens = np.asarray(lens)
                 cuts = np.asarray(cuts)
+                drain_phase(self, FINISH)
                 self._finish_drain((r, lens[r], cuts[r]) for r in rows)
                 return
             b = bucket(len(rows))
@@ -539,17 +548,20 @@ class RepoTLOG:
                 d_cut[i] = cuts_in.get(row, 0)
                 if trim is not None and row == trim[0]:
                     counts[i] = trim[1]
+            drain_phase(self, DEVICE)
             new_state, ovf, lens, cuts = _drain(
                 self._state, ki, d_ts, d_vid, d_cut, counts
             )
             if bool(np.asarray(ovf)[: len(rows)].any()):
                 # retry from the retained pre-merge state with doubled slots
+                drain_phase(self, ASSEMBLE)
                 self._len_cap *= 2
                 self._state = tlog.grow(self._state, self._key_cap, self._len_cap)
                 continue
             self._state = new_state
             lens = np.asarray(lens)
             cuts = np.asarray(cuts)
+            drain_phase(self, FINISH)
             self._finish_drain(zip(rows, lens, cuts))
             return
 
@@ -580,12 +592,14 @@ class RepoTLOG:
                 self._n_shards,
                 self._key_cap // self._n_shards,
             )
+            drain_phase(self, DEVICE)
             out = drain_sharded_tlog(
                 self._mesh, *self._state, lr, jnp.asarray(pay), ld
             )
             ovf = np.asarray(out[5])
             if bool(ovf[slots >= 0].any()):
                 # retry from the retained pre-merge state with doubled slots
+                drain_phase(self, ASSEMBLE)
                 self._len_cap *= 2
                 self._state = self._place(
                     tlog.grow(self._state, self._key_cap, self._len_cap)
@@ -593,6 +607,7 @@ class RepoTLOG:
                 continue
             self._state = tlog.TLogState(*out[:5])
             lens, cuts = np.asarray(out[6]), np.asarray(out[7])
+            drain_phase(self, FINISH)
             self._finish_drain(
                 (int(g), lens[j], cuts[j])
                 for j, g in enumerate(slots)

@@ -37,7 +37,7 @@ from ..parallel import (
 )
 from .base import ParseError, bucket, need, pad_rows, parse_u64
 from .treg_table import NativeTregTable, PyTregTable
-from ..utils.metrics import timed_drain
+from ..utils.metrics import DEVICE, FINISH, drain_phase, timed_drain
 from .help import RepoHelp
 
 TREG_HELP = RepoHelp("TREG", {"GET": "key", "SET": "key value timestamp"})
@@ -227,6 +227,7 @@ class RepoTREG:
             values[slot] = value
         ts_hi, ts_lo = planes.split64_np(d_ts)
         rank_hi, rank_lo = planes.split64_np(d_rank)
+        drain_phase(self, DEVICE)
         if dense:
             self._state, tie, out_ts_hi, out_ts_lo, out_vid = _drain_dense(
                 self._state, ts_hi, ts_lo, rank_hi, rank_lo, d_vid
@@ -240,6 +241,7 @@ class RepoTREG:
         tie = np.asarray(tie)
         out_ts = planes.combine64_np(np.asarray(out_ts_hi), np.asarray(out_ts_lo))
         out_vid = np.asarray(out_vid).copy()
+        drain_phase(self, FINISH)
         if tie[slots].any():
             # prefix collision: full-string compare decides; patch losers
             patch_ki, patch_vid = [], []
@@ -305,11 +307,13 @@ class RepoTREG:
         lr, d_hi, d_lo, slots = route_drain(
             np.asarray(rows, np.int64), payload, self._n_shards, rps
         )
+        drain_phase(self, DEVICE)
         out = drain_sharded_treg(self._mesh, *self._state, lr, d_hi, d_lo)
         self._state = treg.TRegState(*out[:5])
         tie = np.asarray(out[5])
         out_ts = planes.combine64_np(np.asarray(out[6]), np.asarray(out[7]))
         out_vid = np.asarray(out[8]).copy()
+        drain_phase(self, FINISH)
         patch_rows: list[int] = []
         patch_vids: list[int] = []
         for j, g in enumerate(slots):

@@ -16,9 +16,13 @@ records. Three pillars:
   list increment, no allocation — cheap enough to stay armed on the
   serving hot path permanently (bench.py records `obs_cost_frac` to
   prove it). Wired into every timed seam the repo already has: native
-  burst + Python dispatch (server), per-type device drains
+  burst + Python dispatch (server), per-type drains
   (utils/metrics.timed_drain), journal append/fsync, and cluster
-  heartbeat round-trips.
+  heartbeat round-trips — and, through the span instrument
+  (`span.Seam`: the same histogram plus, while profiling is armed, a
+  profiler annotation of the same interval), into the host time
+  budget: the event loop (`loop.py`), the repo-lock waits, a drain's
+  three phases, cluster decode/apply and the delta flush.
 * **Convergence-lag tracking**: every cluster transport frame carries
   its sender's wall-clock origin (schema v6, cluster/cluster.py);
   receivers record push→apply lag per peer into a `converge_lag_ms`
@@ -78,6 +82,19 @@ SEAMS = (
     "pipeline.classify",
     "pipeline.dispatch",
     "pipeline.reply_write",
+    # the host time budget (obs/span.py; exact starts and ends in
+    # docs/observability.md). None of these names may begin with
+    # "drain.": the benchmark reads seam="drain.*" as the per-type
+    # drain() intervals, which the three phases below are PARTS of.
+    "loop.busy",
+    "lock.wait_serve",
+    "lock.wait_cluster",
+    "drain_phase.assemble",
+    "drain_phase.device",
+    "drain_phase.finish",
+    "cluster.decode",
+    "cluster.apply",
+    "repo.flush",
 )
 
 # Node-wide gauges (per-peer convergence lag lives on the Cluster and

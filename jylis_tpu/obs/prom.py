@@ -34,6 +34,26 @@ def _esc(v: str) -> str:
     return v.replace("\\", "\\\\").replace('"', '\\"').replace("\n", "\\n")
 
 
+def device_bytes() -> dict[str, int]:
+    """HBM of the fullest local device, in bytes, as the backend reports
+    it NOW: ``in_use``, ``peak``, ``limit`` — the shutdown log's
+    ``device memory:`` line (main.py) reads the same ``memory_stats()``.
+    Empty where the backend reports nothing (XLA:CPU)."""
+    import jax
+
+    out: dict[str, int] = {}
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        for kind, key in (
+            ("in_use", "bytes_in_use"),
+            ("peak", "peak_bytes_in_use"),
+            ("limit", "bytes_limit"),
+        ):
+            if key in stats:
+                out[kind] = max(out.get(kind, 0), int(stats[key]))
+    return out
+
+
 def render(database) -> str:
     """The full exposition body for one node. ``database`` carries the
     registry plus the served/serving/cluster views RepoSYSTEM uses, so
@@ -177,6 +197,24 @@ def render(database) -> str:
     )
     for ms, _, ok in slo:
         out.append(f'jylis_converge_slo_total{{kind="ok_{ms}"}} {ok}')
+
+    # the loop thread's CPU clock (obs/loop.py): loop.busy's sum minus
+    # this is time the loop was runnable and did not run (the GIL held
+    # by a drain or journal thread, a blocking call)
+    out.append(
+        "# HELP jylis_loop_cpu_seconds_total CPU seconds of the event-loop "
+        "thread (its busy intervals and its select() calls)."
+    )
+    out.append("# TYPE jylis_loop_cpu_seconds_total counter")
+    out.append(f"jylis_loop_cpu_seconds_total {reg.loop_cpu_s():.9f}")
+
+    out.append(
+        "# HELP jylis_device_bytes Device memory of the fullest local "
+        "device at scrape time."
+    )
+    out.append("# TYPE jylis_device_bytes gauge")
+    for kind, v in device_bytes().items():
+        out.append(f'jylis_device_bytes{{kind="{kind}"}} {v}')
 
     out.append("# HELP jylis_gauge Node-wide observability gauges.")
     out.append("# TYPE jylis_gauge gauge")

@@ -32,6 +32,7 @@ from collections import defaultdict, deque
 from . import GAUGES, SEAMS
 from .hist import Histogram
 from .jtrace import SpanStats
+from .span import Seam
 from .trace import TraceRing
 
 JOURNAL_KEYS = ("appends", "bytes", "fsyncs", "replayed_batches", "errors")
@@ -48,7 +49,9 @@ HEAT_FANOUT = 256  # digest-tree leaf fanout (models/database.py SYNC_FANOUT)
 class MetricsRegistry:
     def __init__(self, trace_cap: int = 512):
         self.enabled = True
-        # per-type device drain accumulators (batches / keys / seconds)
+        # per-type drain accumulators (batches / keys / HOST seconds
+        # inside drain(): the reports call them device_ms, a name kept
+        # for byte-stability; the drain_phase.* seams split them)
         self.counters: dict[str, dict[str, float]] = defaultdict(
             lambda: {"batches": 0, "keys": 0, "seconds": 0.0}
         )
@@ -67,6 +70,18 @@ class MetricsRegistry:
             "busy_refusals": 0,
         }
         self.hists: dict[str, Histogram] = {name: Histogram() for name in SEAMS}
+        # the three phases of a drain (utils/metrics.timed_drain), in
+        # DRAIN_PHASES order: recorded WITH their parent drain.<TYPE>
+        # when the drain returns, so a scrape never sees a phase
+        # without its drain and the three always add up to it
+        self._h_phases = (
+            self.hist("drain_phase.assemble"),
+            self.hist("drain_phase.device"),
+            self.hist("drain_phase.finish"),
+        )
+        # reads the event-loop thread's CPU clock, once a timing
+        # selector is attached (obs/loop.py): see loop_cpu_s
+        self.loop_cpu_fn = None
         self.gauges: dict[str, float] = {name: 0.0 for name in GAUGES}
         self.trace = TraceRing(trace_cap)
         # provenance-span folds (obs/jtrace.py): per-hop + per-region-
@@ -81,7 +96,9 @@ class MetricsRegistry:
 
     # ---- counters ----------------------------------------------------------
 
-    def note_drain(self, name: str, n_keys: int, seconds: float) -> None:
+    def note_drain(
+        self, name: str, n_keys: int, seconds: float, phases=None
+    ) -> None:
         c = self.counters[name]
         c["batches"] += 1
         c["keys"] += n_keys
@@ -89,6 +106,9 @@ class MetricsRegistry:
         h = self.hists.get("drain." + name)
         if h is not None:
             h.record(seconds)
+        if phases is not None:
+            for hp, s in zip(self._h_phases, phases):
+                hp.record(s)
 
     def note_journal(self, counter: str, n: int = 1) -> None:
         self.journal_counters[counter] += n
@@ -110,6 +130,17 @@ class MetricsRegistry:
     def hist(self, name: str) -> Histogram:
         return self.hists[name]  # KeyError = undeclared seam, fail loud
 
+    def loop_cpu_s(self) -> float:
+        """CPU seconds of the event-loop thread since its timing
+        selector was attached (jylis_loop_cpu_seconds_total); 0.0 under
+        a loop built elsewhere."""
+        return self.loop_cpu_fn() if self.loop_cpu_fn is not None else 0.0
+
+    def seam(self, name: str) -> Seam:
+        """The span instrument of one declared seam (obs/span.py): the
+        same histogram, plus a profiler annotation while armed."""
+        return Seam(name, self.hists[name], self)
+
     def gauge_set(self, name: str, value: float) -> None:
         if name not in self.gauges:
             raise KeyError(name)  # undeclared gauge, fail loud
@@ -124,7 +155,8 @@ class MetricsRegistry:
     # ---- reporting ---------------------------------------------------------
 
     def type_stats(self):
-        """(name, drains, keys, device_ms) per drained type — the ONE
+        """(name, drains, keys, device_ms) per drained type (device_ms:
+        host milliseconds inside drain(), see `counters`) — the ONE
         iteration the reporting surfaces share. list() snapshots the key
         set atomically under the GIL: note_drain runs in worker threads
         and may insert a type's key mid-request."""

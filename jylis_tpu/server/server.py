@@ -57,6 +57,11 @@ class Server:
         self._h_classify = self._reg.hist("pipeline.classify")
         self._h_dispatch = self._reg.hist("pipeline.dispatch")
         self._h_reply_write = self._reg.hist("pipeline.reply_write")
+        # lock.wait_serve (obs/span.py): wanting the engine's five repo
+        # locks to holding them — what pipeline.dispatch and
+        # server.py_dispatch INCLUDE for every command that queues behind
+        # a drain, summed over connections; server.native_burst does not
+        self._s_lock_wait = self._reg.seam("lock.wait_serve")
 
     async def start(self) -> None:
         try:
@@ -321,8 +326,10 @@ class Server:
             # follows the DATABASE MAP order (TREG, TLOG, G, PN, UJSON),
             # the same order database.all_locks uses, so the shutdown
             # snapshot can never deadlock against a serving burst.
+            t_wait = self._s_lock_wait.begin()
             async with mgrs[2]._lock, mgrs[3]._lock, mgrs[0]._lock, \
                     mgrs[1]._lock, mgrs[4]._lock:
+                self._s_lock_wait.end(t_wait)
                 try:
                     # native.scan_apply: a failure AT the FFI burst
                     # boundary must demote this connection to the Python

@@ -1,4 +1,4 @@
-"""Merge-path metrics + opt-in JAX profiler tracing.
+"""Merge-path metrics: per-type drain counters and the drain's spans.
 
 The reference's only observability is the replicated SYSTEM log
 (SURVEY.md §2.6 — no tracing, no profiler, no metrics endpoint); §5.1
@@ -10,44 +10,86 @@ documented caveat — Databases in one process cross-talking — had been
 this module's known wart): every repo carries a ``metrics`` attribute
 pointing at its Database's registry, and registry-less direct drives
 (standalone repos, a bare Journal) fall back to the process-wide
-``DEFAULT`` instance below. Two pieces stay here:
+``DEFAULT`` instance below. What stays here:
 
-* every device drain runs under `timed_drain`, accumulating per-type
-  batch counts / batched-key counts / device seconds AND a log2 latency
-  histogram per type (``drain.<TYPE>`` in SYSTEM LATENCY) — dumped into
-  the (replicated, queryable) SYSTEM log at clean shutdown;
-* set ``JYLIS_PROFILE_DIR=/some/dir`` to wrap each drain in a
-  ``jax.profiler.trace`` step so the XLA timeline of the merge path can
-  be inspected in TensorBoard/XProf.
+* every drain runs under `timed_drain`, accumulating per-type batch
+  counts / batched-key counts / host seconds inside ``drain()`` AND a
+  log2 latency histogram per type (``drain.<TYPE>`` in SYSTEM LATENCY) —
+  dumped into the (replicated, queryable) SYSTEM log at clean shutdown;
+* the drain body marks its three phases with `drain_phase` (assemble →
+  device → finish), recorded into the ``drain_phase.*`` seams; while
+  profiling is armed (obs/span.py) the drain is a ``drain_<TYPE>`` step
+  annotation on the profiler's timeline with its phases nested inside
+  it as ``drain_<TYPE>.<phase>``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import os
-import time
 
+from ..obs import span
 from ..obs.registry import JOURNAL_KEYS as _JOURNAL_KEYS  # noqa: F401 (re-export)
 from ..obs.registry import MetricsRegistry
 
-_PROFILE_DIR = os.environ.get("JYLIS_PROFILE_DIR", "")
-_profiling = False
+# the phases of a drain, in the order of MetricsRegistry._h_phases
+ASSEMBLE, DEVICE, FINISH = 0, 1, 2
+DRAIN_PHASES = ("assemble", "device", "finish")
 
 
-def _drain_scope(name: str):
-    """One long-lived profiler session (started lazily at the first drain),
-    with a StepTraceAnnotation per drain — per-drain start/stop would dump
-    a whole trace directory per batch and distort the timings."""
-    global _profiling
-    if not _PROFILE_DIR:
-        return contextlib.nullcontext()
-    import jax
+class _DrainClock:
+    """One repo's drain instrument, made at its first timed drain and
+    reused (a repo drains under its lock, one drain at a time). `open`
+    starts the ``drain_<TYPE>`` span and its first phase, `to` moves to
+    another phase, `close` hands the drain and its three phase sums to
+    the registry in ONE call."""
 
-    if not _profiling:
-        jax.profiler.start_trace(_PROFILE_DIR)
-        _profiling = True
-    return jax.profiler.StepTraceAnnotation(f"drain_{name}")
+    __slots__ = (
+        "name", "label", "labels", "seq", "tok", "ptok", "phase", "acc", "meta"
+    )
+
+    def __init__(self, name: str):
+        self.name = name
+        self.label = f"drain_{name}"
+        self.labels = tuple(f"drain_{name}.{p}" for p in DRAIN_PHASES)
+        self.seq = 0
+        self.tok = None  # None: no drain open, `to` is a no-op
+        self.acc = [0.0, 0.0, 0.0]
+
+    def open(self, rows: int) -> None:
+        self.seq += 1
+        self.acc[:] = (0.0, 0.0, 0.0)
+        if span.armed():
+            # what a reader of the trace needs: the batch size, and the
+            # sequence number the three phases share with their drain;
+            # _r=1 makes the parent a profiler STEP (StepTraceAnnotation)
+            self.meta = {"seq": self.seq, "rows": rows}
+            step = {"_r": 1, "step_num": self.seq, "rows": rows}
+        else:
+            self.meta = step = None
+        self.tok = span.begin(self.label, step)
+        self.phase = ASSEMBLE
+        self.ptok = span.begin(self.labels[ASSEMBLE], self.meta)
+
+    def to(self, phase: int) -> None:
+        self.acc[self.phase] += span.elapsed(self.ptok)
+        self.phase = phase
+        self.ptok = span.begin(self.labels[phase], self.meta)
+
+    def close(self) -> float:
+        self.acc[self.phase] += span.elapsed(self.ptok)
+        tok, self.tok = self.tok, None
+        return span.elapsed(tok)
+
+
+def drain_phase(repo, phase: int) -> None:
+    """Called by a drain body where its next phase starts: DEVICE at the
+    jitted call (ends when the ``np.asarray`` that waits for its result
+    returns), FINISH where results go back into the host cache. Until
+    the first mark a drain is in ASSEMBLE. Outside a timed drain (obs
+    disabled, or an empty drain) it does nothing."""
+    dc = repo.__dict__.get("_drain_clock")
+    if dc is not None and dc.tok is not None:
+        dc.to(phase)
 
 
 # The process-wide fallback registry for callers constructed without an
@@ -83,12 +125,14 @@ def note_drain(name: str, n_keys: int, seconds: float) -> None:
 
 def timed_drain(name: str, key_count):
     """Decorator for repo drain() methods: per-batch counters, a log2
-    latency histogram (``drain.<name>``), and an optional profiler
-    trace. ``key_count(self)`` returns the pending batch size. The
-    registry resolves per call from the repo's ``metrics`` attribute
-    (set by Database) so one decorated class serves any number of
-    registry-carrying instances; jlint pass 5 maps the literal ``name``
-    here to the ``drain.<name>`` histogram in the metrics manifest."""
+    latency histogram (``drain.<name>``), the three phase seams, and —
+    armed — the profiler annotations. ``key_count(self)`` returns the
+    pending batch size. The registry resolves per call from the repo's
+    ``metrics`` attribute (set by Database) so one decorated class
+    serves any number of registry-carrying instances; jlint pass 5 maps
+    the literal ``name`` here to the ``drain.<name>`` histogram in the
+    metrics manifest. A drain that raises records nothing (as before);
+    its annotations still close."""
 
     def wrap(fn):
         @functools.wraps(fn)
@@ -104,25 +148,20 @@ def timed_drain(name: str, key_count):
                 v is not None for v in kwargs.values()
             ):
                 return fn(self, *args, **kwargs)
-            with _drain_scope(name):
-                t0 = time.perf_counter()
+            dc = self.__dict__.get("_drain_clock")
+            if dc is None:
+                dc = self._drain_clock = _DrainClock(name)
+            dc.open(n)
+            try:
                 out = fn(self, *args, **kwargs)
-                reg.note_drain(name, max(n, 1), time.perf_counter() - t0)
+            finally:
+                seconds = dc.close()
+            reg.note_drain(name, max(n, 1), seconds, dc.acc)
             return out
 
         return inner
 
     return wrap
-
-
-def stop_profiling() -> None:
-    """Flush the long-lived profiler session (called at clean shutdown)."""
-    global _profiling
-    if _profiling:
-        import jax
-
-        jax.profiler.stop_trace()
-        _profiling = False
 
 
 def metric_lines(

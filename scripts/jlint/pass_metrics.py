@@ -7,7 +7,7 @@ is a KeyError at runtime — but only on the path that typo'd it — and a
 histogram/gauge/trace event added without documentation is invisible to
 operators. Same cure as pass 4, same mechanics:
 
-* every ``.hist(...)`` / ``.gauge_set(...)`` call in the product tree
+* every ``.hist(...)`` / ``.seam(...)`` / ``.gauge_set(...)`` call in the product tree
   must use a STRING LITERAL name, every ``.trace_event(...)`` literal
   subsystem+event args, and every ``timed_drain("<TYPE>", ...)``
   decorator a literal type (its histogram is ``drain.<TYPE>``); each
@@ -53,6 +53,9 @@ PLACEHOLDER = "(describe this metric)"
 # attr-tail -> (kind, how many leading literal args form the name)
 _CALL_KINDS = {
     "hist": ("hist", 1),
+    # registry.seam("<name>"): the same histogram behind the span
+    # instrument (obs/span.py)
+    "seam": ("hist", 1),
     "gauge_set": ("gauge", 1),
     "trace_event": ("trace", 2),
 }

@@ -842,10 +842,12 @@ def test_real_native_surface_is_python_subset():
         # TOPOLOGY is the cluster-aware client's discovery surface;
         # OBSERVE/SPANS/WINDOW are the jtrace round's SLO + span-fold +
         # windowed-quantile views (SPANS and WINDOW are selector words
-        # of SYSTEM TRACE SPANS / SYSTEM LATENCY WINDOW)
+        # of SYSTEM TRACE SPANS / SYSTEM LATENCY WINDOW); PROFILE is
+        # the device-trace window (SYSTEM PROFILE START/STOP)
         "SYSTEM": [
             "DIGEST", "GETLOG", "LATENCY", "METRICS", "OBSERVE",
-            "SPANS", "TOPOLOGY", "TRACE", "TYPES", "VERSION", "WINDOW",
+            "PROFILE", "SPANS", "TOPOLOGY", "TRACE", "TYPES", "VERSION",
+            "WINDOW",
         ],
         "TENSOR": ["GET", "MRG", "SET"],
         "TLOG": ["CLR", "TRIM", "TRIMAT"],
