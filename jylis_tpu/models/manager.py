@@ -1,8 +1,8 @@
 """Per-type repo manager: dispatch, help-on-failure, proactive flush.
 
 Reference analog: RepoManagerCore (repo_manager.pony:36-108). The actor
-boundary becomes the asyncio event loop plus a per-repo asyncio.Lock;
-what this class keeps is the behavioral contract:
+boundary becomes the asyncio event loop plus a per-repo lock (RepoLock);
+what RepoManager keeps is the behavioral contract:
 
 * shutdown flag rejects new commands with the SHUTDOWN error (:49-55),
 * parse failure renders the repo's help text (:62-66),
@@ -15,18 +15,27 @@ Concurrency (SURVEY.md §7(c) host↔device pipelining): commands that will
 hit the device (the repo's ``may_drain`` predicate) run in a worker
 thread via ``asyncio.to_thread`` so a multi-millisecond drain never
 stalls the event loop — other repos' commands, other client connections,
-and the cluster heartbeat all proceed. The per-repo lock is what the
-one-actor-per-type boundary becomes: every repo access (apply, cluster
-converge, heartbeat flush) serialises through it, so repo state is
-never touched concurrently with an offloaded drain. FIFO holds among
-lock-taking paths only — host-only commands take a lock-free inline
-fast path when no drain is active (see apply_async), which preserves
-per-connection order (the reference's guarantee) while
-cross-connection interleaving stays unordered as it always was.
-Replies from
-offloaded commands are buffered and replayed on the loop thread
-(transports are not thread-safe). The sync ``apply`` path remains for
-single-threaded callers (warmup, persistence restore, direct-drive
+and the cluster heartbeat all proceed. The per-repo lock (``RepoLock``,
+below) is what the one-actor-per-type boundary becomes: every repo
+access (apply, cluster converge, heartbeat flush) serialises through it,
+so repo state is never touched concurrently with an offloaded drain.
+
+The lock's rule: a take that cannot yield while it holds — the server's
+native burst (``RepoLock.acquire_all``), and the inline fast path of
+``apply_async``, which needs no take at all — goes ahead whenever nobody
+HOLDS the lock, sleepers or not; a release wakes EVERY sleeper, in
+arrival order. So only a holder that keeps the lock across a yield (a
+threaded drain, a cluster apply, a flush, a digest, a snapshot) can make
+anyone sleep, and everyone who slept behind it is settled in the loop
+iteration after it lets go. Among ``async with`` takers (the ones that
+may hold across a yield) the lock is first come, first served. This
+preserves per-connection order (the reference's guarantee: a connection
+awaits its own commands one by one) while cross-connection interleaving
+stays unordered as it always was (lattice operations commute).
+
+Replies from offloaded commands are buffered and replayed on the loop
+thread (transports are not thread-safe). The sync ``apply`` path remains
+for single-threaded callers (warmup, persistence restore, direct-drive
 tests and benchmarks).
 """
 
@@ -60,6 +69,131 @@ class _ReplayResp:
         for name, args in self.calls:
             getattr(resp, name)(*args)
 
+
+class RepoLock:
+    """The repo lock: ``asyncio.Lock``'s surface (``async with``,
+    ``locked()``) with a hand-off rule made for one event loop whose
+    short holders never yield.
+
+    Two kinds of take:
+
+    * ``async with lock`` / ``acquire()`` — a holder that MAY keep the
+      lock across a yield (threaded drain, cluster apply, flush, digest,
+      dump, snapshot, shutdown). First come, first served: it goes
+      straight in only when nobody holds the lock and nobody is in line;
+      otherwise it sleeps at the end of the line.
+    * ``RepoLock.acquire_all(locks)`` — a holder that releases before it
+      yields (the server's native burst). It takes every lock at once
+      whenever nobody HOLDS one, whoever is in line, and holds nothing
+      while it sleeps: on the first held lock it waits its turn in that
+      lock's line and starts over. (``RepoManager.apply_async``'s inline
+      fast path is the same kind with no take at all: it runs whenever
+      ``locked()`` is false.)
+
+    ``release()`` wakes EVERY sleeper, in arrival order; each retries
+    when it runs, and one that finds the lock held again (a sleeper
+    ahead of it took it and kept it) sleeps again IN ITS PLACE, before
+    later arrivals. ``asyncio.Lock`` instead queued any taker behind a
+    sleeper and woke one sleeper per release, one loop iteration later:
+    once a drain had made the connections queue, every later burst
+    queued behind them and the loop settled one command per iteration
+    for good (PERF.md section 6, PR 25).
+
+    Why nobody starves. Task steps run in the order their wake-ups were
+    scheduled, and wake-ups are scheduled in line order, so a sleeper
+    runs before everyone behind it. When it runs, the lock can be held
+    only by (a) an ``acquire()`` taker that was AHEAD of it in line — a
+    later one sleeps behind it, because the line is not empty — or (b)
+    an ``acquire_all`` taker, which cannot be observed holding: it let
+    go within the task step it took in. So a sleeper's wait is at most
+    the holds of those ahead of it when it arrived, whatever stream of
+    bursts and later long takers follows; a burst sleeps only behind
+    holders that yield, and retries on each of their releases. (The one
+    burst that yields while holding is a drill's: an armed
+    ``native.scan_apply`` sleep. It adds its injected sleep to the
+    bound, nothing else.)
+
+    A sleeper that leaves the line without taking (cancelled) wakes the
+    rest if the lock is free: a taker that lined up behind it after the
+    last release has nobody else to wake it.
+    """
+
+    __slots__ = ("_held", "_line", "_tickets")
+
+    def __init__(self):
+        self._held = False
+        # ticket -> the future its sleeper awaits; a dict keeps arrival
+        # order, and a sleeper that sleeps again re-uses its ticket
+        self._line: dict[int, asyncio.Future] = {}
+        self._tickets = 0
+
+    def locked(self) -> bool:
+        """True exactly while somebody holds the lock."""
+        return self._held
+
+    async def acquire(self) -> None:
+        if not self._held and not self._line:
+            self._held = True
+            return
+        self._tickets = ticket = self._tickets + 1
+        new_future = asyncio.get_running_loop().create_future
+        try:
+            while True:
+                self._line[ticket] = fut = new_future()
+                await fut
+                if not self._held:
+                    self._held = True
+                    return
+        finally:
+            self._line.pop(ticket, None)
+            if not self._held:  # cancelled: left the line without taking
+                self._wake()
+
+    def release(self) -> None:
+        if not self._held:
+            raise RuntimeError("RepoLock is not held")
+        self._held = False
+        if self._line:
+            self._wake()
+
+    def _wake(self) -> None:
+        for fut in self._line.values():
+            if not fut.done():
+                fut.set_result(None)
+
+    async def __aenter__(self) -> None:
+        await self.acquire()
+
+    async def __aexit__(self, exc_type, exc, tb) -> None:
+        self.release()
+
+    @staticmethod
+    async def acquire_all(locks) -> bool:
+        """Take every lock of ``locks`` or none — for a holder that
+        releases (``release_all``) before it yields. No yield when all
+        are free; True when it had to sleep (what the caller checked
+        before the call may no longer hold)."""
+        slept = False
+        while True:
+            for lock in locks:
+                if lock._held:
+                    break
+            else:
+                for lock in locks:
+                    lock._held = True
+                return slept
+            # wait our turn in the held lock's line, holding nothing;
+            # letting go again wakes whoever lined up behind us
+            await lock.acquire()
+            lock.release()
+            slept = True
+
+    @staticmethod
+    def release_all(locks) -> None:
+        for lock in locks:
+            lock.release()
+
+
 PROACTIVE_FLUSH_INTERVAL = 0.5  # seconds; repo_manager.pony:80
 
 SHUTDOWN_ERR = "SHUTDOWN (server is shutting down, rejecting all requests)"
@@ -85,7 +219,7 @@ class RepoManager:
         self._deltas_fn = None
         self._last_proactive = None
         self._shutdown = False
-        self._lock = asyncio.Lock()
+        self._lock = RepoLock()
         # admission control (Database.set_admission_cap): commands of
         # THIS class queued behind the repo lock past the cap are
         # refused with a typed BUSY instead of queuing without bound —
@@ -137,11 +271,11 @@ class RepoManager:
         it, and releases only on the loop thread) and the command needs
         no device offload, apply synchronously with no await at all —
         the event loop is single-threaded, so the inline apply is atomic.
-        This can barge ahead of waiters queued on the lock, so per-repo
-        FIFO holds only among lock-taking paths; cross-connection
-        interleaving is unordered anyway (lattice ops commute) and
-        per-connection order is preserved by the server's sequential
-        awaits."""
+        This goes ahead of sleepers in the lock's line (RepoLock: a
+        holder that cannot yield never waits for a lock nobody holds);
+        cross-connection interleaving is unordered anyway (lattice ops
+        commute) and per-connection order is preserved by the server's
+        sequential awaits."""
         if self._shutdown:
             resp.err(SHUTDOWN_ERR)
             return

@@ -12,6 +12,13 @@ runs in a worker thread under a per-repo lock (models/manager.py), so a
 slow drain stalls neither other connections nor the heartbeat. Within one
 connection commands complete strictly in order (RESP replies must match
 request order), which each connection's sequential await provides.
+
+A native burst takes the engine's five repo locks all at once
+(RepoLock.acquire_all) and lets go before it yields, so it never waits
+for a lock that nobody holds and never makes anyone queue: one loop
+iteration settles every connection whose bytes are ready. Only a holder
+that keeps a lock across a yield (a threaded drain, a cluster apply, a
+flush) makes bursts sleep, and its release wakes them all at once.
 """
 
 from __future__ import annotations
@@ -22,6 +29,7 @@ import time
 from .. import admission as admission_mod
 from .. import faults
 from ..models.database import Database
+from ..models.manager import RepoLock
 from ..native.resp import make_parser
 from ..utils.net import ipv4_port
 from .resp import Respond, RespError
@@ -316,20 +324,29 @@ class Server:
             buf.clear()
             return False
 
+        # DATABASE MAP order (TREG, TLOG, G, PN, UJSON), the order
+        # database.all_locks takes them in
+        locks = [mgrs[i]._lock for i in (2, 3, 0, 1, 4)]
         while True:
             if any(m._shutdown for m in mgrs):
                 return demote()
             # all five type tables can mutate inside one native call: hold
             # every engine-backed repo lock, exactly the boundary
             # apply_async enforces per repo — a threaded drain holding any
-            # one of them keeps the engine out entirely. Acquisition
-            # follows the DATABASE MAP order (TREG, TLOG, G, PN, UJSON),
-            # the same order database.all_locks uses, so the shutdown
-            # snapshot can never deadlock against a serving burst.
+            # one of them keeps the engine out entirely. All five or
+            # none: a burst holds nothing while it sleeps, so it can
+            # never deadlock against the shutdown snapshot (all_locks),
+            # and it takes free locks whoever is in line (RepoLock).
             t_wait = self._s_lock_wait.begin()
-            async with mgrs[2]._lock, mgrs[3]._lock, mgrs[0]._lock, \
-                    mgrs[1]._lock, mgrs[4]._lock:
-                self._s_lock_wait.end(t_wait)
+            slept = await RepoLock.acquire_all(locks)
+            self._s_lock_wait.end(t_wait)
+            try:
+                if slept and any(m._shutdown for m in mgrs):
+                    # a burst that slept behind a drain may wake after a
+                    # repo's final flush: applying now would acknowledge
+                    # a write that never replicates (apply_async looks
+                    # again under its lock for the same reason)
+                    return demote()
                 try:
                     # native.scan_apply: a failure AT the FFI burst
                     # boundary must demote this connection to the Python
@@ -366,6 +383,8 @@ class Server:
                 for mgr, ch in zip(mgrs, changed):
                     if ch:
                         mgr._maybe_proactive_flush()
+            finally:
+                RepoLock.release_all(locks)
             del buf[:consumed]
             # slow-consumer hard bound (--admission-queue-bytes): engine
             # replies land straight in the transport buffer; once the

@@ -1,0 +1,312 @@
+"""The repo lock's hand-off rule (models/manager.py, RepoLock), alone and
+under a served node.
+
+Alone: a holder that cannot yield takes a lock nobody holds whoever is
+in line; a release wakes every sleeper, in arrival order; a sleeper that
+is beaten keeps its place; `async with` takers are first come, first
+served, so none starves; a cancelled sleeper strands nobody.
+
+Served: after a drain lets go, the connections that slept behind it are
+settled together, and the loop goes back to many commands per iteration
+— pinned by COUNTING loop iterations, not by timing them.
+"""
+
+import asyncio
+import time
+
+import pytest
+
+import jylis_tpu  # noqa: F401
+from jylis_tpu.models.manager import RepoLock
+from jylis_tpu.models.repo_treg import PENDING_DRAIN_THRESHOLD
+from jylis_tpu.obs import loop as loop_mod
+
+from test_async_serving import SLOW, make_server, slow_down_drain
+from test_server import send_recv
+
+
+def run(coro):
+    return asyncio.run(asyncio.wait_for(coro, 10))
+
+
+async def steps(n=3):
+    """Let every runnable task take its step (and what that wakes)."""
+    for _ in range(n):
+        await asyncio.sleep(0)
+
+
+async def hold(lock, log, name, release=None):
+    """An `async with` taker; a `release` event makes it a LONG holder."""
+    async with lock:
+        log.append(name)
+        if release is not None:
+            await release.wait()
+
+
+def no_yield(coro) -> bool:
+    """Drive `coro` by hand: True when it finished without yielding."""
+    try:
+        coro.send(None)
+    except StopIteration:
+        return True
+    coro.close()
+    return False
+
+
+def test_locked_is_true_exactly_while_held():
+    async def main():
+        lock = RepoLock()
+        assert not lock.locked()
+        async with lock:
+            assert lock.locked()
+        assert not lock.locked()
+        assert await RepoLock.acquire_all([lock]) is False  # never slept
+        assert lock.locked()
+        RepoLock.release_all([lock])
+        assert not lock.locked()
+        with pytest.raises(RuntimeError):
+            lock.release()
+
+    run(main())
+
+
+def test_a_free_lock_is_taken_without_a_yield():
+    async def main():
+        a, b = RepoLock(), RepoLock()
+        assert no_yield(a.acquire())
+        a.release()
+        assert no_yield(RepoLock.acquire_all([a, b]))
+        assert a.locked() and b.locked()
+        RepoLock.release_all([a, b])
+
+    run(main())
+
+
+def test_a_free_lock_with_sleepers_is_taken_without_a_yield():
+    """The convoy's cure: between a release and the sleepers' steps the
+    lock is free, and a burst takes it there and then. An
+    ``asyncio.Lock`` queued the burst behind the sleepers."""
+
+    async def main():
+        lock, other, log = RepoLock(), RepoLock(), []
+        await lock.acquire()  # the drain
+        sleepers = [asyncio.create_task(hold(lock, log, i)) for i in range(3)]
+        await steps()
+        assert log == [] and len(lock._line) == 3
+        lock.release()  # three sleepers woken, none has run yet
+        assert not lock.locked() and len(lock._line) == 3
+        assert no_yield(RepoLock.acquire_all([other, lock]))
+        RepoLock.release_all([other, lock])
+        await asyncio.gather(*sleepers)
+        assert log == [0, 1, 2]
+
+    run(main())
+
+
+def test_release_wakes_every_sleeper_and_they_run_in_arrival_order():
+    async def main():
+        lock, log = RepoLock(), []
+        gate = asyncio.Event()
+        first = asyncio.create_task(hold(lock, log, "drain", gate))
+        await steps()
+        sleepers = [asyncio.create_task(hold(lock, log, i)) for i in range(8)]
+        await steps()
+        gate.set()
+        await first
+        # ONE pass of the loop settles all eight (each takes and lets go
+        # within its step); asyncio.Lock woke one per release, each a
+        # loop iteration later
+        await asyncio.sleep(0)
+        assert log == ["drain", *range(8)]
+        assert all(s.done() for s in sleepers) and not lock._line
+
+    run(main())
+
+
+def test_a_sleeper_beaten_by_a_long_holder_keeps_its_place():
+    async def main():
+        lock, log = RepoLock(), []
+        gates = [asyncio.Event() for _ in range(2)]
+        first = asyncio.create_task(hold(lock, log, "drain", gates[0]))
+        await steps()
+        long = asyncio.create_task(hold(lock, log, "long", gates[1]))
+        early = asyncio.create_task(hold(lock, log, "early"))
+        await steps()
+        gates[0].set()
+        await first
+        await steps()
+        # `long` got there first and keeps it: `early` sleeps again...
+        assert log == ["drain", "long"] and lock.locked()
+        late = asyncio.create_task(hold(lock, log, "late"))
+        await steps()
+        gates[1].set()
+        await asyncio.gather(long, early, late)
+        # ...in its place, before the later arrival
+        assert log == ["drain", "long", "early", "late"]
+
+    run(main())
+
+
+def test_a_long_taker_among_a_stream_of_bursts_gets_the_lock_in_one_release():
+    """No starvation: bursts take the free lock whoever is in line, but
+    never hold it across a yield, so the sleeper finds it free when it
+    runs; and a LATER long taker cannot slip in between the release and
+    the sleeper's step."""
+
+    async def main():
+        lock, log = RepoLock(), []
+        gates = [asyncio.Event() for _ in range(2)]
+        bursts = stop = 0
+
+        async def burst_stream():
+            nonlocal bursts
+            while not stop:
+                await RepoLock.acquire_all([lock])
+                bursts += 1
+                RepoLock.release_all([lock])
+                await asyncio.sleep(0)
+
+        await lock.acquire()  # the drain
+        streams = [asyncio.create_task(burst_stream()) for _ in range(4)]
+        sleeper = asyncio.create_task(hold(lock, log, "cluster", gates[0]))
+        await steps()
+        assert bursts == 0  # the drain holds: everybody sleeps
+        lock.release()
+        # released, sleepers woken, none has run: a later long taker
+        # arrives in that gap, finds the lock free, and still waits
+        assert not lock.locked() and not no_yield(lock.acquire())
+        later = asyncio.create_task(hold(lock, log, "snapshot", gates[1]))
+        await steps()
+        assert log == ["cluster"]  # within the ONE release
+        held_at = bursts
+        await steps(5)
+        assert bursts == held_at  # and it keeps the bursts out
+        gates[0].set()
+        await sleeper
+        await steps(5)
+        assert log == ["cluster", "snapshot"]
+        gates[1].set()
+        await later
+        await steps(5)
+        assert bursts > held_at  # the stream goes on
+        stop = 1
+        await asyncio.gather(*streams)
+
+    run(main())
+
+
+def test_a_burst_sleeps_holding_nothing_and_says_that_it_slept():
+    async def main():
+        a, b, log = RepoLock(), RepoLock(), []
+        gate = asyncio.Event()
+        drain = asyncio.create_task(hold(b, log, "drain", gate))
+        await steps()
+        burst = asyncio.create_task(RepoLock.acquire_all([a, b]))
+        await steps()
+        assert not burst.done() and not a.locked()  # all or none
+        gate.set()
+        assert await burst is True
+        assert a.locked() and b.locked()
+        RepoLock.release_all([a, b])
+        await drain
+
+    run(main())
+
+
+def test_cancelling_a_sleeper_leaves_the_lock_usable():
+    async def main():
+        lock, log = RepoLock(), []
+        gate = asyncio.Event()
+        first = asyncio.create_task(hold(lock, log, "drain", gate))
+        await steps()
+        doomed = asyncio.create_task(hold(lock, log, "doomed"))
+        await steps()
+        doomed.cancel()  # while the drain still holds
+        await steps()
+        assert not lock._line
+        woken = asyncio.create_task(hold(lock, log, "woken"))
+        await steps()
+        gate.set()
+        await first  # let go: `woken` took its turn
+        assert log == ["drain", "woken"]
+        await lock.acquire()
+        woken = asyncio.create_task(hold(lock, log, "woken again"))
+        await steps()
+        lock.release()
+        # `woken` is woken and has not run; `behind` lines up behind it
+        # with nobody else to wake it; `woken` is cancelled in that gap
+        behind = asyncio.create_task(hold(lock, log, "behind"))
+        woken.cancel()
+        await asyncio.gather(woken, return_exceptions=True)
+        await behind
+        assert log == ["drain", "woken", "behind"] and not lock.locked()
+        assert not lock._line
+        async with lock:
+            pass
+
+    run(main())
+
+
+def test_after_a_drain_the_loop_settles_many_commands_per_iteration():
+    """32 closed-loop connections sleep behind one slow TREG drain (the
+    engine's FIRST lock: a burst that queues there holds nothing that
+    would send the others down the Python path). Once it lets go, the
+    next 300 commands must cost far fewer than 300 loop iterations
+    (C{loop.busy}): with ``asyncio.Lock`` every burst queued behind the
+    sleepers and was woken one per iteration. And no command waited for
+    the lock longer than the drain held it."""
+    conns, after = 32, 300
+    get = b"TREG GET x\r\n"
+
+    async def main():
+        server, db = make_server()
+        await server.start()
+        assert loop_mod.attach(db.metrics)
+        iters = db.metrics.hist("loop.busy")
+        wait = db.metrics.hist("lock.wait_serve")
+        seen = {"cmds": 0, "base": None, "iters": None}
+
+        async def client(reply):
+            reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+            try:
+                while seen["iters"] is None:
+                    writer.write(get)
+                    assert await reader.readexactly(len(reply)) == reply
+                    seen["cmds"] += 1
+                    base = seen["base"]
+                    if base is not None and seen["cmds"] - base[0] >= after:
+                        seen["iters"] = iters.count - base[1]
+            finally:
+                writer.close()
+
+        try:
+            assert await send_recv(server.port, b"TREG SET x v 1\r\n") == b"+OK\r\n"
+            reply = await send_recv(server.port, get)
+            # one row short of the drain threshold: the next SET drains
+            repo = db.manager("TREG").repo
+            slow_down_drain(db, "TREG")
+            for i in range(PENDING_DRAIN_THRESHOLD - 1 - repo._tbl.pend_count()):
+                repo.converge(b"p%d" % i, (b"v", i + 1))
+            clients = [asyncio.create_task(client(reply)) for _ in range(conns)]
+            while seen["cmds"] < 4 * conns:  # the loop serves them all
+                await asyncio.sleep(0.005)
+            t_drain = time.perf_counter()
+            slow = asyncio.create_task(send_recv(server.port, b"TREG SET y v 5\r\n"))
+            while not db.manager("TREG").busy():
+                await asyncio.sleep(0.005)
+            await asyncio.sleep(SLOW / 2)
+            stalled = seen["cmds"]
+            await asyncio.sleep(0.05)
+            assert seen["cmds"] == stalled  # every connection sleeps
+            while db.manager("TREG").busy():  # (the drain is a thread's)
+                await asyncio.sleep(0.005)
+            seen["base"] = (seen["cmds"], iters.count)
+            held = time.perf_counter() - t_drain
+            assert await slow == b"+OK\r\n"
+            await asyncio.wait_for(asyncio.gather(*clients), 20)
+        finally:
+            await server.dispose()
+        assert seen["iters"] < after / 3, seen
+        assert SLOW / 2 < wait.max < held + 0.05, (wait.max, held)
+
+    asyncio.run(asyncio.wait_for(main(), 60), loop_factory=loop_mod.new_event_loop)
