@@ -45,7 +45,7 @@ from ..utils.address import Address
 from ..utils.config import Config
 from ..utils.net import ipv4_port
 from . import codec
-from .framing import FrameReader, FramingError, frame
+from .framing import HEADER_SIZE, FrameReader, FramingError, frame
 from .heart import Heart
 from .msg import (
     MsgAnnounceAddrs,
@@ -583,6 +583,16 @@ class Cluster:
             "bridge_handovers": 0,
             "repair_relays": 0,
             "relay_dropped": 0,
+            # steady-state delta traffic (PR 26): sequenced pushes
+            # (MsgSeqPush / MsgRelayPush) first sent, counted once per
+            # link written, and decoded here — batches, keys and wire
+            # bytes; retransmits are deltas_reshipped on the sender and
+            # count as received like any other decoded push. Integer
+            # adds per batch, none per key
+            "push_batches_sent": 0, "push_keys_sent": 0,
+            "push_bytes_sent": 0,
+            "push_batches_recv": 0, "push_keys_recv": 0,
+            "push_bytes_recv": 0,
         }
         self._drop_counts: dict[str, int] = {}
         # declared message-level drops (MsgDrop reasons): frame
@@ -600,9 +610,9 @@ class Cluster:
         # SURVEY.md §2.5); holding them until a peer is reachable strictly
         # reduces loss without changing fire-and-forget semantics. Bounded:
         # oldest batches drop past the cap. Entries are (held_at_ms,
-        # frame): the age of the OLDEST entry is the anti-entropy
-        # backlog's time dimension (the backlog_ms gauge).
-        self._held: list[tuple[int, bytes]] = []
+        # frame, keys in it): the age of the OLDEST entry is the
+        # anti-entropy backlog's time dimension (the backlog_ms gauge).
+        self._held: list[tuple[int, bytes, int]] = []
         self._held_cap = 1024
         # ---- delta-interval replication (schema v8) --------------------
         # per-sender monotone sequence over CONTENT-CARRYING delta
@@ -865,7 +875,9 @@ class Cluster:
             "sync_full_dumps", "interval_resets_sent",
             "interval_resets_recv", "relays_sent", "relays_recv",
             "region_prunes", "bridge_handovers", "repair_relays",
-            "relay_dropped",
+            "relay_dropped", "push_batches_sent", "push_keys_sent",
+            "push_bytes_sent", "push_batches_recv", "push_keys_recv",
+            "push_bytes_recv",
         ):
             out[key] = self._stats[key]
         # bridge failover (PR 15): whether THIS node is its region's
@@ -1272,6 +1284,10 @@ class Cluster:
                         self._drop(conn, Drop.CODEC)
                         return
                     self._s_decode.end(t_dec)
+                    if isinstance(msg, (MsgSeqPush, MsgRelayPush)):
+                        self._stats["push_batches_recv"] += 1
+                        self._stats["push_keys_recv"] += len(msg.batch)
+                        self._stats["push_bytes_recv"] += HEADER_SIZE + len(raw)
                     if active:
                         await self._active_msg(
                             conn, msg, origin_ms, nbytes=len(body)
@@ -2346,7 +2362,7 @@ class Cluster:
             # relay frames never consume it, so receivers (direct or
             # relay-hops away) see a gapless stream per origin.
             self._sessions.note_local(self._srid, self._own_seq)
-        self._ship_sequenced(seq, data)
+        self._ship_sequenced(seq, data, len(batch))
         return self._srid, self._own_seq
 
     def relay_deltas(self, origin: str, oseq: int, deltas,
@@ -2375,7 +2391,7 @@ class Cluster:
                 MsgRelayPush(seq, origin, oseq, name, tuple(batch), span)
             )
         )
-        self._ship_sequenced(seq, data)
+        self._ship_sequenced(seq, data, len(batch))
 
     def push_unsequenced(self, deltas) -> None:
         """Best-effort unsequenced content push (MsgPushDeltas) to the
@@ -2475,19 +2491,20 @@ class Cluster:
         finally:
             self._relay_inflight = False
 
-    def _ship_sequenced(self, seq: int, data: bytes) -> None:
+    def _ship_sequenced(self, seq: int, data: bytes, keys: int) -> None:
         """Common tail of the two sequenced send paths: log into the
         retransmit window, flush anything held first (strict FIFO),
-        then broadcast-or-hold."""
+        then broadcast-or-hold. ``keys`` is the batch's key count, for
+        the push counters."""
         self._log_delta(seq, data)
         self._flush_held()
-        if self._held or not self._send_to_actives(data, expect_pong=True):
+        if self._held or not self._send_push(data, keys):
             # nobody reachable right now (maybe nobody known yet): hold
             # instead of losing, so a late-joining peer still converges on
             # pre-join writes up to the cap (the delta log ALSO keeps the
             # frame, but replay only serves peers with ack history — the
             # held queue is what reaches a first-ever joiner).
-            self._held.append((self._clock.now_ms(), data))
+            self._held.append((self._clock.now_ms(), data, keys))
             over = len(self._held) - self._held_cap
             if over > 0:
                 # oldest-first eviction at the cap: DOCUMENTED data
@@ -2579,7 +2596,7 @@ class Cluster:
         # (flush-first ordering: nothing newer is ever sent while older
         # frames are held), so skipping them keeps the replay contiguous
         # below the held run and per-peer seq order intact.
-        held = {data for _, data in self._held}
+        held = {data for _, data, _keys in self._held}
         pending = [
             (seq, data)
             for seq, data in self._delta_log
@@ -2609,16 +2626,16 @@ class Cluster:
                 "cluster", "reship", "", f"{n} to {self._conn_desc(conn)}"
             )
 
-    def _send_to_actives(self, data: bytes, expect_pong: bool = False) -> bool:
+    def _send_to_actives(self, data: bytes, expect_pong: bool = False) -> int:
         """Write one pre-framed message to every established active conn;
-        True if it reached at least one. ``expect_pong`` stamps the send
+        the number of conns it reached. ``expect_pong`` stamps the send
         time per conn so the peer's Pong closes a cluster.rtt sample
         (pushes and announces solicit Pongs; exchanges do not)."""
-        sent = False
+        sent = 0
         for conn in list(self._actives.values()):
             if conn.established:
                 if conn.send_raw(data):
-                    sent = True
+                    sent += 1
                     if expect_pong and not conn.last_write_dropped:
                         # stamp unconditionally (one float append — not
                         # the serving hot path the enabled switch
@@ -2647,10 +2664,20 @@ class Cluster:
                 "are being lost beyond the documented held window"
             )
 
+    def _send_push(self, data: bytes, keys: int) -> bool:
+        """First send of one sequenced push frame to every established
+        active conn, counted per link written (the push_*_sent
+        counters); True if it reached at least one."""
+        links = self._send_to_actives(data, expect_pong=True)
+        self._stats["push_batches_sent"] += links
+        self._stats["push_keys_sent"] += keys * links
+        self._stats["push_bytes_sent"] += len(data) * links
+        return links > 0
+
     def _flush_held(self) -> None:
         while self._held:
-            data = self._held[0][1]
-            if not self._send_to_actives(data, expect_pong=True):
+            _ms, data, keys = self._held[0]
+            if not self._send_push(data, keys):
                 return
             self._held.pop(0)
         self._held_drop_episode = False  # drained: next eviction is news

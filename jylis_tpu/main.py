@@ -325,6 +325,15 @@ async def run(argv: list[str] | None = None) -> None:
             journal.open()  # jlint: blocking-ok (pre-serving boot)
             database.set_journal(journal)
 
+    # the drain programs whose shapes depend on the recovered capacity
+    # (database.warmup above compiled them at the default one)
+    t_warm = time.perf_counter()
+    database.warm_drain_shapes()
+    log.info() and log.i(
+        "warmup: threshold drains at the recovered capacity ready in "
+        f"{time.perf_counter() - t_warm:.2f}s"
+    )
+
     server = Server(config, database)
     lane_tick_task = None
     if lane_id is None:

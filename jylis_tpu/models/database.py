@@ -488,6 +488,15 @@ class Database:
         for mgr in self._map.values():
             mgr.repo.drain()
 
+    def warm_drain_shapes(self) -> None:
+        """Boot, after recovery: repos whose serving-time drain shapes
+        depend on the recovered capacity compile them now, not under
+        the repo lock with clients waiting (single-threaded caller)."""
+        for mgr in self._map.values():
+            warm = getattr(mgr.repo, "warm_drain_shapes", None)
+            if warm is not None:
+                warm()
+
     async def dump_state_async(self, names=None):
         """Full state per type for the cluster sync path: [(name, batch)].
         Each repo dumps under its own lock with device touches in a

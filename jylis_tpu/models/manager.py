@@ -323,13 +323,20 @@ class RepoManager:
         finally:
             self._inflight -= 1
 
-    # keys converged per event-loop slice: a multi-thousand-key batch (a
-    # sync dump chunk, a post-load flush) converged in one go blocks the
-    # loop long enough to slip heartbeats and Pongs past peers'
-    # idle-eviction windows — the connection churn then LOSES deltas
-    # (fire-and-forget). Slicing under the same lock keeps liveness
-    # traffic flowing between slices with identical lattice results.
+    # keys converged per slice, and how long the fold may run before it
+    # yields: a multi-thousand-key batch (a sync dump chunk, a post-load
+    # flush) converged in one go blocks the loop long enough to slip
+    # heartbeats and Pongs past peers' idle-eviction windows — the
+    # connection churn then LOSES deltas (fire-and-forget). Yielding
+    # under the same lock keeps liveness traffic flowing with identical
+    # lattice results. The yield is by TIME, checked after each slice: a
+    # lock held across a yield is one every client command of the type
+    # then meets held (the native burst demotes to the Python path and
+    # sleeps in the lock's line), so a fold that is over in a couple of
+    # milliseconds — a peer's 500 ms flush of 1 KB registers — runs
+    # through, and only a fold that would hold the loop longer yields.
     CONVERGE_SLICE = 256
+    CONVERGE_RUN_S = 0.002
 
     async def converge_async(self, batch) -> None:
         t_wait = self._s_wait_cluster.begin()
@@ -339,14 +346,19 @@ class RepoManager:
                 return  # fire-and-forget: late deltas re-deliver elsewhere
             batch = list(batch)
             meta = {"keys": len(batch)} if span.armed() else None
+            t_run = self._clock()
             for i in range(0, len(batch), self.CONVERGE_SLICE):
                 # cluster.apply: the fold of one slice into the pending
                 # dicts — no lock wait, no yield, not the drain below
                 t_fold = self._s_apply.begin(None, meta)
                 self.converge_deltas(batch[i : i + self.CONVERGE_SLICE])
                 self._s_apply.end(t_fold)
-                if i + self.CONVERGE_SLICE < len(batch):
+                if (
+                    i + self.CONVERGE_SLICE < len(batch)
+                    and self._clock() - t_run >= self.CONVERGE_RUN_S
+                ):
                     await asyncio.sleep(0)  # let pings/pongs interleave
+                    t_run = self._clock()
             # threshold drains run AFTER buffering, in a worker thread —
             # never inline on the event loop; the post-state check is
             # exact where any pre-batch prediction can miss per-row sizes

@@ -196,6 +196,32 @@ class RepoTREG:
 
     # -- device drain -------------------------------------------------------
 
+    def warm_drain_shapes(self) -> None:
+        """Compile the sparse drain at the two batch shapes a serving
+        node meets at its present capacity: the threshold batch (a local
+        SET trips it at exactly PENDING_DRAIN_THRESHOLD rows) and the
+        next bucket up (a foreign batch landing on a nearly full pending
+        window drains threshold + batch rows). Run at boot, after
+        recovery has settled the capacity: otherwise the first such
+        drain compiles while it holds the repo lock, and every client of
+        the type waits out an XLA compile (seconds when the persistent
+        cache is cold). Every row is an out-of-range pad, so the scatter
+        drops them all and the state is what it was. A shape the
+        capacity would send down the dense path is skipped (a small or
+        empty keyspace compiles nothing here); the mesh path has its own
+        programs and is left to its first drain."""
+        if self._mesh is not None:
+            return
+        self.drain()  # what recovery buffered: settles the capacity
+        for b in (PENDING_DRAIN_THRESHOLD, 2 * PENDING_DRAIN_THRESHOLD):
+            if b * DENSE_FRACTION >= self._key_cap:
+                continue
+            zeros = np.zeros(b, np.uint32)
+            self._state, *_ = _drain(
+                self._state, pad_rows(b), zeros, zeros, zeros, zeros,
+                np.full(b, -1, np.int32),
+            )
+
     @timed_drain("TREG", lambda self: self._tbl.pend_count())
     def drain(self) -> None:
         pend = self._tbl.export_pend()  # [(row, ts, value)], not yet cleared

@@ -29,7 +29,8 @@ What is extracted, per (section, key):
   ``_sync_actives``, ``_dial``, ``_active_missed``,
   ``_inbound_contact``, ``_drop``).
 * ``send`` — the broadcast/held-queue path (``broadcast_deltas``,
-  ``_flush_held``, ``_send_to_actives``, ``_send``, ``_broadcast_msg``).
+  ``_flush_held``, ``_send_push``, ``_send_to_actives``, ``_send``,
+  ``_broadcast_msg``).
 * ``recv`` — the message pump (``_accept``, ``_read_loop``): framing,
   CRC and codec teardown reasons, the pre-handshake gate.
 
@@ -81,8 +82,8 @@ DIAL_FUNCS = (
 RECV_FUNCS = ("_accept", "_read_loop")
 SEND_FUNCS = (
     "broadcast_deltas", "_log_delta", "_retransmit_unacked",
-    "_send_reset", "_flush_held", "_send_to_actives", "_send",
-    "_broadcast_msg",
+    "_send_reset", "_flush_held", "_send_push", "_send_to_actives",
+    "_send", "_broadcast_msg",
 )
 
 # query-only helpers whose calls are not effects (they mutate nothing

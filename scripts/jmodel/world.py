@@ -1283,7 +1283,7 @@ class World:
                     "held_bound",
                     f"{key}: {len(c._held)} held > cap {c._held_cap}",
                 )
-            stamps = [ts for ts, _ in c._held]
+            stamps = [ts for ts, _data, _keys in c._held]
             if stamps != sorted(stamps):
                 raise Violation("held_fifo", f"{key}: held stamps {stamps}")
             # delta-interval sender state (schema v8): the retransmit
@@ -1545,7 +1545,7 @@ class World:
         for inst in self.instances.values():
             if inst.alive:
                 c = inst.cluster
-                times.update(ts for ts, _ in c._held)
+                times.update(ts for ts, _data, _keys in c._held)
                 if c._defer_since_ms is not None:
                     times.add(c._defer_since_ms)
         rank = {t: i for i, t in enumerate(sorted(times))}
@@ -1652,7 +1652,7 @@ class World:
                     len(c._range_queue),
                 ],
                 "held": [
-                    [rank[ts], self._sha(data)] for ts, data in c._held
+                    [rank[ts], self._sha(data)] for ts, data, _keys in c._held
                 ],
                 # region topology state (v10): the gossiped region map
                 # drives dial policy and relay roles
