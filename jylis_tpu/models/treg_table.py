@@ -16,19 +16,32 @@ reference's TReg last-writer-wins with value tiebreak
 (repo_treg.pony:24-68). The winner is the join of the drained cache and
 the pending window, so a drain never changes it: `fold_pend` just moves
 the window into the cache.
+
+A drain talks to its table in three calls, none of them per row on the
+native side: `export_planes` fills the kernel's batch arrays from the
+pending window, `settle_ties` decides the rows whose 8-byte prefix tied
+on the device by the full strings, `fold_pend` is the epilogue. The
+device mirror's vid plane carries a per-row GENERATION (native/engine.h,
+"TREG table"): a pending write that differs from the drained winner
+exports generation + 1, an identical re-delivery the generation itself.
 """
 
 from __future__ import annotations
 
+import numpy as np
+
+from ..ops.interner import prefix_rank
+
 
 class PyTregTable:
-    __slots__ = ("_keys", "_rkeys", "_cache", "_pending", "_deltas",
+    __slots__ = ("_keys", "_rkeys", "_cache", "_gen", "_pending", "_deltas",
                  "_sync_dirty")
 
     def __init__(self):
         self._keys: dict[bytes, int] = {}
         self._rkeys: list[bytes] = []
         self._cache: dict[int, tuple[int, bytes]] = {}  # drained winner
+        self._gen: dict[int, int] = {}  # its id in the device mirror
         self._pending: dict[int, tuple[int, bytes]] = {}  # max since drain
         self._deltas: dict[int, tuple[int, bytes]] = {}  # max since flush
         self._sync_dirty: dict[int, None] = {}  # since last digest pass
@@ -73,13 +86,42 @@ class PyTregTable:
     def pend_count(self) -> int:
         return len(self._pending)
 
-    def export_pend(self) -> list[tuple[int, int, bytes]]:
-        return [(row, ts, v) for row, (ts, v) in self._pending.items()]
+    def _pend_vid(self, row: int) -> int:
+        if self._cache.get(row) == self._pending[row]:
+            return self._gen[row]
+        return (self._gen.get(row, -1) + 1) & 0x7FFFFFFF
+
+    def export_planes(
+        self, ki, ts_hi, ts_lo, rank_hi, rank_lo, vid, dense: bool
+    ) -> int:
+        for i, (row, (ts, value)) in enumerate(self._pending.items()):
+            slot = row if dense else i
+            rank = prefix_rank(value)
+            ki[i] = row
+            ts_hi[slot] = ts >> 32
+            ts_lo[slot] = ts & 0xFFFFFFFF
+            rank_hi[slot] = rank >> 32
+            rank_lo[slot] = rank & 0xFFFFFFFF
+            vid[slot] = self._pend_vid(row)
+        return len(self._pending)
+
+    def settle_ties(self, rows):
+        won = []
+        for row in map(int, rows):
+            p = self._pending.get(row)
+            c = self._cache.get(row)
+            if p is not None and (c is None or p > c):
+                won.append(row)
+        return (
+            np.asarray(won, np.int32),
+            np.asarray([self._pend_vid(r) for r in won], np.int32),
+        )
 
     def fold_pend(self) -> None:
         for row, p in self._pending.items():
             c = self._cache.get(row)
             if c is None or p > c:
+                self._gen[row] = self._pend_vid(row)
                 self._cache[row] = p
         self._pending.clear()
 
@@ -139,8 +181,15 @@ class NativeTregTable:
     def pend_count(self) -> int:
         return self._eng.treg_pend_count()
 
-    def export_pend(self) -> list[tuple[int, int, bytes]]:
-        return self._eng.treg_export_pend()
+    def export_planes(
+        self, ki, ts_hi, ts_lo, rank_hi, rank_lo, vid, dense: bool
+    ) -> int:
+        return self._eng.treg_export_planes(
+            ki, ts_hi, ts_lo, rank_hi, rank_lo, vid, dense
+        )
+
+    def settle_ties(self, rows):
+        return self._eng.treg_settle_ties(rows)
 
     def fold_pend(self) -> None:
         self._eng.treg_fold_pend()

@@ -81,8 +81,10 @@ def _declare(c: ctypes.CDLL) -> None:
         "jy_treg_note_delta": (None, [vp, i64, u64, u8p, i64]),
         "jy_treg_winner": (i32, [vp, i64, pu64, pvp, pi64]),
         "jy_treg_pend_count": (i64, [vp]),
-        "jy_treg_export_pend": (i64, [vp, vp, vp, i64]),
-        "jy_treg_pend_val": (None, [vp, i64, pvp, pi64]),
+        "jy_treg_export_planes": (
+            i64, [vp, vp, vp, vp, vp, vp, vp, i64, i32],
+        ),
+        "jy_treg_settle_ties": (i64, [vp, vp, i64, vp]),
         "jy_treg_fold_pend": (None, [vp]),
         "jy_treg_delta_count": (i64, [vp]),
         "jy_treg_export_deltas": (i64, [vp, vp, vp, i64]),
@@ -312,27 +314,45 @@ class ServeEngine:
     def treg_pend_count(self) -> int:
         return self._lib.jy_treg_pend_count(self._h)
 
-    def treg_export_pend(self):
-        """[(row, ts, value)] without clearing (clear = treg_fold_pend)."""
-        cap = 256
-        while True:
-            rows = np.empty(cap, np.int64)
-            ts = np.empty(cap, np.uint64)
-            n = self._lib.jy_treg_export_pend(
-                self._h, rows.ctypes.data, ts.ctypes.data, cap
-            )
-            if n >= 0:
-                break
-            cap = -n
-        ptr = ctypes.c_void_p()
-        ln = ctypes.c_int64()
-        out = []
-        for i in range(n):
-            self._lib.jy_treg_pend_val(
-                self._h, int(rows[i]), ctypes.byref(ptr), ctypes.byref(ln)
-            )
-            out.append((int(rows[i]), int(ts[i]), ctypes.string_at(ptr, ln.value)))
-        return out
+    def treg_export_planes(
+        self, ki, ts_hi, ts_lo, rank_hi, rank_lo, vid, dense: bool
+    ) -> int:
+        """The pending window as the drain kernel's batch planes, written
+        into the caller's arrays in ONE pass (not cleared: clear =
+        treg_fold_pend). ``ki`` (int32, at least the window long) takes
+        the rows in window order; the u32 planes and ``vid`` (int32) are
+        the padded batch arrays the jitted call takes: sparse fills slot
+        i, dense fills slot row. Returns the rows written."""
+        cap = len(vid)
+        n = self._lib.jy_treg_pend_count(self._h)
+        planes = (ts_hi, ts_lo, rank_hi, rank_lo)
+        if (
+            len(ki) < n
+            or ki.dtype != np.int32
+            or vid.dtype != np.int32
+            or any(len(p) != cap or p.dtype != np.uint32 for p in planes)
+            or not all(a.flags.c_contiguous for a in (ki, vid, *planes))
+        ):
+            raise ValueError("treg_export_planes: batch arrays do not fit")
+        n = self._lib.jy_treg_export_planes(
+            self._h, ki.ctypes.data, ts_hi.ctypes.data, ts_lo.ctypes.data,
+            rank_hi.ctypes.data, rank_lo.ctypes.data, vid.ctypes.data, cap,
+            int(dense),
+        )
+        if n < 0:
+            raise ValueError("treg_export_planes: a slot outside the batch")
+        return n
+
+    def treg_settle_ties(self, rows):
+        """Rows the device flagged as prefix ties -> (rows whose pending
+        write wins by the full (ts, value) rule, the ids the mirror must
+        be patched to): one call, int32 arrays."""
+        rows = np.array(rows, np.int32)  # a copy: compacted in place
+        vids = np.empty(len(rows), np.int32)
+        m = self._lib.jy_treg_settle_ties(
+            self._h, rows.ctypes.data, len(rows), vids.ctypes.data
+        )
+        return rows[:m], vids[:m]
 
     def treg_fold_pend(self) -> None:
         self._lib.jy_treg_fold_pend(self._h)

@@ -41,8 +41,8 @@ endpoint emitting Prometheus text exposition (`prom.py`).
 Naming discipline: every histogram/gauge/trace-event name is a string
 literal at its call site, declared and described in
 `scripts/jlint/metrics_manifest.json` (jlint pass 5, rules
-JL501/JL502), and every histogram/gauge is pre-registered below so a
-scrape shows the full surface (with zero counts) from boot.
+JL501/JL502), and every histogram/gauge/tally is pre-registered below
+so a scrape shows the full surface (with zero counts) from boot.
 """
 
 from __future__ import annotations
@@ -95,6 +95,17 @@ SEAMS = (
     "cluster.decode",
     "cluster.apply",
     "repo.flush",
+)
+
+# Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`
+# (`registry.tally`): on /metrics they are further `kind`s of
+# jylis_drain_total, in SYSTEM METRICS `<TYPE> <kind> <n>` lines. TREG:
+# rows the engine's bulk call assembled (0 on a node that serves from
+# the Python tables: no compiler on the host), and rows whose 8-byte
+# prefix tied on the device and were settled by the full strings.
+TALLIES = (
+    "drain.TREG.bulk_rows",
+    "drain.TREG.tie_rows",
 )
 
 # Node-wide gauges (per-peer convergence lag lives on the Cluster and

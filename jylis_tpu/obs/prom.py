@@ -115,6 +115,8 @@ def render(database) -> str:
         t = _esc(name)
         out.append(f'jylis_drain_total{{type="{t}",kind="batches"}} {drains}')
         out.append(f'jylis_drain_total{{type="{t}",kind="keys"}} {keys}')
+    for typ, kind, n in reg.tally_stats():
+        out.append(f'jylis_drain_total{{type="{typ}",kind="{kind}"}} {n}')
 
     cluster = system.cluster_fn() if system.cluster_fn else {}
     if cluster:

@@ -29,7 +29,7 @@ from __future__ import annotations
 import time
 from collections import defaultdict, deque
 
-from . import GAUGES, SEAMS
+from . import GAUGES, SEAMS, TALLIES
 from .hist import Histogram
 from .jtrace import SpanStats
 from .span import Seam
@@ -83,6 +83,9 @@ class MetricsRegistry:
         # selector is attached (obs/loop.py): see loop_cpu_s
         self.loop_cpu_fn = None
         self.gauges: dict[str, float] = {name: 0.0 for name in GAUGES}
+        # exact per-type drain event counts (obs.TALLIES); each has one
+        # writer, a drain under its repo's lock
+        self.tallies: dict[str, int] = dict.fromkeys(TALLIES, 0)
         self.trace = TraceRing(trace_cap)
         # provenance-span folds (obs/jtrace.py): per-hop + per-region-
         # pair convergence histograms, SLO counters, worst exemplars
@@ -109,6 +112,11 @@ class MetricsRegistry:
         if phases is not None:
             for hp, s in zip(self._h_phases, phases):
                 hp.record(s)
+
+    def tally(self, name: str, n: int) -> None:
+        if name not in self.tallies:
+            raise KeyError(name)  # undeclared tally, fail loud
+        self.tallies[name] += n
 
     def note_journal(self, counter: str, n: int = 1) -> None:
         self.journal_counters[counter] += n
@@ -165,6 +173,12 @@ class MetricsRegistry:
             if c is not None:
                 yield name, int(c["batches"]), int(c["keys"]), c["seconds"] * 1e3
 
+    def tally_stats(self):
+        """(type, kind, n) per declared drain tally, TALLIES order."""
+        for name in TALLIES:
+            _, typ, kind = name.split(".")
+            yield typ, kind, self.tallies[name]
+
     def seam_stats(self):
         """(name, snapshot) per declared seam, SEAMS order."""
         for name in SEAMS:
@@ -209,8 +223,12 @@ class MetricsRegistry:
         ]
 
     def report(self) -> str:
+        tallies: dict[str, str] = defaultdict(str)
+        for typ, kind, n in self.tally_stats():
+            tallies[typ] += f", {n} {kind}"
         parts = [
             f"{name}: {drains} drains, {keys} keys, {ms:.1f}ms device"
+            + tallies[name]
             for name, drains, keys, ms in self.type_stats()
         ]
         return "; ".join(parts) if parts else "no drains"

@@ -232,6 +232,7 @@ def metric_lines(
         lines.append(f"{name} drains {drains}")
         lines.append(f"{name} keys {keys}")
         lines.append(f"{name} device_ms {ms:.1f}")
+    lines.extend(f"{t} {kind} {n}" for t, kind, n in reg.tally_stats())
     if reg.journal_enabled or any(reg.journal_counters.values()):
         # every JOURNAL_KEYS line whenever journaling is live — explicit
         # zeros from boot (e.g. fsyncs under --journal-fsync off), not a

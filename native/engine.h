@@ -26,6 +26,7 @@
 #include <string>
 #include <unordered_map>
 #include <unordered_set>
+#include <utility>
 #include <vector>
 
 extern "C" int32_t resp_scan(const uint8_t* buf, int64_t len,
@@ -183,6 +184,14 @@ struct Table {
 // compare, so the native winner NEVER needs a device read-back: a drain
 // just folds the pending window into the drained cache (the join of what
 // both already hold), and the device converges to the same winner.
+//
+// The device mirror's vid plane holds a per-row GENERATION, not an id
+// into a table of values: the kernel compares two ids only at one row
+// (the state's and that row's delta's, ops/treg._b_wins), so it needs
+// id >= 0 for a set register and id_a != id_b exactly when the two
+// (ts, value) pairs differ. A pending write that differs from the
+// drained winner exports generation + 1, an identical re-delivery
+// exports the generation itself; the fold adopts what it exported.
 
 struct TregTable {
     KeyIndex idx;
@@ -190,6 +199,7 @@ struct TregTable {
     std::vector<uint64_t> cache_ts;
     std::vector<std::string> cache_val;
     std::vector<uint8_t> cache_set;
+    std::vector<int32_t> cache_gen;  // the mirror's vid; -1 while unset
     // max (ts, value) written since the last drain
     std::vector<uint64_t> pend_ts;
     std::vector<std::string> pend_val;
@@ -220,6 +230,7 @@ struct TregTable {
             cache_ts.push_back(0);
             cache_val.emplace_back();
             cache_set.push_back(0);
+            cache_gen.push_back(-1);
             pend_ts.push_back(0);
             pend_val.emplace_back();
             pend_set.push_back(0);
@@ -260,16 +271,19 @@ struct TregTable {
         }
     }
 
+    bool pend_wins(int64_t row) const {
+        return !cache_set[row] ||
+               wins(pend_ts[row],
+                    reinterpret_cast<const uint8_t*>(pend_val[row].data()),
+                    static_cast<int64_t>(pend_val[row].size()), cache_ts[row],
+                    cache_val[row]);
+    }
+
     // serving winner = join(cache, pend); returns false when the row has
     // never been written (GET -> null)
     bool winner(int64_t row, uint64_t* ts, const std::string** val) const {
         if (!cache_set[row] && !pend_set[row]) return false;
-        if (!pend_set[row] ||
-            (cache_set[row] &&
-             !wins(pend_ts[row],
-                   reinterpret_cast<const uint8_t*>(pend_val[row].data()),
-                   static_cast<int64_t>(pend_val[row].size()), cache_ts[row],
-                   cache_val[row]))) {
+        if (!pend_set[row] || !pend_wins(row)) {
             *ts = cache_ts[row];
             *val = &cache_val[row];
         } else {
@@ -279,17 +293,76 @@ struct TregTable {
         return true;
     }
 
+    // the id the pending write of `row` carries to the device (wraps
+    // inside int32's non-negative half: neighbours still differ)
+    int32_t pend_vid(int64_t row) const {
+        if (cache_set[row] && pend_ts[row] == cache_ts[row] &&
+            pend_val[row] == cache_val[row])
+            return cache_gen[row];
+        return static_cast<int32_t>(
+            (static_cast<uint32_t>(cache_gen[row]) + 1u) & 0x7fffffffu);
+    }
+
+    // big-endian first 8 bytes, zero padded: ops/interner.prefix_rank
+    static uint64_t prefix_rank(const std::string& v) {
+        uint64_t r = 0;
+        size_t m = v.size() < 8 ? v.size() : 8;
+        for (size_t i = 0; i < m; i++)
+            r |= static_cast<uint64_t>(static_cast<uint8_t>(v[i]))
+                 << (56 - 8 * i);
+        return r;
+    }
+
+    // drain prologue: the pending window as the drain kernel's batch
+    // planes, in pend_rows order, written into the caller's padded
+    // arrays (`cap` long; `ki` at least pend_rows long). Sparse: row i
+    // of the window fills slot i; dense: slot = its row, and the caller
+    // pre-filled the lattice identity everywhere else. Returns the rows
+    // written, or -1 when a slot would fall outside `cap`.
+    int64_t export_planes(int32_t* ki, uint32_t* ts_hi, uint32_t* ts_lo,
+                          uint32_t* rank_hi, uint32_t* rank_lo, int32_t* vid,
+                          int64_t cap, bool dense) const {
+        int64_t n = static_cast<int64_t>(pend_rows.size());
+        if (!dense && n > cap) return -1;
+        for (int64_t i = 0; i < n; i++) {
+            int64_t row = pend_rows[i];
+            int64_t slot = dense ? row : i;
+            if (slot >= cap) return -1;
+            uint64_t rank = prefix_rank(pend_val[row]);
+            ki[i] = static_cast<int32_t>(row);
+            ts_hi[slot] = static_cast<uint32_t>(pend_ts[row] >> 32);
+            ts_lo[slot] = static_cast<uint32_t>(pend_ts[row]);
+            rank_hi[slot] = static_cast<uint32_t>(rank >> 32);
+            rank_lo[slot] = static_cast<uint32_t>(rank);
+            vid[slot] = pend_vid(row);
+        }
+        return n;
+    }
+
+    // rows the device flagged (ts and 8-byte rank equal, ids differ):
+    // the full strings decide. Compacts `rows` in place to those whose
+    // pending write wins, with the id the mirror must be patched to.
+    int64_t settle_ties(int32_t* rows, int64_t n, int32_t* vids) const {
+        int64_t m = 0;
+        for (int64_t i = 0; i < n; i++) {
+            int64_t row = rows[i];
+            if (row < 0 || row >= idx.rows()) continue;
+            if (!pend_set[row] || !pend_wins(row)) continue;
+            rows[m] = static_cast<int32_t>(row);
+            vids[m++] = pend_vid(row);
+        }
+        return m;
+    }
+
     // drain epilogue: the pending window folds into the drained cache
-    // (the join both sides already agree on) and clears
+    // (the join both sides already agree on) and clears; a winning
+    // value MOVES (its buffer changes owner, no byte is copied)
     void fold_pending() {
         for (int64_t row : pend_rows) {
-            if (!cache_set[row] ||
-                wins(pend_ts[row],
-                     reinterpret_cast<const uint8_t*>(pend_val[row].data()),
-                     static_cast<int64_t>(pend_val[row].size()), cache_ts[row],
-                     cache_val[row])) {
+            if (pend_wins(row)) {
+                cache_gen[row] = pend_vid(row);
                 cache_ts[row] = pend_ts[row];
-                cache_val[row] = pend_val[row];
+                cache_val[row] = std::move(pend_val[row]);
                 cache_set[row] = 1;
             }
             pend_set[row] = 0;

@@ -72,23 +72,20 @@ int64_t jy_treg_pend_count(void* e) {
         static_cast<Engine*>(e)->treg.pend_rows.size());
 }
 
-int64_t jy_treg_export_pend(void* e, int64_t* rows, uint64_t* ts,
-                            int64_t cap) {
-    TregTable& t = static_cast<Engine*>(e)->treg;
-    int64_t n = static_cast<int64_t>(t.pend_rows.size());
-    if (n > cap) return -n;
-    for (int64_t i = 0; i < n; i++) {
-        rows[i] = t.pend_rows[i];
-        ts[i] = t.pend_ts[t.pend_rows[i]];
-    }
-    return n;
+// the drain's two bulk calls (TregTable::export_planes / settle_ties):
+// the pending window leaves as the kernel's batch planes, the flagged
+// prefix ties come back settled by the full strings
+int64_t jy_treg_export_planes(void* e, int32_t* ki, uint32_t* ts_hi,
+                              uint32_t* ts_lo, uint32_t* rank_hi,
+                              uint32_t* rank_lo, int32_t* vid, int64_t cap,
+                              int32_t dense) {
+    return static_cast<Engine*>(e)->treg.export_planes(
+        ki, ts_hi, ts_lo, rank_hi, rank_lo, vid, cap, dense != 0);
 }
 
-void jy_treg_pend_val(void* e, int64_t row, const uint8_t** ptr,
-                      int64_t* len) {
-    TregTable& t = static_cast<Engine*>(e)->treg;
-    *ptr = reinterpret_cast<const uint8_t*>(t.pend_val[row].data());
-    *len = static_cast<int64_t>(t.pend_val[row].size());
+int64_t jy_treg_settle_ties(void* e, int32_t* rows, int64_t n,
+                            int32_t* vids) {
+    return static_cast<Engine*>(e)->treg.settle_ties(rows, n, vids);
 }
 
 void jy_treg_fold_pend(void* e) { static_cast<Engine*>(e)->treg.fold_pending(); }

@@ -7,7 +7,8 @@ is a KeyError at runtime — but only on the path that typo'd it — and a
 histogram/gauge/trace event added without documentation is invisible to
 operators. Same cure as pass 4, same mechanics:
 
-* every ``.hist(...)`` / ``.seam(...)`` / ``.gauge_set(...)`` call in the product tree
+* every ``.hist(...)`` / ``.seam(...)`` / ``.gauge_set(...)`` /
+  ``.tally(...)`` call in the product tree
   must use a STRING LITERAL name, every ``.trace_event(...)`` literal
   subsystem+event args, and every ``timed_drain("<TYPE>", ...)``
   decorator a literal type (its histogram is ``drain.<TYPE>``); each
@@ -16,8 +17,8 @@ operators. Same cure as pass 4, same mechanics:
   (JL501);
 * every manifest entry must still have a call site and a
   non-placeholder description (JL502: stale / undescribed);
-* every histogram/gauge name must be pre-registered in
-  ``jylis_tpu/obs/__init__.py``'s SEAMS/GAUGES tuples (and every
+* every histogram/gauge/tally name must be pre-registered in
+  ``jylis_tpu/obs/__init__.py``'s SEAMS/GAUGES/TALLIES tuples (and every
   declared name used), so a scrape shows the full surface from boot and
   the declarations can't rot (JL501/JL502).
 
@@ -27,8 +28,8 @@ fails JL502 until a human describes the metric. The CI metrics-smoke
 step (scripts/metrics_smoke.py) reads the same manifest to assert every
 histogram/gauge is actually present in a live node's scrape.
 
-Manifest keys are ``<kind>:<name>`` with kind in {hist, gauge, trace};
-trace names are ``<subsystem>.<event>``.
+Manifest keys are ``<kind>:<name>`` with kind in {hist, gauge, counter,
+trace}; trace names are ``<subsystem>.<event>``.
 """
 
 from __future__ import annotations
@@ -57,6 +58,8 @@ _CALL_KINDS = {
     # instrument (obs/span.py)
     "seam": ("hist", 1),
     "gauge_set": ("gauge", 1),
+    # registry.tally("drain.<TYPE>.<kind>", n): an exact event counter
+    "tally": ("counter", 1),
     "trace_event": ("trace", 2),
 }
 
@@ -131,30 +134,39 @@ def extract_sites(
     return sites, problems
 
 
-def declared_names(root: str = ROOT) -> tuple[set[str], set[str]]:
-    """(SEAMS, GAUGES) parsed from jylis_tpu/obs/__init__.py by AST —
-    jlint must not import the product package (jylis_tpu imports jax at
-    import time)."""
+# (manifest kind, its obs/__init__.py tuple, the word for one, what a
+# call site does to it): the pre-registration parity `check` enforces
+_PARITY = (
+    ("hist", "SEAMS", "histogram", "records into"),
+    ("gauge", "GAUGES", "gauge", "sets"),
+    ("counter", "TALLIES", "tally", "adds to"),
+)
+_DECLARED = tuple(p[1] for p in _PARITY)
+
+
+def declared_names(root: str = ROOT) -> tuple[set[str], set[str], set[str]]:
+    """(SEAMS, GAUGES, TALLIES) parsed from jylis_tpu/obs/__init__.py by
+    AST — jlint must not import the product package (jylis_tpu imports
+    jax at import time)."""
     path = os.path.join(root, OBS_INIT_REL)
-    seams: set[str] = set()
-    gauges: set[str] = set()
+    found: dict[str, set[str]] = {name: set() for name in _DECLARED}
+    declared = tuple(found[name] for name in _DECLARED)
     if not os.path.exists(path):
-        return seams, gauges
+        return declared
     tree = ast.parse(open(path, encoding="utf-8").read())
     for node in ast.walk(tree):
         if not isinstance(node, ast.Assign):
             continue
         for tgt in node.targets:
-            if not isinstance(tgt, ast.Name) or tgt.id not in ("SEAMS", "GAUGES"):
+            if not isinstance(tgt, ast.Name) or tgt.id not in _DECLARED:
                 continue
             if isinstance(node.value, (ast.Tuple, ast.List)):
-                names = {
+                found[tgt.id].update(
                     e.value
                     for e in node.value.elts
                     if isinstance(e, ast.Constant) and isinstance(e.value, str)
-                }
-                (seams if tgt.id == "SEAMS" else gauges).update(names)
-    return seams, gauges
+                )
+    return declared
 
 
 def load_manifest(path: str = METRICS_MANIFEST_PATH) -> dict[str, str]:
@@ -177,13 +189,14 @@ def write_manifest(path: str = METRICS_MANIFEST_PATH) -> dict[str, str]:
                 "_comment": (
                     "Generated by `python -m scripts.jlint "
                     "--write-manifest` from .hist()/.gauge_set()/"
-                    ".trace_event()/timed_drain() call sites under "
-                    "jylis_tpu/. Keys are kind:name (hist/gauge/trace). "
+                    ".tally()/.trace_event()/timed_drain() call sites "
+                    "under jylis_tpu/. Keys are kind:name "
+                    "(hist/gauge/counter/trace). "
                     "Descriptions are human-written and preserved across "
                     "regeneration; `make lint` fails on undeclared names "
                     "(JL501) and on stale or placeholder entries (JL502). "
                     "The CI metrics-smoke scrapes a live node and asserts "
-                    "every hist/gauge entry here is present."
+                    "every hist/gauge/counter entry here is present."
                 ),
                 "metrics": entries,
             },
@@ -197,7 +210,7 @@ def check(
     manifest_path: str = METRICS_MANIFEST_PATH,
     sites: dict[str, list[tuple[str, int]]] | None = None,
     pre_problems: list[Finding] | None = None,
-    declared: tuple[set[str], set[str]] | None = None,
+    declared: tuple[set[str], ...] | None = None,
 ) -> list[Finding]:
     if sites is None:
         sites, pre_problems = extract_sites()
@@ -247,48 +260,31 @@ def check(
                     name,
                 )
             )
-    # pre-registration parity: every used hist/gauge name must be in
-    # obs.SEAMS/GAUGES (or it KeyErrors at runtime), and every declared
-    # name must be used (or the scrape advertises a dead metric)
-    seams, gauges = declared if declared is not None else declared_names()
-    used_hists = {n[5:] for n in sites if n.startswith("hist:")}
-    used_gauges = {n[6:] for n in sites if n.startswith("gauge:")}
-    for name in sorted(used_hists - seams):
-        where, line = sites[f"hist:{name}"][0]
-        out.append(
-            Finding(
-                "JL501", where, line,
-                f"histogram `{name}` is not pre-registered in "
-                f"{OBS_INIT_REL} SEAMS (KeyError at runtime)",
-                name,
+    # pre-registration parity: every used hist/gauge/tally name must be
+    # in obs.SEAMS/GAUGES/TALLIES (or it KeyErrors at runtime), and every
+    # declared name must be used (or the scrape advertises a dead metric)
+    declared = declared if declared is not None else declared_names()
+    for (kind, tup, what, verb), names in zip(_PARITY, declared):
+        used = {
+            n[len(kind) + 1:] for n in sites if n.startswith(kind + ":")
+        }
+        for name in sorted(used - names):
+            where, line = sites[f"{kind}:{name}"][0]
+            out.append(
+                Finding(
+                    "JL501", where, line,
+                    f"{what} `{name}` is not pre-registered in "
+                    f"{OBS_INIT_REL} {tup} (KeyError at runtime)",
+                    name,
+                )
             )
-        )
-    for name in sorted(used_gauges - gauges):
-        where, line = sites[f"gauge:{name}"][0]
-        out.append(
-            Finding(
-                "JL501", where, line,
-                f"gauge `{name}` is not pre-registered in "
-                f"{OBS_INIT_REL} GAUGES (KeyError at runtime)",
-                name,
+        for name in sorted(names - used):
+            out.append(
+                Finding(
+                    "JL502", OBS_INIT_REL, 1,
+                    f"{tup} declares {what} `{name}` but no "
+                    f"call site {verb} it — delete the declaration",
+                    name,
+                )
             )
-        )
-    for name in sorted(seams - used_hists):
-        out.append(
-            Finding(
-                "JL502", OBS_INIT_REL, 1,
-                f"SEAMS declares histogram `{name}` but no call site "
-                "records into it — delete the declaration",
-                name,
-            )
-        )
-    for name in sorted(gauges - used_gauges):
-        out.append(
-            Finding(
-                "JL502", OBS_INIT_REL, 1,
-                f"GAUGES declares gauge `{name}` but no call site sets "
-                "it — delete the declaration",
-                name,
-            )
-        )
     return out

@@ -1,7 +1,7 @@
 """CI metrics-smoke: boot a real node with --metrics-port, scrape it,
-validate the Prometheus text exposition, and assert every histogram and
-gauge declared in scripts/jlint/metrics_manifest.json is present from
-boot (zero counts included — the observability surface must not depend
+validate the Prometheus text exposition, and assert every histogram,
+gauge and counter declared in scripts/jlint/metrics_manifest.json is
+present from boot (zero counts included — the observability surface must not depend
 on traffic having happened).
 
 Then boot a MULTI-LANE node (`--lanes N`, N from JYLIS_SMOKE_LANES,
@@ -204,6 +204,7 @@ def main() -> int:
     manifest = json.load(open(MANIFEST))["metrics"]
     hists = sorted(n[5:] for n in manifest if n.startswith("hist:"))
     gauges = sorted(n[6:] for n in manifest if n.startswith("gauge:"))
+    counters = sorted(n[8:] for n in manifest if n.startswith("counter:"))
 
     body = _boot_and_scrape(lanes=1)
 
@@ -216,6 +217,10 @@ def main() -> int:
     for name in gauges:
         if f'name="{name}"' not in body:
             failures.append(f"  manifest gauge absent from scrape: {name}")
+    for name in counters:  # drain.<TYPE>.<kind>: a kind of the drain totals
+        _, typ, kind = name.split(".")
+        if f'jylis_drain_total{{type="{typ}",kind="{kind}"}}' not in body:
+            failures.append(f"  manifest counter absent from scrape: {name}")
     # the traffic above must have armed the dispatch surface
     m = re.search(
         r'jylis_seam_latency_seconds_count\{seam="server\.(native_burst|'
@@ -276,8 +281,8 @@ def main() -> int:
         return 1
     print(
         f"metrics-smoke: {n_samples} valid samples; {len(hists)} histograms"
-        f" + {len(gauges)} gauges all present; {n_hist_series} cumulative "
-        f"_bucket series valid; lanes={lanes} aggregate scrape: "
+        f" + {len(gauges)} gauges + {len(counters)} counters all present; "
+        f"{n_hist_series} cumulative _bucket series valid; lanes={lanes} aggregate scrape: "
         f"{n_lane_samples} samples, {n_lane_hist} _bucket series, "
         f"per-lane + aggregate series ok"
     )

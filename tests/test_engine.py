@@ -258,14 +258,18 @@ def test_dense_drain_equivalence():
         for i, k in enumerate(keys):
             repo.converge(k, (shared + (b"zzz" if i % 2 else b"aaa"), 100))
         repo.drain()  # tie rows resolve on host: zzz must win either order
-    for k in keys:
+    sdump, bdump = dict(small.dump_state()), dict(big.dump_state())
+    for i, k in enumerate(keys):
         srow, brow = small._tbl.find(k), big._tbl.find(k)
-        assert small._cache[srow][0] == big._cache[brow][0] == 100
-        assert (
-            small._interner.lookup(small._cache[srow][1])
-            == big._interner.lookup(big._cache[brow][1])
-            == shared + b"zzz"
-        )
+        assert int(small._state.ts_lo[srow]) == int(big._state.ts_lo[brow]) == 100
+        # the mirror's id is the row's generation: it moved where the tie's
+        # winner came second (odd rows), and stayed where it came first
+        assert int(small._state.vid[srow]) == int(big._state.vid[brow]) == i % 2
+        assert sdump[k] == bdump[k] == (shared + b"zzz", 100)
+        for repo in (small, big):
+            out = Out()
+            repo.apply(Respond(out.sink), [b"GET", k])
+            assert out.take() == b"*2\r\n$20\r\n%s\r\n:100\r\n" % (shared + b"zzz")
 
 
 # -- UJSON -----------------------------------------------------------------

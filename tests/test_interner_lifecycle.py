@@ -3,7 +3,9 @@ bound (VERDICT round-2 weak spot 1 — ops/interner.py was append-only for
 the process lifetime). Epoch compaction rebuilds the table from the live
 set at drain boundaries and remaps the device planes; these tests churn
 far more distinct values than stay live and assert the table tracks the
-LIVE state while reads remain exact."""
+LIVE state while reads remain exact. TREG left the interner (PR 27): its
+device ids are per-row generations kept by the table, so the same churn is
+asserted against what bounds THAT id space, the number of drains."""
 
 import numpy as np
 import pytest
@@ -51,9 +53,13 @@ def test_treg_set_churn_keeps_interner_flat():
                 r, [b"SET", b"k%d" % k, b"gen%d-val%d" % (g, k), b"%d" % ts]
             )
         repo.drain()
-    bound = 2 * n_keys + mod.COMPACT_SLACK
-    assert len(repo._interner) <= bound, len(repo._interner)
-    # exact reads survive every compaction epoch
+    # the id space: a row's id in the device mirror is its generation, one
+    # step per drain that changed the row, whatever the number of distinct
+    # values churned through it; the repo keeps no table of values at all
+    vid = np.asarray(repo._state.vid)[:n_keys]
+    assert vid.min() >= 0 and vid.max() < rounds, (vid.min(), vid.max())
+    assert not hasattr(repo, "_interner") and not hasattr(repo, "_cache")
+    # exact reads survive every drain
     for k in (0, 17, n_keys - 1):
         out = _R()
         repo.apply(out, [b"GET", b"k%d" % k])
@@ -63,7 +69,7 @@ def test_treg_set_churn_keeps_interner_flat():
             b"gen%d-val%d" % (rounds - 1, k),
             want_ts,
         ], out.vals
-    # snapshot dump (device vid plane) agrees with the remapped table
+    # the snapshot dump reads the same host winner
     dump = dict(repo.dump_state())
     assert dump[b"k3"][0] == b"gen%d-val%d" % (rounds - 1, 3)
 

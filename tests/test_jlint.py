@@ -599,6 +599,7 @@ class Thing:
     def work(self, reg, name):
         reg.gauge_set("good.gauge", 1.0)
         reg.trace_event("sub", "event", "why", "detail")
+        reg.tally("drain.FAKETYPE.rows", 3)
         reg.hist("undeclared.seam")
         reg.hist("pre" + "computed")  # non-literal: JL501
 
@@ -613,6 +614,7 @@ class Repo:
 FAKE_DECLARED = (
     {"good.seam", "undeclared.seam", "drain.FAKETYPE"},
     {"good.gauge"},
+    {"drain.FAKETYPE.rows"},
 )
 
 
@@ -634,6 +636,7 @@ GOOD_ENTRIES = {
     "gauge:good.gauge": "a fine gauge",
     "trace:sub.event": "a fine event",
     "hist:drain.FAKETYPE": "a fine drain",
+    "counter:drain.FAKETYPE.rows": "a fine tally",
 }
 
 
@@ -642,6 +645,7 @@ def test_metric_nonliteral_name_fails(tmp_path):
     assert set(sites) == {
         "hist:good.seam", "hist:undeclared.seam", "gauge:good.gauge",
         "trace:sub.event", "hist:drain.FAKETYPE",
+        "counter:drain.FAKETYPE.rows",
     }
     assert any(
         f.rule == "JL501" and "string literal" in f.msg for f in problems
@@ -678,8 +682,14 @@ def test_unregistered_and_dead_obs_declarations_fail(tmp_path):
     entries = dict(GOOD_ENTRIES)
     entries["hist:undeclared.seam"] = "described now"
     path = _met_manifest(tmp_path, entries)
-    declared = ({"good.seam", "drain.FAKETYPE", "dead.seam"}, {"good.gauge"})
+    declared = (
+        {"good.seam", "drain.FAKETYPE", "dead.seam"},
+        {"good.gauge"},
+        {"dead.tally"},
+    )
     findings = pass_metrics.check(path, sites, problems, declared=declared)
+    for name, tup in (("drain.FAKETYPE.rows", "TALLIES"), ("dead.tally", "TALLIES")):
+        assert any(name in f.msg and tup in f.msg for f in findings)
     assert any(
         f.rule == "JL501" and "undeclared.seam" in f.msg
         and "pre-registered" in f.msg
@@ -718,9 +728,10 @@ def test_real_metrics_manifest_matches_sites():
     sites, problems = pass_metrics.extract_sites()
     assert problems == []
     assert sorted(manifest) == sorted(sites)
-    seams, gauges = pass_metrics.declared_names()
+    seams, gauges, tallies = pass_metrics.declared_names()
     assert {n[5:] for n in manifest if n.startswith("hist:")} == seams
     assert {n[6:] for n in manifest if n.startswith("gauge:")} == gauges
+    assert {n[8:] for n in manifest if n.startswith("counter:")} == tallies
 
 
 # ---- pass 6: cross-lane shared-state manifest (JL601/JL602) -----------------

@@ -17,6 +17,7 @@ jylis_tpu.ops imports.
 
 from __future__ import annotations
 
+import numpy as np
 import pytest
 
 from jylis_tpu.native import lib
@@ -119,6 +120,89 @@ def test_treg_lww_winner_rule(eng):
     rc, replies, _, _ = drain_native(eng, burst)
     assert rc == 0
     assert replies.endswith(b"*2\r\n$2\r\nzz\r\n:5\r\n")
+
+
+def _treg_batch(b: int):
+    """A b-slot drain batch at the lattice identity, as RepoTREG builds
+    it: [ts_hi, ts_lo, rank_hi, rank_lo, vid]."""
+    return [np.zeros(b, np.uint32) for _ in range(4)] + [
+        np.full(b, -1, np.int32)
+    ]
+
+
+def test_treg_bulk_export_settle_ties_and_fold(eng):
+    """The drain's two bulk calls while the engine serves: bursts fill
+    the pending window, `treg_export_planes` hands it over as batch
+    planes (sparse, then dense), `treg_settle_ties` decides equal
+    prefixes by the full strings, and the fold MOVES the winners into
+    the drained cache, which the next burst reads and overwrites."""
+    keys = [b"reg-%d" % i for i in range(40)]
+    burst = b"".join(
+        resp(b"TREG", b"SET", k, b"same-prefix-%04d" % i, b"%d" % ((1 << 33) + 5))
+        for i, k in enumerate(keys)
+    )
+    rc, replies, deferred, _ = drain_native(eng, burst)
+    assert rc == 0 and not deferred and replies == b"+OK\r\n" * 40
+    n = eng.treg_pend_count()
+    assert n == 40
+    ki = np.empty(64, np.int32)
+    d = _treg_batch(64)
+    assert eng.treg_export_planes(ki, *d, False) == n
+    assert ki[:n].tolist() == list(range(40))
+    assert (d[0][:n] == 2).all() and (d[1][:n] == 5).all()  # ts hi/lo
+    want_rank = int.from_bytes(b"same-pre", "big")
+    assert (d[2][:n] == want_rank >> 32).all()
+    assert (d[3][:n] == want_rank & 0xFFFFFFFF).all()
+    assert (d[4][:n] == 0).all() and (d[4][n:] == -1).all()  # first generation
+    dense = _treg_batch(64)
+    assert eng.treg_export_planes(ki, *dense, True) == n  # slot = row
+    for a, b in zip(d, dense):
+        assert (a == b).all()
+    eng.treg_fold_pend()
+    assert eng.treg_pend_count() == 0
+
+    # the next window: equal (ts, prefix), tails that win on even rows and
+    # lose on odd ones, one identical re-delivery, one short value
+    burst = b"".join(
+        resp(
+            b"TREG", b"SET", k,
+            b"same-prefix-%04d" % (i + 1 if i % 2 == 0 else i - 1),
+            b"%d" % ((1 << 33) + 5),
+        )
+        for i, k in enumerate(keys[:10])
+    )
+    burst += resp(b"TREG", b"SET", keys[10], b"same-prefix-0010", b"%d" % ((1 << 33) + 5))
+    burst += resp(b"TREG", b"SET", keys[11], b"ab", b"1")
+    rc, _, deferred, _ = drain_native(eng, burst + resp(b"TREG", b"GET", keys[0]))
+    assert rc == 0 and not deferred
+    n = eng.treg_pend_count()
+    assert n == 12
+    d = _treg_batch(16)
+    ki = np.empty(16, np.int32)
+    assert eng.treg_export_planes(ki, *d, False) == n
+    assert d[4][:n].tolist() == [1] * 10 + [0, 1]  # a re-delivery keeps its id
+    assert d[2][11] == int.from_bytes(b"ab\0\0", "big") and d[3][11] == 0
+    asked = np.arange(12, dtype=np.int32)
+    rows, vids = eng.treg_settle_ties(asked)
+    assert rows.tolist() == [0, 2, 4, 6, 8] and vids.tolist() == [1] * 5
+    assert asked.tolist() == list(range(12))  # the caller's array is not the scratch
+    assert eng.treg_settle_ties(np.asarray([-1, 10**6], np.int32))[0].size == 0
+    eng.treg_fold_pend()
+    rc, replies, _, _ = drain_native(
+        eng,
+        resp(b"TREG", b"GET", keys[0])
+        + resp(b"TREG", b"GET", keys[1])
+        + resp(b"TREG", b"SET", keys[1], b"x" * 2000, b"%d" % (1 << 40))
+        + resp(b"TREG", b"GET", keys[1]),
+    )
+    assert rc == 0
+    assert replies == (
+        b"*2\r\n$16\r\nsame-prefix-0001\r\n:8589934597\r\n"
+        b"*2\r\n$16\r\nsame-prefix-0001\r\n:8589934597\r\n"
+        b"+OK\r\n*2\r\n$2000\r\n" + b"x" * 2000 + b"\r\n:1099511627776\r\n"
+    )
+    with pytest.raises(ValueError):  # a batch the window does not fit
+        eng.treg_export_planes(ki, *_treg_batch(0), False)
 
 
 def test_tlog_ins_size_get_cutoff(eng):

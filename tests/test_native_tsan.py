@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import threading
 
+import numpy as np
 import pytest
 
 from jylis_tpu.native import lib
@@ -191,6 +192,62 @@ def test_shared_engine_under_external_mutex(cdll):
         )
     total = N_THREADS * N_ROUNDS
     assert out == b":%d\r\n:%d\r\n:%d\r\n" % (total, total, total)
+
+
+def test_treg_bulk_drain_under_mutex_while_serving(cdll):
+    """The TREG drain's bulk calls racing (under the mutex) with SET/GET
+    bursts on the same registers: a drainer thread exports the pending
+    window as batch planes, asks the tie call about every exported row
+    and folds, while writers keep filling the window. Every register
+    must end at its last writer's value, and no exported id may be
+    negative."""
+    eng = ServeEngine(cdll)
+    mu = threading.Lock()
+    n_keys = 16
+
+    def writer(n: int):
+        for i in range(N_ROUNDS):
+            k = b"reg-%d" % ((n + i) % n_keys)
+            with mu:
+                rc, out, deferred, rest = drain_native(
+                    eng,
+                    resp(b"TREG", b"SET", k, b"shared-prefix-%d-%d" % (n, i),
+                         b"%d" % (i + 1))
+                    + resp(b"TREG", b"GET", k),
+                )
+                assert (rc, rest) == (0, b"") and not deferred
+                assert out.startswith(b"+OK\r\n*2\r\n")
+
+    def drainer():
+        for _ in range(N_ROUNDS):
+            with mu:
+                n = eng.treg_pend_count()
+                if not n:
+                    continue
+                ki = np.empty(n, np.int32)
+                d = [np.zeros(n, np.uint32) for _ in range(4)] + [
+                    np.full(n, -1, np.int32)
+                ]
+                assert eng.treg_export_planes(ki, *d, False) == n
+                assert (d[4] >= 0).all()
+                rows, vids = eng.treg_settle_ties(ki)
+                assert set(rows.tolist()) <= set(ki.tolist())
+                assert (vids >= 0).all()
+                eng.treg_fold_pend()
+
+    _run_threads([lambda n=n: writer(n) for n in range(4)] + [drainer])
+    with mu:
+        for j in range(n_keys):
+            ts, val = eng.treg_winner(eng.treg_find(b"reg-%d" % j))
+            # the highest timestamp any writer gave this register, and
+            # among its writers the bytewise-largest value
+            want = max(
+                (i + 1, b"shared-prefix-%d-%d" % (n, i))
+                for n in range(4)
+                for i in range(N_ROUNDS)
+                if (n + i) % n_keys == j
+            )
+            assert (ts, val) == want
 
 
 def test_memo_install_invalidate_under_mutex(cdll):

@@ -23,7 +23,7 @@ import pytest
 
 import jylis_tpu  # noqa: F401
 from jylis_tpu.models.database import Database
-from jylis_tpu.obs import GAUGES, SEAMS
+from jylis_tpu.obs import GAUGES, SEAMS, TALLIES
 from jylis_tpu.obs.hist import Histogram
 from jylis_tpu.obs.registry import MetricsRegistry
 from jylis_tpu.obs.trace import DETAIL_CAP, TraceRing
@@ -131,6 +131,7 @@ def test_registry_preregisters_all_declared_names():
     reg = MetricsRegistry()
     assert set(reg.hists) == set(SEAMS)
     assert set(reg.gauges) == set(GAUGES)
+    assert set(reg.tallies) == set(TALLIES) and not any(reg.tallies.values())
     with pytest.raises(KeyError):
         reg.hist("not.a.seam")
     with pytest.raises(KeyError):
@@ -318,6 +319,9 @@ def test_prom_render_grammar_and_presence():
         assert f'seam="{seam}"' in body
     for g in GAUGES:
         assert f'name="{g}"' in body
+    for t in TALLIES:  # further kinds of the type's drain totals
+        _, typ, kind = t.split(".")
+        assert f'jylis_drain_total{{type="{typ}",kind="{kind}"}} 0' in body
     assert 'jylis_cmds_total{type="GCOUNT"} 1' in body
     assert 'jylis_seam_latency_seconds_count{seam="journal.append"} 1' in body
     # and the manifest agrees with the declared surface (the CI smoke
@@ -329,6 +333,7 @@ def test_prom_render_grammar_and_presence():
     manifest = json.load(open(manifest_path))["metrics"]
     assert {n[5:] for n in manifest if n.startswith("hist:")} == set(SEAMS)
     assert {n[6:] for n in manifest if n.startswith("gauge:")} == set(GAUGES)
+    assert {n[8:] for n in manifest if n.startswith("counter:")} == set(TALLIES)
 
 
 def test_prom_http_endpoint_serves_and_404s():

@@ -65,4 +65,4 @@ def test_the_warm_up_changes_no_state_and_every_repo_takes_the_call():
     repo = db.manager("TREG").repo
     assert (repo.sync_canon(b"a"), repo.sync_canon(b"b")) == digest
     if repo._mesh is None:  # (the suite's 8 virtual devices serve from a mesh: left alone)
-        assert repo._cache[repo._tbl.find(b"a")][0] == 5  # drained: the device mirror holds it
+        assert int(repo._state.ts_lo[repo._tbl.find(b"a")]) == 5  # drained: the device mirror holds it
