@@ -3,10 +3,10 @@
 
 PY ?= python
 
-.PHONY: test soak bench bench-all bench-full bench-smoke native run clean \
-        check-graft ci check-prose image compose-smoke smoke3 release \
+.PHONY: test soak native run clean \
+        check-graft ci image compose-smoke smoke3 release \
         lint lint-native sanitize sanitize-threads chaos metrics-smoke \
-        model-smoke loadgen-smoke
+        model-smoke
 
 # what CI runs per commit (.github/workflows/ci.yml + .circleci/config.yml):
 # hermetic on any host. `test` includes the journal suite
@@ -18,8 +18,8 @@ PY ?= python
 # native test subset; `sanitize-threads` rebuilds it under TSAN and runs
 # the multi-threaded engine drive; `chaos` is the tiny fault-injection
 # drill smoke.
-ci: native lint lint-native test chaos model-smoke check-graft check-prose \
-    bench-smoke metrics-smoke loadgen-smoke sanitize sanitize-threads
+ci: native lint lint-native test chaos model-smoke check-graft \
+    metrics-smoke sanitize sanitize-threads
 
 # the eleven jlint passes + the hygiene rules (broad-except, suppression
 # reasons/staleness), against the committed baseline
@@ -83,17 +83,6 @@ sanitize-threads:
 	  TSAN_OPTIONS=halt_on_error=1,second_deadlock_stack=1 \
 	  $(PY) -m pytest tests/test_native_tsan.py -q -p no:cacheprovider
 
-# every README headline number must match the committed BENCH_full.json
-check-prose:
-	$(PY) scripts/check_prose.py
-
-# tiny-iteration pass over the serving-bench harness (the RESP reply
-# counter, fallback accounting, demotion path, latency loop) so the
-# plumbing behind the recorded numbers can't rot between re-records;
-# pinned to CPU — it checks the harness, not the hardware
-bench-smoke:
-	JAX_PLATFORMS=cpu $(PY) bench.py --smoke
-
 # boot a real node with --metrics-port, scrape it, validate the
 # Prometheus exposition grammar + presence of every histogram/gauge in
 # scripts/jlint/metrics_manifest.json; then boot a --lanes 4 node and
@@ -101,17 +90,6 @@ bench-smoke:
 # lane-less counter sums) — neither surface can rot
 metrics-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/metrics_smoke.py
-
-# tiny in-process pass over the open-loop load harness (scripts/loadgen.py
-# — the worker protocol, Zipfian key draw, phase ladder, reservoir
-# percentiles, BUSY/shed accounting against a real armed node) so the
-# plumbing behind the overload-shed numbers can't rot between re-records.
-# The per-phase JSON artifact (throughput, refusals, full log2 latency
-# histogram per class) lands in loadgen_phases.json; both CI configs
-# upload it so load-shape drift is diffable like lint_findings.json
-loadgen-smoke:
-	JAX_PLATFORMS=cpu $(PY) scripts/loadgen.py --smoke \
-		--out loadgen_phases.json
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -155,18 +133,6 @@ model-smoke:
 # matrix (tests/test_drill_matrix.py)
 soak:
 	$(PY) -m pytest tests/ -q -m soak
-
-bench:
-	$(PY) bench.py
-
-# every BASELINE config, one JSON line each (north star first)
-bench-all:
-	$(PY) bench.py --all
-
-# machine-recorded sweep: writes BENCH_full.json (committed per round so
-# every perf claim in README/VERDICT_RESPONSE is auditable)
-bench-full:
-	$(PY) bench.py --full
 
 # build the native codecs explicitly (they also build lazily on first use).
 # Same build() as the lazy path, run as a script so jax is not imported:

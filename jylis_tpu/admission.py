@@ -257,7 +257,7 @@ class AdmissionController:
     def _shed_floor(self) -> int:
         """Lowest rank that still gets served while overloaded. Ranks at
         or past the floor shed; the floor never drops below ``protect``
-        (those ranks are the contract the bench's protected-class p99.9
+        (those ranks are the contract a protected class's tail
         is measured against), and escalates one step tighter — toward
         protect, not past it — when the EWMA says severe. The
         escalation is a STICKY latch: it engages at SEVERE_FACTOR x

@@ -1890,8 +1890,8 @@ class Cluster:
             return
         try:
             # cluster.relay: the WAN seam. sleep injects inter-region
-            # RTT (pacing this conn like real WAN backpressure — the
-            # wan-converge bench's knob); drop/error lose the relay,
+            # RTT (pacing this conn like real WAN backpressure);
+            # drop/error lose the relay,
             # healed by the periodic digest sync.
             await faults.async_point("cluster.relay")
         except faults.FaultError:

@@ -42,7 +42,7 @@ that a genuine failure would, not an injection-only special case.
 **Unarmed points are free.** ``point(name)`` / ``async_point(name)``
 cost exactly one dict miss when nothing is armed — the registry dict is
 empty unless ``JYLIS_FAILPOINTS`` is set or a test armed a point — so
-the seams stay on the hot path permanently (verified by bench-smoke).
+the seams stay on the hot path permanently.
 
 Every ``faults.point(...)`` name in the product tree must be declared
 in ``scripts/jlint/failpoints_manifest.json`` with a one-line

@@ -31,8 +31,7 @@ Threading: ``append`` only enqueues; a dedicated writer thread does the
 encode + write + fsync. The flush paths run on the serving event loop,
 and a large TLOG/UJSON batch's wire encode costs tens of milliseconds —
 paying that (plus fsync latency) inline would tax every client the loop
-is serving (measured: the inline version cost ~20% of `concurrent`
-bench throughput; threaded it is ~2%). The writer preserves append
+is serving. The writer preserves append
 order, ``flush()``/``close()`` drain the queue, and rotation drains
 before touching files. The durability point is therefore "flushed, then
 journaled within the writer's (millisecond) lag": a SIGKILL loses at

@@ -1,8 +1,8 @@
 """Multi-lane serving: shard the node across host cores.
 
-One asyncio loop plus the GIL is the hard ceiling behind the recorded
-``vs_one_conn = 1.01`` (BENCH_full.json ``concurrent``: 64 connections
-served no faster than one). This module runs a node as N serving
+One asyncio loop plus the GIL is the ceiling of one process: the loop's
+own work per command bounds throughput however many connections it
+serves (PERF.md section 5). This module runs a node as N serving
 **lanes** — worker processes, each owning a complete serving stack
 (ServeEngine, Database, journal segment, MetricsRegistry — the
 per-Database registry refactor exists precisely so N databases coexist

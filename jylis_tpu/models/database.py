@@ -103,7 +103,7 @@ class Database:
         # SYSTEM METRICS' "cmds" lines: THIS instance's Python-path
         # tally merged with THIS instance's engine counters — wired
         # per-Database (a global registry would cross-talk between
-        # Database instances in tests/benches)
+        # Database instances in tests)
         self._served_py: dict[str, int] = {}
         self.system.served_fn = self._served_totals
         self.system.serving_fn = self.serving_totals
@@ -185,7 +185,7 @@ class Database:
 
     def serving_totals(self) -> dict[str, int]:
         """The native-vs-demoted serving split (SYSTEM METRICS SERVING
-        lines, and the bench's recorded fallback_frac): commands the
+        lines): commands the
         engine settled in C++ vs commands that went through the Python
         dispatch path (engine defers, demoted connections, and direct
         applies), plus whole-connection demotion events."""

@@ -224,7 +224,7 @@ class RepoBCOUNT:
             self.converge(key, delta)
         self.drain()
 
-    # -- direct host views (tests / bench / jmodel) --------------------------
+    # -- direct host views (tests / jmodel) -----------------------------------
 
     def counter(self, key: bytes) -> BCount | None:
         if self._pending:

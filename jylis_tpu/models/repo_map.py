@@ -193,7 +193,7 @@ class RepoMAP:
             self.converge(packed, unit)
         self.drain()
 
-    # -- direct host views (tests / bench) -----------------------------------
+    # -- direct host views (tests) --------------------------------------------
 
     def get_value(self, key: bytes, field: bytes, itype: str):
         if self._tbl.pending:

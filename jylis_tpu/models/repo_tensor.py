@@ -14,8 +14,7 @@ incoming cluster deltas coalesce per key in the host table and drain
 to the device mirror in one fused gather->vmap-join->scatter batch
 when the pending window trips the threshold. The mirror is where
 thousands of vector merges collapse into one XLA launch
-(ops/tensor.py; the `tensor-merge` bench drives the same kernels at
-the 1M-key x 64-dim x 64-replica shape).
+(ops/tensor.py).
 
 Device row mapping: one row per MAX/LWW key; one row per (key,
 contributing replica) for AVG keys — so all three merge modes drain

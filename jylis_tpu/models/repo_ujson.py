@@ -45,10 +45,9 @@ from .help import RepoHelp
 DEVICE_FANIN_MIN = 256
 # per-key fan-in worth joining a SEGMENTED drain: when many keys drain
 # together the dispatch is shared, so smaller fan-ins than
-# DEVICE_FANIN_MIN pay for their slice of the launch. Measured crossover
-# vs the host loop on single-entry deltas: ~64-128 per key (bench.py
-# --config ujson-multikey; the host fold is O(D^2) per key, the delta
-# encode is O(D))
+# DEVICE_FANIN_MIN pay for their slice of the launch: the host fold is
+# O(D^2) per key, the delta encode is O(D). No benchmark cell sits on
+# either side of this threshold (ROADMAP D7)
 SEG_FANIN_MIN = 64
 # buffered remote deltas across all keys before the converge path forces
 # a drain: bounds host memory for write-hot, never-read keys the same way

@@ -2,7 +2,7 @@
 and a bounded structured trace ring.
 
 Until this package, every latency number the repo could show was
-measured from OUTSIDE by bench.py, and `SYSTEM METRICS` was monotonic
+measured from OUTSIDE by a load generator, and `SYSTEM METRICS` was monotonic
 counters only — the node itself could not answer "how long does a drain
 take at p99?" or "how stale is the data a peer pushed me?". The
 delta-CRDT literature frames exactly those two quantities as THE trade
@@ -14,8 +14,8 @@ records. Three pillars:
 * **Fixed-bucket log2 latency histograms** (`hist.Histogram`): 64
   power-of-two nanosecond buckets, record = one index computation + one
   list increment, no allocation — cheap enough to stay armed on the
-  serving hot path permanently (bench.py records `obs_cost_frac` to
-  prove it). Wired into every timed seam the repo already has: native
+  serving hot path permanently (what it costs on the chip host:
+  PERF.md, PR 24). Wired into every timed seam the repo already has: native
   burst + Python dispatch (server), per-type drains
   (utils/metrics.timed_drain), journal append/fsync, and cluster
   heartbeat round-trips — and, through the span instrument
@@ -67,7 +67,7 @@ SEAMS = (
     "cluster.converge_lag",
     # the serving-pipeline profiler (server.py): per-stage timers on
     # the RESP path, so ROADMAP item 1's socket-tax attribution is a
-    # measured per-stage split instead of one bench-derived ratio.
+    # measured per-stage split instead of one externally derived ratio.
     # Stage semantics (docs/observability.md): accept = connection
     # setup (one sample per conn), read = one socket read await
     # (includes client idle — meaningful under saturation), parse =

@@ -5,8 +5,8 @@ i (1..63) holds durations in [2^(i-1), 2^i) ns, with everything past
 ~2^62 ns clamped into the last bucket. `record` is one float→int
 conversion, one `int.bit_length`, and one list increment — no
 allocation, no branching on the data, so the seams stay armed on the
-serving hot path permanently (bench.py's `obs_cost_frac` records the
-measured cost).
+serving hot path permanently (the measured cost on the chip host is
+in PERF.md, PR 24).
 
 Quantile queries walk the 64 buckets and report the matched bucket's
 UPPER bound, so the reported value is within one bucket (a factor of

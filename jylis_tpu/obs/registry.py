@@ -2,7 +2,7 @@
 
 Before this class the drain / journal / serving counters were
 process-global module state in utils/metrics.py, with a documented
-caveat: multiple Databases in one process (tests, benches, the warmup
+caveat: multiple Databases in one process (tests, the warmup
 throwaway) cross-talked through them. The registry makes the whole
 observability surface — counters, histograms, gauges, trace ring — a
 per-`Database` instance passed down explicitly: Database creates one,
@@ -14,8 +14,7 @@ registry-less direct drives (standalone repos, a bare Journal) still
 record somewhere.
 
 ``enabled`` is the one global switch the seams check before paying for
-`perf_counter` pairs: bench.py flips it off for the `obs_cost_frac`
-comparison run, so the recorded overhead covers the FULL cost of
+`perf_counter` pairs: with it off a run skips the FULL cost of
 observation (clock reads included), not just the bucket increment.
 
 Histogram and gauge names are pre-registered from obs.SEAMS/GAUGES —

@@ -75,9 +75,7 @@ class BCount:
     ``xi``/``xd`` must be mutated through :meth:`transfer` /
     :meth:`converge` / :meth:`from_wire` — the per-rid net-transfer
     cache that makes rights checks O(1) (instead of a full matrix scan
-    per spend, the difference between ~3k and ~1M grants/sec under the
-    bcount-contention bench) is maintained by exactly those entry
-    points."""
+    per spend) is maintained by exactly those entry points."""
 
     __slots__ = ("grants", "incs", "decs", "xi", "xd",
                  "_xi_net", "_xd_net")

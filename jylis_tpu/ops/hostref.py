@@ -1,11 +1,10 @@
 """Pure-Python reference lattices.
 
 Direct, obviously-correct implementations of the documented CRDT semantics
-(docs/_docs/types/*.md "Detailed Semantics"). Three jobs:
+(docs/_docs/types/*.md "Detailed Semantics"). Two jobs:
 
 1. differential-test oracle for the device kernels (tests/),
-2. the CPU baseline the benchmark compares against (bench.py),
-3. the SYSTEM log's tiny single-key TLog (models/repo_system.py), where a
+2. the SYSTEM log's tiny single-key TLog (models/repo_system.py), where a
    device round-trip would be absurd.
 
 These are NOT the serving path — the serving path is the device kernels.

@@ -89,10 +89,6 @@ def canon_f32(raw: bytes) -> bytes:
     return u.tobytes()
 
 
-def unpack_f32(raw: bytes) -> list[float]:
-    return np.frombuffer(raw, "<f4").astype(float).tolist()
-
-
 def pack_f32(values) -> bytes:
     return canon_f32(np.asarray(list(values), "<f4").tobytes())
 

@@ -147,12 +147,6 @@ def _decode_vid(nv):
     return (~nv).astype(jnp.int32).astype(INT64) - 1
 
 
-def _decode_ts(state_planes, wide: bool):
-    if wide:
-        return _join_neg64(state_planes[0], state_planes[1])
-    return (~state_planes[0]).astype(UINT64)
-
-
 def _assemble(a_planes, a_cut, d_ts, d_vid, d_cut, wide: bool, tail: bool):
     """Combine state plane rows with delta rows under the joined cutoff.
     tail=True writes the delta into the rows' trailing Ld columns (the

@@ -151,7 +151,7 @@ def converge_many(
     coalesces concurrent deltas per key host-side with the exact LWW rule
     (full strings, no rank-collision ambiguity — repo_treg.py:_write), so
     a drain always carries one winner per key; this kernel exists for
-    bench/offline folds where batches arrive pre-formed.
+    offline folds where batches arrive pre-formed.
     """
 
     def step(st, batch):

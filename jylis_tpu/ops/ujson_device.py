@@ -39,8 +39,7 @@ host re-buckets between rounds (`compact`).
 and commutative, so N deltas fold in ceil(log_8 N) batched device calls
 (8 rows reduce per launch — dispatch cost is per launch) instead of N
 sequential host merges, and the folded delta
-then joins every replica in ONE batched call (`bench.py --config
-ujson-32`).
+then joins every replica in ONE batched call.
 """
 
 from __future__ import annotations
@@ -333,57 +332,6 @@ def _slot_cols(lens: np.ndarray) -> np.ndarray:
 def narrow_shift(n_rep: int) -> int:
     """The int32 layout's shift for this replica-column budget."""
     return 31 - max(int(n_rep - 1).bit_length(), 1)
-
-
-def _auto_shift_encode(encode_one, n_rep: int, prefer: int | None):
-    """Shared narrow-first/wide-fallback layout policy: encode at the
-    narrow int32 layout, falling back to u64/32 when any seq (or pad
-    collision) overflows mid-pass. The encode's own validity checks
-    subsume a separate `plan_shift` scan, which measured as ~30% of the
-    whole fan-in path; retrying is safe because rid_cols/pay_ids updates
-    are idempotent setdefaults. ``prefer=32`` skips the narrow attempt —
-    callers memoise it (e.g. the serving repo) so a steady-state wide
-    workload doesn't pay a doomed narrow pass on every drain."""
-    shift = 32 if prefer == 32 else narrow_shift(n_rep)
-    try:
-        return encode_one(shift), shift
-    except OverflowError:
-        if shift == 32:
-            raise  # genuinely un-encodable (caller falls back to host)
-        return encode_one(32), 32
-
-
-def encode_docs_auto(docs, rid_cols, pay_ids, n_rep, prefer=None):
-    """`encode_docs` under the narrow-first layout policy; returns
-    (batch, shift)."""
-    return _auto_shift_encode(
-        lambda sh: encode_docs(docs, rid_cols, pay_ids, n_rep, shift=sh),
-        n_rep,
-        prefer,
-    )
-
-
-def encode_doc_lists_auto(lists, rid_cols, pay_ids, n_rep, prefer=None):
-    """Several doc lists encoded under ONE shared layout (joins require
-    identical shifts); returns (batches, shift)."""
-    return _auto_shift_encode(
-        lambda sh: [
-            encode_docs(docs, rid_cols, pay_ids, n_rep, shift=sh)
-            for docs in lists
-        ],
-        n_rep,
-        prefer,
-    )
-
-
-def encode_doc_groups_auto(groups, rid_cols, pay_ids, n_rep, prefer=None):
-    """`encode_doc_groups` under the narrow-first layout policy; returns
-    (batch, shift)."""
-    return _auto_shift_encode(
-        lambda sh: encode_doc_groups(groups, rid_cols, pay_ids, n_rep, shift=sh),
-        n_rep,
-        prefer,
-    )
 
 
 def _encode_docs_np(

@@ -18,8 +18,7 @@ This lattice lives on the host for SERVING: per-document data is tiny and
 pointer-heavy. The anti-entropy fan-in — joining many deltas into many
 replicas — is tensorised in ops/ujson_device.py (sorted packed-dot rows,
 vv planes, log-depth delta folds), differentially tested against this
-oracle and measured faster than the host loop on the 32-replica
-benchmark (bench.py --config ujson-32).
+oracle.
 
 Values are stored as canonical JSON tokens (the exact primitive serialisation,
 e.g. '"user"', '42', 'true', 'null') so value identity is representation
@@ -153,8 +152,7 @@ class UJSON:
     #
     # set_doc/rm/clr observe (then remove) the dots at or under a path;
     # scanning every entry per write made write-hot documents quadratic —
-    # the measured floor of the all-commands serving mix (bench.py
-    # `concurrent`, where 95% of mix time was this scan). The index maps
+    # the floor of an all-commands serving mix. The index maps
     # path -> dots, built lazily at the first observe and maintained by
     # the internal mutators; it is keyed on the entries dict's IDENTITY,
     # so consumers that install a fresh entries dict wholesale

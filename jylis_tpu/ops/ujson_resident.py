@@ -4,8 +4,8 @@ Round-3 shape (superseded): every drain re-encoded each hot key's pending
 deltas host->device, folded them on device, pulled the folded delta back
 and host-converged it into the authoritative host doc — O(new deltas)
 encode per drain, but also a device->host pull and a host O(doc) converge
-per drain, and the 32-replica bench additionally re-encoded the replica
-documents themselves every round (bench.py admitted the encode dominated).
+per drain, and a 32-replica fan-in additionally re-encoded the replica
+documents themselves every round (the encode dominated).
 
 This module keeps the hot keys' packed rows (ops/ujson_device.DocBatch:
 sorted packed-dot planes + payload ids + vv + cloud) RESIDENT on the
@@ -160,7 +160,7 @@ def _compact_ctx_row(vv, cloud, shift: int):
     col = jnp.minimum((cloud >> dt.type(shift)).astype(I32), r - 1)
     seq = (cloud & dt.type((1 << shift) - 1)).astype(U32)
     # computed-index gathers/scatters are pathologically slow on this
-    # chip (BENCH r01 note); R is small and static, so per-column masks
+    # chip; R is small and static, so per-column masks
     # do the vv lookup and the absorb counting as dense lane ops instead
     colmask = col[None, :] == jnp.arange(r, dtype=I32)[:, None]  # (R, C)
     vvc = jnp.sum(jnp.where(colmask, vv[:, None], U32(0)), axis=0, dtype=U32)
@@ -263,7 +263,7 @@ def fold_broadcast_rows(
 ) -> tuple[DocBatch, jax.Array]:
     """Fold a (D, W) delta batch to ONE doc and join it into every
     OCCUPIED resident row — the N-replica anti-entropy fan-in with the
-    replica documents already resident (bench config 5 drives this).
+    replica documents already resident.
     Scratch row 0 and free rows re-clear in the same dispatch, so the
     row-0-is-identity invariant holds and the returned live widths
     measure occupied rows only (free-row garbage would inflate the
@@ -923,11 +923,9 @@ class ResidentStore:
             self._fold_aligned(pending, grow_w, grow_c)
 
     # buffered broadcast deltas past this count force a flush, bounding
-    # host memory and the single fold's delta axis. Measured on the
-    # 32-replica stream (bench.py --config ujson-32): coalescing is
-    # monotonically better through 10k+ deltas (one 10240-delta fold
-    # beats two 5120-delta folds ~1.3x and eager per-round folds ~2.4x),
-    # so the cap is a memory/width bound, not a performance knob
+    # host memory and the single fold's delta axis. One large fold
+    # shares its dispatches across more deltas than several smaller
+    # ones, so the cap is a memory/width bound, not a performance knob
     BCAST_FLUSH_DELTAS = 16384
 
     def fold_in_broadcast(self, deltas: list) -> None:

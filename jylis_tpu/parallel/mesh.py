@@ -52,7 +52,7 @@ def serving_mesh() -> Mesh | None:
         # one node would make every drain a multi-controller SPMD program
         # — the wrong tool for an event-driven server. Cross-host scale is
         # the CLUSTER layer's job (gossip over DCN), same as the
-        # reference's one-process-one-node model. See parallel/PLAN.md.
+        # reference's one-process-one-node model.
         n = len(jax.local_devices())
         _SERVING_MESH.append(make_mesh(n) if n > 1 else None)
     return _SERVING_MESH[0]

@@ -47,14 +47,14 @@ class Server:
         # a native burst (one engine scan_apply call settling many
         # commands) vs one Python-path dispatch (deferred, demoted, or
         # busy-routed command). Resolved once; the registry's `enabled`
-        # flag is checked per record so bench.py's obs-off comparison
-        # run skips the clock reads too.
+        # flag is checked per record so an obs-off run skips the clock
+        # reads too.
         self._reg = database.metrics
         self._h_burst = self._reg.hist("server.native_burst")
         self._h_py = self._reg.hist("server.py_dispatch")
         # serving-pipeline profiler (obs/): per-stage timers across the
-        # whole RESP path, so the socket tax bench.py can only report as
-        # one ratio (socket_cost_frac) is attributable stage by stage.
+        # whole RESP path, so the socket tax a client can only see as
+        # one number is attributable stage by stage.
         # Each record is gated on the registry's `enabled` flag at the
         # seam, and the dispatch stage REUSES the burst/py elapsed above
         # rather than reading the clock again — the native hot path pays

@@ -178,8 +178,7 @@ def metric_lines(
     (Database merges its Python-path tally with its engine's native
     counters and wires the result through RepoSYSTEM). ``serving`` is
     the native-vs-demoted split (native_cmds / demoted_cmds /
-    demotions), emitted with the live fallback_frac so the bench
-    record's headline condition is checkable on a running node.
+    demotions), emitted with the live fallback_frac.
     ``cluster`` is the node's peer lifecycle view (Cluster.metrics_totals:
     per-state peer counts, dial/eviction/sync counters, held-delta
     drops, and the convergence-lag/backlog gauges). ``registry`` is the

@@ -176,7 +176,7 @@ def run_all(
                 # ROADMAP item 1's native-surface gap as a tracked number:
                 # commands only the Python oracle serves (MAP/BCOUNT/
                 # SESSION/…) — moving it means re-recording the parity
-                # manifest, and check_prose pins the documented figure
+                # manifest
                 "python_only": sum(
                     len(v)
                     for v in pass_parity.build_manifest()[

@@ -5,8 +5,8 @@ jit function serialises the device pipeline, a Python branch on a traced
 value either crashes at trace time or silently bakes one side into the
 compiled program, an implicit dtype leaves promotion to the ambient
 ``jax_enable_x64`` state (the lattices are u64; the documented guard is
-``with enable_x64(False)`` around kernel-dtype blocks — see bench.py's
-Pallas tensor-merge kernel), and a ``jax.jit`` constructed per call throws
+``with enable_x64(False)`` around kernel-dtype blocks), and a
+``jax.jit`` constructed per call throws
 the compile cache away every time.
 
 Reachability: a function is "jit code" when decorated with ``jax.jit`` /

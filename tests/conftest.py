@@ -6,7 +6,7 @@ pins the CPU via jax.config.update, which wins over whatever JAX_PLATFORMS
 the environment names, so the suite runs hermetically on a virtual 8-device
 CPU mesh — mirroring how the driver's dryrun_multichip check runs. The chip
 is reached only by `python chip_smoke.py` (the on-chip gate) and
-`python bench.py`.
+`python3 benchmark/run.py`, through the chip tool.
 
 Under `make sanitize` (JYLIS_SANITIZE=1) jax must NOT be imported at all:
 the ASAN runtime is LD_PRELOADed before jaxlib's pybind11 modules load,

@@ -9,6 +9,7 @@ virtual mesh to exercise sharded serving, not the plain CPU platform.)
 
 from __future__ import annotations
 
+import itertools
 import os
 import socket
 import subprocess
@@ -26,12 +27,29 @@ SPAWN_CPU = (
 )
 
 
+# Where tests' nodes listen: BELOW the kernel's ephemeral range (32768 up,
+# where bind(0) and every outbound connection of every worker's nodes take
+# their ports, so a port drawn there and bound later can be gone by then),
+# clear of the fixed ports of chip_smoke.py and benchmark/ (29471 up), in a
+# span of this xdist worker's own, walked in order: no two workers draw the
+# same port and one worker draws none twice before _PORT_SPAN draws.
+_PORT_BASE, _PORT_SPAN, _PORT_SLOTS = 20000, 700, 12
+_draws = itertools.count()
+
+
 def free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    p = s.getsockname()[1]
-    s.close()
-    return p
+    # "gw3" -> slot 4; a run without xdist takes slot 0
+    slot = int(os.environ.get("PYTEST_XDIST_WORKER", "gw-1")[2:]) + 1
+    base = _PORT_BASE + slot % _PORT_SLOTS * _PORT_SPAN
+    for _ in range(_PORT_SPAN):
+        port = base + next(_draws) % _PORT_SPAN
+        with socket.socket() as s:
+            try:
+                s.bind(("", port))  # every interface, as the cluster listener binds
+            except OSError:
+                continue  # held by something outside the suite
+        return port
+    raise RuntimeError(f"no free port in {base}..{base + _PORT_SPAN - 1}")
 
 
 def spawn_node(port: int, cport: int, name: str, *extra: str) -> subprocess.Popen:

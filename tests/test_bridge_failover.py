@@ -230,7 +230,7 @@ def test_dead_bridge_fails_over_and_cross_region_converges():
             assert await converge_wait(successor, ticks=600)
             # bounded handover: bee demoted aye within the demotion
             # bound plus the announce/dial slack (ticks are cheap in
-            # process; the recorded wall-clock bound is the bench's)
+            # process)
             assert b.cluster._tick - kill_tick_b <= 8 + 30
             assert b.cluster._stats["bridge_handovers"] > h0
             # the successor carries cross-region traffic

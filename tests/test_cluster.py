@@ -22,6 +22,7 @@ from jylis_tpu.system import System
 from jylis_tpu.utils.address import Address
 from jylis_tpu.utils.config import Config
 from jylis_tpu.utils.log import Log
+from procutil import free_port
 
 TICK = 0.05  # the reference test's 50 ms heartbeat (test_cluster.pony:70)
 
@@ -92,19 +93,9 @@ async def resp_call(port: int, payload: bytes) -> bytes:
 
 
 def grab_ports(n: int) -> list[int]:
-    """Reserve n distinct ephemeral loopback ports (the reference test uses
-    fixed ports 9999/9998/9997; ephemeral keeps parallel CI runs safe)."""
-    import socket
-
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
+    """n distinct loopback ports from this worker's own range (the
+    reference test uses fixed ports 9999/9998/9997)."""
+    return [free_port() for _ in range(n)]
 
 
 async def make_three_nodes():

@@ -1591,7 +1591,7 @@ def test_chaos_bridge_sigkill_fails_over_within_bound():
         # successor observed within the demotion bound (plus generous
         # scheduling slack: heartbeat ticks stretch on loaded hosts —
         # the tight tick-level bound is the in-process test's and the
-        # model's; the recorded wall-clock gap is the bench's)
+        # model's)
         bound_s = demote * hb + 10.0
         while _metric(cb, b"CLUSTER", b"bridge_is_self") != 1:
             assert time.time() - t_kill < bound_s, (

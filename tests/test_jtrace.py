@@ -425,38 +425,3 @@ def test_system_observe_shows_slo_and_write_heat():
             await n.stop()
 
     asyncio.run(main())
-
-
-# ---- loadgen artifact shape -------------------------------------------------
-
-
-def test_loadgen_log2_hist_shape():
-    """The per-phase artifact's latency histogram: [upper_ms, count]
-    pairs, powers-of-two uppers, counts summing to the sample count,
-    empty buckets dropped — the shape both CIs upload for diffing."""
-    import os
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
-    from scripts.loadgen import _log2_hist
-
-    assert _log2_hist([]) == []
-    # exact powers land in the bucket they bound (upper-inclusive)
-    hist = _log2_hist(sorted([0.5, 1.0, 1.1, 3.9, 4.0, 100.0]))
-    uppers = [u for u, _n in hist]
-    assert uppers == sorted(uppers)
-    for u in uppers:
-        f = u
-        while f < 1.0:
-            f *= 2.0
-        while f > 1.0 and f == f // 1 and int(f) % 2 == 0:
-            f /= 2.0
-        # every upper is 2^k for integer k
-        assert f == 1.0, u
-    assert sum(n for _u, n in hist) == 6
-    assert all(n > 0 for _u, n in hist)  # empties dropped
-    # sub-microsecond samples clamp into the smallest bucket, not crash
-    tiny = _log2_hist([0.0, 1e-9])
-    assert sum(n for _u, n in tiny) == 2

@@ -200,7 +200,7 @@ def test_fold_deltas_matches_sequential_convergence(n_rep, edits):
     ):
         assert_same_doc(got, want_doc)
 
-    # the single-dispatch fused path (what bench config 5 runs) agrees
+    # the single-dispatch fused path agrees
     fused = dev.fold_and_broadcast(rbatch, dbatch, shift=shift)
     for got, want_doc in zip(
         dev.decode_batch(fused, cols_rid, pay.lookup, shift=shift), want

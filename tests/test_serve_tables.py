@@ -884,32 +884,6 @@ def test_server_demote_then_recover_ordering_and_counters():
     assert b"SERVING native_cmds" in nm and b"SERVING fallback_frac" in nm
 
 
-def test_bench_resp_reply_counter():
-    """The bench harness's reply parser (the thing that makes the
-    re-recorded `concurrent` honest) counts structured replies once,
-    across arbitrary chunk splits."""
-    import bench
-
-    stream = (
-        b"+OK\r\n"
-        b":42\r\n"
-        b"$-1\r\n"
-        b"$5\r\nhe\r\no\r\n"  # bulk with embedded CRLF: one reply
-        b"*0\r\n"
-        b"*2\r\n$1\r\nv\r\n:7\r\n"  # TREG GET shape
-        b"*2\r\n*2\r\n$1\r\na\r\n:2\r\n*2\r\n$1\r\nb\r\n:1\r\n"  # TLOG GET
-        b"-ERR nope\r\n"
-    )
-    c = bench.RespReplyCounter()
-    assert c.feed(stream) == 8
-    # byte-at-a-time: same count, no double-count at chunk boundaries
-    c = bench.RespReplyCounter()
-    got = 0
-    for i in range(len(stream)):
-        got = c.feed(stream[i : i + 1])
-    assert got == 8
-
-
 def assert_size(repo, expect: int) -> None:
     r = R()
     repo.apply(r, [b"SIZE", b"k"])

@@ -13,8 +13,7 @@ ladder, relayed across bridges — never a whole-state dump), and after
 the final round `bridge_is_self` must sum to exactly one per region.
 
 This is the soak tier of the failover proof; the tick-exact bound is
-jmodel's `bridge_demotion` invariant, the wall-clock record is the
-`wan-converge` bench's failover phase, and the single-kill smoke is
+jmodel's `bridge_demotion` invariant, and the single-kill smoke is
 `test_chaos_bridge_sigkill_fails_over_within_bound`.
 """
 

@@ -10,17 +10,8 @@ import pytest
 import jylis_tpu  # noqa: F401
 from jylis_tpu.cluster import cluster as cluster_mod
 
+from procutil import free_port
 from test_cluster import TICK, Node, _CollectResp, converge_wait, resp_call
-
-
-def free_port() -> int:
-    import socket
-
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    p = s.getsockname()[1]
-    s.close()
-    return p
 
 
 def test_in_sync_peer_reconnect_ships_zero_frames():
