@@ -74,6 +74,7 @@ from jylis_tpu.client import Client, pack_command  # noqa: E402
 from jylis_tpu.models.database import DATA_TYPE_NAMES  # noqa: E402
 from jylis_tpu.ops import hostref  # noqa: E402
 from jylis_tpu.utils.address import Address  # noqa: E402
+from jylis_tpu.utils.batching import bucket  # noqa: E402
 from jylis_tpu.utils.net import free_port  # noqa: E402
 
 U64 = (1 << 64) - 1
@@ -547,6 +548,53 @@ def cache_entries() -> int:
         return 0
 
 
+# Run as `python -c` in a child of its own while NO node holds the chip:
+# compiles the sparse PNCOUNT drain for the first device, at the block one
+# device holds, and prints what the compiler made of it.
+_DRAIN_COMPILE = r"""
+import json, re, sys
+import jax, jax.numpy as jnp
+import jylis_tpu
+from jylis_tpu.models.repo_counters import _drain_pn
+k, w, b = map(int, sys.argv[1:])
+dev = jax.devices()[0]
+one = jax.sharding.SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+c = _drain_pn.lower(
+    S((k, w), jnp.uint32), S((b,), jnp.int32), S((b, w), jnp.uint32)
+).compile()
+copies = re.findall(r"= u32\[%d,%d\]\S* copy\(" % (k, w), c.as_text())
+print(json.dumps({
+    "platform": dev.platform, "plane": [k, w], "rows": b,
+    "plane_bytes": 4 * k * w,
+    "temp_bytes": c.memory_analysis().temp_size_in_bytes,
+    "plane_copies": len(copies),
+}))
+"""
+
+
+def sparse_drain_compile(shape: list[int], devices: int, rows: int,
+                         expect_platform: str) -> dict:
+    """What the device's compiler makes of `_drain_pn` at the smoke's
+    shapes: a sparse drain must touch its rows, not its plane (PERF.md, PR
+    29: a row gather out of a column-major plane made the TPU compiler
+    transpose the whole plane first). The copies are that compiler's, so
+    off the accelerator there is nothing to check."""
+    if expect_platform == "cpu":
+        return {"skipped": "off-accelerator: the plane copies are the TPU compiler's"}
+    out = subprocess.run(
+        [sys.executable, "-c", _DRAIN_COMPILE,
+         str(shape[0] // devices), str(shape[1]), str(rows)],
+        cwd=REPO, env=CHILD_ENV, capture_output=True, text=True, timeout=600,
+    )
+    check(out.returncode == 0, f"sparse drain compile failed:\n{out.stderr[-2000:]}")
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    check(got["platform"] == expect_platform, f"compiled for {got['platform']!r}")
+    check(got["plane_copies"] == 0 and got["temp_bytes"] < got["plane_bytes"],
+          f"the sparse PNCOUNT drain copies its plane: {got}")
+    return got
+
+
 # ---- RESP legs ---------------------------------------------------------------
 
 
@@ -950,6 +998,12 @@ def run(plan: Plan, seed: int, expect_platform: str, workdir: str) -> dict:
                   f"{t} planes are on {report1['state'][t]['devices']} of "
                   f"{dev['count']} devices")
 
+        # no node holds the chip between the two boots
+        drain_compile = sparse_drain_compile(
+            pn["shape"], dev["count"], bucket(plan.foreign_keys), expect_platform
+        )
+        say(f"sparse PNCOUNT drain as compiled: {drain_compile}")
+
         entries_before2 = cache_entries()
         chip.spawn()
         boot2 = boot_report(chip, chip.wait_serving(900))
@@ -1007,6 +1061,7 @@ def run(plan: Plan, seed: int, expect_platform: str, workdir: str) -> dict:
             "serving": serving,
             "over_the_wire": list(DRAIN_TYPES),
             "second_child": [],
+            "sparse_drain_compile": drain_compile,
             "boot_first": boot1,
             "boot_second": boot2,
             "compile_cache": cache,

@@ -11,6 +11,8 @@ import asyncio
 import hashlib
 from contextlib import AsyncExitStack, asynccontextmanager
 
+import jax
+
 from .. import sessions as sessions_mod
 from .help import DATATYPE_HELP, respond_help
 
@@ -520,8 +522,8 @@ class Database:
         shutdown log's `device state` line (main.py)."""
         out = []
         for mgr in self._map.values():
-            state = getattr(mgr.repo, "_state", None)
-            planes = [p for p in state or () if p is not None]
+            # a counter keyspace is one plane, the others a tuple of them
+            planes = jax.tree_util.tree_leaves(getattr(mgr.repo, "_state", None))
             if planes:
                 widest = max(planes, key=lambda p: p.size)
                 spread = min(len(p.sharding.device_set) for p in planes)

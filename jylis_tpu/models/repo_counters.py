@@ -2,8 +2,9 @@
 
 Reference analog: repo_gcount.pony:11-60 and repo_pncount.pony:12-67, where
 each repo is a Map[key -> counter] and converge is a per-key loop. Here the
-whole keyspace is ONE (keys x replicas) tensor per polarity (ops/gcount,
-ops/pncount), and all mutations — local INCs and incoming anti-entropy
+whole keyspace is ONE u32 plane of (keys x replicas) u64 cells, PNCOUNT's
+two polarities side by side in it (ops/gcount, ops/pncount, ops/planes),
+and all mutations — local INCs and incoming anti-entropy
 deltas alike — funnel into a coalesced pending batch that drains as a
 single fused scatter-max + row-sum XLA call. The drain's row sums feed a
 host value cache, so GET is a table lookup and the device only ever sees
@@ -36,7 +37,7 @@ from ..ops import gcount, planes, pncount
 from ..parallel import (
     drain_sharded_g,
     drain_sharded_pn,
-    route_drain,
+    route_drain64,
     serving_mesh,
     shard_plane,
 )
@@ -51,30 +52,31 @@ PNCOUNT_HELP = RepoHelp(
 )
 
 
+# sparse drains: `drain_batch` gathers the batch's rows, joins them and sums
+# the JOINED rows, so the donated plane is touched at those rows alone
+# (the programs' names are the trace's: `jit__drain_g`, `jit__drain_pn`)
 @partial(jax.jit, donate_argnums=0)
-def _drain_g(state, ki, d_hi, d_lo):
-    st = gcount.converge_batch(state, ki, d_hi, d_lo)
-    return st, gcount.read(st, ki)
+def _drain_g(state, ki, d):
+    return gcount.drain_batch(state, ki, d)
 
 
 @partial(jax.jit, donate_argnums=0)
-def _drain_pn(state, ki, dp_hi, dp_lo, dn_hi, dn_lo):
-    st = pncount.converge_batch(state, ki, dp_hi, dp_lo, dn_hi, dn_lo)
-    return st, pncount.read(st, ki)
+def _drain_pn(state, ki, d):
+    return pncount.drain_batch(state, ki, d)
 
 
 # dense drains: when a batch covers most of the keyspace (a full
-# anti-entropy sweep), an elementwise join streams each plane once instead
-# of paying random-access gathers + scatters twice per plane
+# anti-entropy sweep), an elementwise join streams the plane once instead
+# of paying random-access gathers + scatters per row
 @partial(jax.jit, donate_argnums=0)
-def _drain_g_dense(state, d_hi, d_lo):
-    st = gcount.join(state, gcount.GCountState(d_hi, d_lo))
+def _drain_g_dense(state, d):
+    st = gcount.join(state, d)
     return st, gcount.read_all(st)
 
 
 @partial(jax.jit, donate_argnums=0)
-def _drain_pn_dense(state, dp_hi, dp_lo, dn_hi, dn_lo):
-    st = pncount.join(state, pncount.PNCountState(dp_hi, dp_lo, dn_hi, dn_lo))
+def _drain_pn_dense(state, d):
+    st = pncount.join(state, d)
     return st, pncount.read_all(st)
 
 
@@ -150,10 +152,10 @@ class _CounterRepo:
         return -(-k // ns) * ns
 
     def _place(self, state):
-        """(Re-)place state planes keys-sharded when a mesh is active."""
+        """(Re-)place the state plane keys-sharded when a mesh is active."""
         if self._mesh is None:
             return state
-        return type(state)(*(shard_plane(self._mesh, p) for p in state))
+        return shard_plane(self._mesh, state)
 
     def _grow_to_fit(self) -> None:
         k = self._round_cap(bucket(max(self._tbl.rows(), 1), self._key_cap))
@@ -305,17 +307,15 @@ class RepoGCOUNT(_CounterRepo):
             for i, row in enumerate(rows):
                 for col, v in pending.get(row, {}).items():
                     deltas[i, col] = v
-            lr, d_hi, d_lo, slots = route_drain(
+            lr, payload, slots = route_drain64(
                 np.asarray(rows, np.int64),
                 deltas,
                 self._n_shards,
                 self._key_cap // self._n_shards,
             )
+            d = planes.pack64_np(payload)
             drain_phase(self, DEVICE)
-            hi, lo, sums = drain_sharded_g(
-                self._mesh, self._state.hi, self._state.lo, lr, d_hi, d_lo
-            )
-            self._state = gcount.GCountState(hi, lo)
+            self._state, sums = drain_sharded_g(self._mesh, self._state, lr, d)
             sums = np.asarray(sums)
             drain_phase(self, FINISH)
             live = [(int(g), sums[j]) for j, g in enumerate(slots) if g >= 0]
@@ -325,9 +325,9 @@ class RepoGCOUNT(_CounterRepo):
             for row in rows:
                 for col, v in pending.get(row, {}).items():
                     dense[row, col] = v
-            d_hi, d_lo = planes.split64_np(dense)
+            d = planes.pack64_np(dense)
             drain_phase(self, DEVICE)
-            self._state, sums = _drain_g_dense(self._state, d_hi, d_lo)
+            self._state, sums = _drain_g_dense(self._state, d)
             sums = np.asarray(sums)
             drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[row] for row in rows])
@@ -339,9 +339,9 @@ class RepoGCOUNT(_CounterRepo):
             for i, row in enumerate(rows):
                 for col, v in pending.get(row, {}).items():
                     deltas[i, col] = v
-            d_hi, d_lo = planes.split64_np(deltas)
+            d = planes.pack64_np(deltas)
             drain_phase(self, DEVICE)
-            self._state, sums = _drain_g(self._state, ki, d_hi, d_lo)
+            self._state, sums = _drain_g(self._state, ki, d)
             sums = np.asarray(sums)
             drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[i] for i in range(len(rows))])
@@ -436,44 +436,38 @@ class RepoPNCOUNT(_CounterRepo):
             return
         self._grow_to_fit()
         pend_p, pend_n = per_pol
+        # every batch is the (rows, 2R) u64 matrix [P | N], as the plane is
+        r = self._rep_cap
         if self._mesh is not None:
-            # polarity-stacked (B, 2R) so one routing pass serves both
-            stacked = np.zeros((len(rows), 2 * self._rep_cap), np.uint64)
-            r = self._rep_cap
+            deltas = np.zeros((len(rows), 2 * r), np.uint64)
             for i, row in enumerate(rows):
                 for col, v in pend_p.get(row, {}).items():
-                    stacked[i, col] = v
+                    deltas[i, col] = v
                 for col, v in pend_n.get(row, {}).items():
-                    stacked[i, r + col] = v
-            lr, d_hi, d_lo, slots = route_drain(
+                    deltas[i, r + col] = v
+            lr, payload, slots = route_drain64(
                 np.asarray(rows, np.int64),
-                stacked,
+                deltas,
                 self._n_shards,
                 self._key_cap // self._n_shards,
             )
+            d = planes.pack64_np(payload)
             drain_phase(self, DEVICE)
-            p_hi, p_lo, n_hi, n_lo, sums = drain_sharded_pn(
-                self._mesh, *self._state, lr, d_hi, d_lo
-            )
-            self._state = pncount.PNCountState(p_hi, p_lo, n_hi, n_lo)
+            self._state, sums = drain_sharded_pn(self._mesh, self._state, lr, d)
             sums = np.asarray(sums).view(np.uint64)
             drain_phase(self, FINISH)
             live = [(int(g), sums[j]) for j, g in enumerate(slots) if g >= 0]
             self._finish_drain([r for r, _ in live], [v for _, v in live])
         elif len(rows) * DENSE_FRACTION >= self._key_cap:
-            dp = np.zeros((self._key_cap, self._rep_cap), np.uint64)
-            dn = np.zeros((self._key_cap, self._rep_cap), np.uint64)
+            dense = np.zeros((self._key_cap, 2 * r), np.uint64)
             for row in rows:
                 for col, v in pend_p.get(row, {}).items():
-                    dp[row, col] = v
+                    dense[row, col] = v
                 for col, v in pend_n.get(row, {}).items():
-                    dn[row, col] = v
-            dp_hi, dp_lo = planes.split64_np(dp)
-            dn_hi, dn_lo = planes.split64_np(dn)
+                    dense[row, r + col] = v
+            d = planes.pack64_np(dense)
             drain_phase(self, DEVICE)
-            self._state, sums = _drain_pn_dense(
-                self._state, dp_hi, dp_lo, dn_hi, dn_lo
-            )
+            self._state, sums = _drain_pn_dense(self._state, d)
             sums = np.asarray(sums).view(np.uint64)
             drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[row] for row in rows])
@@ -481,19 +475,15 @@ class RepoPNCOUNT(_CounterRepo):
             b = bucket(len(rows))
             ki = pad_rows(b)
             ki[: len(rows)] = rows
-            dp = np.zeros((b, self._rep_cap), np.uint64)
-            dn = np.zeros((b, self._rep_cap), np.uint64)
+            deltas = np.zeros((b, 2 * r), np.uint64)
             for i, row in enumerate(rows):
                 for col, v in pend_p.get(row, {}).items():
-                    dp[i, col] = v
+                    deltas[i, col] = v
                 for col, v in pend_n.get(row, {}).items():
-                    dn[i, col] = v
-            dp_hi, dp_lo = planes.split64_np(dp)
-            dn_hi, dn_lo = planes.split64_np(dn)
+                    deltas[i, r + col] = v
+            d = planes.pack64_np(deltas)
             drain_phase(self, DEVICE)
-            self._state, sums = _drain_pn(
-                self._state, ki, dp_hi, dp_lo, dn_hi, dn_lo
-            )
+            self._state, sums = _drain_pn(self._state, ki, d)
             sums = np.asarray(sums).view(np.uint64)
             drain_phase(self, FINISH)
             self._finish_drain(rows, [sums[i] for i in range(len(rows))])
@@ -515,12 +505,8 @@ class RepoPNCOUNT(_CounterRepo):
         # jlint: order-ok — builds a col->rid LOOKUP map (order unused);
         # the wire encoder sorts every span by rid before any byte ships
         cols = {col: rid for rid, col in self._rids.items()}
-        p = planes.combine64_np(
-            np.asarray(self._state.p_hi), np.asarray(self._state.p_lo)
-        )
-        n = planes.combine64_np(
-            np.asarray(self._state.n_hi), np.asarray(self._state.n_lo)
-        )
+        counts = planes.unpack64_np(self._state)  # [P | N]
+        p, n = counts[:, : self._rep_cap], counts[:, self._rep_cap :]
         out = []
         for key, row in self._sorted_keys():
             dp = {cols[c]: int(v) for c, v in enumerate(p[row, : len(cols)]) if v}

@@ -5,125 +5,83 @@ maps, P and N, converged independently by per-replica max; the value is
 sum(P) - sum(N) as a signed 64-bit integer. Reference repo:
 jylis/repo_pncount.pony:26-67 (INC grows P, DEC grows N, GET nets them).
 
-Layout mirrors gcount: each polarity is a (K, R) u64 tensor stored as
-hi/lo u32 planes (ops/planes.py); batched converge is two gather->joint
-max->scatter composites. This type is the north-star benchmark target
-(BASELINE.json: 1M-key, 64-replica anti-entropy). Batches must carry
-UNIQUE key rows (serving repos guarantee it via their pending dicts).
+Layout mirrors gcount with the polarities side by side: the keyspace is
+the (K, 2R) u64 matrix ``[P | N]``, stored as one u32 plane of (K, 4R)
+cells (ops/planes.py): per row P's high words, N's high words, P's low
+words, N's low words. Joining it is gcount's join over 2R columns, so a
+batched converge is ONE gather -> joint max -> scatter composite for both
+polarities. This type is the north-star benchmark target (BASELINE.json:
+1M-key, 64-replica anti-entropy: a row is 256 lanes, two whole tiles).
+Batches must carry UNIQUE key rows (serving repos guarantee it via their
+pending dicts).
 """
 
 from __future__ import annotations
-
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import planes
+from . import gcount, planes
 
-U32 = jnp.uint32
-U64 = jnp.uint64
 I64 = jnp.int64
 
 
-class PNCountState(NamedTuple):
-    p_hi: jax.Array  # (K, R) uint32
-    p_lo: jax.Array
-    n_hi: jax.Array
-    n_lo: jax.Array
+def init(num_keys: int, num_replicas: int) -> jax.Array:
+    """Empty keyspace: (K, 4R) u32 cells."""
+    return gcount.init(num_keys, 2 * num_replicas)
 
 
-def init(num_keys: int, num_replicas: int) -> PNCountState:
-    # distinct buffers: the drain path donates the state, and XLA rejects
-    # donating one aliased buffer twice
-    return PNCountState(
-        *(jnp.zeros((num_keys, num_replicas), U32) for _ in range(4))
-    )
+def from_counts(p, n) -> jax.Array:
+    """Build from (K, R) u64 ndarrays of each polarity (tests / interop)."""
+    return gcount.from_counts(np.concatenate([np.asarray(p), np.asarray(n)], axis=1))
 
 
-def from_counts(p, n) -> PNCountState:
-    p_hi, p_lo = planes.split64_np(np.asarray(p))
-    n_hi, n_lo = planes.split64_np(np.asarray(n))
-    return PNCountState(
-        jnp.asarray(p_hi), jnp.asarray(p_lo), jnp.asarray(n_hi), jnp.asarray(n_lo)
-    )
+join = gcount.join
+converge_batch = gcount.converge_batch
+increment = gcount.increment  # INC grows a P column
 
 
-def join(a: PNCountState, b: PNCountState) -> PNCountState:
-    p = planes.join_max(a.p_hi, a.p_lo, b.p_hi, b.p_lo)
-    n = planes.join_max(a.n_hi, a.n_lo, b.n_hi, b.n_lo)
-    return PNCountState(p[0], p[1], n[0], n[1])
-
-
-def converge_batch(
-    state: PNCountState,
-    key_idx: jax.Array,
-    dp_hi: jax.Array,
-    dp_lo: jax.Array,
-    dn_hi: jax.Array,
-    dn_lo: jax.Array,
-) -> PNCountState:
-    """Join a delta batch at UNIQUE (B,) key rows; (B, R) u32 planes per
-    polarity."""
-    p = planes.scatter_join(state.p_hi, state.p_lo, key_idx, dp_hi, dp_lo)
-    n = planes.scatter_join(state.n_hi, state.n_lo, key_idx, dn_hi, dn_lo)
-    return PNCountState(p[0], p[1], n[0], n[1])
-
-
-def _bump(hi, lo, key_idx, replica_idx, amount):
-    a_hi = (amount >> jnp.uint64(32)).astype(U32)
-    a_lo = amount.astype(U32)
-    new_hi, new_lo = planes.add_carry(
-        hi[key_idx, replica_idx], lo[key_idx, replica_idx], a_hi, a_lo
-    )
-    return (
-        hi.at[key_idx, replica_idx].set(new_hi, mode="drop", unique_indices=True),
-        lo.at[key_idx, replica_idx].set(new_lo, mode="drop", unique_indices=True),
-    )
-
-
-def increment(
-    state: PNCountState, key_idx: jax.Array, replica_idx: jax.Array, amount: jax.Array
-) -> PNCountState:
-    """INC at UNIQUE (key, replica) coordinates; amount (B,) uint64."""
-    p_hi, p_lo = _bump(state.p_hi, state.p_lo, key_idx, replica_idx, amount)
-    return PNCountState(p_hi, p_lo, state.n_hi, state.n_lo)
-
-
-def decrement(
-    state: PNCountState, key_idx: jax.Array, replica_idx: jax.Array, amount: jax.Array
-) -> PNCountState:
-    n_hi, n_lo = _bump(state.n_hi, state.n_lo, key_idx, replica_idx, amount)
-    return PNCountState(state.p_hi, state.p_lo, n_hi, n_lo)
-
-
-def read(state: PNCountState, key_idx: jax.Array) -> jax.Array:
-    """GET for a batch of keys: signed net value.
+def value(cells: jax.Array) -> jax.Array:
+    """Signed net values of (..., 4R) rows.
 
     Computed with u64 wraparound then bitcast to int64, matching the
     reference's Pony (p_sum - n_sum).i64() modular behavior
     (repo_pncount.pony:55-57).
     """
-    p = planes.rowsum64(state.p_hi[key_idx], state.p_lo[key_idx])
-    n = planes.rowsum64(state.n_hi[key_idx], state.n_lo[key_idx])
+    hi, lo = planes.halves(cells)
+    r = hi.shape[-1] // 2
+    p = planes.rowsum64(hi[..., :r], lo[..., :r])
+    n = planes.rowsum64(hi[..., r:], lo[..., r:])
     return jax.lax.bitcast_convert_type(p - n, I64)
 
 
-def read_all(state: PNCountState) -> jax.Array:
-    p = planes.rowsum64(state.p_hi, state.p_lo)
-    n = planes.rowsum64(state.n_hi, state.n_lo)
-    return jax.lax.bitcast_convert_type(p - n, I64)
+def drain_batch(state: jax.Array, key_idx: jax.Array, d: jax.Array):
+    """Join a (B, 4R) delta batch at UNIQUE (B,) key rows and return
+    (state, the batch rows' net values), summed from the joined rows."""
+    state, rows = planes.scatter_join(state, key_idx, d)
+    return state, value(rows)
 
 
-def grow(state: PNCountState, num_keys: int, num_replicas: int) -> PNCountState:
-    k, r = state.p_hi.shape
-    if num_keys == k and num_replicas == r:
-        return state
-    z = jnp.zeros((num_keys, num_replicas), U32)
-    return PNCountState(
-        z.at[:k, :r].set(state.p_hi),
-        z.at[:k, :r].set(state.p_lo),
-        z.at[:k, :r].set(state.n_hi),
-        z.at[:k, :r].set(state.n_lo),
+def decrement(
+    state: jax.Array, key_idx: jax.Array, replica_idx: jax.Array, amount: jax.Array
+) -> jax.Array:
+    """DEC at UNIQUE (key, replica) coordinates: grows the N column."""
+    return gcount.increment(
+        state, key_idx, replica_idx + state.shape[1] // 4, amount
     )
+
+
+def read(state: jax.Array, key_idx: jax.Array) -> jax.Array:
+    """GET for a batch of keys: signed net value."""
+    return value(state[key_idx])
+
+
+def read_all(state: jax.Array) -> jax.Array:
+    return value(state)
+
+
+def grow(state: jax.Array, num_keys: int, num_replicas: int) -> jax.Array:
+    if state.shape == (num_keys, 4 * num_replicas):
+        return state
+    return planes.grow_cells(state, num_keys, 4, num_replicas)

@@ -1,11 +1,11 @@
 """Key-sharded counter keyspaces: shard_map merge kernels + join collective.
 
 The north-star path (BASELINE.json): PNCOUNT/GCOUNT anti-entropy over a
-(keys × replicas) u64 tensor — stored as hi/lo u32 planes (ops/planes.py;
-XLA's u64 emulation is 4-25x slower on scatters/reduces) — scaled over a
-device mesh:
+(keys × replicas) u64 tensor — stored as one u32 plane of hi|lo cells
+(ops/planes.py; XLA's u64 emulation is 4-25x slower on scatters/reduces) —
+scaled over a device mesh:
 
-* **State layout:** each plane sharded ``P("keys", None)`` — a device owns
+* **State layout:** the plane sharded ``P("keys", None)`` — a device owns
   a contiguous block of key rows with all replica columns resident, so
   both the join composite and the row-sum read are LOCAL.
 * **Routing:** the host assigns key rows blockwise to shards
@@ -40,7 +40,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..utils.batching import bucket, pad_rows
-from ..ops import planes, treg
+from ..ops import gcount, planes, pncount, treg
 
 U32 = jnp.uint32
 
@@ -109,22 +109,22 @@ def _route(key_idx, deltas, n_shards: int, rows_per_shard: int, bucket_width=Fal
 
 
 def route_batch(key_idx, deltas, n_shards: int, rows_per_shard: int):
-    """Host-side shard routing: global (B,) rows + (B, R) u64 deltas become
-    ((n_shards * W,) local rows, hi/lo (n_shards * W, R) u32 planes) with
+    """Host-side shard routing: global (B,) rows + (B, C) u64 deltas become
+    ((n_shards * W,) local rows, (n_shards * W, 2C) u32 delta cells) with
     the leading axis blockwise-sharded; W is the padded per-shard width.
     Duplicate keys are max-combined here (the device composite requires
     unique rows); padded slots carry PAD_ROW, which the scatter drops.
     """
     local_rows, payload, _ = _route(key_idx, deltas, n_shards, rows_per_shard)
-    d_hi, d_lo = planes.split64_np(payload)
-    return local_rows, d_hi, d_lo
+    return local_rows, planes.pack64_np(payload)
 
 
 def route_drain(key_idx, deltas, n_shards: int, rows_per_shard: int):
     """Serving-path routing: like `route_batch`, but the per-shard width is
-    bucketed to a power of two (bounds the jit cache over drain sizes) and
-    the slot -> global-row map is returned so the host value cache can be
-    refreshed from the per-slot sums the sharded drain kernels emit."""
+    bucketed to a power of two (bounds the jit cache over drain sizes), the
+    payload comes back as hi/lo u32 planes (TREG's columns), and the slot ->
+    global-row map is returned so the host value cache can be refreshed
+    from the per-slot results the sharded drain kernels emit."""
     local_rows, payload, slot_rows = _route(
         key_idx, deltas, n_shards, rows_per_shard, bucket_width=True
     )
@@ -133,129 +133,78 @@ def route_drain(key_idx, deltas, n_shards: int, rows_per_shard: int):
 
 
 def route_drain64(key_idx, deltas, n_shards: int, rows_per_shard: int):
-    """`route_drain` for kernels that take u64 payload columns directly
-    (TLOG's segment tensors) instead of hi/lo u32 planes."""
+    """`route_drain` with the u64 payload columns as they are: TLOG's
+    segment tensors take them directly, the counters pack them into cells
+    (`planes.pack64_np`)."""
     return _route(key_idx, deltas, n_shards, rows_per_shard, bucket_width=True)
 
 
-def _local_converge(hi_blk, lo_blk, rows_blk, dhi_blk, dlo_blk):
-    """Per-shard join composite (same kernel as ops/gcount.converge_batch,
-    applied to this device's key block)."""
-    return planes.scatter_join(hi_blk, lo_blk, rows_blk, dhi_blk, dlo_blk)
+_CELLS = P("keys", None)  # a (K, 2C) plane or a routed (n * W, 2C) batch
 
 
 # jit hoisted to module level with the mesh static: rebuilding the
 # jit(shard_map) wrapper per call would retrace and recompile every merge
-@partial(jax.jit, static_argnames=("mesh",), donate_argnums=(1, 2))
-def _converge_sharded(mesh, hi, lo, local_rows, d_hi, d_lo):
+@partial(jax.jit, static_argnames=("mesh",), donate_argnums=(1,))
+def _converge_sharded(mesh, cells, local_rows, d):
+    # per-shard join composite: the single-chip kernel on this device's block
     return jax.shard_map(
-        _local_converge,
+        gcount.converge_batch,
         mesh=mesh,
-        in_specs=(
-            P("keys", None),
-            P("keys", None),
-            P("keys"),
-            P("keys", None),
-            P("keys", None),
-        ),
-        out_specs=(P("keys", None), P("keys", None)),
-    )(hi, lo, local_rows, d_hi, d_lo)
+        in_specs=(_CELLS, P("keys"), _CELLS),
+        out_specs=_CELLS,
+    )(cells, local_rows, d)
 
 
-def converge_sharded(mesh, hi, lo, local_rows, d_hi, d_lo):
+def converge_sharded(mesh, cells, local_rows, d):
     """One anti-entropy merge step over the mesh: every device joins its
     routed slice into its key block. No communication."""
-    return _converge_sharded(mesh, hi, lo, local_rows, d_hi, d_lo)
+    return _converge_sharded(mesh, cells, local_rows, d)
 
 
 @partial(jax.jit, static_argnames=("mesh",))
-def _read_all_sharded(mesh, hi, lo):
+def _read_all_sharded(mesh, cells):
     return jax.shard_map(
-        planes.rowsum64,
-        mesh=mesh,
-        in_specs=(P("keys", None), P("keys", None)),
-        out_specs=P("keys"),
-    )(hi, lo)
+        planes.rowsum_cells, mesh=mesh, in_specs=(_CELLS,), out_specs=P("keys")
+    )(cells)
 
 
-def read_all_sharded(mesh, hi, lo):
+def read_all_sharded(mesh, cells):
     """Row sums (counter values, u64 wrapping) for the whole keyspace;
     output stays keys-sharded — only materialise on host what you need."""
-    return _read_all_sharded(mesh, hi, lo)
+    return _read_all_sharded(mesh, cells)
 
 
 # ---- serving drains: converge + read-back in ONE sharded launch ------------
 #
 # The counter repos' drain needs the post-join row sums for its host value
-# cache. Doing the read inside the same shard_map body keeps the whole
-# drain one device launch (one dispatch, one read-back) and keeps read work proportional to the BATCH, not the keyspace: each
-# device gathers only its routed rows. Pad slots gather clamped garbage,
-# which the host drops via the slot_rows map.
+# cache. Each device runs the single-chip drain (`drain_batch`: join, then
+# sum the joined rows it holds) on its key block, so the whole drain is one
+# device launch (one dispatch, one read-back) and its work is proportional
+# to the BATCH, not the keyspace. Pad slots join clamped garbage, which the
+# host drops via the slot_rows map.
 
 
-def _local_drain_g(hi_blk, lo_blk, rows_blk, dhi_blk, dlo_blk):
-    hi_blk, lo_blk = planes.scatter_join(hi_blk, lo_blk, rows_blk, dhi_blk, dlo_blk)
-    sums = planes.rowsum64(hi_blk[rows_blk], lo_blk[rows_blk])
-    return hi_blk, lo_blk, sums
+def _drain_sharded(drain_batch, mesh, cells, local_rows, d):
+    return jax.shard_map(
+        drain_batch,
+        mesh=mesh,
+        in_specs=(_CELLS, P("keys"), _CELLS),
+        out_specs=(_CELLS, P("keys")),
+    )(cells, local_rows, d)
 
 
-@partial(jax.jit, static_argnames=("mesh",), donate_argnums=(1, 2))
-def drain_sharded_g(mesh, hi, lo, local_rows, d_hi, d_lo):
+@partial(jax.jit, static_argnames=("mesh",), donate_argnums=(1,))
+def drain_sharded_g(mesh, cells, local_rows, d):
     """GCOUNT sharded drain: join the routed batch into each device's key
-    block and return (hi, lo, per-slot u64 row sums)."""
-    return jax.shard_map(
-        _local_drain_g,
-        mesh=mesh,
-        in_specs=(
-            P("keys", None),
-            P("keys", None),
-            P("keys"),
-            P("keys", None),
-            P("keys", None),
-        ),
-        out_specs=(P("keys", None), P("keys", None), P("keys")),
-    )(hi, lo, local_rows, d_hi, d_lo)
+    block and return (cells, per-slot u64 row sums)."""
+    return _drain_sharded(gcount.drain_batch, mesh, cells, local_rows, d)
 
 
-def _local_drain_pn(p_hi, p_lo, n_hi, n_lo, rows_blk, dhi_blk, dlo_blk):
-    # deltas arrive polarity-stacked (W, 2R): one routing pass serves both
-    r = p_hi.shape[1]
-    p_hi, p_lo = planes.scatter_join(
-        p_hi, p_lo, rows_blk, dhi_blk[:, :r], dlo_blk[:, :r]
-    )
-    n_hi, n_lo = planes.scatter_join(
-        n_hi, n_lo, rows_blk, dhi_blk[:, r:], dlo_blk[:, r:]
-    )
-    p = planes.rowsum64(p_hi[rows_blk], p_lo[rows_blk])
-    n = planes.rowsum64(n_hi[rows_blk], n_lo[rows_blk])
-    sums = jax.lax.bitcast_convert_type(p - n, jnp.int64)
-    return p_hi, p_lo, n_hi, n_lo, sums
-
-
-@partial(jax.jit, static_argnames=("mesh",), donate_argnums=(1, 2, 3, 4))
-def drain_sharded_pn(mesh, p_hi, p_lo, n_hi, n_lo, local_rows, d_hi, d_lo):
+@partial(jax.jit, static_argnames=("mesh",), donate_argnums=(1,))
+def drain_sharded_pn(mesh, cells, local_rows, d):
     """PNCOUNT sharded drain: both polarities join in one launch; returns
-    (state planes..., per-slot i64 net values)."""
-    return jax.shard_map(
-        _local_drain_pn,
-        mesh=mesh,
-        in_specs=(
-            P("keys", None),
-            P("keys", None),
-            P("keys", None),
-            P("keys", None),
-            P("keys"),
-            P("keys", None),
-            P("keys", None),
-        ),
-        out_specs=(
-            P("keys", None),
-            P("keys", None),
-            P("keys", None),
-            P("keys", None),
-            P("keys"),
-        ),
-    )(p_hi, p_lo, n_hi, n_lo, local_rows, d_hi, d_lo)
+    (cells, per-slot i64 net values)."""
+    return _drain_sharded(pncount.drain_batch, mesh, cells, local_rows, d)
 
 
 # ---- TREG sharded drain ----------------------------------------------------

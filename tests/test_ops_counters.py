@@ -4,8 +4,8 @@ Covers the lattice laws (commutativity, associativity, idempotence — the
 convergence guarantee the reference gets from pony-crdt) and agreement with
 the pure-Python reference lattices under random workloads, mirroring the
 documented semantics at docs/_docs/types/gcount.md:43-47 and
-pncount.md:49-55. The kernels store u64 counters as hi/lo u32 planes
-(ops/planes.py), so values straddling the 2^32 boundary are exercised
+pncount.md:49-55. The kernels store u64 counters as one u32 plane of hi|lo
+cells (ops/planes.py), so values straddling the 2^32 boundary are exercised
 explicitly.
 """
 
@@ -90,8 +90,7 @@ def test_gcount_converge_batch_with_duplicate_keys():
     ki = np.array([1, 1, 3], dtype=np.int32)
     deltas = np.array([[5, 0], [3, 9], [2, 2]], dtype=np.uint64)
     uki, udeltas = planes.coalesce(ki, deltas)
-    d_hi, d_lo = planes.split64_np(udeltas)
-    state = gcount.converge_batch(state, uki, d_hi, d_lo)
+    state = gcount.converge_batch(state, uki, planes.pack64_np(udeltas))
     got = gcount.to_counts(state)
     np.testing.assert_array_equal(got[1], [5, 9])  # elementwise max of dup rows
     np.testing.assert_array_equal(got[3], [2, 2])
@@ -99,9 +98,8 @@ def test_gcount_converge_batch_with_duplicate_keys():
 
 
 def _converge_u64(state, ki, p, n):
-    dp_hi, dp_lo = planes.split64_np(p)
-    dn_hi, dn_lo = planes.split64_np(n)
-    return pncount.converge_batch(state, ki, dp_hi, dp_lo, dn_hi, dn_lo)
+    d = planes.pack64_np(np.concatenate([p, n], axis=1))
+    return pncount.converge_batch(state, ki, d)
 
 
 def test_pncount_random_convergence_order_independent():
@@ -166,7 +164,7 @@ def test_grow_preserves_state():
         np.array([42], dtype=np.uint64),
     )
     state = gcount.grow(state, 8, 4)
-    assert state.hi.shape == (8, 4)
+    assert gcount.to_counts(state).shape == (8, 4)
     assert int(np.asarray(gcount.read_all(state))[1]) == 42
 
 
@@ -176,3 +174,96 @@ def test_rowsum_wraps_mod_2_64():
     state = gcount.from_counts(counts)
     got = int(np.asarray(gcount.read_all(state))[0])
     assert got == (4 * ((1 << 63) + 5)) % (1 << 64)
+
+
+# ---- the jitted sparse drains a client reaches (models/repo_counters.py) ----
+
+
+def _hostref_drain(oracle, kind, rows, d):
+    """Converge one (rows, C) u64 delta batch into the per-row oracle
+    counters; returns the batch rows' values as the drain's sums are."""
+    r = d.shape[1] // (2 if kind == "pn" else 1)
+    out = []
+    for row, vals in zip(rows, d):
+        other = hostref.PNCounter() if kind == "pn" else hostref.GCounter()
+        for col in np.flatnonzero(vals):
+            if kind == "g":
+                other.counts[int(col)] = int(vals[col])
+            elif col < r:
+                other.p.counts[int(col)] = int(vals[col])
+            else:
+                other.n.counts[int(col - r)] = int(vals[col])
+        mine = oracle.setdefault(int(row), type(other)())
+        mine.converge(other)
+        out.append(mine.value())
+    return out
+
+
+def _oracle_counts(oracle, kind, k, r):
+    c = 2 * r if kind == "pn" else r
+    want = np.zeros((k, c), np.uint64)
+    for row, ctr in oracle.items():
+        if kind == "g":
+            for col, v in ctr.counts.items():
+                want[row, col] = v
+        else:
+            for col, v in ctr.p.counts.items():
+                want[row, col] = v
+            for col, v in ctr.n.counts.items():
+                want[row, r + col] = v
+    return want
+
+
+@pytest.mark.parametrize("kind", ["g", "pn"])
+@pytest.mark.parametrize("r", [8, 64])
+@pytest.mark.parametrize("fill", ["one", "37pct", "full"])
+@pytest.mark.parametrize("b", [16, 1024, 16384])
+def test_jitted_sparse_drain_matches_hostref(kind, r, fill, b):
+    """`_drain_g` / `_drain_pn` as `drain()` calls them: a padded bucket
+    (the rest `pad_rows`), 63-bit values on both polarities, rows 0 and K-1,
+    two drains in a row. State AND returned sums bit for bit against
+    ops/hostref.py; the donated plane is consumed by each call."""
+    from jylis_tpu.models.base import pad_rows
+    from jylis_tpu.models.repo_counters import _drain_g, _drain_pn
+
+    ops, drain = (pncount, _drain_pn) if kind == "pn" else (gcount, _drain_g)
+    rng = np.random.default_rng([kind == "pn", r, len(fill), b])
+    k = max(2 * b, 64)
+    c = 2 * r if kind == "pn" else r
+    n = {"one": 1, "37pct": max(2, (37 * b) // 100), "full": b}[fill]
+    # a small batch fills every column; a big one 8 random ones a row and
+    # polarity, which still reaches every lane and wraps the row sums
+    per_row = c if b == 16 else 8 * (c // r)
+
+    state = ops.init(k, r)
+    oracle: dict = {}
+    prev_inner = None
+    for step in range(2):
+        if n == 1:
+            rows = np.array([0 if step == 0 else k - 1])
+        else:
+            inner = rng.permutation(np.arange(1, k - 1))[: n - 2]
+            if prev_inner is not None:  # half of the rows meet their old cells
+                keep = prev_inner[: (n - 2) // 2]
+                inner = np.unique(np.concatenate([keep, inner]))[: n - 2]
+            prev_inner = inner
+            rows = rng.permutation(np.concatenate([[0, k - 1], inner]))
+        d = np.zeros((len(rows), c), np.uint64)
+        cols = np.argsort(rng.random((len(rows), c)), axis=1)[:, :per_row]
+        vals = rng.integers(1 << 53, 1 << 62, size=cols.shape, dtype=np.uint64)
+        np.put_along_axis(d, cols, vals, axis=1)
+        ki = pad_rows(b)
+        ki[: len(rows)] = rows
+        padded = np.zeros((b, c), np.uint64)
+        padded[: len(rows)] = d
+
+        donated = state
+        state, sums = drain(donated, ki, planes.pack64_np(padded))
+        assert donated.is_deleted()
+        want_sums = _hostref_drain(oracle, kind, rows, d)
+        got = np.asarray(sums)[: len(rows)]
+        assert got.dtype == (np.int64 if kind == "pn" else np.uint64)
+        assert [int(v) for v in got] == want_sums
+        np.testing.assert_array_equal(
+            planes.unpack64_np(np.asarray(state)), _oracle_counts(oracle, kind, k, r)
+        )

@@ -35,7 +35,7 @@ def test_mesh_shapes():
 def test_route_batch_blocks_pads_and_coalesces():
     rows = np.array([0, 5, 17, 18, 33, 5], np.int32)  # 5 duplicated
     deltas = np.arange(6 * 2, dtype=np.uint64).reshape(6, 2)
-    local_rows, d_hi, d_lo = route_batch(rows, deltas, n_shards=4, rows_per_shard=16)
+    local_rows, d = route_batch(rows, deltas, n_shards=4, rows_per_shard=16)
     lr = local_rows.reshape(4, -1)
     assert lr.shape[1] == 2  # padded to the max shard load
     assert list(lr[0]) == [0, 5]
@@ -47,7 +47,7 @@ def test_route_batch_blocks_pads_and_coalesces():
     for shard in lr:
         assert len(set(map(int, shard))) == len(shard)
     # duplicate row 5 max-combined: deltas[1]=[2,3], deltas[5]=[10,11]
-    dl = d_lo.reshape(4, 2, 2)
+    dl = planes.unpack64_np(d).reshape(4, 2, 2)
     np.testing.assert_array_equal(dl[0, 1], [10, 11])
 
 
@@ -57,20 +57,56 @@ def test_sharded_converge_matches_single_chip():
     n = 8
     mesh = make_mesh(n)
     reference = np.zeros((K, R), np.uint64)
-    hi = shard_plane(mesh, np.zeros((K, R), np.uint32))
-    lo = shard_plane(mesh, np.zeros((K, R), np.uint32))
+    cells = shard_plane(mesh, np.zeros((K, 2 * R), np.uint32))
     for _ in range(3):
         rows = rng.integers(0, K, B).astype(np.int32)
         deltas = rng.integers(0, 1 << 48, (B, R)).astype(np.uint64)
         np.maximum.at(reference, rows, deltas)
-        lr, dh, dl = route_batch(rows, deltas, n, K // n)
-        hi, lo = converge_sharded(mesh, hi, lo, lr, dh, dl)
-    got = planes.combine64_np(
-        np.asarray(jax.device_get(hi)), np.asarray(jax.device_get(lo))
-    )
+        lr, d = route_batch(rows, deltas, n, K // n)
+        cells = converge_sharded(mesh, cells, lr, d)
+    got = planes.unpack64_np(jax.device_get(cells))
     np.testing.assert_array_equal(got, reference)
-    sums = np.asarray(jax.device_get(read_all_sharded(mesh, hi, lo)))
+    sums = np.asarray(jax.device_get(read_all_sharded(mesh, cells)))
     np.testing.assert_array_equal(sums, reference.sum(axis=1, dtype=np.uint64))
+
+
+@pytest.mark.parametrize("kind", ["g", "pn"])
+def test_sharded_drain_matches_single_chip_at_cell_widths(kind):
+    """The mesh drain and the one-chip drain on the north star's own widths
+    (64 replica ids: a GCOUNT row is one 128-lane tile, a PNCOUNT row two)
+    with 63-bit values: same plane, same per-row values, bit for bit."""
+    from jylis_tpu.models.base import pad_rows
+    from jylis_tpu.models.repo_counters import _drain_g, _drain_pn
+    from jylis_tpu.ops import gcount, pncount
+    from jylis_tpu.parallel import drain_sharded_g, drain_sharded_pn, route_drain64
+
+    ops, one_chip, sharded = (
+        (pncount, _drain_pn, drain_sharded_pn)
+        if kind == "pn"
+        else (gcount, _drain_g, drain_sharded_g)
+    )
+    rng = np.random.default_rng(29)
+    K, R, B, n = 256, 64, 100, 8
+    C = 2 * R if kind == "pn" else R
+    mesh = make_mesh(n)
+    single = ops.init(K, R)
+    cells = shard_plane(mesh, np.zeros(single.shape, np.uint32))
+    for _ in range(2):
+        rows = np.concatenate([[0, K - 1], 1 + rng.permutation(K - 2)[: B - 2]])
+        deltas = rng.integers(1 << 53, 1 << 63, (B, C), dtype=np.uint64)
+        ki = pad_rows(128)
+        ki[:B] = rows
+        padded = np.zeros((128, C), np.uint64)
+        padded[:B] = deltas
+        single, want = one_chip(single, ki, planes.pack64_np(padded))
+        lr, payload, slots = route_drain64(rows, deltas, n, K // n)
+        cells, sums = sharded(mesh, cells, lr, planes.pack64_np(payload))
+        np.testing.assert_array_equal(jax.device_get(cells), np.asarray(single))
+        by_row = dict(zip(rows.tolist(), np.asarray(want)[:B].tolist()))
+        sums = np.asarray(jax.device_get(sums))
+        live = slots >= 0
+        assert live.sum() == B
+        assert sums[live].tolist() == [by_row[g] for g in slots[live].tolist()]
 
 
 class _R:
@@ -99,7 +135,7 @@ def test_serving_repos_auto_shard_disjoint_key_blocks():
     assert repo._mesh is not None and repo._n_shards == 8
     k = repo._key_cap
     blocks = []
-    for shard in repo._state.hi.addressable_shards:
+    for shard in repo._state.addressable_shards:
         (rows, cols) = shard.index
         blocks.append((rows.start or 0, rows.stop if rows.stop else k))
         assert cols == slice(None) or (cols.start or 0) == 0  # all replica cols resident
@@ -163,8 +199,8 @@ def test_sharded_repo_grows_past_initial_capacity():
     # foreign deltas force a real sharded drain
     repo.converge(b"g0", {99: 7})
     repo.drain()
-    assert repo._state.hi.shape[0] >= n
-    assert len(repo._state.hi.addressable_shards) == 8
+    assert repo._state.shape[0] >= n
+    assert len(repo._state.addressable_shards) == 8
     for i in range(n):
         r = _R()
         repo.apply(r, [b"GET", b"g%d" % i])
