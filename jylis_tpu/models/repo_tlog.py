@@ -25,6 +25,9 @@ Delta wire shape: (entries: list[(value: bytes, ts: u64)], cutoff: u64).
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
+from functools import partial
+
 import jax
 import numpy as np
 
@@ -50,6 +53,7 @@ from ..utils.metrics import (
     DEVICE,
     FINISH,
     drain_phase,
+    resolve_registry,
     timed_drain,
 )
 from .help import RepoHelp
@@ -58,6 +62,48 @@ from .help import RepoHelp
 # log entries, rebuild it from the live set (ops/interner.compact) so
 # INS/TRIM churn can't grow host memory without bound
 COMPACT_SLACK = 8192
+
+# The drain's shape lattice. The jitted drain is specialised on the row
+# bucket, the pending-width bucket and the plane's len_cap at once, and a
+# TLOG program (two multi-key sorts, the fused trim) compiles in seconds
+# (~20 s on the chip host) under the repo lock. So each batch dimension
+# starts at a floor that every drain of a serving window falls into (a
+# TRIM-forced drain carries the few rows written since the last one, a
+# few entries each) and goes up in powers of FOUR: at most four times
+# the work of the exact size, a quarter of the programs of a power-of-two
+# lattice that started at 1.
+DRAIN_ROWS_FLOOR = 64
+DRAIN_WIDTH_FLOOR = 16
+# Compiling ahead: once the longest row passes WARM_FILL of len_cap the
+# programs of the NEXT len_cap are compiled, off the lock, so that the
+# `grow` that row will force meets them ready. Planes narrower than
+# WARM_MIN_LEN are left alone: rows that short double sooner than a
+# program compiles.
+WARM_FILL = 0.75
+WARM_MIN_LEN = 256
+# one worker: a level's programs compile one after another, and the
+# interpreter waits for the one in flight at exit
+_WARM_POOL = ThreadPoolExecutor(max_workers=1, thread_name_prefix="tlog-warm")
+
+
+def drain_bucket(n: int, floor: int) -> int:
+    """A drain batch dimension padded to the lattice above."""
+    b = floor
+    while b < n:
+        b <<= 2
+    return b
+
+def sparse_batch(b: int, ld: int):
+    """An empty sparse drain batch (ki, d_ts, d_vid, d_cut, counts): every
+    row an out-of-range pad the scatter drops, every trim a no-op."""
+    return (
+        np.full(b, PAD_ROW, np.int32),
+        np.zeros((b, ld), np.uint64),
+        np.full((b, ld), -1, np.int64),
+        np.zeros(b, np.uint64),
+        np.full(b, tlog.TRIM_NOOP, np.int64),
+    )
+
 
 TLOG_HELP = RepoHelp(
     "TLOG",
@@ -74,7 +120,7 @@ TLOG_HELP = RepoHelp(
 
 
 @jax.jit
-def _drain(state, ki, d_ts, d_vid, d_cut, counts):
+def _drain_tlog(state, ki, d_ts, d_vid, d_cut, counts):
     # fused merge + optional per-row trim (counts >= TRIM_NOOP are no-ops):
     # TRIM/CLR ride the same single dispatch as the drain they need first.
     # NOT donated: on overflow the caller retries from the pre-merge state
@@ -83,7 +129,7 @@ def _drain(state, ki, d_ts, d_vid, d_cut, counts):
 
 
 @jax.jit
-def _drain_dense(state, d_ts, d_vid, d_cut, trim_ki, counts):
+def _drain_tlog_dense(state, d_ts, d_vid, d_cut, trim_ki, counts):
     # dense drain: delta rows aligned 1:1 with the keyspace — no gather or
     # scatter (ops/tlog converge_batch key_idx=None); full length/cutoff
     # vectors read back in the same launch
@@ -94,9 +140,16 @@ def _drain_dense(state, d_ts, d_vid, d_cut, trim_ki, counts):
 
 
 @jax.jit
-def _get_row(state, k):
+def _get_row_tlog(state, k):
     ts, vid, _length = tlog.read_row(state, k)
     return ts, vid
+
+
+# one program a (shape, target) pair: eager, `tlog.grow` would trace and
+# compile its pad, slices and scatters one by one while the lock is held
+@partial(jax.jit, static_argnums=(1, 2))
+def _grow_tlog(state, num_keys, max_len):
+    return tlog.grow(state, num_keys, max_len)
 
 
 class RepoTLOG:
@@ -137,6 +190,15 @@ class RepoTLOG:
         # row -> (table gen, desc-sorted merged list): the GET-order memo
         # over the table's merged view
         self._sorted: dict[int, tuple[int, list[tuple[int, bytes]]]] = {}
+        # the longest row a drain has reported (never lowered by a trim:
+        # an overestimate only compiles early), the plane shapes whose
+        # serving programs are compiled, the level compiling now, and
+        # whether the boot's own warming is over (`warm_drain_shapes`:
+        # until then nothing is compiled ahead beside it)
+        self._longest = 0
+        self._warmed: set[tuple[int, int]] = set()
+        self._warming = None
+        self._warm_on = False
 
     def _round_cap(self, k: int) -> int:
         """Key capacity must split evenly over the mesh's keys axis."""
@@ -225,7 +287,8 @@ class RepoTLOG:
                 if base is not None:
                     ents = sorted(base, reverse=True)
                 else:
-                    ts_row, vid_row = _get_row(self._state, row)
+                    resolve_registry(self).tally("drain.TLOG.row_gathers", 1)
+                    ts_row, vid_row = _get_row_tlog(self._state, row)
                     ts_row = np.asarray(ts_row)
                     vid_row = np.asarray(vid_row)
                     ents = [
@@ -233,6 +296,7 @@ class RepoTLOG:
                         for i in range(length)
                     ]
                     ents.sort(reverse=True)
+                resolve_registry(self).tally("drain.TLOG.view_sorts", 1)
             self._render[row] = ents
         if not self._tbl.base_valid(row):
             self._tbl.set_base(row, ents)
@@ -263,6 +327,7 @@ class RepoTLOG:
         if hit is not None and hit[0] == gen:
             return hit[1], cut
         ents = sorted(self._tbl.merged_entries(row), reverse=True)
+        resolve_registry(self).tally("drain.TLOG.view_sorts", 1)
         self._sorted[row] = (gen, ents)
         return ents, cut
 
@@ -287,6 +352,7 @@ class RepoTLOG:
         weak item 6)."""
         row = self._tbl.upsert(key)
         self._tbl.converge_cutoff(row, ts)
+        resolve_registry(self).tally("drain.TLOG.trims", 1)
         self.drain()
         self._tbl.delta_raise_cutoff(row, self._tbl.cut_cache(row))
 
@@ -295,6 +361,7 @@ class RepoTLOG:
         first, so it rides the drain dispatch as the fused per-row count
         column — ONE launch total (was drain-then-trim, two)."""
         row = self._tbl.upsert(key)
+        resolve_registry(self).tally("drain.TLOG.trims", 1)
         # counts above any possible length are no-ops (tlog.md:58); clamping
         # to the kernel sentinel keeps huge client counts out of int64 range
         self.drain(trim=(row, min(count, tlog.TRIM_NOOP)))
@@ -449,6 +516,67 @@ class RepoTLOG:
             else jax.numpy.asarray(new_nv)
         )
 
+    def _grow(self, key_cap: int, len_cap: int) -> None:
+        """Regrow the planes, single-chip as ONE jitted program (compiled
+        ahead by `_warm_levels` where the row that forces it was seen
+        coming), so that the lock is not held for a compile."""
+        grow = _grow_tlog if self._mesh is None else tlog.grow
+        self._key_cap, self._len_cap = key_cap, len_cap
+        self._state = self._place(grow(self._state, key_cap, len_cap))
+        resolve_registry(self).tally("drain.TLOG.grows", 1)
+
+    def _warm_levels(self, state: tlog.TLogState, first: int, last: int) -> None:
+        """Compile what a serving drain runs at the plane widths
+        ``first``..``last`` doublings above ``state``'s: the sparse drain
+        at the lattice's floor, the one-row gather of a read, and the
+        `grow` to the next width, which also carries the walk there.
+        The batch is all pads and the drain not donated: ``state`` stays
+        what it was."""
+        for level in range(last + 1):
+            if level >= first:
+                _drain_tlog(state, *sparse_batch(DRAIN_ROWS_FLOOR, DRAIN_WIDTH_FLOOR))
+                _get_row_tlog(state, 0)
+                self._warmed.add(tuple(state.shape))
+            k, l = state.shape
+            state = _grow_tlog(state, k, 2 * l)
+
+    def warm_drain_shapes(self) -> None:
+        """Boot, after recovery has settled the capacity (single-threaded
+        caller): compile the serving programs for the recovered len_cap
+        AND the next one, so that neither the first drains nor the first
+        `grow` compile with clients waiting. A longest row already past
+        WARM_FILL regrows the planes first: it would overflow within the
+        first seconds of serving, and nobody waits now. Planes under
+        WARM_MIN_LEN (an empty or young keyspace: `Database.warmup`
+        compiled the default shape) and the mesh path, which has its
+        own programs, are left to their first drain."""
+        if self._mesh is not None:
+            return
+        self.drain()  # what recovery buffered (a restore has drained already)
+        self._warm_on = True
+        if self._len_cap < WARM_MIN_LEN:
+            return
+        if self._longest > WARM_FILL * self._len_cap:
+            self._grow(self._key_cap, 2 * self._len_cap)
+        self._warm_levels(self._state, 0, 1)
+
+    def _warm_ahead(self) -> None:
+        """After a drain of a booted single-chip node: once the longest
+        row is past WARM_FILL of len_cap, compile the next len_cap's
+        programs on the warm thread, from the state as it is now (device
+        arrays are immutable: the thread shares it safely, and drops the
+        grown copies it makes)."""
+        k, l = self._key_cap, self._len_cap
+        if (
+            not self._warm_on
+            or l < WARM_MIN_LEN
+            or self._longest <= WARM_FILL * l
+            or (k, 2 * l) in self._warmed
+            or (self._warming is not None and not self._warming.done())
+        ):
+            return
+        self._warming = _WARM_POOL.submit(self._warm_levels, self._state, 1, 1)
+
     def _finish_drain(self, updates) -> None:
         """Common drain epilogue: refresh the per-row host caches from the
         kernel's (row, length, cutoff) read-backs, then clear pending."""
@@ -456,7 +584,9 @@ class RepoTLOG:
             self._render.pop(row, None)
             self._sorted.pop(row, None)
             self._tbl.finish_row(row, int(ln), int(ct))
+            self._longest = max(self._longest, int(ln))
         self._tbl.finish_drain_end()
+        self._warm_ahead()
 
     @timed_drain("TLOG", lambda self: self._tbl.touched_count())
     def drain(self, trim: tuple[int, int] | None = None) -> None:
@@ -488,13 +618,18 @@ class RepoTLOG:
         )
         lcap = bucket(max(need_len, 1), self._len_cap)
         if kcap != self._key_cap or lcap != self._len_cap:
-            self._key_cap, self._len_cap = kcap, lcap
-            self._state = self._place(tlog.grow(self._state, kcap, lcap))
+            self._grow(kcap, lcap)
+        resolve_registry(self).tally(
+            "drain.TLOG.entries", sum(len(pend.get(r, ())) for r in rows)
+        )
         if self._mesh is not None:
             self._drain_sharded(rows, pend, cuts_in, trim)
             return
         while True:
-            ld = bucket(max((len(pend.get(r, ())) for r in rows), default=1), 1)
+            ld = drain_bucket(
+                max((len(pend.get(r, ())) for r in rows), default=1),
+                DRAIN_WIDTH_FLOOR,
+            )
             # dense path (repo_counters precedent): when the batch covers a
             # quarter of the keyspace and rows are narrow, aligned delta
             # rows skip the gather/scatter entirely
@@ -515,7 +650,7 @@ class RepoTLOG:
                 if trim is not None:
                     trim_ki[0], counts[0] = trim
                 drain_phase(self, DEVICE)
-                new_state, ovf, lens, cuts = _drain_dense(
+                new_state, ovf, lens, cuts = _drain_tlog_dense(
                     self._state, d_ts, d_vid, d_cut, trim_ki, counts
                 )
                 # check EVERY row: the dense kernel flags any row whose
@@ -523,10 +658,7 @@ class RepoTLOG:
                 # through, including rows with no pending delta
                 if bool(np.asarray(ovf).any()):
                     drain_phase(self, ASSEMBLE)  # the retry builds anew
-                    self._len_cap *= 2
-                    self._state = tlog.grow(
-                        self._state, self._key_cap, self._len_cap
-                    )
+                    self._grow(self._key_cap, 2 * self._len_cap)
                     continue
                 self._state = new_state
                 lens = np.asarray(lens)
@@ -534,12 +666,8 @@ class RepoTLOG:
                 drain_phase(self, FINISH)
                 self._finish_drain((r, lens[r], cuts[r]) for r in rows)
                 return
-            b = bucket(len(rows))
-            ki = np.full(b, PAD_ROW, np.int32)
-            d_ts = np.zeros((b, ld), np.uint64)
-            d_vid = np.full((b, ld), -1, np.int64)
-            d_cut = np.zeros(b, np.uint64)
-            counts = np.full(b, tlog.TRIM_NOOP, np.int64)
+            b = drain_bucket(len(rows), DRAIN_ROWS_FLOOR)
+            ki, d_ts, d_vid, d_cut, counts = sparse_batch(b, ld)
             for i, row in enumerate(rows):
                 ki[i] = row
                 for j, (ts, value) in enumerate(pend.get(row, ())):
@@ -549,14 +677,13 @@ class RepoTLOG:
                 if trim is not None and row == trim[0]:
                     counts[i] = trim[1]
             drain_phase(self, DEVICE)
-            new_state, ovf, lens, cuts = _drain(
+            new_state, ovf, lens, cuts = _drain_tlog(
                 self._state, ki, d_ts, d_vid, d_cut, counts
             )
             if bool(np.asarray(ovf)[: len(rows)].any()):
                 # retry from the retained pre-merge state with doubled slots
                 drain_phase(self, ASSEMBLE)
-                self._len_cap *= 2
-                self._state = tlog.grow(self._state, self._key_cap, self._len_cap)
+                self._grow(self._key_cap, 2 * self._len_cap)
                 continue
             self._state = new_state
             lens = np.asarray(lens)
@@ -600,10 +727,7 @@ class RepoTLOG:
             if bool(ovf[slots >= 0].any()):
                 # retry from the retained pre-merge state with doubled slots
                 drain_phase(self, ASSEMBLE)
-                self._len_cap *= 2
-                self._state = self._place(
-                    tlog.grow(self._state, self._key_cap, self._len_cap)
-                )
+                self._grow(self._key_cap, 2 * self._len_cap)
                 continue
             self._state = tlog.TLogState(*out[:5])
             lens, cuts = np.asarray(out[6]), np.asarray(out[7])

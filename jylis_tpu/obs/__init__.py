@@ -102,10 +102,21 @@ SEAMS = (
 # jylis_drain_total, in SYSTEM METRICS `<TYPE> <kind> <n>` lines. TREG:
 # rows the engine's bulk call assembled (0 on a node that serves from
 # the Python tables: no compiler on the host), and rows whose 8-byte
-# prefix tied on the device and were settled by the full strings.
+# prefix tied on the device and were settled by the full strings. TLOG:
+# what `drain.TLOG`'s batches and keys cannot tell apart (a drain of 8
+# rows with 1 pending entry each and one of 8 rows with 500 each are both
+# "8 keys"): pending entries a drain carried, drains a TRIM / TRIMAT / CLR
+# forced, regrows of the planes, one-row device gathers made for a read
+# whose drained base the host did not hold, and whole-row sorts of a
+# view by the Python read path.
 TALLIES = (
     "drain.TREG.bulk_rows",
     "drain.TREG.tie_rows",
+    "drain.TLOG.entries",
+    "drain.TLOG.trims",
+    "drain.TLOG.grows",
+    "drain.TLOG.row_gathers",
+    "drain.TLOG.view_sorts",
 )
 
 # Node-wide gauges (per-peer convergence lag lives on the Cluster and

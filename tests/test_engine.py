@@ -156,7 +156,7 @@ def test_tlog_reads_never_drain(db, monkeypatch):
 
     calls = {"n": 0}
     monkeypatch.setattr(
-        repo_tlog, "_drain",
+        repo_tlog, "_drain_tlog",
         lambda *a: calls.__setitem__("n", calls["n"] + 1),
     )
     monkeypatch.setattr(
@@ -191,12 +191,12 @@ def test_tlog_quiescent_reads_skip_device(db, monkeypatch):
     calls = {"get_row": 0, "drain": 0}
     monkeypatch.setattr(
         repo_tlog,
-        "_get_row",
+        "_get_row_tlog",
         lambda *a: calls.__setitem__("get_row", calls["get_row"] + 1),
     )
     monkeypatch.setattr(
         repo_tlog,
-        "_drain",
+        "_drain_tlog",
         lambda *a: calls.__setitem__("drain", calls["drain"] + 1),
     )
     for _ in range(3):
