@@ -9,9 +9,14 @@ into that same dispatch (the kernel's per-row count column), and their
 returned (length, cutoff) pairs maintain the host caches. Reads never
 drain: GET/SIZE/CUTOFF serve the exact merged view (union + dedup +
 cutoff filter over the drained base and the pending buffer, memoised per
-row); the only device touch a read can make is the one-row gather that
-rebuilds the render base after a drain whose merged view was not
-current, and a quiescent read performs zero device calls.
+row). A drain keeps the row it drained: its epilogue folds the pending
+window into the drained base the host holds (tlog_table.py
+``finish_row``), so no read goes back to the device for a row, a
+restored row's first read included. The only device touch a read can
+make is the repair path: the one-row gather that rebuilds the base after
+a drain whose folded base failed the length guard
+(``drain.TLOG.bases_lost`` counts those, ``drain.TLOG.row_gathers`` the
+gathers).
 
 Host bookkeeping (keys, pending windows, length/cutoff caches, the
 merged-view memo, delta accumulators) lives behind the table backends in
@@ -274,9 +279,10 @@ class RepoTLOG:
         common case: the drain kept the exact row content host-side); only
         a base-invalid row pays the ONE device row gather — and then
         REPAIRS the table's base from it (ADVICE round 5): without the
-        repair a quiescent row whose drain landed while the merged memo
-        was stale would serve correctly but never settle natively again,
-        paying the FFI stop + Python dispatch on every later GET."""
+        repair a quiescent row whose drain lost its base (the length
+        guard of ``finish_row``) would serve correctly but never settle
+        natively again, paying the FFI stop + Python dispatch on every
+        later GET."""
         ents = self._render.get(row)
         if ents is None:
             length = self._tbl.len_cache(row)
@@ -304,8 +310,8 @@ class RepoTLOG:
 
     def _size_nonquiescent(self, row: int) -> int:
         """Merged-view size with the drained-base handshake: the table
-        serves it host-side unless its base is unknown (a drain landed
-        while the merged memo was stale), in which case ONE device row
+        serves it host-side unless its base is unknown (a drain lost
+        it: `drain.TLOG.bases_lost`), in which case ONE device row
         gather rebuilds it (_drained_entries also writes it back as the
         table's base)."""
         n = self._tbl.size(row)
@@ -579,12 +585,16 @@ class RepoTLOG:
 
     def _finish_drain(self, updates) -> None:
         """Common drain epilogue: refresh the per-row host caches from the
-        kernel's (row, length, cutoff) read-backs, then clear pending."""
+        kernel's (row, length, cutoff) read-backs, then clear pending.
+        A row the table could not keep a host base for is a later read's
+        device gather."""
+        lost = 0
         for row, ln, ct in updates:
             self._render.pop(row, None)
             self._sorted.pop(row, None)
-            self._tbl.finish_row(row, int(ln), int(ct))
+            lost += not self._tbl.finish_row(row, int(ln), int(ct))
             self._longest = max(self._longest, int(ln))
+        resolve_registry(self).tally("drain.TLOG.bases_lost", lost)
         self._tbl.finish_drain_end()
         self._warm_ahead()
 

@@ -16,12 +16,20 @@ Semantics mirror repo_tlog.pony:16-111 via docs tlog.md: entries dedup on
 (ts, value), cutoffs are grow-only and filter the view, TRIM/CLR raise
 cutoffs. The merged view (drained ∪ pending, deduped, cutoff-filtered) is
 memoised per row with the exact state-key discipline the round-4 repo
-used; additionally the drained "base" CARRIES ACROSS drains — when the
-memo is current at drain time, the post-drain row content equals the memo
-filtered by the returned cutoff (the device performs the same lattice
-join), so reads keep serving host-side without a device gather. A length
-mismatch at that handoff invalidates the base (``size`` then returns -1
-and the repo rebuilds it from one device row gather via ``set_base``).
+used; additionally the drained "base" CARRIES ACROSS drains: a drain's
+epilogue (``finish_row``) folds the row's pending window into the base
+the host holds. The post-drain row content equals the merged memo (base
+and pend, each filtered by the cutoff view, deduplicated) filtered by
+the cutoff the device returned, because the device performs the same
+lattice join; a memo that is not current at drain time (after
+``set_base``, a cutoff raise or a foreign ``converge_entry``) is rebuilt
+first, by the helper ``size`` uses. So reads keep serving host-side
+without a device gather, a restored row's first read included. The one
+guard: the folded base is kept only when its size equals the length the
+device computed. A mismatch, or a base that was already unknown, loses
+the base (``finish_row`` returns False, the repo counts
+``drain.TLOG.bases_lost``); ``size`` then returns -1 and the repo
+rebuilds the base from one device row gather via ``set_base``.
 """
 
 from __future__ import annotations
@@ -170,6 +178,13 @@ class PyTlogTable:
             return len(r.memo)
         if not r.base_valid:
             return -1
+        self._rebuild_memo(r)
+        return len(r.memo)
+
+    def _rebuild_memo(self, r: _Row) -> None:
+        """The merged view from what the host holds: a valid base and the
+        pending window, each filtered by the cutoff view, deduplicated on
+        (ts, value)."""
         cut = max(r.pend_cutoff, r.cut_cache)
         r.memo = {e for e in r.base if e[0] >= cut}
         r.memo.update(e for e in r.pend if e[0] >= cut)
@@ -177,7 +192,6 @@ class PyTlogTable:
         r.memo_plen = len(r.pend)
         r.memo_cut = cut
         r.gen += 1
-        return len(r.memo)
 
     def merged_entries(self, row: int):
         r = self._rows[row]
@@ -250,8 +264,12 @@ class PyTlogTable:
     def export_pend_bulk(self, rows: list[int]):
         return {r: list(self._rows[r].pend) for r in rows}
 
-    def finish_row(self, row: int, length: int, cut: int) -> None:
+    def finish_row(self, row: int, length: int, cut: int) -> bool:
+        """Drain epilogue for one row: the device reported (length, cut).
+        Returns whether the host still holds the row's drained base."""
         r = self._rows[row]
+        if r.base_valid and not self._memo_current(r):
+            self._rebuild_memo(r)
         if self._memo_current(r):
             r.base = [e for e in r.memo if e[0] >= cut]
             r.base_valid = len(r.base) == length
@@ -275,6 +293,7 @@ class PyTlogTable:
             r.memo_valid = False
             r.memo = set()
         r.gen += 1
+        return r.base_valid
 
     def finish_drain_end(self) -> None:
         for row in self._touched:
@@ -414,8 +433,8 @@ class NativeTlogTable:
     def export_pend_bulk(self, rows: list[int]):
         return self._eng.tlog_export_pend_bulk(rows)
 
-    def finish_row(self, row: int, length: int, cut: int) -> None:
-        self._eng.tlog_finish_row(row, int(length), int(cut))
+    def finish_row(self, row: int, length: int, cut: int) -> bool:
+        return self._eng.tlog_finish_row(row, int(length), int(cut))
 
     def finish_drain_end(self) -> None:
         self._eng.tlog_finish_end()

@@ -117,7 +117,7 @@ def _declare(c: ctypes.CDLL) -> None:
         "jy_tlog_export_pend": (i64, [vp, i64, vp, vp, i64]),
         "jy_tlog_val": (None, [vp, i32, pvp, pi64]),
         "jy_tlog_intern": (i32, [vp, u8p, i64]),
-        "jy_tlog_finish_row": (None, [vp, i64, i64, u64]),
+        "jy_tlog_finish_row": (i32, [vp, i64, i64, u64]),
         "jy_tlog_finish_end": (None, [vp]),
         "jy_tlog_set_base": (None, [vp, i64, i64, vp, vp]),
         "jy_tlog_export_merged": (i64, [vp, i64, vp, vp, i64]),
@@ -569,8 +569,9 @@ class ServeEngine:
     def tlog_intern(self, value: bytes) -> int:
         return self._lib.jy_tlog_intern(self._h, value, len(value))
 
-    def tlog_finish_row(self, row: int, length: int, cut: int) -> None:
-        self._lib.jy_tlog_finish_row(self._h, row, length, cut)
+    def tlog_finish_row(self, row: int, length: int, cut: int) -> bool:
+        """True when the host still holds the row's drained base."""
+        return bool(self._lib.jy_tlog_finish_row(self._h, row, length, cut))
 
     def tlog_finish_end(self) -> None:
         self._lib.jy_tlog_finish_end(self._h)

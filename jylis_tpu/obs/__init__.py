@@ -106,15 +106,17 @@ SEAMS = (
 # what `drain.TLOG`'s batches and keys cannot tell apart (a drain of 8
 # rows with 1 pending entry each and one of 8 rows with 500 each are both
 # "8 keys"): pending entries a drain carried, drains a TRIM / TRIMAT / CLR
-# forced, regrows of the planes, one-row device gathers made for a read
-# whose drained base the host did not hold, and whole-row sorts of a
-# view by the Python read path.
+# forced, regrows of the planes, rows a drain left without a host base
+# (its fold failed the length guard), one-row device gathers made for a
+# read whose drained base the host did not hold (every one has a lost
+# base before it), and whole-row sorts of a view by the Python read path.
 TALLIES = (
     "drain.TREG.bulk_rows",
     "drain.TREG.tie_rows",
     "drain.TLOG.entries",
     "drain.TLOG.trims",
     "drain.TLOG.grows",
+    "drain.TLOG.bases_lost",
     "drain.TLOG.row_gathers",
     "drain.TLOG.view_sorts",
 )

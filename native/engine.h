@@ -558,6 +558,14 @@ struct TlogTable {
         if (quiescent(r)) return r.len_cache;
         if (memo_current(r)) return static_cast<int64_t>(r.memo.size());
         if (!r.base_valid) return -1;
+        rebuild_memo(r);
+        return static_cast<int64_t>(r.memo.size());
+    }
+
+    // the merged view from what the host holds: a valid base and the
+    // pending window, each filtered by the cutoff view, deduplicated on
+    // (ts, value id)
+    void rebuild_memo(TlogRow& r) {
         uint64_t cut = cutoff_view(r);
         r.memo.clear();
         for (const TlogEnt& e : r.base)
@@ -568,7 +576,6 @@ struct TlogTable {
         r.memo_plen = static_cast<int64_t>(r.pend.size());
         r.memo_cut = cut;
         r.gen++;
-        return static_cast<int64_t>(r.memo.size());
     }
 
     // the merged view sorted (ts, value-bytes) desc — TLOG GET's serving
@@ -603,12 +610,16 @@ struct TlogTable {
         std::vector<TlogEnt>().swap(r.sorted_view);
     }
 
-    // drain epilogue for one drained row: device reported (len, cut)
-    void finish_drain_row(int64_t row_i, int64_t len, uint64_t cut) {
+    // drain epilogue for one drained row: device reported (len, cut).
+    // The pending window folds into the base the host holds (PyTlogTable.
+    // finish_row states the same rule): the post-drain row is the merged
+    // memo filtered by the returned cutoff, kept only when its size
+    // equals the device's length. Returns whether the base is still held.
+    bool finish_drain_row(int64_t row_i, int64_t len, uint64_t cut) {
         TlogRow& r = rows[row_i];
         drop_sorted(r);  // free rather than wait for the gen-key miss
-        bool memo_cur = memo_current(r);
-        if (memo_cur) {
+        if (r.base_valid && !memo_current(r)) rebuild_memo(r);
+        if (memo_current(r)) {
             r.base.clear();
             for (const TlogEnt& e : r.memo)
                 if (e.ts >= cut) r.base.push_back(e);
@@ -635,6 +646,7 @@ struct TlogTable {
             r.memo.clear();
         }
         r.gen++;
+        return r.base_valid;
     }
 
     // global drain tail: mirrors repo_tlog.py _finish_drain's

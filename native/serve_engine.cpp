@@ -412,8 +412,9 @@ int32_t jy_tlog_intern(void* e, const uint8_t* v, int64_t n) {
     return static_cast<Engine*>(e)->tlog.intern(v, n);
 }
 
-void jy_tlog_finish_row(void* e, int64_t row, int64_t len, uint64_t cut) {
-    static_cast<Engine*>(e)->tlog.finish_drain_row(row, len, cut);
+int32_t jy_tlog_finish_row(void* e, int64_t row, int64_t len, uint64_t cut) {
+    TlogTable& t = static_cast<Engine*>(e)->tlog;
+    return t.finish_drain_row(row, len, cut) ? 1 : 0;
 }
 
 void jy_tlog_finish_end(void* e) {

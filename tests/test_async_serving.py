@@ -16,6 +16,7 @@ from jylis_tpu.utils.config import Config
 from jylis_tpu.utils.log import Log
 
 from test_server import send_recv
+from test_tlog_tallies import lose_base
 
 SLOW = 0.6  # seconds a slowed drain blocks its worker thread
 
@@ -262,9 +263,10 @@ def test_ujson_converge_path_is_bounded():
 
 
 def test_tlog_read_gather_offload_predicate():
-    """The first GET/SIZE after a drain rebuilds the render base with a
-    device row gather: may_drain must route it to the worker thread; a
-    quiescent cached read stays inline."""
+    """A drain keeps the row it drained, so a read after it stays inline;
+    only a row whose base a drain LOST (the length guard) rebuilds it with
+    a device row gather, and may_drain must route that read to the worker
+    thread."""
     from jylis_tpu.models.repo_tlog import RepoTLOG
 
     repo = RepoTLOG(identity=1, mesh=None)
@@ -274,12 +276,19 @@ def test_tlog_read_gather_offload_predicate():
             return lambda *a: None
 
     repo.apply(_Null(), [b"INS", b"k", b"v1", b"5"])
-    repo.drain()  # render cache for the row is now dropped
+    repo.drain()  # the render cache is dropped, the base folded and kept
+    assert not repo.may_drain([b"GET", b"k"])
+    assert not repo.may_drain([b"SIZE", b"k"])
+    assert not repo.may_drain([b"GET", b"missing"])
+    repo.converge(b"k", ([(b"v2", 6)], 0))  # pending: merged on the host
+    assert not repo.may_drain([b"SIZE", b"k"])
+    repo.drain()
+    assert not repo.may_drain([b"GET", b"k"])
+    lose_base(repo, b"k")  # the guard fails
     assert repo.may_drain([b"GET", b"k"])
     assert not repo.may_drain([b"SIZE", b"k"])  # quiescent: O(1) len cache
-    assert not repo.may_drain([b"GET", b"missing"])
-    repo.converge(b"k", ([(b"v2", 6)], 0))  # pending: SIZE must merge now
+    repo.converge(b"k", ([(b"v3", 7)], 0))  # pending: SIZE must merge now
     assert repo.may_drain([b"SIZE", b"k"])
-    repo.apply(_Null(), [b"GET", b"k"])  # rebuilds the render cache
+    repo.apply(_Null(), [b"GET", b"k"])  # gathers once, repairs the base
     assert not repo.may_drain([b"GET", b"k"])
     assert not repo.may_drain([b"SIZE", b"k"])

@@ -24,6 +24,8 @@ from jylis_tpu.models.repo_treg import RepoTREG
 from jylis_tpu.models.repo_ujson import RepoUJSON
 from jylis_tpu.native.engine import make_engine
 
+from test_tlog_tallies import lose_base
+
 
 class R:
     def __init__(self):
@@ -257,6 +259,69 @@ def test_tlog_merged_view_fuzz_vs_drain_rebuilt(engine, seed):
         assert pre_get.vals == post_get.vals
 
 
+def test_tlog_drain_fold_differential_native_vs_python():
+    """3,000 mixed commands on the native and the Python table at once:
+    after every drain the two agree on which rows still hold their base,
+    on every length and on the GET bytes, and no read ever went back to
+    the device for a row (the drain's epilogue folds the pending window
+    into the base: `finish_row` / `finish_drain_row` state one rule)."""
+    from jylis_tpu.obs.registry import MetricsRegistry
+
+    rng = np.random.default_rng(31)
+    native, oracle = _tlog_pair()
+    regs = []
+    for repo in (native, oracle):
+        repo.metrics = MetricsRegistry()
+        regs.append(repo.metrics)
+    keys = [b"t%d" % i for i in range(8)]
+
+    def after_drain():
+        for k in keys:
+            rn, ro = native._tbl.find(k), oracle._tbl.find(k)
+            assert (rn < 0) == (ro < 0)
+            if rn < 0:
+                continue
+            assert native._tbl.base_valid(rn) and oracle._tbl.base_valid(ro), k
+            assert native._tbl.len_cache(rn) == oracle._tbl.len_cache(ro), k
+            assert _oracle_reply(native, [b"GET", k]) == _oracle_reply(oracle, [b"GET", k]), k
+        for reg in regs:
+            assert reg.tallies["drain.TLOG.row_gathers"] == 0
+            assert reg.tallies["drain.TLOG.bases_lost"] == 0
+
+    drains = 0
+    for step in range(3000):
+        k = keys[rng.integers(len(keys))]
+        roll = rng.integers(20)
+        if roll < 6:  # duplicates on purpose: small ts and value ranges collide
+            both(native, oracle, [b"INS", k, b"e%d" % rng.integers(12), b"%d" % rng.integers(1, 200)])
+        elif roll < 10:
+            both(native, oracle, [b"GET", k, b"%d" % rng.integers(1, 30)])
+        elif roll < 12:
+            both(native, oracle, [b"SIZE", k])
+        elif roll < 15:
+            ents = [(b"r%d" % rng.integers(12), int(rng.integers(1, 200))) for _ in range(rng.integers(1, 6))]
+            cut = int(rng.integers(0, 2) * rng.integers(1, 120))
+            native.converge(k, (ents, cut))
+            oracle.converge(k, (ents, cut))
+        else:
+            if roll == 15:
+                both(native, oracle, [b"TRIMAT", k, b"%d" % rng.integers(1, 150)])
+            elif roll == 16:
+                both(native, oracle, [b"TRIM", k, b"%d" % rng.integers(1, 12)])
+            elif roll == 17 and rng.integers(4) == 0:
+                both(native, oracle, [b"CLR", k])
+            else:
+                native.drain()
+                oracle.drain()
+            drains += 1
+            after_drain()
+    assert drains > 500
+    native.drain()
+    oracle.drain()
+    after_drain()
+    assert native.dump_state() == oracle.dump_state()
+
+
 def test_tlog_native_value_interner_stays_flat_under_churn():
     """INS/TRIM churn of ever-fresh values must not grow the native
     value table without bound (engine.h TlogTable::compact_values; the
@@ -366,13 +431,16 @@ def test_scan_apply_tlog_get_and_cutoff_byte_match_oracle():
 
 
 def test_scan_apply_tlog_get_defers_when_base_unknown():
-    """A drain that lands while the merged memo is stale leaves the
+    """A drain whose folded base fails the length guard leaves the
     drained base unknown (finish_drain_row) — the native GET must bounce
     to Python, whose path pays the one-row device gather; SIZE keeps
     serving natively from the length cache."""
     native = RepoTLOG(identity=1)
-    native.converge(b"k", ([(b"v", 7)], 0))  # no memo upkeep on converge
+    native.converge(b"k", ([(b"v", 7)], 0))
     native.drain()
+    # a converge + drain keeps the base: only a failed guard loses it
+    assert native._tbl.base_valid(native._tbl.find(b"k"))
+    lose_base(native, b"k")
     rc, consumed, replies, unhandled, _ = native.engine.scan_apply(
         bytearray(b"TLOG GET k\r\n")
     )
