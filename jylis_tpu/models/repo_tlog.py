@@ -47,12 +47,7 @@ from ..parallel import (
     shard_vec,
 )
 from .base import PAD_ROW, ParseError, bucket, need, parse_opt_count, parse_u64
-from .tlog_table import (
-    NativeTlogTable,
-    PENDING_DRAIN_THRESHOLD,
-    PyTlogTable,
-    ROW_DRAIN_THRESHOLD,
-)
+from .tlog_table import NativeTlogTable, PyTlogTable
 from ..utils.metrics import (
     ASSEMBLE,
     DEVICE,
@@ -76,9 +71,22 @@ COMPACT_SLACK = 8192
 # TRIM-forced drain carries the few rows written since the last one, a
 # few entries each) and goes up in powers of FOUR: at most four times
 # the work of the exact size, a quarter of the programs of a power-of-two
-# lattice that started at 1.
+# lattice that started at 1. Only the floor's program is ever compiled
+# ahead (`warm_drain_shapes`, `_warm_ahead`), and a node that has it runs
+# nothing else: a drain that outgrows the floor's batch runs as passes of
+# its shape (`_passes`), and the table calls a drain overdue once as many
+# entries are pending, over all rows, as ONE floor batch holds
+# (`set_entries_bound`: DRAIN_ROWS_FLOOR x DRAIN_WIDTH_FLOOR), so that a
+# replica whose cutoffs all come from peers drains every few seconds in a
+# dozen passes, not after a row has gathered a thousand entries in a
+# hundred. The lattice above the floor is for a node with nothing compiled
+# ahead: the boot's restore, a young keyspace, the mesh.
 DRAIN_ROWS_FLOOR = 64
 DRAIN_WIDTH_FLOOR = 16
+# passes dispatched before the host waits for one: each holds a copy of
+# the planes on the device until the next has read it (the drain is not
+# donated), so a rejoin's hundreds of passes must not all be in flight
+PASSES_IN_FLIGHT = 4
 # Compiling ahead: once the longest row passes WARM_FILL of len_cap the
 # programs of the NEXT len_cap are compiled, off the lock, so that the
 # `grow` that row will force meets them ready. Planes narrower than
@@ -235,10 +243,7 @@ class RepoTLOG:
             ts = parse_u64(need(args, 3))
             row = self._tbl.upsert(key)
             self._tbl.ins(row, ts, value)
-            if (
-                self._tbl.pend_len(row) >= ROW_DRAIN_THRESHOLD
-                or self._tbl.pend_rows_count() >= PENDING_DRAIN_THRESHOLD
-            ):
+            if self._tbl.overdue():
                 self.drain()
             resp.ok()
             return True
@@ -382,7 +387,10 @@ class RepoTLOG:
         row = self._tbl.upsert(key)
         for value, ts in entries:
             self._tbl.converge_entry(row, ts, value)
-        if cutoff:
+        reg = resolve_registry(self)
+        reg.tally("drain.TLOG.foreign_entries", len(entries))
+        if cutoff and cutoff > self._tbl.cutoff_view(row):
+            reg.tally("drain.TLOG.foreign_cutoffs", 1)
             self._tbl.converge_cutoff(row, cutoff)
 
     def deltas_size(self) -> int:
@@ -404,10 +412,7 @@ class RepoTLOG:
         if op == b"INS" and len(args) >= 2:
             row = self._tbl.find(args[1])
             in_row = self._tbl.pend_len(row) if row >= 0 else 0
-            return (
-                in_row + 1 >= ROW_DRAIN_THRESHOLD
-                or self._tbl.pend_rows_count() + 1 >= PENDING_DRAIN_THRESHOLD
-            )
+            return self._tbl.ins_tips(in_row)
         if op in (b"GET", b"SIZE") and len(args) >= 2:
             row = self._tbl.find(args[1])
             if row < 0:
@@ -425,12 +430,9 @@ class RepoTLOG:
 
     def drain_overdue(self) -> bool:
         """Cluster converge path: after buffering a batch, the manager
-        offloads the drain to a worker thread when any threshold trips.
-        O(1): the table flags row-threshold crossings as it appends."""
-        return (
-            self._tbl.row_overdue()
-            or self._tbl.pend_rows_count() >= PENDING_DRAIN_THRESHOLD
-        )
+        offloads the drain to a worker thread when a bound of the table
+        trips. O(1): the table keeps the counts as it appends."""
+        return self._tbl.overdue()
 
     def flush_deltas(self):
         return self._tbl.flush_deltas()
@@ -530,6 +532,13 @@ class RepoTLOG:
         self._key_cap, self._len_cap = key_cap, len_cap
         self._state = self._place(grow(self._state, key_cap, len_cap))
         resolve_registry(self).tally("drain.TLOG.grows", 1)
+        self._bound_drains()
+
+    def _bound_drains(self) -> None:
+        """Once the floor's program is compiled for the planes as they
+        are, a drain is overdue at the entries one floor batch holds."""
+        if (self._key_cap, self._len_cap) in self._warmed:
+            self._tbl.set_entries_bound(DRAIN_ROWS_FLOOR * DRAIN_WIDTH_FLOOR)
 
     def _warm_levels(self, state: tlog.TLogState, first: int, last: int) -> None:
         """Compile what a serving drain runs at the plane widths
@@ -565,6 +574,7 @@ class RepoTLOG:
         if self._longest > WARM_FILL * self._len_cap:
             self._grow(self._key_cap, 2 * self._len_cap)
         self._warm_levels(self._state, 0, 1)
+        self._bound_drains()
 
     def _warm_ahead(self) -> None:
         """After a drain of a booted single-chip node: once the longest
@@ -583,11 +593,11 @@ class RepoTLOG:
             return
         self._warming = _WARM_POOL.submit(self._warm_levels, self._state, 1, 1)
 
-    def _finish_drain(self, updates) -> None:
-        """Common drain epilogue: refresh the per-row host caches from the
-        kernel's (row, length, cutoff) read-backs, then clear pending.
-        A row the table could not keep a host base for is a later read's
-        device gather."""
+    def _finish_rows(self, updates) -> None:
+        """A drain's epilogue for the rows it is done with: refresh the
+        per-row host caches from the kernel's (row, length, cutoff)
+        read-backs and clear their pending windows. A row the table
+        could not keep a host base for is a later read's device gather."""
         lost = 0
         for row, ln, ct in updates:
             self._render.pop(row, None)
@@ -595,18 +605,24 @@ class RepoTLOG:
             lost += not self._tbl.finish_row(row, int(ln), int(ct))
             self._longest = max(self._longest, int(ln))
         resolve_registry(self).tally("drain.TLOG.bases_lost", lost)
-        self._tbl.finish_drain_end()
-        self._warm_ahead()
 
     @timed_drain("TLOG", lambda self: self._tbl.touched_count())
     def drain(self, trim: tuple[int, int] | None = None) -> None:
-        """Flush pending entries/cutoffs in one dispatch; with ``trim``
-        = (row, count), the TRIM/CLR of that row fuses into the SAME
-        dispatch via the kernel's per-row count column (counts of
-        TRIM_NOOP leave other rows untouched)."""
+        """Flush pending entries/cutoffs; with ``trim`` = (row, count),
+        the TRIM/CLR of that row fuses into the dispatch that carries
+        the last of its entries, via the kernel's per-row count column
+        (counts of TRIM_NOOP leave other rows untouched). One dispatch,
+        or the passes of `_passes` on a node whose floor program is
+        compiled ahead."""
         row_set = set(self._tbl.touched_rows())
         if not row_set and trim is None:
             return
+        reg = resolve_registry(self)
+        if self._tbl.overdue():
+            # a bound tripped (the INS that crossed it, the batch a peer
+            # sent, a restore): every trim drains at once, under the
+            # lock that buffered its cutoff, so none finds this set
+            reg.tally("drain.TLOG.overdue", 1)
         self._maybe_compact_interner()
         if trim is not None:
             row_set.add(trim[0])
@@ -629,78 +645,107 @@ class RepoTLOG:
         lcap = bucket(max(need_len, 1), self._len_cap)
         if kcap != self._key_cap or lcap != self._len_cap:
             self._grow(kcap, lcap)
-        resolve_registry(self).tally(
+        reg.tally(
             "drain.TLOG.entries", sum(len(pend.get(r, ())) for r in rows)
         )
         if self._mesh is not None:
             self._drain_sharded(rows, pend, cuts_in, trim)
             return
+        batches = [
+            self._batch(chunk, lo, hi, pend, cuts_in, trim)
+            for chunk, lo, hi in self._passes(rows, pend)
+        ]
+        reg.tally("drain.TLOG.passes", len(batches))
+        drain_phase(self, DEVICE)
         while True:
-            ld = drain_bucket(
-                max((len(pend.get(r, ())) for r in rows), default=1),
-                DRAIN_WIDTH_FLOOR,
-            )
-            # dense path (repo_counters precedent): when the batch covers a
-            # quarter of the keyspace and rows are narrow, aligned delta
-            # rows skip the gather/scatter entirely
-            dense = len(rows) * 4 >= self._key_cap and ld <= 64
-            if dense:
-                kc = self._key_cap
-                d_ts = np.zeros((kc, ld), np.uint64)
-                d_vid = np.full((kc, ld), -1, np.int64)
-                d_cut = np.zeros(kc, np.uint64)
-                for row in rows:
-                    for j, (ts, value) in enumerate(pend.get(row, ())):
-                        d_ts[row, j] = ts
-                        d_vid[row, j] = self._interner.intern(value)
-                    d_cut[row] = cuts_in.get(row, 0)
-                tb = bucket(1)
-                trim_ki = np.full(tb, PAD_ROW, np.int32)
-                counts = np.full(tb, tlog.TRIM_NOOP, np.int64)
-                if trim is not None:
-                    trim_ki[0], counts[0] = trim
-                drain_phase(self, DEVICE)
-                new_state, ovf, lens, cuts = _drain_tlog_dense(
-                    self._state, d_ts, d_vid, d_cut, trim_ki, counts
-                )
-                # check EVERY row: the dense kernel flags any row whose
-                # entries reach into the tail columns the delta writes
-                # through, including rows with no pending delta
-                if bool(np.asarray(ovf).any()):
-                    drain_phase(self, ASSEMBLE)  # the retry builds anew
-                    self._grow(self._key_cap, 2 * self._len_cap)
-                    continue
-                self._state = new_state
-                lens = np.asarray(lens)
-                cuts = np.asarray(cuts)
-                drain_phase(self, FINISH)
-                self._finish_drain((r, lens[r], cuts[r]) for r in rows)
-                return
+            # every pass is dispatched before any result is read: the
+            # device runs them back to back, the host waits once
+            state, outs = self._state, []
+            for n, (program, args, _done) in enumerate(batches, 1):
+                state, *out = program(state, *args)
+                outs.append(out)
+                if n % PASSES_IN_FLIGHT == 0:
+                    out[0].block_until_ready()
+            outs = [[np.asarray(a) for a in out] for out in outs]
+            if not any(out[0].any() for out in outs):
+                break
+            # a row outgrew its slots (the dense kernel flags every row
+            # whose entries reach the tail columns the delta writes
+            # through, pending or not): again from the retained
+            # pre-merge state, with the slots doubled
+            self._grow(self._key_cap, 2 * self._len_cap)
+        self._state = state
+        drain_phase(self, FINISH)
+        for (_program, _args, done), (_ovf, lens, cuts) in zip(batches, outs):
+            self._finish_rows((r, lens[i], cuts[i]) for r, i in done.items())
+        self._tbl.finish_drain_end()
+        self._warm_ahead()
+
+    def _passes(self, rows, pend) -> list[tuple[list[int], int, int]]:
+        """What a single-chip drain dispatches, as (rows, lo, hi): the
+        entries ``pend[row][lo:hi]`` of each row of the chunk. ONE pass
+        takes everything, padded to the lattice, unless the floor's
+        program is compiled for these planes and the batch outgrows it:
+        then the rows go DRAIN_ROWS_FLOOR at a time, DRAIN_WIDTH_FLOOR
+        entries of each, level by level while any row has entries left.
+        A log is a set and a cutoff a maximum, so the passes leave the
+        state one dispatch would."""
+        widest = max((len(pend.get(r, ())) for r in rows), default=0)
+        if (self._key_cap, self._len_cap) not in self._warmed or (
+            len(rows) <= DRAIN_ROWS_FLOOR and widest <= DRAIN_WIDTH_FLOOR
+        ):
+            return [(rows, 0, max(widest, 1))]
+        out, lo, live = [], 0, rows
+        while live:
+            out += [
+                (live[i : i + DRAIN_ROWS_FLOOR], lo, lo + DRAIN_WIDTH_FLOOR)
+                for i in range(0, len(live), DRAIN_ROWS_FLOOR)
+            ]
+            lo += DRAIN_WIDTH_FLOOR
+            live = [r for r in live if len(pend.get(r, ())) > lo]
+        return out
+
+    def _batch(self, rows, lo, hi, pend, cuts_in, trim):
+        """One pass as (program, its arguments after the state, {row:
+        index of the row in the program's length and cutoff outputs} of
+        the rows whose entries END in this pass): ``pend[row][lo:hi]`` and
+        the cutoff of every row of ``rows``. A row that ends here takes
+        its trim here and is finished from this pass's outputs (the host
+        folds its WHOLE pending window into the base it holds, which is
+        what the device row then is); one that goes on is left alone."""
+        ld = drain_bucket(hi - lo, DRAIN_WIDTH_FLOOR)
+        # dense path (repo_counters precedent): when the batch covers a
+        # quarter of the keyspace and rows are narrow, aligned delta
+        # rows skip the gather/scatter entirely
+        dense = len(rows) * 4 >= self._key_cap and ld <= 64
+        if dense:
+            kc = self._key_cap
+            d_ts = np.zeros((kc, ld), np.uint64)
+            d_vid = np.full((kc, ld), -1, np.int64)
+            d_cut = np.zeros(kc, np.uint64)
+            slot = {row: row for row in rows}
+        else:
             b = drain_bucket(len(rows), DRAIN_ROWS_FLOOR)
             ki, d_ts, d_vid, d_cut, counts = sparse_batch(b, ld)
-            for i, row in enumerate(rows):
-                ki[i] = row
-                for j, (ts, value) in enumerate(pend.get(row, ())):
-                    d_ts[i, j] = ts
-                    d_vid[i, j] = self._interner.intern(value)
-                d_cut[i] = cuts_in.get(row, 0)
-                if trim is not None and row == trim[0]:
-                    counts[i] = trim[1]
-            drain_phase(self, DEVICE)
-            new_state, ovf, lens, cuts = _drain_tlog(
-                self._state, ki, d_ts, d_vid, d_cut, counts
-            )
-            if bool(np.asarray(ovf)[: len(rows)].any()):
-                # retry from the retained pre-merge state with doubled slots
-                drain_phase(self, ASSEMBLE)
-                self._grow(self._key_cap, 2 * self._len_cap)
-                continue
-            self._state = new_state
-            lens = np.asarray(lens)
-            cuts = np.asarray(cuts)
-            drain_phase(self, FINISH)
-            self._finish_drain(zip(rows, lens, cuts))
-            return
+            ki[: len(rows)] = rows
+            slot = {row: i for i, row in enumerate(rows)}
+        for row, i in slot.items():
+            for j, (ts, value) in enumerate(pend.get(row, ())[lo:hi]):
+                d_ts[i, j] = ts
+                d_vid[i, j] = self._interner.intern(value)
+            d_cut[i] = cuts_in.get(row, 0)
+        done = {r: slot[r] for r in rows if len(pend.get(r, ())) <= hi}
+        fused = trim is not None and trim[0] in done
+        if dense:
+            tb = bucket(1)
+            trim_ki = np.full(tb, PAD_ROW, np.int32)
+            counts = np.full(tb, tlog.TRIM_NOOP, np.int64)
+            if fused:
+                trim_ki[0], counts[0] = trim
+            return _drain_tlog_dense, (d_ts, d_vid, d_cut, trim_ki, counts), done
+        if fused:
+            counts[slot[trim[0]]] = trim[1]
+        return _drain_tlog, (ki, d_ts, d_vid, d_cut, counts), done
 
     def _drain_sharded(self, rows, pend, cuts_in, trim=None) -> None:
         """Mesh-mode drain: per-row deltas route as u64 payload columns
@@ -742,9 +787,10 @@ class RepoTLOG:
             self._state = tlog.TLogState(*out[:5])
             lens, cuts = np.asarray(out[6]), np.asarray(out[7])
             drain_phase(self, FINISH)
-            self._finish_drain(
+            self._finish_rows(
                 (int(g), lens[j], cuts[j])
                 for j, g in enumerate(slots)
                 if g >= 0
             )
+            self._tbl.finish_drain_end()
             return

@@ -22,8 +22,9 @@ the host holds. The post-drain row content equals the merged memo (base
 and pend, each filtered by the cutoff view, deduplicated) filtered by
 the cutoff the device returned, because the device performs the same
 lattice join; a memo that is not current at drain time (after
-``set_base``, a cutoff raise or a foreign ``converge_entry``) is rebuilt
-first, by the helper ``size`` uses. So reads keep serving host-side
+``set_base`` or a cutoff raise; an entry, local or foreign, keeps a
+current memo current) is rebuilt first, by the helper ``size`` uses. So
+reads keep serving host-side
 without a device gather, a restored row's first read included. The one
 guard: the folded base is kept only when its size equals the length the
 device computed. A mismatch, or a base that was already unknown, loses
@@ -34,9 +35,13 @@ rebuilds the base from one device row gather via ``set_base``.
 
 from __future__ import annotations
 
-# drain thresholds; native/engine.h TlogTable must match
+# drain thresholds; native/engine.h TlogTable must match: pending entries
+# on one row, rows with pending entries. A third bound, on the pending
+# entries of ALL rows, is off until the repo sets it (`set_entries_bound`:
+# what one batch of the drain program it compiled ahead holds)
 ROW_DRAIN_THRESHOLD = 1024
 PENDING_DRAIN_THRESHOLD = 4096
+NO_ENTRIES_BOUND = 1 << 62
 
 
 class _Row:
@@ -68,6 +73,7 @@ class PyTlogTable:
     __slots__ = (
         "_keys", "_rkeys", "_rows", "_pend_rows_count", "_row_overdue",
         "_delta_rows", "_touched", "_live_total", "_sync_dirty",
+        "_pend_total", "entries_bound",
     )
 
     def __init__(self):
@@ -80,6 +86,13 @@ class PyTlogTable:
         self._touched: list[int] = []  # rows with pend or pend_cutoff
         self._live_total = 0  # sum of len_cache over all rows
         self._sync_dirty: dict[int, None] = {}  # since last digest pass
+        self._pend_total = 0  # pending entries over all rows
+        self.entries_bound = NO_ENTRIES_BOUND
+
+    def set_entries_bound(self, n: int) -> None:
+        """A drain is overdue, too, once ``n`` entries are pending over
+        all rows."""
+        self.entries_bound = n
 
     # -- keys ---------------------------------------------------------------
 
@@ -128,6 +141,7 @@ class PyTlogTable:
         if not r.pend:
             self._pend_rows_count += 1
         r.pend.append(e)
+        self._pend_total += 1
         self._touch(r, row)
         if len(r.pend) >= ROW_DRAIN_THRESHOLD:
             self._row_overdue = True
@@ -135,6 +149,21 @@ class PyTlogTable:
     # -- mutations ------------------------------------------------------------
 
     def ins(self, row: int, ts: int, value: bytes) -> None:
+        """A local INS: buffered as a peer's entry is, and owed to the
+        peers in turn (the delta accumulator)."""
+        self.converge_entry(row, ts, value)
+        r = self._rows[row]
+        if ts >= r.cut_cache:
+            if not r.delta_present:
+                r.delta_present = True
+                self._delta_rows.append(row)
+            if ts >= r.delta_cutoff:
+                r.delta.add((ts, value))
+
+    def converge_entry(self, row: int, ts: int, value: bytes) -> None:
+        """Buffer one entry, a peer's or a client's: a memo that was
+        current stays current (one set insert), so the next read of the
+        row does not rebuild it from the whole base."""
         r = self._rows[row]
         e = (ts, value)
         self._append_pend(r, row, e)
@@ -148,18 +177,6 @@ class PyTlogTable:
                 if ts >= cut:
                     r.memo.add(e)
                 r.memo_plen = len(r.pend)
-                r.memo_cut = cut
-        if ts >= r.cut_cache:
-            if not r.delta_present:
-                r.delta_present = True
-                self._delta_rows.append(row)
-            if ts >= r.delta_cutoff:
-                r.delta.add(e)
-
-    def converge_entry(self, row: int, ts: int, value: bytes) -> None:
-        r = self._rows[row]
-        self._append_pend(r, row, (ts, value))
-        r.gen += 1
 
     def converge_cutoff(self, row: int, c: int) -> None:
         r = self._rows[row]
@@ -246,11 +263,28 @@ class PyTlogTable:
     def pend_len(self, row: int) -> int:
         return len(self._rows[row].pend)
 
-    def pend_rows_count(self) -> int:
-        return self._pend_rows_count
+    def pend_total(self) -> int:
+        return self._pend_total
 
-    def row_overdue(self) -> bool:
-        return self._row_overdue
+    def ins_tips(self, in_row: int) -> bool:
+        """Would one more INS on a row with ``in_row`` pending entries
+        make a drain due (conservative: the row may hold pending already)."""
+        return (
+            in_row + 1 >= ROW_DRAIN_THRESHOLD
+            or self._pend_rows_count + 1 >= PENDING_DRAIN_THRESHOLD
+            or self._pend_total + 1 >= self.entries_bound
+        )
+
+    def overdue(self) -> bool:
+        """A drain is due: a row reached ROW_DRAIN_THRESHOLD pending
+        entries (flagged as it was appended), PENDING_DRAIN_THRESHOLD
+        rows have pending entries, or ``entries_bound`` entries are
+        pending over all rows. O(1)."""
+        return (
+            self._row_overdue
+            or self._pend_rows_count >= PENDING_DRAIN_THRESHOLD
+            or self._pend_total >= self.entries_bound
+        )
 
     def touched_rows(self) -> list[int]:
         return list(self._touched)
@@ -282,10 +316,14 @@ class PyTlogTable:
         r.cut_cache = int(cut)
         if r.pend:
             self._pend_rows_count -= 1
+            self._pend_total -= len(r.pend)
         r.pend = []
         r.pend_cutoff = 0
         if r.base_valid:
-            r.memo = set(r.base)
+            # the base was filtered OUT of the memo: equal sizes, equal
+            # sets, and the memo stays as it is
+            if not r.memo_valid or len(r.memo) != len(r.base):
+                r.memo = set(r.base)
             r.memo_valid = True
             r.memo_plen = 0
             r.memo_cut = max(r.pend_cutoff, r.cut_cache)
@@ -306,6 +344,7 @@ class PyTlogTable:
             r.pend_cutoff = 0
         self._touched.clear()
         self._pend_rows_count = 0
+        self._pend_total = 0
         self._row_overdue = False
 
     # -- outbound deltas ------------------------------------------------------
@@ -344,10 +383,15 @@ class PyTlogTable:
 class NativeTlogTable:
     """The TLOG view over a shared native serving engine."""
 
-    __slots__ = ("_eng",)
+    __slots__ = ("_eng", "entries_bound")
 
     def __init__(self, engine):
         self._eng = engine
+        self.entries_bound = NO_ENTRIES_BOUND
+
+    def set_entries_bound(self, n: int) -> None:
+        self.entries_bound = n
+        self._eng.tlog_set_entries_bound(n)
 
     def rows(self) -> int:
         return self._eng.tlog_rows()
@@ -415,11 +459,14 @@ class NativeTlogTable:
     def pend_len(self, row: int) -> int:
         return self._eng.tlog_pend_len(row)
 
-    def pend_rows_count(self) -> int:
-        return self._eng.tlog_pend_rows_count()
+    def pend_total(self) -> int:
+        return self._eng.tlog_pend_total()
 
-    def row_overdue(self) -> bool:
-        return self._eng.tlog_row_overdue()
+    def ins_tips(self, in_row: int) -> bool:
+        return self._eng.tlog_ins_tips(in_row)
+
+    def overdue(self) -> bool:
+        return self._eng.tlog_overdue()
 
     def touched_rows(self) -> list[int]:
         return self._eng.tlog_touched_rows()

@@ -106,8 +106,10 @@ def _declare(c: ctypes.CDLL) -> None:
         "jy_tlog_quiescent": (i32, [vp, i64]),
         "jy_tlog_gen": (u64, [vp, i64]),
         "jy_tlog_pend_len": (i64, [vp, i64]),
-        "jy_tlog_pend_rows_count": (i64, [vp]),
-        "jy_tlog_row_overdue": (i32, [vp]),
+        "jy_tlog_overdue": (i32, [vp]),
+        "jy_tlog_ins_tips": (i32, [vp, i64]),
+        "jy_tlog_pend_total": (i64, [vp]),
+        "jy_tlog_set_entries_bound": (None, [vp, i64]),
         "jy_tlog_touched_rows": (i64, [vp, vp, i64]),
         "jy_tlog_touched_count": (i64, [vp]),
         "jy_tlog_export_base": (i64, [vp, i64, vp, vp, i64]),
@@ -476,11 +478,17 @@ class ServeEngine:
     def tlog_pend_len(self, row: int) -> int:
         return self._lib.jy_tlog_pend_len(self._h, row)
 
-    def tlog_pend_rows_count(self) -> int:
-        return self._lib.jy_tlog_pend_rows_count(self._h)
+    def tlog_overdue(self) -> bool:
+        return bool(self._lib.jy_tlog_overdue(self._h))
 
-    def tlog_row_overdue(self) -> bool:
-        return bool(self._lib.jy_tlog_row_overdue(self._h))
+    def tlog_ins_tips(self, in_row: int) -> bool:
+        return bool(self._lib.jy_tlog_ins_tips(self._h, in_row))
+
+    def tlog_pend_total(self) -> int:
+        return self._lib.jy_tlog_pend_total(self._h)
+
+    def tlog_set_entries_bound(self, n: int) -> None:
+        self._lib.jy_tlog_set_entries_bound(self._h, n)
 
     def tlog_touched_rows(self) -> list[int]:
         cap = 256

@@ -109,7 +109,10 @@ SEAMS = (
 # forced, regrows of the planes, rows a drain left without a host base
 # (its fold failed the length guard), one-row device gathers made for a
 # read whose drained base the host did not hold (every one has a lost
-# base before it), and whole-row sorts of a view by the Python read path.
+# base before it), and whole-row sorts of a view by the Python read path;
+# entries `converge` buffered (a peer's, a restore's, a journal replay's),
+# cutoffs it raised, drains that began with a bound of the table tripped
+# (not forced by a trim), and the dispatches drains were made of.
 TALLIES = (
     "drain.TREG.bulk_rows",
     "drain.TREG.tie_rows",
@@ -119,6 +122,10 @@ TALLIES = (
     "drain.TLOG.bases_lost",
     "drain.TLOG.row_gathers",
     "drain.TLOG.view_sorts",
+    "drain.TLOG.foreign_entries",
+    "drain.TLOG.foreign_cutoffs",
+    "drain.TLOG.overdue",
+    "drain.TLOG.passes",
 )
 
 # Node-wide gauges (per-peer convergence lag lives on the Cluster and

@@ -445,9 +445,27 @@ struct TlogTable {
     int64_t live_total = 0;  // sum of len_cache over all rows (O(1) reads)
     int64_t compact_floor;  // value-interner size below which no compact
 
-    static constexpr int64_t ROW_DRAIN_THRESHOLD = 1024;   // repo_tlog.py:40
+    static constexpr int64_t ROW_DRAIN_THRESHOLD = 1024;   // tlog_table.py
     static constexpr int64_t PENDING_DRAIN_THRESHOLD = 4096;
     static constexpr int64_t VAL_COMPACT_SLACK = 8192;
+    // a third bound, on the pending entries of ALL rows: off until the
+    // repo sets it to what one batch of the drain program it compiled
+    // ahead holds (tlog_table.py set_entries_bound)
+    int64_t pend_total = 0;
+    int64_t entries_bound = int64_t{1} << 62;
+
+    bool overdue() const {
+        return row_overdue || pend_rows_count >= PENDING_DRAIN_THRESHOLD ||
+               pend_total >= entries_bound;
+    }
+
+    // would one more INS on a row with `in_row` pending entries make a
+    // drain due (repo_tlog.py may_drain's predicate)
+    bool ins_tips(int64_t in_row) const {
+        return in_row + 1 >= ROW_DRAIN_THRESHOLD ||
+               pend_rows_count + 1 >= PENDING_DRAIN_THRESHOLD ||
+               pend_total + 1 >= entries_bound;
+    }
 
     TlogTable() : compact_floor(VAL_COMPACT_SLACK) {}
 
@@ -499,15 +517,31 @@ struct TlogTable {
     void append_pend(TlogRow& r, int64_t row_i, TlogEnt e) {
         if (r.pend.empty()) pend_rows_count++;
         r.pend.push_back(e);
+        pend_total++;
         touch(r, row_i);
         if (static_cast<int64_t>(r.pend.size()) >= ROW_DRAIN_THRESHOLD)
             row_overdue = true;
     }
 
-    // local INS (repo_tlog.py apply INS): pend append + memo upkeep
-    // (_note_local_insert) + delta insert when ts clears the drained
-    // cutoff
+    // local INS (repo_tlog.py apply INS): buffered as a peer's entry is,
+    // plus the delta insert when ts clears the drained cutoff
     void ins(int64_t row_i, uint64_t ts, const uint8_t* v, int64_t n) {
+        TlogEnt e = converge_entry(row_i, ts, v, n);
+        TlogRow& r = rows[row_i];
+        if (ts >= r.cut_cache) {
+            if (!r.delta_present) {
+                r.delta_present = true;
+                delta_rows.push_back(row_i);
+            }
+            if (ts >= r.delta_cutoff) r.delta.insert(e);
+        }
+    }
+
+    // buffer one entry, a peer's or a client's: pend append + memo
+    // upkeep: a memo that was current stays current (one set insert), so
+    // the next read of the row does not rebuild it from the whole base
+    TlogEnt converge_entry(int64_t row_i, uint64_t ts, const uint8_t* v,
+                           int64_t n) {
         TlogRow& r = rows[row_i];
         TlogEnt e{ts, intern(v, n)};
         append_pend(r, row_i, e);
@@ -521,25 +555,9 @@ struct TlogTable {
             } else {
                 if (ts >= cut) r.memo.insert(e);
                 r.memo_plen = static_cast<int64_t>(r.pend.size());
-                r.memo_cut = cut;
             }
         }
-        if (ts >= r.cut_cache) {
-            if (!r.delta_present) {
-                r.delta_present = true;
-                delta_rows.push_back(row_i);
-            }
-            if (ts >= r.delta_cutoff) r.delta.insert(e);
-        }
-    }
-
-    // cluster converge: entries/cutoff buffer without memo upkeep (the
-    // memo's state key goes stale, exactly like the Python dict path)
-    void converge_entry(int64_t row_i, uint64_t ts, const uint8_t* v,
-                        int64_t n) {
-        TlogRow& r = rows[row_i];
-        append_pend(r, row_i, TlogEnt{ts, intern(v, n)});
-        r.gen++;
+        return e;
     }
 
     void raise_pend_cutoff(int64_t row_i, uint64_t c) {
@@ -633,11 +651,16 @@ struct TlogTable {
         r.len_cache = len;
         r.cut_cache = cut;
         if (!r.pend.empty()) pend_rows_count--;
+        pend_total -= static_cast<int64_t>(r.pend.size());
         r.pend.clear();
         r.pend_cutoff = 0;
         if (r.base_valid) {
-            r.memo.clear();
-            r.memo.insert(r.base.begin(), r.base.end());
+            // the base was filtered OUT of the memo: equal sizes, equal
+            // sets, and the memo stays as it is (no thousand re-inserts)
+            if (!r.memo_valid || r.memo.size() != r.base.size()) {
+                r.memo.clear();
+                r.memo.insert(r.base.begin(), r.base.end());
+            }
             r.memo_valid = true;
             r.memo_plen = 0;
             r.memo_cut = cutoff_view(r);
@@ -649,7 +672,7 @@ struct TlogTable {
         return r.base_valid;
     }
 
-    // global drain tail: mirrors repo_tlog.py _finish_drain's
+    // global drain tail (repo_tlog.py drain(), after its last pass):
     // pend.clear() across every row + flag reset
     void finish_drain_end() {
         for (int64_t row_i : touched_list) {
@@ -664,6 +687,7 @@ struct TlogTable {
         }
         touched_list.clear();
         pend_rows_count = 0;
+        pend_total = 0;
         row_overdue = false;
     }
 

@@ -18,8 +18,8 @@ using namespace jy;
 namespace {
 
 // pending-rows thresholds past which writes bounce so the Python repo
-// runs its device drain (must match repo_treg.py PENDING_DRAIN_THRESHOLD
-// and repo_tlog.py ROW/PENDING_DRAIN_THRESHOLD — pinned by
+// runs its device drain (must match repo_treg.py PENDING_DRAIN_THRESHOLD;
+// TLOG's live in engine.h TlogTable and tlog_table.py — pinned by
 // tests/test_serve_tables.py)
 constexpr int64_t TREG_PENDING_DRAIN = 4096;
 
@@ -242,12 +242,20 @@ int64_t jy_tlog_pend_len(void* e, int64_t row) {
         static_cast<Engine*>(e)->tlog.rows[row].pend.size());
 }
 
-int64_t jy_tlog_pend_rows_count(void* e) {
-    return static_cast<Engine*>(e)->tlog.pend_rows_count;
+int32_t jy_tlog_overdue(void* e) {
+    return static_cast<Engine*>(e)->tlog.overdue() ? 1 : 0;
 }
 
-int32_t jy_tlog_row_overdue(void* e) {
-    return static_cast<Engine*>(e)->tlog.row_overdue ? 1 : 0;
+int32_t jy_tlog_ins_tips(void* e, int64_t in_row) {
+    return static_cast<Engine*>(e)->tlog.ins_tips(in_row) ? 1 : 0;
+}
+
+int64_t jy_tlog_pend_total(void* e) {
+    return static_cast<Engine*>(e)->tlog.pend_total;
+}
+
+void jy_tlog_set_entries_bound(void* e, int64_t n) {
+    static_cast<Engine*>(e)->tlog.entries_bound = n;
 }
 
 // rows with pending entries OR a pending cutoff — the drain's row set,
@@ -811,10 +819,7 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
                             : static_cast<int64_t>(t.rows[row].pend.size());
                 // repo_tlog.py may_drain's exact predicate: Python must
                 // run (and thread-offload) the drain this INS triggers
-                if (in_row + 1 >= TlogTable::ROW_DRAIN_THRESHOLD ||
-                    t.pend_rows_count + 1 >=
-                        TlogTable::PENDING_DRAIN_THRESHOLD)
-                    return defer();
+                if (t.ins_tips(in_row)) return defer();
                 if (row < 0) row = t.upsert(buf + offs[2], lens[2]);
                 t.ins(row, ts, buf + offs[3], lens[3]);
                 changed[3]++;

@@ -1,4 +1,4 @@
-"""The six TLOG tallies beside `drain.TLOG` count what they say, on both
+"""The TLOG tallies beside `drain.TLOG` count what they say, on both
 table backends, and show on every surface the TREG pair shows on:
 `jylis_drain_total{type="TLOG",kind=...}`, `SYSTEM METRICS`, the shutdown
 log's `merge metrics:` line. And what `bases_lost` and `row_gathers`
@@ -51,11 +51,11 @@ def reference(entries, cut=0):
 
 def lose_base(repo, key):
     """Force the epilogue's length guard to fail through the repo's own
-    `_finish_drain`: it is told one entry more than the fold holds, then
+    `_finish_rows`: it is told one entry more than the fold holds, then
     (on the table alone) the true length again; the base stays unknown."""
     row = repo._tbl.find(key)
     n, cut = repo._tbl.len_cache(row), repo._tbl.cut_cache(row)
-    repo._finish_drain([(row, n + 1, cut)])
+    repo._finish_rows([(row, n + 1, cut)])
     assert repo._tbl.finish_row(row, n, cut) is False
     return row
 
@@ -245,3 +245,57 @@ def test_the_tallies_are_on_the_scrape_in_system_metrics_and_in_the_shutdown_lin
     assert "TLOG entries 4" in lines and "TLOG trims 1" in lines and "TLOG grows 0" in lines
     assert "TLOG bases_lost 0" in lines
     assert ", 4 entries, 1 trims, 0 grows, 0 bases_lost, 0 row_gathers, " in db.metrics.report()
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_foreign_entries_cutoffs_overdue_and_passes_count_what_they_say(engine):
+    """`foreign_entries`: every (value, ts) `converge` buffers, duplicates
+    included; `foreign_cutoffs`: cutoffs above the key's view; `overdue`:
+    drains that began with a bound of the table tripped, not a trim's;
+    `passes`: dispatches a single-chip drain was made of."""
+    repo, reg, _read = fresh(engine)
+    new = lambda: {k: reg.tallies["drain.TLOG." + k] for k in ("foreign_entries", "foreign_cutoffs", "overdue", "passes")}
+    repo.converge(b"k", ([(b"a", 10), (b"b", 20), (b"a", 10)], 0))
+    repo.converge(b"j", ([], 15))
+    assert new() == {"foreign_entries": 3, "foreign_cutoffs": 1, "overdue": 0, "passes": 0}
+    repo.converge(b"j", ([(b"c", 30)], 15))  # the cutoff is the view already: buffered once
+    repo.converge(b"j", ([], 12))
+    assert new() == {"foreign_entries": 4, "foreign_cutoffs": 1, "overdue": 0, "passes": 0}
+    repo.apply(_Resp(), [b"TRIMAT", b"k", b"15"])  # a trim's drain, of everything pending: not overdue
+    assert new()["overdue"] == 0 and new()["passes"] == 1 and reg.tallies["drain.TLOG.trims"] == 1
+    assert get(repo, b"k") == [(20, b"b")] and get(repo, b"j") == [(30, b"c")]
+    repo.converge(b"j", ([], 31))
+    assert new()["foreign_cutoffs"] == 2
+    # the entries bound (a cold table has none; a warmed repo sets what one floor batch holds)
+    repo._tbl.set_entries_bound(4)
+    for j in range(3):
+        repo.converge(b"k", ([(b"v%d" % j, 40 + j)], 0))
+    assert not repo.drain_overdue()
+    repo.drain()  # a snapshot's or a shutdown's drain: no bound tripped
+    assert new()["overdue"] == 0 and new()["passes"] == 2
+    for j in range(4):
+        repo.converge(b"k", ([(b"w%d" % j, 50 + j)], 0))
+    assert repo.drain_overdue()
+    repo.drain()  # what `RepoManager.converge_async` does then
+    assert new() == {"foreign_entries": 11, "foreign_cutoffs": 2, "overdue": 1, "passes": 3}
+    resp = _Resp()
+    for j in range(4):  # a local INS that crosses the bound drains on the spot
+        repo.apply(resp, [b"INS", b"k", b"x%d" % j, b"%d" % (60 + j)])
+    assert new()["overdue"] == 2 and new()["foreign_entries"] == 11 and repo._tbl.pend_len(repo._tbl.find(b"k")) == 0
+    assert len(get(repo, b"k")) == 1 + 3 + 4 + 4 and get(repo, b"j") == []
+
+
+def test_the_new_tallies_are_on_the_scrape_in_system_metrics_and_in_the_shutdown_line():
+    from jylis_tpu.models.database import Database
+    from jylis_tpu.obs import prom
+
+    db = Database(identity=4)
+    db.converge_deltas(("TLOG", [(b"k", ([(b"v%d" % j, 10 + j) for j in range(5)], 12))]))
+    db.manager("TLOG").repo.drain()
+    text = prom.render(db)
+    for kind, n in (("foreign_entries", 5), ("foreign_cutoffs", 1), ("overdue", 0)):
+        assert f'jylis_drain_total{{type="TLOG",kind="{kind}"}} {n}' in text
+    assert 'jylis_drain_total{type="TLOG",kind="passes"}' in text
+    lines = metric_lines(registry=db.metrics)
+    assert "TLOG foreign_entries 5" in lines and "TLOG foreign_cutoffs 1" in lines and "TLOG overdue 0" in lines
+    assert ", 5 foreign_entries, 1 foreign_cutoffs, 0 overdue, " in db.metrics.report()
