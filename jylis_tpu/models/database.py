@@ -101,6 +101,8 @@ class Database:
         # engine="python" pins the pure-Python table backends everywhere
         # (differential tests compare the two whole stacks).
         self.native_engine = resolve_engine(engine)
+        if self.native_engine is not None:
+            self.native_engine.bind_metrics(self.metrics)
         self._map: dict[bytes, RepoManager] = {}
         # SYSTEM METRICS' "cmds" lines: THIS instance's Python-path
         # tally merged with THIS instance's engine counters — wired

@@ -18,12 +18,18 @@ import struct
 
 import numpy as np
 
+from ..utils.metrics import resolve_registry
 from . import lib
 
 G = 0
 PN = 1
 
+# the reply buffer a burst's replies are written into: it starts at
+# _OUT_CAP, is replaced by a larger one when ONE reply outgrows it, and
+# never passes _OUT_CEIL (a whole thread of 16,000 x 1 KB posts); a reply
+# beyond that is the Python path's, which renders it in bounded flushes
 _OUT_CAP = 1 << 16
+_OUT_CEIL = 1 << 24
 _MAX_ARGS = 1024
 
 # jy_tlog_export_merged's "view unavailable" sentinel (serve_engine.cpp)
@@ -142,7 +148,7 @@ def _declare(c: ctypes.CDLL) -> None:
         # batch applier
         "jy_eng_scan_apply2": (
             i32,
-            [vp, vp, i64, vp, i64, pi64, pi64, vp, vp, i32, pi32, vp],
+            [vp, vp, i64, vp, i64, i64, pi64, pi64, vp, vp, i32, pi32, vp],
         ),
     }
     for fn_name, (restype, argtypes) in sigs.items():
@@ -749,26 +755,52 @@ class ServeEngine:
 
     # ---- the batch applier -------------------------------------------------
 
+    def bind_metrics(self, registry) -> None:
+        """Count this engine's reply buffer in ``registry`` (its
+        Database's): the bytes it holds now, then every grow."""
+        self.metrics = registry
+        registry.tally("serving.ENGINE.reply_buffer_bytes", len(self._out))
+
+    def _grow_out(self, need: int) -> None:
+        """Replace the reply buffer by one of the next power of two that
+        holds ``need`` bytes. The loop is one thread and `scan_apply`
+        copies a burst's replies out before it returns, so nothing
+        still reads the old array."""
+        cap = 1 << (need - 1).bit_length()
+        reg = resolve_registry(self)
+        reg.tally("serving.ENGINE.reply_grows", 1)
+        reg.tally("serving.ENGINE.reply_buffer_bytes", cap - len(self._out))
+        self._out = (ctypes.c_uint8 * cap)()
+
     def scan_apply(self, buf):
         """Apply a pipelined burst. Returns
         (rc, consumed, replies: bytes, unhandled: list[bytes] | None,
         changed: tuple of 5 per-type counts (G, PN, TREG, TLOG, UJSON));
-        rc as documented in serve_engine.cpp."""
+        rc as documented in serve_engine.cpp, but for its 3 (answered
+        here: the reply buffer grows to the reply and the burst runs
+        again) and its 4 (counted here, handed on as 1)."""
         if not buf:
             return 0, 0, b"", None, (0, 0, 0, 0, 0)
         base = ctypes.addressof(ctypes.c_char.from_buffer(buf))
         out_len = ctypes.c_int64()
         consumed = ctypes.c_int64()
         n_args = ctypes.c_int32()
-        rc = self._lib.jy_eng_scan_apply2(
-            self._h, ctypes.c_void_p(base), len(buf),
-            self._out, _OUT_CAP, ctypes.byref(out_len),
-            ctypes.byref(consumed),
-            self._offs, self._lens, _MAX_ARGS, ctypes.byref(n_args),
-            self._changed,
-        )
+        while True:
+            rc = self._lib.jy_eng_scan_apply2(
+                self._h, ctypes.c_void_p(base), len(buf),
+                self._out, len(self._out), _OUT_CEIL, ctypes.byref(out_len),
+                ctypes.byref(consumed),
+                self._offs, self._lens, _MAX_ARGS, ctypes.byref(n_args),
+                self._changed,
+            )
+            if rc != 3:
+                break
+            self._grow_out(out_len.value)  # the bytes the reply needs
         replies = ctypes.string_at(self._out, out_len.value)
         unhandled = None
+        if rc == 4:
+            resolve_registry(self).tally("serving.ENGINE.oversize_defers", 1)
+            rc = 1
         if rc == 1:
             view = memoryview(buf)
             unhandled = [

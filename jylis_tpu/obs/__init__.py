@@ -99,7 +99,9 @@ SEAMS = (
 
 # Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`
 # (`registry.tally`): on /metrics they are further `kind`s of
-# jylis_drain_total, in SYSTEM METRICS `<TYPE> <kind> <n>` lines. TREG:
+# jylis_drain_total, in SYSTEM METRICS `<TYPE> <kind> <n>` lines; and, on
+# the same surfaces under the type ENGINE, the native engine's reply
+# buffer (`serving.ENGINE.<kind>`). TREG:
 # rows the engine's bulk call assembled (0 on a node that serves from
 # the Python tables: no compiler on the host), and rows whose 8-byte
 # prefix tied on the device and were settled by the full strings. TLOG:
@@ -112,7 +114,11 @@ SEAMS = (
 # base before it), and whole-row sorts of a view by the Python read path;
 # entries `converge` buffered (a peer's, a restore's, a journal replay's),
 # cutoffs it raised, drains that began with a bound of the table tripped
-# (not forced by a trim), and the dispatches drains were made of.
+# (not forced by a trim), and the dispatches drains were made of. ENGINE:
+# times the reply buffer was replaced by a larger one (a reply alone
+# outgrew it), the bytes it holds now (it only grows, so the sum of its
+# steps), and commands whose reply passed the buffer's ceiling and went
+# to the Python path.
 TALLIES = (
     "drain.TREG.bulk_rows",
     "drain.TREG.tie_rows",
@@ -126,6 +132,9 @@ TALLIES = (
     "drain.TLOG.foreign_cutoffs",
     "drain.TLOG.overdue",
     "drain.TLOG.passes",
+    "serving.ENGINE.reply_grows",
+    "serving.ENGINE.reply_buffer_bytes",
+    "serving.ENGINE.oversize_defers",
 )
 
 # Node-wide gauges (per-peer convergence lag lives on the Cluster and

@@ -173,7 +173,7 @@ class MetricsRegistry:
                 yield name, int(c["batches"]), int(c["keys"]), c["seconds"] * 1e3
 
     def tally_stats(self):
-        """(type, kind, n) per declared drain tally, TALLIES order."""
+        """(type, kind, n) per declared tally, TALLIES order."""
         for name in TALLIES:
             _, typ, kind = name.split(".")
             yield typ, kind, self.tallies[name]
@@ -223,11 +223,16 @@ class MetricsRegistry:
 
     def report(self) -> str:
         tallies: dict[str, str] = defaultdict(str)
+        live = set()  # tally types that counted anything
         for typ, kind, n in self.tally_stats():
             tallies[typ] += f", {n} {kind}"
+            if n:
+                live.add(typ)
         parts = [
             f"{name}: {drains} drains, {keys} keys, {ms:.1f}ms device"
-            + tallies[name]
+            + tallies.pop(name, "")
             for name, drains, keys, ms in self.type_stats()
         ]
+        # a type that counts and never drains (ENGINE, the reply buffer)
+        parts += [f"{typ}: {t[2:]}" for typ, t in tallies.items() if typ in live]
         return "; ".join(parts) if parts else "no drains"

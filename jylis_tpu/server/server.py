@@ -307,7 +307,10 @@ class Server:
         can't settle route through the normal per-repo async path in
         order (`resp` buffers those replies; `flush` pushes them to the
         writer before the engine's next direct write so the reply stream
-        stays in command order). Returns True (stay native) or False
+        stays in command order). A reply of any size is the engine's:
+        its reply buffer grows to the reply inside `scan_apply`, and
+        only one past that buffer's ceiling comes back as a command for
+        the Python path. Returns True (stay native) or False
         (demote this connection to the Python path; tail moved into
         `parser` — on malformed input the Python parser then renders its
         specific error and the connection drops)."""
@@ -406,8 +409,9 @@ class Server:
                     )
             if rc == 1:  # one command for the Python path, in order
                 await self._dispatch_py(resp, unhandled, writer, out, t_arr)
-                # a burst of repeatedly deferring reads (e.g. renders
-                # too big for the engine's reply buffer) produces no
+                # a burst of repeatedly deferring reads (e.g. rows whose
+                # drained base the host lacks, or replies past the
+                # ceiling of the engine's reply buffer) produces no
                 # engine write to piggyback on: bound the buffer here
                 # exactly like the demoted loop does
                 flush(1 << 16)

@@ -593,14 +593,20 @@ int64_t jy_uj_memo_len(void* e, const uint8_t* k, int64_t n) {
 //   1  stopped at a command Python must apply: its slices are in
 //      offs/lens/n_args and *consumed INCLUDES it
 //   2  reply buffer nearly full: flush replies and call again
+//   3  the next command's reply alone outgrows an EMPTY reply buffer:
+//      nothing is consumed and *out_len is the bytes it needs; call
+//      again with at least as many (engine.py grows its array and does,
+//      so no caller of ServeEngine.scan_apply sees this code)
+//   4  as 1, and the reason is a reply of more than out_ceil bytes
+//      (engine.py counts it and hands the caller a 1)
 //  -1  protocol error at the stop point (serve replies, drop connection)
 //  -2  a command has more than max_args arguments (grow and retry)
 // changed[5] counts state-changing applies per type
 // (G, PN, TREG, TLOG, UJSON) for the caller's on-change notifications.
 int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
-                           uint8_t* out, int64_t out_cap, int64_t* out_len,
-                           int64_t* consumed, int64_t* offs, int64_t* lens,
-                           int32_t max_args, int32_t* n_args,
+                           uint8_t* out, int64_t out_cap, int64_t out_ceil,
+                           int64_t* out_len, int64_t* consumed, int64_t* offs,
+                           int64_t* lens, int32_t max_args, int32_t* n_args,
                            int32_t* changed) {
     Engine* eng = static_cast<Engine*>(ev);
     *out_len = 0;
@@ -630,6 +636,19 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
             *n_args = argc;
             *consumed += sub_consumed;
             return 1;
+        };
+        // a reply of `need` bytes does not fit what is left of `out`:
+        // with replies buffered, flush them and re-enter; with the
+        // buffer empty the caller grows it to the reply, unless that
+        // passes the ceiling: then Python renders it in bounded flushes
+        auto no_room = [&](int64_t need) -> int32_t {
+            if (*out_len > 0) return 2;
+            if (need > out_ceil) {
+                defer();
+                return 4;
+            }
+            *out_len = need;
+            return 3;
         };
 
         // ---- counters (exact round-3 semantics) ---------------------------
@@ -687,10 +706,7 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
                 }
                 int64_t need =
                     static_cast<int64_t>(val->size()) + 64;  // headers + ts
-                if (out_cap - *out_len < need) {
-                    if (*out_len > 0) return 2;  // flush replies, re-enter
-                    return defer();  // value alone outgrows the buffer
-                }
+                if (out_cap - *out_len < need) return no_room(need);
                 uint8_t* o = out + *out_len;
                 int64_t n = 0;
                 memcpy(o + n, "*2\r\n$", 5);
@@ -770,10 +786,7 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
                             static_cast<int64_t>(v.size()) + 2 + 1 +
                             digits10(en.ts) + 2;
                 }
-                if (out_cap - *out_len < need) {
-                    if (*out_len > 0) return 2;  // flush replies, re-enter
-                    return defer();  // reply alone outgrows the buffer
-                }
+                if (out_cap - *out_len < need) return no_room(need);
                 uint8_t* o = out + *out_len;
                 int64_t m = 0;
                 o[m++] = '*';
@@ -856,10 +869,7 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
                     row < 0 ? nullptr : u.get(row, path_blob(3, argc));
                 if (reply == nullptr) return defer();
                 int64_t need = static_cast<int64_t>(reply->size());
-                if (out_cap - *out_len < need) {
-                    if (*out_len > 0) return 2;  // flush replies, re-enter
-                    return defer();  // reply alone outgrows the buffer
-                }
+                if (out_cap - *out_len < need) return no_room(need);
                 memcpy(out + *out_len, reply->data(), reply->size());
                 *out_len += need;
                 eng->served[4]++;

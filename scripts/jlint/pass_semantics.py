@@ -199,14 +199,19 @@ def _native_args(blocks) -> tuple[list[int], list[int]]:
 
 def _native_defers(blocks) -> list[str]:
     """Rendered guard conditions of every `return defer()` — the exact
-    predicates under which the engine bounces to the oracle."""
+    predicates under which the engine bounces to the oracle. A `return
+    no_room(need)` (the reply does not fit the reply buffer) defers only
+    past the buffer's ceiling: below it the buffer is flushed or grown."""
     out: list[str] = []
 
     def rec(block, conds):
         for st in block.stmts:
             if isinstance(st, cpp_ast.Return):
-                if cpp_ast.render(st.value) == "defer ( )":
+                value = cpp_ast.render(st.value)
+                if value == "defer ( )":
                     out.append(" && ".join(conds) if conds else "fallthrough")
+                elif value == "no_room ( need )":
+                    out.append(" && ".join(conds + ["need > out_ceil"]))
             elif isinstance(st, cpp_ast.If):
                 c = cpp_ast.render(st.cond)
                 rec(st.then, conds + [c])

@@ -22,13 +22,25 @@ import pytest
 
 from jylis_tpu.native import lib
 from jylis_tpu.native.engine import ServeEngine
+from jylis_tpu.obs.registry import MetricsRegistry
 
 
 @pytest.fixture
 def eng() -> ServeEngine:
     cdll = lib()
     assert cdll is not None, "native library must build in this environment"
-    return ServeEngine(cdll)
+    e = ServeEngine(cdll)
+    e.bind_metrics(MetricsRegistry())  # its own counts, not the process's
+    return e
+
+
+def reply_tallies(eng) -> tuple[int, int, int]:
+    """(reply_grows, reply_buffer_bytes, oversize_defers) as counted."""
+    t = eng.metrics.tallies
+    return tuple(
+        t["serving.ENGINE." + k]
+        for k in ("reply_grows", "reply_buffer_bytes", "oversize_defers")
+    )
 
 
 def resp(*args: bytes) -> bytes:
@@ -94,10 +106,10 @@ def test_treg_set_get_and_big_value_rc2(eng):
     assert rc == 0 and not deferred
     assert replies == b"+OK\r\n*2\r\n$5\r\nhello\r\n:7\r\n$-1\r\n"
 
-    # a value larger than the 64 KiB reply buffer: SET banks it fine,
-    # GET alone outgrows the buffer -> defers to Python (rc 1); with a
-    # small reply already buffered the engine first asks for a flush
-    # (rc 2) and THEN defers — both paths covered by drain_native
+    # a value larger than the 64 KiB reply buffer: SET banks it fine;
+    # with a small reply already buffered the engine first asks for a
+    # flush (rc 2), and when the GET comes first in its burst the reply
+    # buffer grows to the reply inside scan_apply: nothing defers
     big = b"v" * (1 << 17)
     rc, replies, deferred, _ = drain_native(
         eng,
@@ -105,9 +117,122 @@ def test_treg_set_get_and_big_value_rc2(eng):
         + resp(b"GCOUNT", b"INC", b"pad", b"1")
         + resp(b"TREG", b"GET", b"big"),
     )
-    assert rc == 0
-    assert replies == b"+OK\r\n+OK\r\n"
-    assert deferred == [[b"TREG", b"GET", b"big"]]
+    assert rc == 0 and not deferred
+    assert replies == b"+OK\r\n+OK\r\n*2\r\n$131072\r\n" + big + b"\r\n:9\r\n"
+    # the next power of two over 128 KiB + headers, counted
+    assert reply_tallies(eng) == (1, 1 << 18, 0)
+
+
+# ---- the reply buffer grows to the reply ------------------------------------
+
+TS0 = 1_700_000_000_000_000_000  # 19 digits, as a client's clock gives them
+
+
+def post(i: int) -> bytes:
+    """A YCSB E post: 1,000 B."""
+    return b"%06d" % i + b"p" * 994
+
+
+def _posts(eng, key: bytes, n: int) -> bytes:
+    """INS n posts and return what TLOG GET key must render for all of
+    them: newest first."""
+    vals = [post(i) for i in range(n)]
+    for lo in range(0, n, 50):
+        rc, replies, deferred, _ = drain_native(eng, b"".join(
+            resp(b"TLOG", b"INS", key, vals[i], b"%d" % (TS0 + i))
+            for i in range(lo, min(lo + 50, n))
+        ))
+        assert rc == 0 and not deferred
+    return [
+        b"*2\r\n$1000\r\n%s\r\n:%d\r\n" % (vals[i], TS0 + i)
+        for i in reversed(range(n))
+    ]
+
+
+def _render(entries, count: int) -> bytes:
+    return b"*%d\r\n" % count + b"".join(entries[:count])
+
+
+@pytest.mark.parametrize("count", [63, 64, 65, 100, 1000])
+def test_tlog_get_of_any_count_is_the_engines(eng, count):
+    """63 posts of 1,000 B fit the 64 KiB the buffer starts with, 64 do
+    not: from there on the buffer grows to the reply, and the engine's
+    render is the one of the small reply, byte for byte."""
+    entries = _posts(eng, b"thread", 1000)
+    rc, replies, deferred, _ = drain_native(
+        eng, resp(b"TLOG", b"GET", b"thread", b"%d" % count)
+    )
+    assert rc == 0 and not deferred
+    assert replies == _render(entries, count)
+    need = len(replies)
+    cap = max(1 << 16, 1 << (need - 1).bit_length())
+    assert reply_tallies(eng) == (int(count >= 64), cap, 0)
+
+
+def test_small_and_oversize_replies_keep_command_order(eng):
+    """A pipelined burst: the small replies in front of an outsize one
+    are flushed first (rc 2), the re-entered call grows the buffer, and
+    what follows the outsize reply comes after it."""
+    entries = _posts(eng, b"thread", 200)
+    burst = (
+        resp(b"TLOG", b"SIZE", b"thread")
+        + resp(b"TLOG", b"GET", b"thread", b"3")
+        + resp(b"TLOG", b"GET", b"thread", b"150")
+        + resp(b"GCOUNT", b"INC", b"c", b"4")
+        + resp(b"TLOG", b"GET", b"thread")
+        + resp(b"GCOUNT", b"GET", b"c")
+    )
+    buf = bytearray(burst)
+    rc, consumed, replies, unhandled, _ = eng.scan_apply(buf)
+    assert rc == 2 and unhandled is None  # flush what settled, re-enter
+    assert replies == b":200\r\n" + _render(entries, 3)
+    assert reply_tallies(eng) == (0, 1 << 16, 0)  # not while replies wait
+    rc, replies_rest, deferred, rest = drain_native(eng, bytes(buf[consumed:]))
+    assert (rc, rest) == (0, b"") and not deferred
+    assert replies_rest == (
+        _render(entries, 150) + b"+OK\r\n" + _render(entries, 200) + b":4\r\n"
+    )
+
+
+def test_reply_buffer_grows_once_for_a_size_and_never_shrinks(eng):
+    entries = _posts(eng, b"thread", 300)
+    get100 = resp(b"TLOG", b"GET", b"thread", b"100")
+    assert reply_tallies(eng) == (0, 1 << 16, 0)
+    for _ in range(3):  # the same size again: the buffer is there
+        rc, replies, deferred, _ = drain_native(eng, get100)
+        assert rc == 0 and not deferred and replies == _render(entries, 100)
+        assert reply_tallies(eng) == (1, 1 << 17, 0)
+    first = eng._out
+    drain_native(eng, resp(b"TLOG", b"GET", b"thread", b"2"))
+    drain_native(eng, resp(b"TLOG", b"GET", b"thread", b"110"))  # fits 128 KiB
+    assert eng._out is first and reply_tallies(eng) == (1, 1 << 17, 0)
+    rc, replies, deferred, _ = drain_native(eng, resp(b"TLOG", b"GET", b"thread"))
+    assert rc == 0 and not deferred and replies == _render(entries, 300)
+    assert reply_tallies(eng) == (2, 1 << 19, 0)
+    drain_native(eng, get100)
+    assert reply_tallies(eng) == (2, 1 << 19, 0)
+    assert len(eng._out) == 1 << 19
+
+
+def test_reply_past_the_ceiling_defers_as_before(eng, monkeypatch):
+    """Past the ceiling the command is the Python path's, exactly as a
+    reply over 64 KiB was: flush first (rc 2), then deferred (rc 1) and
+    consumed, the buffer left as it was."""
+    from jylis_tpu.native import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "_OUT_CEIL", 1 << 17)
+    entries = _posts(eng, b"thread", 200)
+    burst = (
+        resp(b"TLOG", b"SIZE", b"thread")
+        + resp(b"TLOG", b"GET", b"thread", b"100")  # 102 KB: under the ceiling
+        + resp(b"TLOG", b"GET", b"thread")  # 207 KB: over it
+        + resp(b"TLOG", b"SIZE", b"thread")
+    )
+    rc, replies, deferred, rest = drain_native(eng, burst)
+    assert (rc, rest) == (0, b"")
+    assert replies == b":200\r\n" + _render(entries, 100) + b":200\r\n"
+    assert deferred == [[b"TLOG", b"GET", b"thread"]]
+    assert reply_tallies(eng) == (1, 1 << 17, 1)
 
 
 def test_treg_lww_winner_rule(eng):

@@ -321,7 +321,9 @@ def test_prom_render_grammar_and_presence():
         assert f'name="{g}"' in body
     for t in TALLIES:  # further kinds of the type's drain totals
         _, typ, kind = t.split(".")
-        assert f'jylis_drain_total{{type="{typ}",kind="{kind}"}} 0' in body
+        # all from zero, but the size of the engine's reply buffer
+        boot = 1 << 16 if kind == "reply_buffer_bytes" and db.native_engine else 0
+        assert f'jylis_drain_total{{type="{typ}",kind="{kind}"}} {boot}' in body
     assert 'jylis_cmds_total{type="GCOUNT"} 1' in body
     assert 'jylis_seam_latency_seconds_count{seam="journal.append"} 1' in body
     # and the manifest agrees with the declared surface (the CI smoke

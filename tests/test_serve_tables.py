@@ -463,21 +463,23 @@ def test_scan_apply_tlog_get_defers_when_base_unknown():
     assert replies == b"*1\r\n*2\r\n$1\r\nv\r\n:7\r\n"
 
 
-def test_scan_apply_tlog_get_big_reply_flushes_then_defers():
-    """A GET whose reply outgrows the 64 KB reply buffer: mid-burst it
-    flushes what settled first (rc 2), then alone it defers to Python
-    (rc 1) — the TREG big-value convention."""
-    native = RepoTLOG(identity=1)
-    r = R()
-    native.apply(r, [b"INS", b"k", b"x" * 70000, b"1"])
+def test_scan_apply_tlog_get_big_reply_flushes_then_grows():
+    """A GET whose reply outgrows the 64 KB the reply buffer starts
+    with: mid-burst the engine flushes what settled first (rc 2), then
+    alone it grows the buffer to the reply and serves it, byte for byte
+    what the Python path renders — the TREG big-value convention."""
+    native, oracle = _tlog_pair()
+    both(native, oracle, [b"INS", b"k", b"x" * 70000, b"1"])
     burst = bytearray(b"TLOG SIZE k\r\nTLOG GET k\r\n")
     rc, consumed, replies, unhandled, _ = native.engine.scan_apply(burst)
     assert rc == 2 and replies == b":1\r\n"
     assert consumed == len(b"TLOG SIZE k\r\n")
     del burst[:consumed]
     rc, consumed, replies, unhandled, _ = native.engine.scan_apply(burst)
-    assert rc == 1 and unhandled == [b"TLOG", b"GET", b"k"]
-    assert replies == b"" and consumed == len(b"TLOG GET k\r\n")
+    assert rc == 0 and unhandled is None
+    assert replies == _oracle_reply(oracle, [b"GET", b"k"])
+    assert consumed == len(b"TLOG GET k\r\n")
+    assert len(native.engine._out) == 1 << 17
 
 
 # ---- UJSON queue + render memo ---------------------------------------------
