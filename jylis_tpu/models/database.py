@@ -192,15 +192,16 @@ class Database:
         lines): commands the
         engine settled in C++ vs commands that went through the Python
         dispatch path (engine defers, demoted connections, and direct
-        applies), plus whole-connection demotion events."""
+        applies), plus the registry's exact serving counters
+        (obs.SERVING: demotion events, refusals, the Python path's
+        commands by cause, the engine's reply bytes)."""
         native = 0
         if self.native_engine is not None:
             native = sum(self.native_engine.served_counts().values())
         return {
             "native_cmds": native,
             "demoted_cmds": sum(self._served_py.values()),
-            "demotions": self.metrics.serving_counters["demotions"],
-            "busy_refusals": self.metrics.serving_counters["busy_refusals"],
+            **self.metrics.serving_counters,
         }
 
     def _sync_update_repo(self, name: str, repo) -> None:
@@ -246,7 +247,7 @@ class Database:
         its dirty keys, under its own lock, in a worker thread."""
         for name in self.DATA_TYPES:
             mgr = self._map[name.encode()]
-            async with mgr._lock:
+            async with mgr.hold_sync():
                 await asyncio.to_thread(self._sync_update_repo, name, mgr.repo)
         return tuple(self._sync_xor[n] for n in self.DATA_TYPES)
 
@@ -262,7 +263,7 @@ class Database:
         the MsgDigestTree payload. Folds the type's dirty keys first
         (same O(dirty) incremental cost as the root digest)."""
         mgr = self._map[name.encode()]
-        async with mgr._lock:
+        async with mgr.hold_sync():
             await asyncio.to_thread(self._sync_update_repo, name, mgr.repo)
         return tuple(
             (i, v.to_bytes(32, "big"))
@@ -294,7 +295,7 @@ class Database:
                 if key in wanted
             ]
 
-        async with mgr._lock:
+        async with mgr.hold_sync():
             return await asyncio.to_thread(dump_filtered)
 
     def _sync_digest_blocking(self) -> bytes:
@@ -513,7 +514,7 @@ class Database:
         for mgr in self._map.values():
             if names is not None and mgr.name not in names:
                 continue
-            async with mgr._lock:
+            async with mgr.hold_sync():
                 batch = await asyncio.to_thread(mgr.repo.dump_state)
             out.append((mgr.name, batch))
         return out
@@ -554,7 +555,7 @@ class Database:
         shutdown snapshot dumps under it so nothing mutates mid-dump."""
         async with AsyncExitStack() as stack:
             for mgr in self._map.values():
-                await stack.enter_async_context(mgr._lock)
+                await stack.enter_async_context(mgr.hold_sync())
             yield
 
 

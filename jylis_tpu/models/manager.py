@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import asyncio
 import time
+from time import perf_counter
 
 from ..obs import span
 from ..utils.metrics import DEFAULT as _DEFAULT_REGISTRY
@@ -168,30 +169,85 @@ class RepoLock:
         self.release()
 
     @staticmethod
+    def take_all(locks) -> bool:
+        """`acquire_all` for the caller that wants to know BEFORE it
+        sleeps (the server times the sleep and nothing else): takes
+        every lock when nobody holds one and says True, else takes
+        nothing. Never yields."""
+        for lock in locks:
+            if lock._held:
+                return False
+        for lock in locks:
+            lock._held = True
+        return True
+
+    @staticmethod
     async def acquire_all(locks) -> bool:
         """Take every lock of ``locks`` or none — for a holder that
         releases (``release_all``) before it yields. No yield when all
         are free; True when it had to sleep (what the caller checked
         before the call may no longer hold)."""
         slept = False
-        while True:
-            for lock in locks:
-                if lock._held:
-                    break
-            else:
-                for lock in locks:
-                    lock._held = True
-                return slept
-            # wait our turn in the held lock's line, holding nothing;
-            # letting go again wakes whoever lined up behind us
+        while not RepoLock.take_all(locks):
+            # wait our turn in the first held lock's line, holding
+            # nothing; letting go again wakes whoever lined up behind us
+            lock = next(lock for lock in locks if lock._held)
             await lock.acquire()
             lock.release()
             slept = True
+        return slept
 
     @staticmethod
     def release_all(locks) -> None:
         for lock in locks:
             lock.release()
+
+
+class _Hold:
+    """``async with _Hold(mgr, wait, held)``: the repo lock taken the
+    long way, by a holder that may keep it across a yield and so can be
+    SEEN holding it (``busy()`` true in somebody else's task step). Its
+    wait and its hold are a span each: ``wait`` (a lock.wait_* seam, or
+    None) runs wanting -> holding; ``held`` (a lock.hold_* seam) runs
+    holding -> the body is left, and the release follows at once. With
+    ``seen=False`` the hold's span starts only where the body calls
+    `seen`: a client command that found the lock held takes it this way
+    too and most often applies inline, lets go within the task step it
+    took in and was never seen. While profiling is armed the hold's
+    annotation carries the repo's type and, for a client's command, its
+    verb."""
+
+    __slots__ = ("_mgr", "_wait", "_held", "_cmd", "_eager", "_tok")
+
+    def __init__(self, mgr, wait, held, cmd=None, seen=True):
+        self._mgr, self._wait, self._held, self._cmd = mgr, wait, held, cmd
+        self._eager = seen
+        self._tok = None
+
+    async def __aenter__(self) -> _Hold:
+        lock, wait = self._mgr._lock, self._wait
+        if wait is None:
+            await lock.acquire()
+        else:
+            t_wait = wait.begin()
+            await lock.acquire()
+            wait.end(t_wait)
+        if self._eager:
+            self.seen()
+        return self
+
+    def seen(self) -> None:
+        meta = None
+        if span.armed():
+            meta = {"type": self._mgr.name}
+            if self._cmd is not None and len(self._cmd) > 1:
+                meta["verb"] = self._cmd[1].decode("ascii", "replace")
+        self._tok = self._held.begin(None, meta)
+
+    async def __aexit__(self, exc_type, exc, tb) -> None:
+        if self._tok is not None:
+            self._held.end(self._tok)
+        self._mgr._lock.release()
 
 
 PROACTIVE_FLUSH_INTERVAL = 0.5  # seconds; repo_manager.pony:80
@@ -234,8 +290,16 @@ class RepoManager:
         self.registry = reg = registry or _DEFAULT_REGISTRY
         self._s_wait_serve = reg.seam("lock.wait_serve")
         self._s_wait_cluster = reg.seam("lock.wait_cluster")
+        self._s_hold_serve = reg.seam("lock.hold_serve")
+        self._s_hold_converge = reg.seam("lock.hold_converge")
+        self._s_hold_flush = reg.seam("lock.hold_flush")
+        self._s_hold_sync = reg.seam("lock.hold_sync")
         self._s_apply = reg.seam("cluster.apply")
         self._s_flush = reg.seam("repo.flush")
+        # serve.py_apply: _apply_core ON THE LOOP THREAD, what a
+        # Python-path command's apply and reply render cost the loop
+        # (lock wait, thread hops and the proactive flush excluded)
+        self._h_py_apply = reg.hist("serve.py_apply")
         self._inflight = 0
         # delta write-ahead journal (journal/journal.py), attached via
         # Database.set_journal: every flushed batch is handed to the
@@ -263,6 +327,20 @@ class RepoManager:
             respond_help(resp, self.help.render(cmd[1:]))
             return False
 
+    def _apply_on_loop(self, resp, cmd: list[bytes]) -> bool:
+        """`_apply_core` from a coroutine, timed as serve.py_apply."""
+        if not self.registry.enabled:
+            return self._apply_core(resp, cmd)
+        t0 = perf_counter()
+        changed = self._apply_core(resp, cmd)
+        self._h_py_apply.record(perf_counter() - t0)
+        return changed
+
+    def hold_sync(self) -> _Hold:
+        """The lock for a digest, a tree, a range or state dump, the
+        shutdown snapshot (models/database.py): lock.hold_sync."""
+        return _Hold(self, None, self._s_hold_sync)
+
     async def apply_async(self, resp, cmd: list[bytes]) -> None:
         """Serving path: device-bound commands offload to a thread under
         the repo lock; host-only commands run inline.
@@ -282,7 +360,7 @@ class RepoManager:
         if not self._lock.locked():
             may = getattr(self.repo, "may_drain", None)
             if may is None or not may(cmd[1:]):
-                if self._apply_core(resp, cmd):
+                if self._apply_on_loop(resp, cmd):
                     self._maybe_proactive_flush()
                 return
         if self.admission_cap and self._inflight >= self.admission_cap:
@@ -300,9 +378,9 @@ class RepoManager:
         try:
             # lock.wait_serve: wanting the repo lock to holding it —
             # queueing behind a drain or a cluster apply, not service
-            t_wait = self._s_wait_serve.begin()
-            async with self._lock:
-                self._s_wait_serve.end(t_wait)
+            async with _Hold(
+                self, self._s_wait_serve, self._s_hold_serve, cmd, seen=False
+            ) as hold:
                 if self._shutdown:
                     # shutdown won the lock race while we queued behind a
                     # drain: the final flush already ran — accepting now
@@ -311,13 +389,19 @@ class RepoManager:
                     return
                 may = getattr(self.repo, "may_drain", None)
                 if may is not None and may(cmd[1:]):
+                    # lock.hold_serve: the hold that others see, of
+                    # which drain.<TYPE> is a part — the hop to the
+                    # worker thread, its wait for the GIL, the drain, the
+                    # completion's wait for the loop to come round, the
+                    # replay, the proactive flush
+                    hold.seen()
                     replay = _ReplayResp()
                     changed = await asyncio.to_thread(
                         self._apply_core, replay, cmd
                     )
                     replay.replay(resp)
                 else:
-                    changed = self._apply_core(resp, cmd)
+                    changed = self._apply_on_loop(resp, cmd)
                 if changed:
                     self._maybe_proactive_flush()
         finally:
@@ -339,9 +423,9 @@ class RepoManager:
     CONVERGE_RUN_S = 0.002
 
     async def converge_async(self, batch) -> None:
-        t_wait = self._s_wait_cluster.begin()
-        async with self._lock:
-            self._s_wait_cluster.end(t_wait)
+        # lock.hold_converge: the sliced fold, its yields, the overdue
+        # drain's thread — all of a cluster apply's hold
+        async with _Hold(self, self._s_wait_cluster, self._s_hold_converge):
             if self._shutdown:
                 return  # fire-and-forget: late deltas re-deliver elsewhere
             batch = list(batch)
@@ -367,9 +451,7 @@ class RepoManager:
                 await asyncio.to_thread(self.repo.drain)
 
     async def flush_async(self, fn) -> None:
-        t_wait = self._s_wait_cluster.begin()
-        async with self._lock:
-            self._s_wait_cluster.end(t_wait)
+        async with _Hold(self, self._s_wait_cluster, self._s_hold_flush):
             # repos with banked native-queue work drain it in a worker
             # thread first (it can touch the device); the loop-side delta
             # flush then sees fully-applied state
@@ -387,7 +469,7 @@ class RepoManager:
         """Lock-holding shutdown: waits out any in-flight threaded drain,
         then stops intake and performs the final flush atomically."""
         self._shutdown = True  # reject commands queued behind the lock
-        async with self._lock:
+        async with _Hold(self, None, self._s_hold_flush):
             prep = getattr(self.repo, "prepare_flush", None)
             if prep is not None:  # banked native-queue writes must ship
                 await asyncio.to_thread(prep)

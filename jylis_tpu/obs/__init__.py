@@ -95,6 +95,47 @@ SEAMS = (
     "cluster.decode",
     "cluster.apply",
     "repo.flush",
+    # a served burst's stages (server.py, docs/observability.md "A
+    # served burst's stages"): with server.native_burst,
+    # pipeline.reply_write and pipeline.parse they tile the handler's
+    # share of loop.busy. route = read returned -> the five locks held;
+    # tail = reply with the transport -> the next read is called;
+    # write_wait = a drain() called with bytes still in the transport
+    # (NOT loop work); py_apply = RepoManager._apply_core on the loop
+    # thread (a Python-path command's apply and reply render).
+    "serve.route",
+    "serve.tail",
+    "serve.write_wait",
+    "serve.py_apply",
+    # repo-lock holds that can be seen held (models/manager.py _Hold,
+    # docs/observability.md "Repo-lock holds"):
+    # holding -> released, by who took the lock. A native burst records
+    # none: it lets go within the task step it took in, and its hold IS
+    # server.native_burst.
+    "lock.hold_serve",
+    "lock.hold_converge",
+    "lock.hold_flush",
+    "lock.hold_sync",
+)
+
+# Exact serving-path counters (`registry.note_serving`): on /metrics
+# `jylis_serving_total{kind=...}` beside native_cmds / demoted_cmds
+# (which Database.serving_totals adds from the engine's and the
+# managers' own tallies), in SYSTEM METRICS the `SERVING <kind> <n>`
+# lines. demotions: whole connections moved off the native engine for
+# good. busy_refusals: commands refused by a per-class admission cap.
+# The next three partition what the server's Python path dispatches, by
+# cause: commands of a chunk the busy() rule routed (a repo lock was
+# held when its bytes arrived), commands the engine handed back (rc 1),
+# commands of a connection with no engine or demoted for good.
+# reply_bytes: bytes of engine replies handed to writers.
+SERVING = (
+    "demotions",
+    "busy_refusals",
+    "busy_routed_cmds",
+    "deferred_cmds",
+    "demoted_conn_cmds",
+    "reply_bytes",
 )
 
 # Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`

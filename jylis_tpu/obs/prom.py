@@ -20,6 +20,7 @@ from __future__ import annotations
 import asyncio
 
 from ..utils.net import ipv4_port
+from . import SERVING
 from .hist import N_BUCKETS, bucket_upper_seconds
 
 # the `le` label per log2 bucket, precomputed once (bucket 0 is the
@@ -70,7 +71,7 @@ def render(database) -> str:
 
     out.append("# TYPE jylis_serving_total counter")
     serving = system.serving_fn() if system.serving_fn else {}
-    for key in ("native_cmds", "demoted_cmds", "demotions", "busy_refusals"):
+    for key in ("native_cmds", "demoted_cmds") + SERVING:
         out.append(
             f'jylis_serving_total{{kind="{key}"}} {serving.get(key, 0)}'
         )

@@ -28,7 +28,7 @@ from __future__ import annotations
 import time
 from collections import defaultdict, deque
 
-from . import GAUGES, SEAMS, TALLIES
+from . import GAUGES, SEAMS, SERVING, TALLIES
 from .hist import Histogram
 from .jtrace import SpanStats
 from .span import Seam
@@ -62,12 +62,11 @@ class MetricsRegistry:
         # JOURNAL section of SYSTEM METRICS then shows explicit zeros
         # from boot instead of appearing at the first nonzero counter
         self.journal_enabled = False
-        # serving-path: whole-connection demotions off the native engine
-        # + per-command-class admission-control refusals (manager.py)
-        self.serving_counters: dict[str, int] = {
-            "demotions": 0,
-            "busy_refusals": 0,
-        }
+        # serving-path (obs.SERVING): whole-connection demotions off the
+        # native engine, per-command-class admission-control refusals
+        # (manager.py), Python-path commands by cause and the engine's
+        # reply bytes (server.py)
+        self.serving_counters: dict[str, int] = dict.fromkeys(SERVING, 0)
         self.hists: dict[str, Histogram] = {name: Histogram() for name in SEAMS}
         # the three phases of a drain (utils/metrics.timed_drain), in
         # DRAIN_PHASES order: recorded WITH their parent drain.<TYPE>

@@ -100,12 +100,18 @@ class Seam:
             return begin(label or self.name, meta)
         return perf_counter()
 
-    def end(self, token) -> None:
+    def end(self, token) -> float:
+        """Close the span and hand back the seconds it recorded (0.0
+        disabled): a caller whose own stage the span interrupts moves
+        that stage's start stamp forward by it."""
         if token.__class__ is float:
-            if token:
-                self.hist.record(perf_counter() - token)
+            if not token:
+                return 0.0
+            seconds = perf_counter() - token
         else:
-            self.hist.record(elapsed(token))
+            seconds = elapsed(token)
+        self.hist.record(seconds)
+        return seconds
 
 
 # ---- the device-trace window (SYSTEM PROFILE START / STOP) ---------------

@@ -24,7 +24,7 @@ flush) makes bursts sleep, and its release wakes them all at once.
 from __future__ import annotations
 
 import asyncio
-import time
+from time import perf_counter
 
 from .. import admission as admission_mod
 from .. import faults
@@ -65,10 +65,25 @@ class Server:
         self._h_classify = self._reg.hist("pipeline.classify")
         self._h_dispatch = self._reg.hist("pipeline.dispatch")
         self._h_reply_write = self._reg.hist("pipeline.reply_write")
+        # a served burst's stages (docs/observability.md): ONE chain of
+        # perf_counter stamps from the read's return to the next read's
+        # call, each boundary read once and shared by the stage it ends
+        # and the stage it begins — route, server.native_burst,
+        # pipeline.reply_write, tail. Histograms only, no profiler
+        # annotation per burst. With pipeline.parse and serve.py_apply
+        # (models/manager.py) they tile the handler's share of loop.busy;
+        # what is left of it is asyncio's own. write_wait is a drain()
+        # called with bytes still in the transport: NOT loop work, the
+        # tail stops before it and goes on after it.
+        self._h_route = self._reg.hist("serve.route")
+        self._h_tail = self._reg.hist("serve.tail")
+        self._h_write_wait = self._reg.hist("serve.write_wait")
         # lock.wait_serve (obs/span.py): wanting the engine's five repo
         # locks to holding them — what pipeline.dispatch and
         # server.py_dispatch INCLUDE for every command that queues behind
-        # a drain, summed over connections; server.native_burst does not
+        # a drain, summed over connections; server.native_burst does not.
+        # A burst records it only when it slept: a take of free locks
+        # is the route's, and costs neither clock read nor annotation
         self._s_lock_wait = self._reg.seam("lock.wait_serve")
 
     async def start(self) -> None:
@@ -115,7 +130,7 @@ class Server:
         # pipeline.accept: one sample per connection, handler entry to
         # first read — the setup cost a new client pays before its first
         # command can even be parsed
-        t_acc = time.perf_counter() if reg.enabled else 0.0
+        t_acc = perf_counter() if reg.enabled else 0.0
         # jlint: blocking-ok — lib() is memoised at boot (warmup builds
         # an auto-engine Database before serving starts), so this never
         # reaches the loader's listdir/compile path on the loop
@@ -125,43 +140,63 @@ class Server:
         # per COMMAND, and a demoted connection's pipelined burst became
         # a per-segment wakeup storm — measured 30-40x under the native
         # path's batched writes on the same burst. The engine's replies
-        # bypass this buffer (they arrive pre-batched); flush() runs
-        # before every direct engine write, so cross-path reply order is
-        # exactly command order.
+        # bypass this buffer (they arrive pre-batched); it is written
+        # out before every direct engine write, so cross-path reply
+        # order is exactly command order.
         out = bytearray()
         resp = Respond(out.extend)
 
-        def flush(bound: int = 0) -> None:
-            if len(out) > bound:
-                t_w = time.perf_counter() if reg.enabled else 0.0
-                writer.write(bytes(out))
-                if t_w:
-                    self._h_reply_write.record(time.perf_counter() - t_w)
-                out.clear()
+        def flush(bound: int = 0) -> float:
+            """Hand `out` to the writer once it is past ``bound``; the
+            seconds this put into pipeline.reply_write (0.0: nothing
+            written, or observation off), which a caller with a stage
+            open moves that stage's start forward by."""
+            if len(out) <= bound:
+                return 0.0
+            t_w = perf_counter() if reg.enabled else 0.0
+            writer.write(bytes(out))
+            out.clear()
+            if not t_w:
+                return 0.0
+            seconds = perf_counter() - t_w
+            self._h_reply_write.record(seconds)
+            return seconds
 
         engine = getattr(self._database, "native_engine", None)
         use_native = engine is not None
         buf = bytearray()
+        # bytes a write left in the transport: the socket did not take
+        # the reply whole (bound once, read once per burst)
+        unsent = writer.transport.get_write_buffer_size
         self._conns.add(writer)
         try:
             adm_armed = self._database.admission.armed
             if t_acc:
-                self._h_accept.record(time.perf_counter() - t_acc)
+                self._h_accept.record(perf_counter() - t_acc)
+            t_tail = 0.0  # start of the open serve.tail stage; 0.0: none
             while True:
                 # pipeline.read: one socket read await. Deliberately
                 # includes client idle time — under saturation this IS
                 # the kernel-queue wait, and an idle connection's long
                 # reads land in the top buckets where windowed quantiles
                 # (SYSTEM LATENCY WINDOW) can separate them from load.
-                t_rd = time.perf_counter() if reg.enabled else 0.0
+                # Where it is called, a served burst's tail ends.
+                t_rd = perf_counter() if reg.enabled else 0.0
+                if t_tail:
+                    if t_rd:
+                        self._h_tail.record(t_rd - t_tail)
+                    t_tail = 0.0
                 data = await reader.read(1 << 16)
+                t_rt = 0.0  # where it returns, serve.route begins
                 if t_rd:
-                    self._h_read.record(time.perf_counter() - t_rd)
+                    t_rt = perf_counter()
+                    self._h_read.record(t_rt - t_rd)
                 if not data:
                     break
                 # the overload signal's arrival stamp: queue time for
                 # every command in this chunk runs from this read
-                t_arr = time.perf_counter() if adm_armed else 0.0
+                t_arr = (t_rt or perf_counter()) if adm_armed else 0.0
+                routed = False  # this chunk took the Python path for busy()
                 if use_native:
                     go_native = not any(
                         m.busy() for m in self._engine_managers()
@@ -186,18 +221,29 @@ class Server:
                         # through the per-repo Python path so unrelated
                         # repos never wait on the engine's two-lock
                         # boundary
+                        routed = True
                         parser.append(bytes(buf))
                         buf.clear()
                     else:
                         buf += data
-                        use_native = await self._apply_native(
+                        t_tail = await self._apply_native(
                             engine, buf, parser, resp, flush, writer, out,
-                            t_arr,
+                            t_arr, t_rt,
                         )
-                        if use_native:
-                            flush()
-                            await writer.drain()
+                        if t_tail is not None:
+                            # still the tail, but for a deferred
+                            # command's reply (pipeline.reply_write's)
+                            # and a wait for the socket (not loop work)
+                            wrote_s = flush()
+                            if t_tail and wrote_s:
+                                t_tail += wrote_s
+                            if unsent():
+                                t_tail = await self._write_wait(writer, t_tail)
+                            else:
+                                await writer.drain()
                             continue
+                        use_native = False
+                        t_tail = 0.0
                         data = b""  # demoted: tail already moved into parser
                 parser.append(data)
                 try:
@@ -207,13 +253,22 @@ class Server:
                     # the for-loop form raised it
                     it = iter(parser)
                     while True:
-                        t_ps = time.perf_counter() if reg.enabled else 0.0
+                        t_ps = perf_counter() if reg.enabled else 0.0
                         cmd = next(it, None)
                         if t_ps:
-                            self._h_parse.record(time.perf_counter() - t_ps)
+                            self._h_parse.record(perf_counter() - t_ps)
                         if cmd is None:
                             break
                         await self._dispatch_py(resp, cmd, writer, out, t_arr)
+                        # the Python path's commands by cause (the third
+                        # is the engine's hand-back, in _apply_native),
+                        # counted once dispatched AND applied: a command
+                        # asleep in a lock's line is in neither count
+                        # nor demoted_cmds, so the two agree at any edge
+                        if routed:
+                            reg.note_serving("busy_routed_cmds")
+                        else:
+                            reg.note_serving("demoted_conn_cmds")
                         flush(1 << 16)  # bound the reply buffer mid-burst
                 except RespError as e:
                     resp.err(str(e))
@@ -260,11 +315,11 @@ class Server:
             # pipeline.classify: the admission toll per command on an
             # armed node — classify plus the gate's token walk, timed
             # for refusals and admissions alike
-            t_cl = time.perf_counter() if self._reg.enabled else 0.0
+            t_cl = perf_counter() if self._reg.enabled else 0.0
             cls = admission_mod.classify(cmd)
             hint = await admission_mod.gate(adm, cls)
             if t_cl:
-                self._h_classify.record(time.perf_counter() - t_cl)
+                self._h_classify.record(perf_counter() - t_cl)
             if hint is not None:
                 resp.err(
                     admission_mod.busy_reply(
@@ -279,18 +334,18 @@ class Server:
                 # load while the shed itself took microseconds
                 await asyncio.sleep(0)
                 return
-            t0 = time.perf_counter()
+            t0 = perf_counter()
             await self._database.apply_async(resp, cmd)
-            t1 = time.perf_counter()
+            t1 = perf_counter()
             adm.done(cls, t1 - (t_arr or t0))
             if self._reg.enabled:
                 self._h_py.record(t1 - t0)
                 self._h_dispatch.record(t1 - t0)
             return
-        t0 = time.perf_counter() if self._reg.enabled else 0.0
+        t0 = perf_counter() if self._reg.enabled else 0.0
         await self._database.apply_async(resp, cmd)
         if t0:
-            el = time.perf_counter() - t0
+            el = perf_counter() - t0
             self._h_py.record(el)
             self._h_dispatch.record(el)
 
@@ -300,32 +355,57 @@ class Server:
     def _engine_managers(self):
         return [self._database.manager(n) for n in self._ENGINE_TYPES]
 
+    async def _write_wait(self, writer, t_stage: float) -> float:
+        """``await writer.drain()`` with bytes still in the transport:
+        the socket did not take the reply whole. serve.write_wait, NOT
+        loop work — the caller's open stage (started at ``t_stage``)
+        stops before it and goes on after it: its start stamp comes back
+        moved forward by the wait. Below asyncio's high-water mark
+        (64 KiB) the drain returns at once and the sample is the call's
+        own cost; past it the handler sleeps until the transport has
+        written down to the low-water mark."""
+        t0 = perf_counter() if t_stage else 0.0
+        await writer.drain()
+        if not t0:
+            return 0.0
+        seconds = perf_counter() - t0
+        self._h_write_wait.record(seconds)
+        return t_stage + seconds
+
     async def _apply_native(
-        self, engine, buf, parser, resp, flush, writer, out, t_arr=0.0
+        self, engine, buf, parser, resp, flush, writer, out, t_arr=0.0,
+        t_route=0.0,
     ):
         """Drain `buf` through the native serving engine; commands it
         can't settle route through the normal per-repo async path in
-        order (`resp` buffers those replies; `flush` pushes them to the
-        writer before the engine's next direct write so the reply stream
+        order (`resp` buffers those replies in `out`, which is written
+        out before the engine's next direct write so the reply stream
         stays in command order). A reply of any size is the engine's:
         its reply buffer grows to the reply inside `scan_apply`, and
         only one past that buffer's ceiling comes back as a command for
-        the Python path. Returns True (stay native) or False
-        (demote this connection to the Python path; tail moved into
-        `parser` — on malformed input the Python parser then renders its
-        specific error and the connection drops)."""
-        mgrs = self._engine_managers()
+        the Python path.
 
-        def demote() -> bool:
+        ``t_route`` is the stamp of the read that delivered these bytes
+        (0.0: observation off), where the burst's stage chain starts.
+        Each round of the loop below is route -> burst -> reply write ->
+        tail; a deferred command's `_dispatch_py` lies between two rounds
+        and belongs to neither. Returns the start stamp of the last
+        round's tail, still open (stay native; 0.0 with observation
+        off), or None (demote this connection to the Python path; tail
+        moved into `parser` — on malformed input the Python parser then
+        renders its specific error and the connection drops)."""
+        mgrs = self._engine_managers()
+        reg = self._reg
+
+        def demote() -> None:
             # the whole connection moves to the Python dispatch path for
             # its remaining lifetime — counted so the live fallback_frac
             # (SYSTEM METRICS SERVING lines) reflects demotion events,
             # and traced so SYSTEM TRACE shows when/why serving slowed
-            self._reg.note_serving("demotions")
-            self._reg.trace_event("server", "demote")
+            reg.note_serving("demotions")
+            reg.trace_event("server", "demote")
             parser.append(bytes(buf))
             buf.clear()
-            return False
 
         # DATABASE MAP order (TREG, TLOG, G, PN, UJSON), the order
         # database.all_locks takes them in
@@ -340,9 +420,14 @@ class Server:
             # none: a burst holds nothing while it sleeps, so it can
             # never deadlock against the shutdown snapshot (all_locks),
             # and it takes free locks whoever is in line (RepoLock).
-            t_wait = self._s_lock_wait.begin()
-            slept = await RepoLock.acquire_all(locks)
-            self._s_lock_wait.end(t_wait)
+            slept = not RepoLock.take_all(locks)
+            if slept:
+                # the sleep is lock.wait_serve's, not the route's
+                t_wait = self._s_lock_wait.begin()
+                await RepoLock.acquire_all(locks)
+                waited_s = self._s_lock_wait.end(t_wait)
+                if t_route:
+                    t_route += waited_s
             try:
                 if slept and any(m._shutdown for m in mgrs):
                     # a burst that slept behind a drain may wake after a
@@ -362,27 +447,35 @@ class Server:
                     # freeze that idle-evicts our peer connections
                     # (caught by jlint's interprocedural JL101)
                     await faults.async_point("native.scan_apply")
-                    t0 = time.perf_counter() if self._reg.enabled else 0.0
+                    # serve.route ends, server.native_burst begins
+                    t_held = 0.0
+                    if t_route:
+                        t_held = perf_counter()
+                        self._h_route.record(t_held - t_route)
                     rc, consumed, replies, unhandled, changed = (
                         engine.scan_apply(buf)
                     )
-                    if t0:
+                    # the burst ends; the reply write, or with nothing
+                    # to write the tail, begins
+                    t_tail = 0.0
+                    if t_held:
                         # pipeline.dispatch reuses the burst elapsed —
-                        # one engine call settles the whole burst and
-                        # the profiler must not add clock reads here
-                        el = time.perf_counter() - t0
+                        # one engine call settles the whole burst
+                        t_tail = perf_counter()
+                        el = t_tail - t_held
                         self._h_burst.record(el)
                         self._h_dispatch.record(el)
                 except faults.FaultError:
                     return demote()
                 if replies:
-                    flush()  # deferred-command replies precede these
-                    t_w = time.perf_counter() if self._reg.enabled else 0.0
+                    if out:  # deferred-command replies precede these
+                        writer.write(bytes(out))
+                        out.clear()
                     writer.write(replies)
-                    if t_w:
-                        self._h_reply_write.record(
-                            time.perf_counter() - t_w
-                        )
+                    reg.note_serving("reply_bytes", len(replies))
+                    if t_tail:
+                        t_scan, t_tail = t_tail, perf_counter()
+                        self._h_reply_write.record(t_tail - t_scan)
                 for mgr, ch in zip(mgrs, changed):
                     if ch:
                         mgr._maybe_proactive_flush()
@@ -402,22 +495,13 @@ class Server:
                     id(writer), writer.transport.get_write_buffer_size()
                 )
                 if adm.queued_bytes > adm.queue_bytes_cap:
-                    await writer.drain()
+                    t_tail = await self._write_wait(writer, t_tail)
                     adm.note_conn_queued(
                         id(writer),
                         writer.transport.get_write_buffer_size(),
                     )
-            if rc == 1:  # one command for the Python path, in order
-                await self._dispatch_py(resp, unhandled, writer, out, t_arr)
-                # a burst of repeatedly deferring reads (e.g. rows whose
-                # drained base the host lacks, or replies past the
-                # ceiling of the engine's reply buffer) produces no
-                # engine write to piggyback on: bound the buffer here
-                # exactly like the demoted loop does
-                flush(1 << 16)
-                continue
-            if rc == 2:  # reply buffer flushed; keep going
-                continue
+            if rc == 0:  # consumed all complete commands
+                return t_tail
             if rc < 0:
                 # rc -1: malformed input — the Python parser (the oracle)
                 # renders its specific error message so both serving paths
@@ -425,7 +509,24 @@ class Server:
                 # connection. rc -2: oversized command — Python handles
                 # this connection from here on.
                 return demote()
-            return True  # rc == 0: consumed all complete commands
+            # another round: this one's tail ends here. rc 2: the reply
+            # buffer was flushed, the next round's route begins at once.
+            # rc 1: one command for the Python path first, in order
+            t_route = 0.0
+            if t_tail:
+                t_route = perf_counter()
+                self._h_tail.record(t_route - t_tail)
+            if rc == 1:
+                await self._dispatch_py(resp, unhandled, writer, out, t_arr)
+                reg.note_serving("deferred_cmds")
+                # a burst of repeatedly deferring reads (e.g. rows whose
+                # drained base the host lacks, or replies past the
+                # ceiling of the engine's reply buffer) produces no
+                # engine write to piggyback on: bound the buffer here
+                # exactly like the demoted loop does
+                flush(1 << 16)
+                if t_route:
+                    t_route = perf_counter()
 
     async def dispose(self) -> None:
         """Stop listening and close client connections (the reference

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import functools
 
-from ..obs import span
+from ..obs import SERVING, span
 from ..obs.registry import JOURNAL_KEYS as _JOURNAL_KEYS  # noqa: F401 (re-export)
 from ..obs.registry import MetricsRegistry
 
@@ -115,10 +115,6 @@ def note_journal(counter: str, n: int = 1) -> None:
     DEFAULT.note_journal(counter, n)
 
 
-def note_serving(counter: str, n: int = 1) -> None:
-    DEFAULT.note_serving(counter, n)
-
-
 def note_drain(name: str, n_keys: int, seconds: float) -> None:
     DEFAULT.note_drain(name, n_keys, seconds)
 
@@ -198,7 +194,7 @@ def metric_lines(
         lines.insert(0, f"LANE count {lane.get('count', 0)}")
         lines.insert(0, f"LANE id {lane.get('id', 0)}")
     if serving and any(serving.values()):
-        for k in ("native_cmds", "demoted_cmds", "demotions", "busy_refusals"):
+        for k in ("native_cmds", "demoted_cmds") + SERVING:
             lines.append(f"SERVING {k} {serving.get(k, 0)}")
         total = serving.get("native_cmds", 0) + serving.get("demoted_cmds", 0)
         if total:

@@ -8,7 +8,7 @@ histogram/gauge/trace event added without documentation is invisible to
 operators. Same cure as pass 4, same mechanics:
 
 * every ``.hist(...)`` / ``.seam(...)`` / ``.gauge_set(...)`` /
-  ``.tally(...)`` call in the product tree
+  ``.tally(...)`` / ``.note_serving(...)`` call in the product tree
   must use a STRING LITERAL name, every ``.trace_event(...)`` literal
   subsystem+event args, and every ``timed_drain("<TYPE>", ...)``
   decorator a literal type (its histogram is ``drain.<TYPE>``); each
@@ -17,8 +17,9 @@ operators. Same cure as pass 4, same mechanics:
   (JL501);
 * every manifest entry must still have a call site and a
   non-placeholder description (JL502: stale / undescribed);
-* every histogram/gauge/tally name must be pre-registered in
-  ``jylis_tpu/obs/__init__.py``'s SEAMS/GAUGES/TALLIES tuples (and every
+* every histogram/gauge/tally/serving-counter name must be
+  pre-registered in ``jylis_tpu/obs/__init__.py``'s
+  SEAMS/GAUGES/TALLIES/SERVING tuples (and every
   declared name used), so a scrape shows the full surface from boot and
   the declarations can't rot (JL501/JL502).
 
@@ -29,7 +30,7 @@ step (scripts/metrics_smoke.py) reads the same manifest to assert every
 histogram/gauge is actually present in a live node's scrape.
 
 Manifest keys are ``<kind>:<name>`` with kind in {hist, gauge, counter,
-trace}; trace names are ``<subsystem>.<event>``.
+serving, trace}; trace names are ``<subsystem>.<event>``.
 """
 
 from __future__ import annotations
@@ -60,6 +61,9 @@ _CALL_KINDS = {
     "gauge_set": ("gauge", 1),
     # registry.tally("drain.<TYPE>.<kind>", n): an exact event counter
     "tally": ("counter", 1),
+    # registry.note_serving("<kind>"[, n]): an exact serving-path
+    # counter, a `kind` of jylis_serving_total
+    "note_serving": ("serving", 1),
     "trace_event": ("trace", 2),
 }
 
@@ -140,12 +144,13 @@ _PARITY = (
     ("hist", "SEAMS", "histogram", "records into"),
     ("gauge", "GAUGES", "gauge", "sets"),
     ("counter", "TALLIES", "tally", "adds to"),
+    ("serving", "SERVING", "serving counter", "adds to"),
 )
 _DECLARED = tuple(p[1] for p in _PARITY)
 
 
-def declared_names(root: str = ROOT) -> tuple[set[str], set[str], set[str]]:
-    """(SEAMS, GAUGES, TALLIES) parsed from jylis_tpu/obs/__init__.py by
+def declared_names(root: str = ROOT) -> tuple[set[str], ...]:
+    """(SEAMS, GAUGES, TALLIES, SERVING) parsed from jylis_tpu/obs/__init__.py by
     AST — jlint must not import the product package (jylis_tpu imports
     jax at import time)."""
     path = os.path.join(root, OBS_INIT_REL)

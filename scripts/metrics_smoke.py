@@ -205,6 +205,7 @@ def main() -> int:
     hists = sorted(n[5:] for n in manifest if n.startswith("hist:"))
     gauges = sorted(n[6:] for n in manifest if n.startswith("gauge:"))
     counters = sorted(n[8:] for n in manifest if n.startswith("counter:"))
+    serving = sorted(n[8:] for n in manifest if n.startswith("serving:"))
 
     body = _boot_and_scrape(lanes=1)
 
@@ -221,6 +222,9 @@ def main() -> int:
         _, typ, kind = name.split(".")
         if f'jylis_drain_total{{type="{typ}",kind="{kind}"}}' not in body:
             failures.append(f"  manifest counter absent from scrape: {name}")
+    for name in serving:  # a kind of the serving totals
+        if f'jylis_serving_total{{kind="{name}"}}' not in body:
+            failures.append(f"  manifest serving counter absent from scrape: {name}")
     # the traffic above must have armed the dispatch surface
     m = re.search(
         r'jylis_seam_latency_seconds_count\{seam="server\.(native_burst|'
@@ -281,7 +285,7 @@ def main() -> int:
         return 1
     print(
         f"metrics-smoke: {n_samples} valid samples; {len(hists)} histograms"
-        f" + {len(gauges)} gauges + {len(counters)} counters all present; "
+        f" + {len(gauges)} gauges + {len(counters) + len(serving)} counters all present; "
         f"{n_hist_series} cumulative _bucket series valid; lanes={lanes} aggregate scrape: "
         f"{n_lane_samples} samples, {n_lane_hist} _bucket series, "
         f"per-lane + aggregate series ok"

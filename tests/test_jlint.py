@@ -728,10 +728,11 @@ def test_real_metrics_manifest_matches_sites():
     sites, problems = pass_metrics.extract_sites()
     assert problems == []
     assert sorted(manifest) == sorted(sites)
-    seams, gauges, tallies = pass_metrics.declared_names()
+    seams, gauges, tallies, serving = pass_metrics.declared_names()
     assert {n[5:] for n in manifest if n.startswith("hist:")} == seams
     assert {n[6:] for n in manifest if n.startswith("gauge:")} == gauges
     assert {n[8:] for n in manifest if n.startswith("counter:")} == tallies
+    assert {n[8:] for n in manifest if n.startswith("serving:")} == serving
 
 
 # ---- pass 6: cross-lane shared-state manifest (JL601/JL602) -----------------

@@ -572,3 +572,62 @@ def test_write_heat_disabled_registry_counts_nothing():
     db.apply(resp, [b"GCOUNT", b"INC", b"cold", b"1"])
     db.flush_deltas(lambda deltas: None)
     assert "GCOUNT" not in db.metrics.write_heat
+
+
+# ---- the Python path's commands by cause -------------------------------------
+
+_CAUSES = ("busy_routed_cmds", "deferred_cmds", "demoted_conn_cmds")
+
+
+@pytest.mark.parametrize("cause", _CAUSES)
+def test_python_path_commands_are_counted_by_cause(cause):
+    """The three causes partition what the server's Python path
+    dispatches: a chunk that met a held repo lock (busy()), a command
+    the engine handed back, a connection with no engine. Their sum is
+    demoted_cmds; all three are on SYSTEM METRICS and the scrape."""
+    from jylis_tpu.obs import prom
+
+    n = 5
+
+    async def main():
+        db = Database(identity=41)
+        if db.native_engine is None and cause != "demoted_conn_cmds":
+            pytest.skip("no native engine on this host")
+        burst = b"TREG SET k v 1\r\n" + b"TREG GET k\r\n" * (n - 1)
+        if cause == "busy_routed_cmds":
+            # somebody holds ANOTHER type's lock across a yield: the
+            # whole chunk takes the Python path, and applies inline
+            lock = db.manager("GCOUNT")._lock
+            await lock.acquire()
+            await _drive_server(db, burst, n)
+            lock.release()
+        elif cause == "deferred_cmds":
+            await _drive_server(db, b"SYSTEM VERSION\r\n" * n, n)
+        else:
+            db.native_engine = None
+            await _drive_server(db, burst, n)
+        return db
+
+    db = asyncio.run(main())
+    serving = db.serving_totals()
+    assert serving[cause] == n
+    assert [serving[c] for c in _CAUSES if c != cause] == [0, 0]
+    assert sum(serving[c] for c in _CAUSES) == serving["demoted_cmds"] == n
+    assert db.metrics.hist("serve.py_apply").count == n
+    resp = _Resp()
+    db.apply(resp, [b"SYSTEM", b"METRICS"])
+    assert f"SERVING {cause} {n}" in [str(s) for s in resp.strings()]
+    assert f'jylis_serving_total{{kind="{cause}"}} {n}\n' in prom.render(db)
+
+
+def test_engine_reply_bytes_are_counted_per_burst():
+    async def main():
+        db = Database(identity=42)
+        if db.native_engine is None:
+            pytest.skip("no native engine on this host")
+        got = await _drive_server(db, b"GCOUNT INC k 7\r\nGCOUNT GET k\r\n", 2)
+        assert got == b"+OK\r\n:7\r\n"
+        assert db.serving_totals()["reply_bytes"] == len(got)
+        assert db.serving_totals()["deferred_cmds"] == 0
+
+    asyncio.run(main())

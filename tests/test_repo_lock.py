@@ -17,6 +17,7 @@ import time
 import pytest
 
 import jylis_tpu  # noqa: F401
+from jylis_tpu.models.database import DATA_TYPE_NAMES
 from jylis_tpu.models.manager import RepoLock
 from jylis_tpu.models.repo_treg import PENDING_DRAIN_THRESHOLD
 from jylis_tpu.obs import loop as loop_mod
@@ -310,3 +311,115 @@ def test_after_a_drain_the_loop_settles_many_commands_per_iteration():
         assert SLOW / 2 < wait.max < held + 0.05, (wait.max, held)
 
     asyncio.run(asyncio.wait_for(main(), 60), loop_factory=loop_mod.new_event_loop)
+
+
+# ---- holds that can be seen held are spans -----------------------------------
+
+
+def test_a_threaded_drains_hold_contains_its_drain_and_inline_takers_record_none():
+    """lock.hold_serve runs from holding to released around the hop to
+    the worker thread, the drain and the hop back, so it is at least the
+    drain.<TYPE> sample inside it. Commands that slept behind it take
+    the lock the long way too, apply inline and let go within their task
+    step: nobody could see them hold it, and they record no hold."""
+
+    async def main():
+        server, db = make_server()
+        await server.start()
+        reg = db.metrics
+        try:
+            slow_down_drain(db, "GCOUNT")
+            db.manager("GCOUNT").repo.converge(b"k", {99: 5})
+            slow = asyncio.create_task(send_recv(server.port, b"GCOUNT GET k\r\n"))
+            while not db.manager("GCOUNT").busy():
+                await asyncio.sleep(0.005)
+            waiters = [asyncio.create_task(send_recv(server.port, b"GCOUNT INC x 1\r\n"))
+                       for _ in range(3)]
+            assert [await w for w in waiters] == [b"+OK\r\n"] * 3
+            assert await slow == b":5\r\n"
+        finally:
+            await server.dispose()
+        hold, drain = reg.hist("lock.hold_serve"), reg.hist("drain.GCOUNT")
+        assert hold.count == 1 and drain.count == 1
+        assert hold.total >= SLOW and hold.total >= drain.total
+        assert reg.hist("lock.wait_serve").count == 4  # the GET's own take too
+        assert reg.hist("serve.py_apply").count == 3  # the drain ran in the thread
+        for other in ("lock.hold_converge", "lock.hold_flush", "lock.hold_sync"):
+            assert reg.hist(other).count == 0
+
+    run(main())
+
+
+@pytest.mark.parametrize("who,seam,holds", [
+    ("converge", "lock.hold_converge", 1),
+    ("flush", "lock.hold_flush", 1),
+    ("shutdown", "lock.hold_flush", 1),
+    ("digest", "lock.hold_sync", len(DATA_TYPE_NAMES)),
+    ("dump", "lock.hold_sync", 1),
+    ("snapshot", "lock.hold_sync", None),
+])
+def test_every_long_holder_records_its_hold(who, seam, holds):
+    """Cluster apply, heartbeat flush, final flush, digest, state dump
+    and the shutdown snapshot each record holding -> released under
+    their own seam, once per repo lock taken, and the lock is seen held
+    inside."""
+
+    async def main():
+        _server, db = make_server()
+        mgr = db.manager("GCOUNT")
+        h = db.metrics.hist(seam)
+        if who == "converge":
+            await db.converge_async(("GCOUNT", [(b"k", {98: 1})]))
+        elif who == "flush":
+            await mgr.flush_async(lambda deltas: None)
+        elif who == "shutdown":
+            await mgr.clean_shutdown_async()
+        elif who == "digest":
+            await db.sync_digest_async()
+        elif who == "dump":
+            await db.dump_state_async(names={"GCOUNT"})
+        else:
+            async with db.all_locks():
+                assert all(m.busy() for m in db.managers())
+                assert h.count == 0  # recorded when it lets go
+        assert h.count == (holds if holds is not None else len(list(db.managers())))
+        assert h.total > 0 and not mgr.busy()
+        others = {"lock.hold_serve", "lock.hold_converge", "lock.hold_flush",
+                  "lock.hold_sync"} - {seam}
+        assert all(db.metrics.hist(o).count == 0 for o in others)
+
+    run(main())
+
+
+def test_an_armed_hold_is_an_annotation_with_the_type_and_the_verb(monkeypatch):
+    from jylis_tpu.obs import span
+
+    made = []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            made.append((name, kwargs))
+
+        def __enter__(self):
+            pass
+
+        def __exit__(self, *exc):
+            pass
+
+    class Profiler:
+        TraceAnnotation = Annotation
+
+    monkeypatch.setattr(span, "_armed", True)
+    monkeypatch.setattr(span, "_profiler", Profiler)
+
+    async def main():
+        _server, db = make_server()
+        db.manager("GCOUNT").repo.converge(b"k", {99: 5})
+        resp = type("R", (), {"__getattr__": lambda self, name: lambda *a: None})()
+        await db.apply_async(resp, [b"GCOUNT", b"GET", b"k"])  # drains: threaded
+        await db.converge_async(("GCOUNT", [(b"k", {98: 1})]))
+
+    run(main())
+    holds = [(n, kw) for n, kw in made if n.startswith("lock.hold_")]
+    assert holds == [("lock.hold_serve", {"type": "GCOUNT", "verb": "GET"}),
+                     ("lock.hold_converge", {"type": "GCOUNT"})]

@@ -41,6 +41,17 @@ NEW_SEAMS = (
     "cluster.apply",
     "repo.flush",
 )
+# a served burst's stages and the repo-lock holds (PR 36)
+SERVE_SEAMS = (
+    "serve.route",
+    "serve.tail",
+    "serve.write_wait",
+    "serve.py_apply",
+    "lock.hold_serve",
+    "lock.hold_converge",
+    "lock.hold_flush",
+    "lock.hold_sync",
+)
 
 
 class _Resp:
@@ -152,7 +163,7 @@ def test_drain_records_its_three_phases_with_their_parent(monkeypatch):
     metrics.drain_phase(repo, metrics.DEVICE)
 
 
-@pytest.mark.parametrize("seam", NEW_SEAMS)
+@pytest.mark.parametrize("seam", NEW_SEAMS + SERVE_SEAMS)
 def test_no_new_seam_reads_as_a_drain_seam(seam):
     """The benchmark's ``models.drain_*`` metrics read ``seam="drain.*"``:
     a new seam whose name matched would change their meaning."""
@@ -165,7 +176,7 @@ def test_no_new_seam_reads_as_a_drain_seam(seam):
 
 def test_new_seams_loop_cpu_and_device_gauge_are_on_the_scrape_from_boot():
     body = prom.render(Database(identity=3))
-    for seam in NEW_SEAMS:
+    for seam in NEW_SEAMS + SERVE_SEAMS:
         assert f'jylis_seam_latency_seconds_count{{seam="{seam}"}} 0\n' in body
         assert f'jylis_seam_latency_seconds_sum{{seam="{seam}"}} 0.000000000\n' in body
     assert "# TYPE jylis_loop_cpu_seconds_total counter\n" in body
@@ -429,3 +440,163 @@ def test_node_profile_window_writes_drains_and_phases_on_the_wall_clock(tmp_path
     # the phases add up to their drains (to within 10%)
     whole = sum(t1 - t0 for t0, t1, _st in steps)
     assert abs(sum(p[2] for p in parts) - whole) < 0.1 * whole
+
+
+# ---- a served burst's stages --------------------------------------------------
+
+# the six that tile the handlers' share of loop.busy
+STAGES = (
+    "serve.route",
+    "server.native_burst",
+    "pipeline.reply_write",
+    "serve.tail",
+    "pipeline.parse",
+    "serve.py_apply",
+)
+
+
+@pytest.mark.parametrize("conns", [1, 8])
+def test_a_served_bursts_stages_tile_the_loops_busy_time(conns):
+    """N native commands over real sockets, one a burst: route, engine
+    burst, reply write and tail count one per burst and, with the two
+    Python-path seams, add up to no more than the loop was busy. A take
+    of free locks is the route's: no lock wait, no hold, is recorded."""
+    per = 40
+    n = conns * per
+
+    async def main():
+        server, db = make_server()
+        if db.native_engine is None:
+            pytest.skip("no native engine on this host")
+        await server.start()
+        assert loop_mod.attach(db.metrics)
+        reg = db.metrics
+
+        async def client(c):
+            reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+            for i in range(per):
+                writer.write(b"TREG SET k%d v%d %d\r\n" % (c, i, i + 1))
+                assert await reader.readexactly(5) == b"+OK\r\n"
+            writer.close()
+
+        try:
+            await asyncio.gather(*(client(c) for c in range(conns)))
+            await asyncio.sleep(0.05)  # the iteration of the last burst is over
+        finally:
+            await server.dispose()
+        for seam in ("serve.route", "server.native_burst", "pipeline.reply_write",
+                     "serve.tail"):
+            assert reg.hist(seam).count == n, seam
+        assert reg.hist("pipeline.read").count >= n
+        stages = sum(reg.hist(s).total for s in STAGES)
+        assert 0 < stages <= reg.hist("loop.busy").total
+        for seam in ("lock.wait_serve", "serve.write_wait", "serve.py_apply",
+                     "lock.hold_serve", "lock.hold_converge", "lock.hold_flush",
+                     "lock.hold_sync"):
+            assert reg.hist(seam).count == 0, seam
+        assert reg.serving_counters["reply_bytes"] == 5 * n
+        assert db.serving_totals()["native_cmds"] == n
+
+    asyncio.run(main(), loop_factory=loop_mod.new_event_loop)
+
+
+class _Writer:
+    """What `_apply_native` needs of a stream writer."""
+
+    def __init__(self):
+        self.data = bytearray()
+        self.transport = self
+
+    def write(self, data):
+        self.data += data
+
+    def get_write_buffer_size(self):
+        return 0
+
+    async def drain(self):
+        pass
+
+
+@pytest.mark.parametrize("held", [False, True])
+def test_a_bursts_sleep_is_the_lock_waits_and_not_the_routes(held):
+    """An unslept burst leaves lock.wait_serve where it was; one that
+    slept behind a held lock adds the sleep to it and NOT to
+    serve.route, which stops where the sleep began and goes on after."""
+    from jylis_tpu.native.resp import make_parser
+    from jylis_tpu.server.resp import Respond
+
+    async def main():
+        server, db = make_server()
+        if db.native_engine is None:
+            pytest.skip("no native engine on this host")
+        reg = db.metrics
+        lock = db.manager("PNCOUNT")._lock
+        out = bytearray()
+        writer = _Writer()
+        if held:
+            await lock.acquire()
+        burst = asyncio.create_task(server._apply_native(
+            db.native_engine, bytearray(b"GCOUNT INC x 1\r\n"), make_parser(),
+            Respond(out.extend), lambda bound=0: 0.0, writer, out, 0.0,
+            time.perf_counter()))
+        if held:
+            await asyncio.sleep(0.2)
+            assert not burst.done()
+            lock.release()
+        t_tail = await burst
+        assert t_tail and bytes(writer.data) == b"+OK\r\n"
+        wait, route = reg.hist("lock.wait_serve"), reg.hist("serve.route")
+        assert route.count == 1 and reg.hist("server.native_burst").count == 1
+        if held:
+            assert wait.count == 1 and wait.total >= 0.19
+            assert route.total < 0.1, route.total
+        else:
+            assert wait.count == 0
+        assert not db.manager("GCOUNT").busy()
+
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("size,waits", [(1000, 0), (4 << 20, 1)])
+def test_a_reply_the_socket_does_not_take_whole_is_one_write_wait(size, waits):
+    """A reply past the transport's high-water mark to a client that
+    does not read: the handler waits in drain(), one serve.write_wait
+    sample as long as the client kept it waiting, and none of it in the
+    tail. A 1 KB reply goes out whole: no sample."""
+    import socket
+
+    async def main():
+        server, db = make_server()
+        if db.native_engine is None:
+            pytest.skip("no native engine on this host")
+        await server.start()
+        reg = db.metrics
+        loop = asyncio.get_running_loop()
+        try:
+            value = b"v" * size
+            assert await send_recv(
+                server.port, b"*5\r\n$4\r\nTREG\r\n$3\r\nSET\r\n$1\r\nk\r\n$%d\r\n%s\r\n$1\r\n1\r\n"
+                % (size, value)) == b"+OK\r\n"
+            tail0 = reg.hist("serve.tail").total
+            sock = socket.socket()
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+            sock.setblocking(False)
+            await loop.sock_connect(sock, ("127.0.0.1", server.port))
+            await loop.sock_sendall(sock, b"TREG GET k\r\n")
+            await asyncio.sleep(0.3)  # does not read
+            wait = reg.hist("serve.write_wait")
+            assert wait.count == 0  # a waiting handler has recorded nothing yet
+            got = b""
+            while not got.endswith(b"\r\n:1\r\n"):
+                got += await asyncio.wait_for(loop.sock_recv(sock, 1 << 20), 5)
+            assert value in got
+            sock.close()
+            await asyncio.sleep(0.05)
+            assert wait.count == waits
+            if waits:
+                assert wait.total >= 0.25, wait.total
+                assert reg.hist("serve.tail").total - tail0 < 0.1
+        finally:
+            await server.dispose()
+
+    asyncio.run(main())
