@@ -415,10 +415,11 @@ class RepoManager:
     # under the same lock keeps liveness traffic flowing with identical
     # lattice results. The yield is by TIME, checked after each slice: a
     # lock held across a yield is one every client command of the type
-    # then meets held (the native burst demotes to the Python path and
-    # sleeps in the lock's line), so a fold that is over in a couple of
-    # milliseconds — a peer's 500 ms flush of 1 KB registers — runs
-    # through, and only a fold that would hold the loop longer yields.
+    # then meets held (its native burst sleeps in the lock's line, and a
+    # burst of another type leaves the engine for the Python path), so
+    # a fold that is over in a couple of milliseconds — a peer's 500 ms
+    # flush of 1 KB registers — runs through, and only a fold that
+    # would hold the loop longer yields.
     CONVERGE_SLICE = 256
     CONVERGE_RUN_S = 0.002
 
@@ -461,8 +462,15 @@ class RepoManager:
             self.flush_deltas(fn)
 
     def busy(self) -> bool:
-        """True while a (possibly threaded) repo access holds the lock —
-        the server's native fast path defers to Python while true."""
+        """True while a (possibly threaded) repo access holds the lock.
+        The server's route (server.py `_handle_client`) reads it of the
+        engine's five managers when a chunk arrives: a chunk whose first
+        command names THIS type stays native and sleeps for the lock
+        (the Python path would sleep in the same line); a chunk of
+        another type, or one whose type cannot be told, takes the
+        per-repo Python path and never meets this lock; with
+        `admission_cap` set this type's chunks take it too, so the wait
+        counts in `_inflight`."""
         return self._lock.locked()
 
     async def clean_shutdown_async(self) -> None:

@@ -150,6 +150,7 @@ def _declare(c: ctypes.CDLL) -> None:
             i32,
             [vp, vp, i64, vp, i64, i64, pi64, pi64, vp, vp, i32, pi32, vp],
         ),
+        "jy_eng_first_type": (i32, [u8p, i64]),
     }
     for fn_name, (restype, argtypes) in sigs.items():
         fn = getattr(c, fn_name)
@@ -809,6 +810,15 @@ class ServeEngine:
             ]
             del view
         return rc, consumed.value, replies, unhandled, tuple(self._changed)
+
+
+    def first_type(self, head: bytes) -> int:
+        """The type the first command of ``head`` addresses, as
+        `scan_apply` would see it: an index into the `changed` order
+        (G, PN, TREG, TLOG, UJSON), 5 for any other first word, -1 when
+        that cannot be told (incomplete or malformed). Reads the first
+        command only."""
+        return self._lib.jy_eng_first_type(head, len(head))
 
 
 # the counter-only name the round-3 engine shipped under; kept for callers

@@ -128,7 +128,9 @@ SEAMS = (
 # cause: commands of a chunk the busy() rule routed (a repo lock was
 # held when its bytes arrived), commands the engine handed back (rc 1),
 # commands of a connection with no engine or demoted for good.
-# reply_bytes: bytes of engine replies handed to writers.
+# reply_bytes: bytes of engine replies handed to writers. slept_bursts:
+# native bursts that found a repo lock held, slept for it holding
+# nothing and then ran in the engine (or were demoted by a shutdown).
 SERVING = (
     "demotions",
     "busy_refusals",
@@ -136,6 +138,7 @@ SERVING = (
     "deferred_cmds",
     "demoted_conn_cmds",
     "reply_bytes",
+    "slept_bursts",
 )
 
 # Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`

@@ -234,4 +234,9 @@ class MetricsRegistry:
         ]
         # a type that counts and never drains (ENGINE, the reply buffer)
         parts += [f"{typ}: {t[2:]}" for typ, t in tallies.items() if typ in live]
+        # the serving-path counters (obs.SERVING), once any has counted
+        if any(self.serving_counters.values()):
+            parts.append("SERVING: " + ", ".join(
+                f"{n} {kind}" for kind, n in self.serving_counters.items()
+            ))
         return "; ".join(parts) if parts else "no drains"

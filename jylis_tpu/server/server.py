@@ -198,9 +198,25 @@ class Server:
                 t_arr = (t_rt or perf_counter()) if adm_armed else 0.0
                 routed = False  # this chunk took the Python path for busy()
                 if use_native:
-                    go_native = not any(
-                        m.busy() for m in self._engine_managers()
-                    )
+                    mgrs = self._engine_managers()
+                    go_native = not any(m.busy() for m in mgrs)
+                    if not go_native and not parser.has_pending():
+                        # a repo lock is held. The chunk's first command
+                        # names its type, and when THAT type's lock is
+                        # the one held the Python path would only sleep
+                        # in its line until the same release: the burst
+                        # sleeps for it instead (_apply_native), holding
+                        # nothing, and runs in the engine. Under an
+                        # admission cap the wait must count in the
+                        # manager's _inflight (its typed BUSY): routed
+                        which = engine.first_type(
+                            bytes(buf) + data if buf else data
+                        )
+                        go_native = (
+                            0 <= which < len(mgrs)
+                            and mgrs[which].busy()
+                            and not mgrs[which].admission_cap
+                        )
                     if go_native and parser.has_pending():
                         # a previous burst was routed through the Python
                         # parser and left a split command's head behind:
@@ -217,10 +233,12 @@ class Server:
                         else:
                             buf += tail
                     if not go_native:
-                        # a drain holds a counter lock: route THIS burst
-                        # through the per-repo Python path so unrelated
-                        # repos never wait on the engine's two-lock
-                        # boundary
+                        # every held lock is ANOTHER type's than the one
+                        # this chunk names (or the type cannot be told):
+                        # route THIS chunk through the per-repo Python
+                        # path, which takes its own repo's lock alone,
+                        # so an unrelated repo never waits on the
+                        # engine's five-lock boundary
                         routed = True
                         parser.append(bytes(buf))
                         buf.clear()
@@ -426,6 +444,7 @@ class Server:
                 t_wait = self._s_lock_wait.begin()
                 await RepoLock.acquire_all(locks)
                 waited_s = self._s_lock_wait.end(t_wait)
+                reg.note_serving("slept_bursts")
                 if t_route:
                     t_route += waited_s
             try:

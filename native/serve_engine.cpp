@@ -919,4 +919,23 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
     }
 }
 
+// Which type does the FIRST command of `buf` address, as scan_apply
+// would see it? 0..4: the engine's five, in the changed[] order
+// (G, PN, TREG, TLOG, UJSON); 5: another first word (SYSTEM, MAP, ...);
+// -1: cannot tell (incomplete, malformed, a blank inline line, more than
+// 64 arguments). Reads the first command only, changes nothing: the
+// server asks it of a chunk that arrived while a repo lock was held.
+int32_t jy_eng_first_type(const uint8_t* buf, int64_t len) {
+    int64_t consumed = 0, offs[64], lens[64];
+    int32_t argc = 0;
+    if (resp_scan(buf, len, &consumed, offs, lens, 64, &argc) != 1 ||
+        argc == 0)
+        return -1;
+    static const char* const names[5] = {"GCOUNT", "PNCOUNT", "TREG", "TLOG",
+                                         "UJSON"};
+    for (int32_t i = 0; i < 5; i++)
+        if (word_is(buf, offs[0], lens[0], names[i])) return i;
+    return 5;
+}
+
 }  // extern "C"

@@ -21,11 +21,11 @@ from test_tlog_tallies import lose_base
 SLOW = 0.6  # seconds a slowed drain blocks its worker thread
 
 
-def make_server():
+def make_server(engine="auto"):
     cfg = Config()
     cfg.port = "0"
     cfg.log = Log.create_none()
-    db = Database(identity=1)
+    db = Database(identity=1, engine=engine)
     return Server(cfg, db), db
 
 
@@ -168,6 +168,12 @@ def test_shutdown_serializes_with_inflight_drain_and_fences_queued_writes():
             assert await slow_task == b":7\r\n"
             late = await late_task
             assert late.startswith(b"-SHUTDOWN"), late
+            if db.native_engine is not None:
+                # it slept as a native burst (a chunk of the held type
+                # stays native) and was demoted when it woke (as the
+                # slow GET's connection is, on its next round)
+                serving = db.metrics.serving_counters
+                assert serving["slept_bursts"] == 1 and serving["demotions"] == 2
             # the pre-shutdown INC flushed; the fenced one did not
             gcount = [b for name, b in flushed if name == "GCOUNT"]
             assert any(

@@ -1687,10 +1687,12 @@ def test_chaos_overload_plus_bridge_sigkill_protected_class_serves():
         from jylis_tpu.client import ResponseError
 
         def raw_inc(key, n):
-            # a raw INC serves natively UNLESS its burst lands while a
-            # device drain holds the counter lock — busy() then routes
-            # the burst through the per-command Python path, where the
-            # forced admission.shed failpoint refuses it. A refusal
+            # a raw INC serves natively UNLESS its burst lands while
+            # ANOTHER type's lock is held (a flush, a cluster apply) —
+            # busy() then routes the burst through the per-command
+            # Python path, where the forced admission.shed failpoint
+            # refuses it (behind its own type's drain it sleeps for
+            # the lock and stays native). A refusal
             # mutates nothing (never an accept the node can't honor),
             # so retrying until a burst goes native keeps the exact
             # convergence counts below sound; the contract drilled here

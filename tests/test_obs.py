@@ -620,6 +620,37 @@ def test_python_path_commands_are_counted_by_cause(cause):
     assert f'jylis_serving_total{{kind="{cause}"}} {n}\n' in prom.render(db)
 
 
+def test_a_slept_burst_is_counted_on_every_surface():
+    """A chunk of the type whose lock is held stays native and sleeps
+    for it: one slept_bursts a burst (not a command), no busy_routed_cmds,
+    on SYSTEM METRICS, the scrape and the shutdown log's line."""
+    from jylis_tpu.obs import prom
+
+    async def main():
+        db = Database(identity=43)
+        if db.native_engine is None:
+            pytest.skip("no native engine on this host")
+        lock = db.manager("TREG")._lock
+        await lock.acquire()
+        asyncio.get_running_loop().call_later(0.1, lock.release)
+        got = await _drive_server(db, b"TREG SET k v 1\r\n" + b"TREG GET k\r\n" * 4, 5)
+        assert got.startswith(b"+OK\r\n*2\r\n$1\r\nv\r\n:1\r\n")
+        return db
+
+    db = asyncio.run(main())
+    serving = db.serving_totals()
+    assert serving["slept_bursts"] == 1 and serving["native_cmds"] == 5
+    assert serving["busy_routed_cmds"] == serving["demoted_cmds"] == 0
+    assert db.metrics.hist("lock.wait_serve").count == 1
+    resp = _Resp()
+    db.apply(resp, [b"SYSTEM", b"METRICS"])
+    assert "SERVING slept_bursts 1" in [str(s) for s in resp.strings()]
+    assert 'jylis_serving_total{kind="slept_bursts"} 1\n' in prom.render(db)
+    assert db.metrics.report().endswith("; SERVING: 0 demotions, 0 busy_refusals, "
+        "0 busy_routed_cmds, 0 deferred_cmds, 0 demoted_conn_cmds, "
+        f"{serving['reply_bytes']} reply_bytes, 1 slept_bursts")
+
+
 def test_engine_reply_bytes_are_counted_per_burst():
     async def main():
         db = Database(identity=42)

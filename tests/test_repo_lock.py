@@ -21,6 +21,7 @@ from jylis_tpu.models.database import DATA_TYPE_NAMES
 from jylis_tpu.models.manager import RepoLock
 from jylis_tpu.models.repo_treg import PENDING_DRAIN_THRESHOLD
 from jylis_tpu.obs import loop as loop_mod
+from jylis_tpu.server.resp import Respond
 
 from test_async_serving import SLOW, make_server, slow_down_drain
 from test_server import send_recv
@@ -313,15 +314,237 @@ def test_after_a_drain_the_loop_settles_many_commands_per_iteration():
     asyncio.run(asyncio.wait_for(main(), 60), loop_factory=loop_mod.new_event_loop)
 
 
+# ---- a chunk that arrives while a repo lock is held ---------------------------
+
+
+def array(line: bytes) -> bytes:
+    """An inline command as a RESP array of bulk strings."""
+    words = line.split()
+    return b"*%d\r\n" % len(words) + b"".join(
+        b"$%d\r\n%s\r\n" % (len(w), w) for w in words)
+
+
+async def python_path(lines) -> list[bytes]:
+    """Each command's reply from a node that has no engine: the oracle."""
+    server, _db = make_server(engine="python")
+    await server.start()
+    try:
+        reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+        out = []
+        for line in lines:
+            writer.write(line + b"\r\n")
+            out.append(await asyncio.wait_for(reader.read(1 << 16), 5))
+        writer.close()
+        return out
+    finally:
+        await server.dispose()
+
+
+async def native_server():
+    server, db = make_server()
+    if db.native_engine is None:
+        pytest.skip("no native engine on this host")
+    await server.start()
+    return server, db
+
+
+async def asleep(lock, n):
+    """Until `n` takers sleep in the lock's line."""
+    while len(lock._line) < n:
+        await asyncio.sleep(0.001)
+
+
+_OF_THE_HELD_TYPE = {
+    "TLOG": [b"TLOG INS k a 1", b"TLOG INS k b 2", b"TLOG GET k",
+             b"TLOG INS k c 3", b"TLOG GET k 2", b"TLOG GET other"],
+    "GCOUNT": [b"GCOUNT INC k 2", b"GCOUNT GET k", b"GCOUNT INC k 3",
+               b"GCOUNT GET k", b"GCOUNT GET other"],
+}
+
+
+@pytest.mark.parametrize("form", ["inline", "array"])
+@pytest.mark.parametrize("held", sorted(_OF_THE_HELD_TYPE))
+def test_a_chunk_of_the_held_type_sleeps_for_the_lock_and_stays_native(held, form):
+    """Somebody holds a type's lock across a yield; n connections send
+    one command of THAT type each. The Python path would sleep in that
+    very line, so the chunks stay native: each burst sleeps holding
+    nothing (one lock.wait_serve sample, one slept_bursts), wakes at the
+    release in arrival order and runs in the engine. Replies are the
+    Python path's byte for byte; nothing was routed or demoted."""
+    lines = _OF_THE_HELD_TYPE[held]
+
+    async def main():
+        want = await python_path(lines)
+        server, db = await native_server()
+        reg = db.metrics
+        lock = db.manager(held)._lock
+        conns = []
+        try:
+            await lock.acquire()
+            for i, line in enumerate(lines):
+                reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+                writer.write(line + b"\r\n" if form == "inline" else array(line))
+                conns.append((reader, writer))
+                await asyncio.wait_for(asleep(lock, i + 1), 5)  # in arrival order
+            await asyncio.sleep(0.05)
+            assert all(not r._buffer for r, _w in conns)  # nobody was answered
+            assert reg.serving_counters["slept_bursts"] == 0  # counted on waking
+            lock.release()
+            got = [await asyncio.wait_for(r.read(1 << 16), 5) for r, _w in conns]
+        finally:
+            for _r, w in conns:
+                w.close()
+            await server.dispose()
+        assert got == want
+        serving = db.serving_totals()
+        assert serving["slept_bursts"] == len(lines)
+        assert serving["busy_routed_cmds"] == 0 and serving["demoted_cmds"] == 0
+        assert serving["native_cmds"] == len(lines)
+        assert reg.hist("lock.wait_serve").count == len(lines)
+        assert reg.hist("serve.py_apply").count == 0
+
+    run(main())
+
+
+@pytest.mark.parametrize("form", ["inline", "array"])
+def test_a_chunk_of_another_type_is_answered_before_the_release(form):
+    """What the busy() rule is kept for: beside a long hold of the TLOG
+    lock a TREG client is served at once, on the Python path, which
+    takes the TREG lock alone."""
+    lines = [b"TREG SET t v 1", b"TREG GET t"]
+
+    async def main():
+        want = await python_path(lines)
+        server, db = await native_server()
+        lock = db.manager("TLOG")._lock
+        try:
+            await lock.acquire()
+            wire = b"".join(l + b"\r\n" if form == "inline" else array(l) for l in lines)
+            got = await send_recv(server.port, wire, len(b"".join(want)))
+            assert lock.locked()  # answered while it was held
+            lock.release()
+        finally:
+            await server.dispose()
+        assert got == b"".join(want)
+        serving = db.serving_totals()
+        assert serving["busy_routed_cmds"] == 2 and serving["slept_bursts"] == 0
+        assert db.metrics.hist("lock.wait_serve").count == 0
+
+    run(main())
+
+
+@pytest.mark.parametrize("case", ["split", "python_only", "malformed"])
+def test_a_chunk_whose_type_cannot_be_told_takes_the_python_path(case):
+    """The parent's route, for a chunk that names none of the engine's
+    types under a held (TLOG) lock: a first command split across two
+    reads (its head, and then its rest behind the parser's pending
+    bytes), a type only Python serves, a protocol error."""
+
+    async def main():
+        server, db = await native_server()
+        lock = db.manager("TLOG")._lock
+        try:
+            assert await send_recv(server.port, b"TLOG INS k a 1\r\n") == b"+OK\r\n"
+            await lock.acquire()
+            reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+            if case == "split":
+                writer.write(b"TLOG GE")
+                await asyncio.sleep(0.05)
+                writer.write(b"T k\r\n")
+                # the Python path sleeps in the TLOG line until the release
+                await asyncio.wait_for(asleep(lock, 1), 5)
+                assert not reader._buffer
+                lock.release()
+                want = b"*1\r\n*2\r\n$1\r\na\r\n:1\r\n"
+            elif case == "python_only":
+                writer.write(b"SYSTEM VERSION\r\n")
+                want = None
+            else:
+                writer.write(b"*x\r\n")
+                want = b"-"
+            got = await asyncio.wait_for(reader.read(1 << 16), 5)
+            if case != "split":
+                assert lock.locked()  # answered while it was held
+                lock.release()
+            writer.close()
+        finally:
+            await server.dispose()
+        assert got == want if case == "split" else got.startswith(want or b"$")
+        serving = db.serving_totals()
+        assert serving["slept_bursts"] == 0
+        assert serving["busy_routed_cmds"] == (0 if case == "malformed" else 1)
+
+    run(main())
+
+
+def test_a_pipelined_chunk_behind_its_first_commands_hold_answers_in_order():
+    """[TLOG INS, TREG GET, TLOG GET] in one chunk behind a TLOG hold:
+    the first command names the type, the whole burst sleeps and the
+    replies come back in command order."""
+    lines = [b"TLOG INS k a 1", b"TREG GET t", b"TLOG GET k"]
+
+    async def main():
+        want = b"".join(await python_path(lines))
+        server, db = await native_server()
+        lock = db.manager("TLOG")._lock
+        try:
+            await lock.acquire()
+            wire = b"".join(l + b"\r\n" for l in lines)
+            burst = asyncio.create_task(send_recv(server.port, wire, len(want)))
+            await asyncio.wait_for(asleep(lock, 1), 5)
+            lock.release()
+            assert await burst == want
+        finally:
+            await server.dispose()
+        serving = db.serving_totals()
+        assert serving["slept_bursts"] == 1 and serving["native_cmds"] == 3
+        assert serving["busy_routed_cmds"] == 0 and serving["demoted_cmds"] == 0
+
+    run(main())
+
+
+def test_a_burst_asleep_when_the_shutdown_takes_the_lock_is_refused():
+    """A write sleeps natively behind a hold; `clean_shutdown_async`
+    lines up for the same lock. When the burst wakes the repo's final
+    flush is spoken for: it is demoted and refused, never acknowledged."""
+    from jylis_tpu.models.manager import SHUTDOWN_ERR
+
+    async def main():
+        server, db = await native_server()
+        mgr = db.manager("TLOG")
+        try:
+            await mgr._lock.acquire()
+            write = asyncio.create_task(send_recv(server.port, b"TLOG INS k a 1\r\n"))
+            await asyncio.wait_for(asleep(mgr._lock, 1), 5)
+            down = asyncio.create_task(mgr.clean_shutdown_async())
+            await asyncio.wait_for(asleep(mgr._lock, 2), 5)
+            mgr._lock.release()
+            assert await write == b"-" + SHUTDOWN_ERR.encode() + b"\r\n"
+            await down
+        finally:
+            await server.dispose()
+        serving = db.serving_totals()
+        assert serving["slept_bursts"] == 1 and serving["demotions"] == 1
+        assert serving["native_cmds"] == 0
+        assert mgr.repo._tbl.rows() == 0  # the write was never applied
+
+    run(main())
+
+
 # ---- holds that can be seen held are spans -----------------------------------
 
 
-def test_a_threaded_drains_hold_contains_its_drain_and_inline_takers_record_none():
+@pytest.mark.parametrize("path", ["served", "python"])
+def test_a_threaded_drains_hold_contains_its_drain_and_later_takers_record_none(path):
     """lock.hold_serve runs from holding to released around the hop to
     the worker thread, the drain and the hop back, so it is at least the
-    drain.<TYPE> sample inside it. Commands that slept behind it take
-    the lock the long way too, apply inline and let go within their task
-    step: nobody could see them hold it, and they record no hold."""
+    drain.<TYPE> sample inside it. Commands of the type that arrive
+    meanwhile sleep in the lock's line and record no hold, whichever
+    way they came: over a socket they stay native (the busy() rule keeps
+    a chunk of the HELD type in the engine: three slept bursts, no
+    Python apply); handed to `apply_async` they take the lock the long
+    way, apply inline and let go within their task step, where nobody
+    could see them hold it."""
 
     async def main():
         server, db = make_server()
@@ -333,9 +556,16 @@ def test_a_threaded_drains_hold_contains_its_drain_and_inline_takers_record_none
             slow = asyncio.create_task(send_recv(server.port, b"GCOUNT GET k\r\n"))
             while not db.manager("GCOUNT").busy():
                 await asyncio.sleep(0.005)
-            waiters = [asyncio.create_task(send_recv(server.port, b"GCOUNT INC x 1\r\n"))
-                       for _ in range(3)]
-            assert [await w for w in waiters] == [b"+OK\r\n"] * 3
+            if path == "served":
+                waiters = [asyncio.create_task(send_recv(server.port, b"GCOUNT INC x 1\r\n"))
+                           for _ in range(3)]
+                assert [await w for w in waiters] == [b"+OK\r\n"] * 3
+            else:
+                got = [bytearray() for _ in range(3)]
+                await asyncio.gather(*(
+                    db.apply_async(Respond(out.extend), [b"GCOUNT", b"INC", b"x", b"1"])
+                    for out in got))
+                assert got == [b"+OK\r\n"] * 3
             assert await slow == b":5\r\n"
         finally:
             await server.dispose()
@@ -343,7 +573,11 @@ def test_a_threaded_drains_hold_contains_its_drain_and_inline_takers_record_none
         assert hold.count == 1 and drain.count == 1
         assert hold.total >= SLOW and hold.total >= drain.total
         assert reg.hist("lock.wait_serve").count == 4  # the GET's own take too
-        assert reg.hist("serve.py_apply").count == 3  # the drain ran in the thread
+        # (the drain ran in the thread: the GET's apply is in neither count)
+        served = path == "served"
+        assert reg.hist("serve.py_apply").count == (0 if served else 3)
+        assert reg.serving_counters["slept_bursts"] == (3 if served else 0)
+        assert reg.serving_counters["busy_routed_cmds"] == 0
         for other in ("lock.hold_converge", "lock.hold_flush", "lock.hold_sync"):
             assert reg.hist(other).count == 0
 

@@ -357,43 +357,58 @@ async def _lane_bounce():
 # ---- admission control ------------------------------------------------------
 
 
-def test_admission_cap_refuses_busy_class_only():
+@pytest.mark.parametrize("path", ["apply_async", "served"])
+def test_admission_cap_refuses_busy_class_only(path):
     """With the cap armed and the repo lock held (a stalled drain), the
     class's queued commands get typed BUSY; other classes still serve;
-    releasing the lock restores service and the refusals are counted."""
-    asyncio.run(_admission_cap())
+    releasing the lock restores service and the refusals are counted.
+    Over a served connection too: under a cap a chunk of the held type
+    is NOT kept native to sleep for the lock (server.py's route), so
+    its wait counts in the manager's inflight and the next is refused."""
+    asyncio.run(_admission_cap(path == "served"))
 
 
-async def _admission_cap():
+async def _admission_cap(served: bool):
+    from jylis_tpu.server.resp import Respond
+    from jylis_tpu.server.server import Server
+
     db = Database(identity=1)
     db.set_admission_cap(1)
+    cfg = Config()
+    cfg.port = "0"
+    cfg.log = Log.create_none()
+    server = Server(cfg, db)
+    await server.start()
 
-    class _Resp:
-        def __init__(self):
-            self.parts = []
+    async def ask(line: bytes) -> bytes:
+        """One command's reply bytes, over a socket or handed to
+        `apply_async` (rendered by the real `Respond`)."""
+        if not served:
+            out = bytearray()
+            await db.apply_async(Respond(out.extend), line.split())
+            return bytes(out)
+        reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+        writer.write(line + b"\r\n")
+        got = await asyncio.wait_for(reader.read(1 << 16), 5)
+        writer.close()
+        return got
 
-        def __getattr__(self, name):
-            return lambda *a: self.parts.append((name, a))
-
-    mgr = db.manager("GCOUNT")
-    async with mgr._lock:  # a drain wedging this class
-        waiter = asyncio.ensure_future(
-            db.apply_async(_Resp(), [b"GCOUNT", b"INC", b"h", b"1"])
-        )
-        await asyncio.sleep(0.01)  # the first queued command: inflight=1
-        busy = _Resp()
-        await db.apply_async(busy, [b"GCOUNT", b"INC", b"h", b"1"])
-        assert busy.parts and busy.parts[0][0] == "err"
-        assert busy.parts[0][1][0].startswith("BUSY"), busy.parts
-        # the node is NOT degraded: another class serves inline
-        other = _Resp()
-        await db.apply_async(other, [b"PNCOUNT", b"GET", b"ok"])
-        assert other.parts and other.parts[0][0] != "err", other.parts
-    await waiter
-    assert db.metrics.serving_counters["busy_refusals"] == 1
-    served = _Resp()
-    await db.apply_async(served, [b"GCOUNT", b"GET", b"h"])
-    assert served.parts and served.parts[0][0] != "err"
+    try:
+        mgr = db.manager("GCOUNT")
+        async with mgr._lock:  # a drain wedging this class
+            waiter = asyncio.ensure_future(ask(b"GCOUNT INC h 1"))
+            while mgr._inflight < 1:  # the first queued command
+                await asyncio.sleep(0.005)
+            busy = await ask(b"GCOUNT INC h 1")
+            assert busy.startswith(b"-BUSY (GCOUNT admission cap 1"), busy
+            # the node is NOT degraded: another class serves inline
+            assert await ask(b"PNCOUNT GET ok") == b":0\r\n"
+        assert await waiter == b"+OK\r\n"
+        assert db.metrics.serving_counters["busy_refusals"] == 1
+        assert db.metrics.serving_counters["slept_bursts"] == 0
+        assert await ask(b"GCOUNT GET h") == b":1\r\n"
+    finally:
+        await server.dispose()
     db.clean_shutdown()
 
 

@@ -259,10 +259,31 @@ def test_attach_under_a_plain_loop_is_refused():
 # ---- lock waits ---------------------------------------------------------------
 
 
-def test_lock_wait_grows_behind_a_drain_and_native_burst_does_not():
+async def _send_split(port: int, head: bytes, rest: bytes) -> bytes:
+    """One command in two reads: the server cannot tell its type from
+    the first, so under a held lock it takes the Python path."""
+    reader, writer = await asyncio.open_connection("127.0.0.1", port)
+    writer.write(head)
+    await asyncio.sleep(0.05)
+    writer.write(rest)
+    out = await asyncio.wait_for(reader.read(1 << 16), 5)
+    writer.close()
+    return out
+
+
+@pytest.mark.parametrize("path", ["native", "python"])
+def test_lock_wait_grows_behind_a_drain_and_native_burst_does_not(path):
     """A command that queues behind a repo lock held by a drain adds its
-    wait to lock.wait_serve (and to pipeline.dispatch, which PERF.md
-    once read as loop work); the native burst's own time does not grow."""
+    wait to lock.wait_serve, whichever path it takes: a chunk of the
+    held type sleeps as a native burst, whose own time (and with it
+    pipeline.dispatch) does not grow; a Python-path command sleeps
+    inside its dispatch, so pipeline.dispatch (which PERF.md once read
+    as loop work) includes the wait."""
+
+    def waiter(port):
+        if path == "native":
+            return send_recv(port, b"GCOUNT INC x 1\r\n")
+        return _send_split(port, b"GCOUNT IN", b"C x 1\r\n")
 
     async def main():
         server, db = make_server()
@@ -276,14 +297,19 @@ def test_lock_wait_grows_behind_a_drain_and_native_burst_does_not():
             slow = asyncio.create_task(send_recv(server.port, b"GCOUNT GET k\r\n"))
             await asyncio.sleep(0.05)  # the slow GET holds the GCOUNT lock
             assert db.manager("GCOUNT").busy()
-            waiters = [asyncio.create_task(send_recv(server.port, b"GCOUNT INC x 1\r\n"))
-                       for _ in range(3)]
+            waiters = [asyncio.create_task(waiter(server.port)) for _ in range(3)]
             assert [await w for w in waiters] == [b"+OK\r\n"] * 3
             assert await slow == b":5\r\n"
             wait = reg.hist("lock.wait_serve")
             assert wait.count >= 4  # the GET's own uncontended take too
             assert wait.total > 3 * (SLOW - 0.2), wait.total  # summed over connections
-            assert reg.hist("pipeline.dispatch").total > wait.total
+            dispatch = reg.hist("pipeline.dispatch").total
+            if path == "native":
+                assert SLOW <= dispatch < wait.total  # the slow GET's own, no wait
+                assert reg.serving_counters["slept_bursts"] == 3
+            else:
+                assert dispatch > wait.total
+                assert reg.serving_counters["busy_routed_cmds"] == 3
             assert reg.hist("server.native_burst").total - burst0 < 0.1
             # the cluster's side of the same lock has its own seam
             assert reg.hist("lock.wait_cluster").count == 0
