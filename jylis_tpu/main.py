@@ -246,6 +246,9 @@ async def run(argv: list[str] | None = None) -> None:
     database.set_admission(
         config.admission_policy, config.admission_queue_bytes
     )
+    # UJSON residency by size (--ujson-resident-min-leaves, 0 = by
+    # fan-in only): set before recovery, which admits what it restores
+    database.set_ujson_resident_min(config.ujson_resident_min_leaves)
     # fleet-convergence SLO thresholds for the provenance-span folds
     # (obs/jtrace.py; validated by config_from_cli, defensive here for
     # direct Config() drives in tests)

@@ -337,6 +337,13 @@ class Database:
         for mgr in self._map.values():
             mgr.admission_cap = cap
 
+    def set_ujson_resident_min(self, leaves: int) -> None:
+        """UJSON residency by size (--ujson-resident-min-leaves): a
+        document of ``leaves`` or more leaves lives in the device-
+        resident store from restore on and takes local writes as row
+        deltas (models/repo_ujson.py). 0 = promotion by fan-in only."""
+        self._map[b"UJSON"].repo.resident_min_leaves = leaves
+
     # ---- session guarantees (sessions.py, docs/sessions.md) ---------------
 
     async def _mint_token(self) -> bytes:

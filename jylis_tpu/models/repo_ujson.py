@@ -19,10 +19,26 @@ Every key is in exactly ONE of two modes:
   per-delta full-doc converge loop, repo_ujson.pony:96-110).
 
 Reads on device-mode keys decode lazily and cache; the cache invalidates
-per key when a fold touches the key. Local writes demote the key back to
+per key when a fold touches the key. Under the default rule (promotion by
+fan-in, ``resident_min_leaves`` 0) local writes demote the key back to
 host mode first (observed-remove mutators need the current doc anyway),
 so write-hot keys simply stay in the reference's host shape while
 anti-entropy-hot keys stay resident.
+
+Residency by SIZE (``--ujson-resident-min-leaves N``, N > 0): a document
+of N or more leaves is admitted when a snapshot or the journal restores
+it or when a write grows it to N, and from then on a local write does NOT
+demote: the mutator runs on the decoded view (so observed-remove sees
+exactly what this node has observed), its delta joins the flush delta for
+the peers AND queues as a ROW DELTA for the resident row, which folds on
+the device with the key's foreign deltas. A 1,000-leaf document is never
+re-encoded around a write. The ROW (with what is pending for it) is the
+document; a decoded view is a read cache of it: the boot leaves none
+(a document's first read or write gathers and decodes its row) and every
+fold drops the view of each key it folds, so the next read or write of
+the key gathers and decodes what the device folded. The store's planes
+are sized from what was restored and its fold programs compiled at boot
+(`warm_drain_shapes`).
 
 Seqs past u32 exceed every device layout; those keys fall back to host
 mode permanently (same contract as round 3).
@@ -32,10 +48,8 @@ Delta wire shape: the UJSON object itself (entries + causal context).
 
 from __future__ import annotations
 
-import time
-
 from ..ops.ujson_host import UJSON
-from ..utils.metrics import resolve_registry
+from ..utils.metrics import DEVICE, FINISH, drain_phase, resolve_registry, timed_drain
 from .base import ParseError, need
 from .help import RepoHelp
 
@@ -46,8 +60,11 @@ DEVICE_FANIN_MIN = 256
 # per-key fan-in worth joining a SEGMENTED drain: when many keys drain
 # together the dispatch is shared, so smaller fan-ins than
 # DEVICE_FANIN_MIN pay for their slice of the launch: the host fold is
-# O(D^2) per key, the delta encode is O(D). No benchmark cell sits on
-# either side of this threshold (ROADMAP D7)
+# O(D^2) per key, the delta encode is O(D). The one UJSON cell,
+# ycsb-ujson-1kx1k-r3.b, sits BELOW it on every key (a 95% read mix gives
+# its hottest document a few foreign deltas a second) and reaches the
+# store by --ujson-resident-min-leaves instead; no cell sits above it
+# (ROADMAP D7)
 SEG_FANIN_MIN = 64
 # buffered remote deltas across all keys before the converge path forces
 # a drain: bounds host memory for write-hot, never-read keys the same way
@@ -59,6 +76,17 @@ PENDING_TOTAL_MAX = 4096
 # and fold for real at the next full drain — a read-heavy key with a
 # delta trickle never pays a device round trip per GET
 TRICKLE_MAX = 16
+# residency by size: row deltas (local writes the decoded view has
+# already absorbed) a resident key queues before it folds them into its
+# row on its own — one small dispatch of a few rows, and one decode of the
+# row at the key's next read or write — so that a write-hot document's
+# list stays short and a FULL drain (every resident key with anything
+# pending, seconds of lock on a CPU peer) is left to the total bound,
+# twice a minute and not six times. Not 64, though the deepest pinned
+# program would hold it: longer lists reach the total bound sooner, and
+# a full drain (and the ~600 rows decoded again after it) costs more
+# than the folds saved (my chip runs, PR 39: 3,100 -> 2,925 ops/s)
+ROW_FOLD_MIN = 32
 
 UJSON_HELP = RepoHelp(
     "UJSON",
@@ -107,6 +135,12 @@ class RepoUJSON:
         self._res_applied: dict[bytes, int] = {}
         self._host_only: set[bytes] = set()  # seqs past u32: never promote
         self._sync_dirty: set[bytes] = set()  # since last digest pass
+        # --ujson-resident-min-leaves (Database.set_ujson_resident_min):
+        # 0 = promotion by fan-in only, local writes demote
+        self.resident_min_leaves = 0
+        self._grown: set[bytes] = set()  # host docs to size up at a drain
+        self._demoted: set[bytes] = set()  # once resident: a readmit
+        self._fold_keys = 0  # timed_drain's batch size of the fold under way
 
     # -- mode plumbing -------------------------------------------------------
 
@@ -123,6 +157,12 @@ class RepoUJSON:
             self._res = ResidentStore(mesh=self._mesh, shard_fn=shard_fn)
         return self._res
 
+    @property
+    def _state(self):
+        """The resident planes, for `Database.device_layout` (the
+        shutdown log's `device state` line)."""
+        return None if self._res is None else self._res._batch
+
     def _is_resident(self, key: bytes) -> bool:
         return self._res is not None and key in self._res
 
@@ -135,15 +175,16 @@ class RepoUJSON:
         if self._is_resident(key):
             doc = self._res_cache.get(key)
             if doc is None:
-                doc = self._res.read(key)
-                self._res_cache[key] = doc
+                doc = self._res_cache[key] = self._res.read(key)
+                resolve_registry(self).tally("drain.UJSON.row_reads", 1)
             return doc
         return None
 
-    def _demote(self, key: bytes) -> None:
-        """Move a device-mode key back to host mode (before any local
-        write: observed-remove mutators walk the doc, and host mode is
-        where local delta accumulation lives)."""
+    def _demote(self, key: bytes, overflow: bool = False) -> None:
+        """Move a device-mode key back to host mode: before a local
+        write under the fan-in rule (observed-remove mutators walk the
+        doc, and host mode is where local delta accumulation lives), or
+        for good when a delta overflows every device layout."""
         if not self._is_resident(key):
             return
         doc = self._res_cache.pop(key, None)
@@ -153,6 +194,106 @@ class RepoUJSON:
         else:
             doc = self._res.evict(key)
         self._data[key] = doc
+        self._demoted.add(key)
+        reg = resolve_registry(self)
+        if overflow:
+            reg.tally("drain.UJSON.demote_overflow", 1)
+        else:
+            reg.tally("drain.UJSON.demote_write", 1)
+        reg.tally("drain.UJSON.resident_rows", -1)
+
+    def _host_fold(self, doc: UJSON, deltas) -> None:
+        """Converge pending deltas into a host document or a resident
+        row's decoded view, one walk of the document each: counted, and
+        each walk a ujson.host_fold span."""
+        reg = resolve_registry(self)
+        if not reg.enabled:
+            for d in deltas:
+                doc.converge(d)
+            return
+        seam = reg.seam("ujson.host_fold")
+        walked = n = 0
+        for d in deltas:
+            walked += len(doc.entries)
+            n += 1
+            t0 = seam.begin()
+            doc.converge(d)
+            seam.end(t0)
+        reg.tally("drain.UJSON.host_deltas", n)
+        reg.tally("drain.UJSON.host_walked", walked)
+
+    def _mutate(self, doc: UJSON, op: bytes, path, value, delta: UJSON) -> None:
+        if op == b"INS":
+            doc.ins(self._identity, path, value, delta)
+        elif op == b"RM":
+            doc.rm(self._identity, path, value, delta)
+        elif op == b"SET":
+            doc.set_doc(self._identity, path, value, delta)
+        else:
+            doc.clr(self._identity, path, delta)
+
+    def _write(self, op: bytes, key: bytes, path, value) -> None:
+        """One local SET / CLR / INS / RM, the one sequence both the
+        direct apply and the banked queue run. Raises ValueError on a
+        value the JSON grammar refuses.
+
+        Host-mode keys, and every key under the fan-in rule: observe
+        (drain) first where the op removes, demote, mutate the host doc,
+        accumulate into the flush delta — the reference's shape. A
+        RESIDENT key under residency by size stays resident
+        (`_write_resident`)."""
+        resolve_registry(self).tally("drain.UJSON.local_writes", 1)
+        if op != b"INS" or self.resident_min_leaves:
+            # observed-remove (and SET clears OBSERVED dots): observe
+            # first; under residency by size an INS too, so that a
+            # resident key's view has absorbed everything pending
+            self._drain_key(key)
+        if self.resident_min_leaves and self._is_resident(key):
+            self._write_resident(key, op, path, value)
+            return
+        self._demote(key)
+        if op in (b"SET", b"INS"):
+            doc = self._data_for(key)
+        else:
+            doc = self._data.get(key)
+        if doc is not None:
+            self._mutate(doc, op, path, value, self._delta_for(key))
+            self._note_size(key, doc)
+        elif op == b"RM":
+            # still validates the value like the reference (:107)
+            from ..ops.ujson_host import parse_value
+
+            parse_value(value)
+
+    def _write_resident(self, key: bytes, op: bytes, path, value) -> None:
+        """A local write on a resident key that stays resident: the
+        mutator runs on the decoded view (what this node has observed:
+        the row joined with every pending delta), with a delta of its
+        own. That delta joins the flush delta — the peers get the same
+        join the host shape would have accumulated — and queues for the
+        row as a delta the view has already absorbed, to fold on the
+        device with the key's foreign deltas. Nothing is re-encoded
+        around the write, and the row is decoded only where the key has
+        no view (its first touch, its next after a fold)."""
+        doc = self._view(key)
+        one = UJSON()
+        self._mutate(doc, op, path, value, one)
+        if not (one.entries or one.ctx.vv or one.ctx.cloud):
+            return  # e.g. an RM of a value that is not there
+        self._delta_for(key).converge(one)
+        lst = self._pend.setdefault(key, [])
+        lst.append(one)
+        self._pend_total += 1
+        self._res_applied[key] = len(lst)
+        resolve_registry(self).tally("drain.UJSON.row_deltas", 1)
+        if len(lst) >= ROW_FOLD_MIN:
+            self._drain_key(key, fold=True)
+
+    def _note_size(self, key: bytes, doc: UJSON) -> None:
+        """Residency by size: a host-mode document that has reached
+        ``resident_min_leaves`` is sized up at the next drain."""
+        if 0 < self.resident_min_leaves <= len(doc.entries):
+            self._grown.add(key)
 
     def _data_for(self, key: bytes) -> UJSON:
         d = self._data.get(key)
@@ -185,35 +326,10 @@ class RepoUJSON:
         for args in self.engine.uq_drain():
             op = args[0]
             if op == b"CLR":
-                key = args[1]
-                self._drain_key(key)  # observed-remove: observe first
-                self._demote(key)
-                doc = self._data.get(key)
-                if doc is not None:
-                    doc.clr(
-                        self._identity, _decode_path(args[2:]),
-                        self._delta_for(key),
-                    )
-                self._sync_dirty.add(key)
-                continue
-            key, path, value = self._path_and_value(args)
-            if op == b"SET":
-                self._drain_key(key)  # SET clears OBSERVED dots
-                self._demote(key)
-                self._data_for(key).set_doc(
-                    self._identity, path, value, self._delta_for(key)
-                )
-            elif op == b"RM":
-                self._drain_key(key)  # observed-remove: observe first
-                self._demote(key)
-                doc = self._data.get(key)
-                if doc is not None:
-                    doc.rm(self._identity, path, value, self._delta_for(key))
-            else:  # INS
-                self._demote(key)
-                self._data_for(key).ins(
-                    self._identity, path, value, self._delta_for(key)
-                )
+                key, path, value = args[1], _decode_path(args[2:]), None
+            else:
+                key, path, value = self._path_and_value(args)
+            self._write(op, key, path, value)
             self._sync_dirty.add(key)
 
     def prepare_flush(self) -> None:
@@ -241,7 +357,12 @@ class RepoUJSON:
             self._drain_key(key)
             path = _decode_path(args[2:])
             doc = self._view(key)
-            text = doc.render(path) if doc is not None else ""
+            text = ""
+            if doc is not None:
+                seam = resolve_registry(self).seam("ujson.render")
+                t0 = seam.begin()
+                text = doc.render(path)
+                seam.end(t0)
             resp.string(text)
             if self.engine is not None and doc is not None:
                 body = text.encode()
@@ -256,50 +377,29 @@ class RepoUJSON:
             return False
         if op == b"SET":
             key, path, value = self._path_and_value(args)
-            self._drain_key(key)  # SET clears OBSERVED dots: observe first
-            self._demote(key)
             try:
-                self._data_for(key).set_doc(
-                    self._identity, path, value, self._delta_for(key)
-                )
+                self._write(op, key, path, value)
             except ValueError:
                 raise ParseError() from None
             resp.ok()
             return True
         if op == b"CLR":
             key = need(args, 1)
-            self._drain_key(key)  # observed-remove: observe first
-            self._demote(key)
-            path = _decode_path(args[2:])
-            doc = self._data.get(key)
-            if doc is not None:
-                doc.clr(self._identity, path, self._delta_for(key))
+            self._write(op, key, _decode_path(args[2:]), None)
             resp.ok()
             return True
         if op == b"INS":
             key, path, value = self._path_and_value(args)
-            self._demote(key)
             try:
-                self._data_for(key).ins(
-                    self._identity, path, value, self._delta_for(key)
-                )
+                self._write(op, key, path, value)
             except ValueError:
                 raise ParseError() from None
             resp.ok()
             return True
         if op == b"RM":
             key, path, value = self._path_and_value(args)
-            self._drain_key(key)  # observed-remove: observe first
-            self._demote(key)
-            doc = self._data.get(key)
             try:
-                if doc is not None:
-                    doc.rm(self._identity, path, value, self._delta_for(key))
-                else:
-                    # still validates the value like the reference (:107)
-                    from ..ops.ujson_host import parse_value
-
-                    parse_value(value)
+                self._write(op, key, path, value)
             except ValueError:
                 raise ParseError() from None
             resp.ok()
@@ -311,6 +411,7 @@ class RepoUJSON:
         lst.append(delta)
         self._pend_total += 1
         self._sync_dirty.add(key)
+        resolve_registry(self).tally("drain.UJSON.foreign_deltas", 1)
         if self.engine is not None:
             # a remote delta can change any subtree: drop every render
             # memo for the key (path () with subtree=True covers all)
@@ -351,7 +452,7 @@ class RepoUJSON:
         whole-node demotion storm under concurrency)."""
         if self.engine is not None and self.engine.uq_count():
             if (
-                self._res is not None
+                self._may_wait()
                 or self._overdue
                 or self._pend_total >= PENDING_TOTAL_MAX
                 or self.engine.uq_count() > self.UQ_INLINE_MAX
@@ -364,30 +465,52 @@ class RepoUJSON:
         if len(self._pend.get(key, ())) >= DEVICE_FANIN_MIN:
             return True
         if self._is_resident(key):
-            return (
-                len(self._pend.get(key, ())) > TRICKLE_MAX
-                or key not in self._res_cache
+            return self._unabsorbed(key) > TRICKLE_MAX or (
+                key not in self._res_cache and self._may_wait()
             )
         return False
 
-    def _drain_key(self, key: bytes) -> None:
+    def _may_wait(self) -> bool:
+        """Can work on a resident key with no decoded view WAIT for the
+        device? Under the fan-in rule any such touch may (a decode, a
+        demotion): a thread's business, as ever. Under residency by size
+        a missing view is a common case (the boot leaves none, a fold
+        drops its keys') and costs one gather of one row, which waits
+        only while a fold is in flight: then it goes to a thread, and
+        else it runs on the loop — a thread hop for every banked flush
+        makes the UJSON lock a convoy (my chip run, PR 39: held 47% of
+        the wall, 96% of bursts slept, `ops_per_s` halved)."""
+        if self._res is None:
+            return False
+        return not self.resident_min_leaves or self._res.busy()
+
+    def _unabsorbed(self, key: bytes) -> int:
+        """Pending deltas of a resident key that a drain would have to
+        deal with now. Under the fan-in rule every pending delta (the
+        trickle budget is on the list's length, as before); under
+        residency by size only those the decoded view has not absorbed:
+        a local write's row delta is absorbed as it is made, and a list
+        of absorbed deltas can wait for the next full drain's fold."""
+        n = len(self._pend.get(key, ()))
+        if self.resident_min_leaves:
+            n -= self._res_applied.get(key, 0)
+        return n
+
+    def _drain_key(self, key: bytes, fold: bool = False) -> None:
+        """Deal with one key's pending deltas; ``fold`` sends a resident
+        key's list to the device whatever its length."""
         deltas = self._pend.get(key)
         if not deltas:
             return
         if self._is_resident(key):
-            if len(deltas) <= TRICKLE_MAX:
+            if not fold and self._unabsorbed(key) <= TRICKLE_MAX:
                 # read-path trickle: converge into the cached view on the
                 # host (idempotent join — the deltas stay pending for the
                 # next full drain's device fold); _res_applied tracks how
                 # many this cache already absorbed, so repeat reads don't
                 # re-walk the doc per pending delta
-                doc = self._res_cache.get(key)
-                if doc is None:
-                    doc = self._res.read(key)
-                    self._res_cache[key] = doc
-                    self._res_applied.pop(key, None)
-                for d in deltas[self._res_applied.get(key, 0):]:
-                    doc.converge(d)
+                doc = self._view(key)
+                self._host_fold(doc, deltas[self._res_applied.get(key, 0):])
                 self._res_applied[key] = len(deltas)
                 return
             self._pend.pop(key)
@@ -407,8 +530,8 @@ class RepoUJSON:
             self._pend.pop(key)
             self._pend_total -= len(deltas)
         doc = self._data_for(key)
-        for d in deltas:
-            doc.converge(d)
+        self._host_fold(doc, deltas)
+        self._note_size(key, doc)
 
     def _resident_fold(self, groups: dict[bytes, list[UJSON]]):
         """Promote keys as needed and fold their pending deltas into the
@@ -416,16 +539,69 @@ class RepoUJSON:
         Returns the groups that must fall back to the host loop (seqs
         beyond the u64/32 device layouts). The one UJSON path that
         dispatches to the device, so it is what `UJSON drains` counts
-        (keys = keys folded; host-loop folds are not drains)."""
-        t0 = time.perf_counter()
-        fallback = self._fold_resident(groups)
-        reg = resolve_registry(self)
-        if reg.enabled:
-            reg.note_drain("UJSON", len(groups), time.perf_counter() - t0)
-        return fallback
+        (keys = keys folded; host-loop folds are not drains) and what
+        drain.UJSON and its three phases time: assemble is admission and
+        the delta encode, device the dispatch (nothing waits for its
+        result), finish the views' bookkeeping."""
+        self._fold_keys = len(groups)
+        return self._fold_resident(groups)
 
+    def _admit(self, store, items: list[tuple[bytes, UJSON]]) -> list[bytes]:
+        """Make host docs resident as they are, each kept as its row's
+        decoded view (row state == this doc). Returns the keys no device
+        layout can hold (seqs past u32): host-only from here on."""
+        refused: list[bytes] = []
+        try:
+            store.admit(items)
+        except OverflowError:
+            # isolate the un-encodable docs; the rest still promote
+            items, bulk = [], items
+            for k, d in bulk:
+                try:
+                    store.admit([(k, d)])
+                except OverflowError:
+                    self._host_only.add(k)
+                    refused.append(k)
+                    continue
+                items.append((k, d))
+        for k, d in items:
+            self._data.pop(k, None)
+            self._res_cache[k] = d  # row state == this doc, cache it
+        reg = resolve_registry(self)
+        reg.tally("drain.UJSON.admits", len(items))
+        reg.tally("drain.UJSON.resident_rows", len(items))
+        reg.tally(
+            "drain.UJSON.readmits", sum(k in self._demoted for k, _ in items)
+        )
+        reg.tally("drain.UJSON.demote_overflow", len(refused))
+        return refused
+
+    def _admit_sized(self) -> None:
+        """Residency by size: host-mode documents that have reached
+        ``resident_min_leaves`` (restored at that size, or grown to it)
+        move to the store in one admission."""
+        grown, self._grown = self._grown, set()
+        items = [
+            (k, d)
+            for k in sorted(grown)
+            if (d := self._data.get(k)) is not None
+            and len(d.entries) >= self.resident_min_leaves
+            and k not in self._host_only
+            and k not in self._pend
+        ]
+        if not items:
+            return
+        store = self._store()
+        if store.full():
+            # HBM admission gate (ResidentStore.BYTE_BUDGET)
+            resolve_registry(self).tally("drain.UJSON.demote_budget", len(items))
+            return
+        self._admit(store, items)
+
+    @timed_drain("UJSON", lambda self: self._fold_keys)
     def _fold_resident(self, groups: dict[bytes, list[UJSON]]):
         store = self._store()
+        reg = resolve_registry(self)
         fallback: dict[bytes, list[UJSON]] = {}
 
         to_admit = [k for k in groups if k not in store]
@@ -435,45 +611,65 @@ class RepoUJSON:
             # rows
             for k in to_admit:
                 fallback[k] = groups[k]
+            reg.tally("drain.UJSON.demote_budget", len(to_admit))
             to_admit = []
         if to_admit:
             items = [(k, self._data.get(k) or UJSON()) for k in to_admit]
-            try:
-                store.admit(items)
-            except OverflowError:
-                # isolate the un-encodable docs; the rest still promote
-                items, bulk = [], items
-                for k, d in bulk:
-                    try:
-                        store.admit([(k, d)])
-                    except OverflowError:
-                        self._host_only.add(k)
-                        fallback[k] = groups[k]
-                        continue
-                    items.append((k, d))
-            for k, d in items:
-                self._data.pop(k, None)
-                self._res_cache[k] = d  # row state == this doc, cache it
+            for k in self._admit(store, items):
+                fallback[k] = groups[k]
 
         fold = {k: v for k, v in groups.items() if k not in fallback}
+        wide = [k for k, v in fold.items() if not all(map(store.fits, v))]
+        if wide:
+            # a delta too wide for the store's pinned grid (a peer's
+            # flush that coalesced many writes, a SET of a document): its
+            # row is rewritten from the decoded view, which absorbs the
+            # key's whole list first — no fold in a shape nobody compiled
+            items = []
+            for k in wide:
+                lst = fold.pop(k)
+                doc = self._view(k)
+                done = self._res_applied.pop(k, 0)
+                self._host_fold(doc, lst[done:])
+                items.append((k, doc))
+            try:
+                store.rewrite(items)
+            except OverflowError:
+                for k, doc in items:
+                    self._demote(k, overflow=True)
+                    self._host_only.add(k)
+            reg.tally("drain.UJSON.row_rewrites", len(items))
+        mark = lambda: drain_phase(self, DEVICE)  # noqa: E731
         try:
-            store.fold_in(fold)
+            store.fold_in(fold, mark)
         except OverflowError:
             for k, v in fold.items():
                 try:
-                    store.fold_in({k: v})
+                    store.fold_in({k: v}, mark)
                 except OverflowError:
-                    self._demote(k)
+                    self._demote(k, overflow=True)
                     self._host_only.add(k)
                     fallback[k] = v
                 else:
-                    self._res_cache.pop(k, None)
-                    self._res_applied.pop(k, None)
+                    self._folded(k, v)
         else:
-            for k in fold:
-                self._res_cache.pop(k, None)
-                self._res_applied.pop(k, None)
+            drain_phase(self, FINISH)
+            for k, v in fold.items():
+                self._folded(k, v)
         return fallback
+
+    def _folded(self, key: bytes, deltas: list[UJSON]) -> None:
+        """A key's pending list has folded into its row, and its decoded
+        view drops, whatever it had absorbed: the next read or write of
+        the key gathers and decodes what the device folded, so the row is
+        what every answer after a fold depends on. Deltas the view had
+        not absorbed reach the document by this fold alone:
+        `device_deltas`."""
+        applied = self._res_applied.pop(key, 0)
+        self._res_cache.pop(key, None)
+        resolve_registry(self).tally(
+            "drain.UJSON.device_deltas", len(deltas) - applied
+        )
 
     # -- sync digest (cluster/syncdigest.py) ---------------------------------
 
@@ -560,9 +756,31 @@ class RepoUJSON:
             self._pend_total -= sum(len(v) for v in groups.values())
             fallback = self._resident_fold(groups)
             for k, lst in fallback.items():
-                doc = self._data_for(k)
-                for d in lst:
-                    doc.converge(d)
+                self._host_fold(self._data_for(k), lst)
         for key in list(self._pend):
             self._drain_key(key)
         self._overdue = False
+        if self._grown:
+            self._admit_sized()
+
+    def warm_drain_shapes(self) -> None:
+        """Boot, after recovery (Database.warm_drain_shapes). Residency
+        by size only: fold what was restored into host documents, admit
+        those at the size in ONE admission, size the planes from them
+        with room and compile the fold and gather programs at the pinned
+        shapes (`ResidentStore.pin_shapes`), so a serving window that
+        stays inside the room compiles nothing. A write that grows
+        another document to the size later admits it with one row's
+        `place_rows` (and, past the row capacity, a `grow_capacity` and
+        the fold programs again): compiles a window would see."""
+        if not self.resident_min_leaves:
+            return
+        self.drain()
+        if self._res is not None:
+            self._res.pin_shapes()
+            self._res.warm_pinned()
+            # the admission's views go: a document's first read or write
+            # gathers and decodes its row, so every answer this node
+            # gives stands on what the device holds, and the host does
+            # not start out with the whole keyspace decoded beside it
+            self._res_cache.clear()

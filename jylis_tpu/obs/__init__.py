@@ -59,6 +59,10 @@ SEAMS = (
     "drain.TENSOR",
     "drain.MAP",
     "drain.BCOUNT",
+    # UJSON's one device path: the resident store's fold of pending
+    # deltas into its rows (models/repo_ujson.py _resident_fold); host
+    # folds are ujson.host_fold below, not drains
+    "drain.UJSON",
     "server.native_burst",
     "server.py_dispatch",
     "journal.append",
@@ -116,6 +120,14 @@ SEAMS = (
     "lock.hold_converge",
     "lock.hold_flush",
     "lock.hold_sync",
+    # the UJSON repo's two host costs per document (models/
+    # repo_ujson.py): the render of a GET that reached the repo (the
+    # walk of the leaves, the sort and the join; a GET the engine's
+    # memo answers renders nothing), and the host fold of ONE pending
+    # delta into a document or a resident row's decoded view
+    # (UJSON.converge, a walk of the whole document)
+    "ujson.render",
+    "ujson.host_fold",
 )
 
 # Exact serving-path counters (`registry.note_serving`): on /metrics
@@ -158,7 +170,18 @@ SERVING = (
 # base before it), and whole-row sorts of a view by the Python read path;
 # entries `converge` buffered (a peer's, a restore's, a journal replay's),
 # cutoffs it raised, drains that began with a bound of the table tripped
-# (not forced by a trim), and the dispatches drains were made of. ENGINE:
+# (not forced by a trim), and the dispatches drains were made of. UJSON
+# (models/repo_ujson.py): documents admitted to the device-resident store
+# and how many of those had been resident before, documents sent back to
+# the host lattice by cause (a local write under the fan-in rule, a
+# sequence number past u32 or an un-encodable document, the store's byte
+# budget), rows resident now (admits less demotions), deltas `converge`
+# buffered (a peer's, a restore's, a journal replay's), of those the ones
+# that reached their document by a device fold alone and the ones a host
+# fold walked in, the leaves those host folds walked, local writes
+# applied, of those the ones that became a delta for a resident row
+# (--ujson-resident-min-leaves), and rows rewritten from their decoded
+# view because a delta was too wide for the store's pinned grid. ENGINE:
 # times the reply buffer was replaced by a larger one (a reply alone
 # outgrew it), the bytes it holds now (it only grows, so the sum of its
 # steps), and commands whose reply passed the buffer's ceiling and went
@@ -176,6 +199,20 @@ TALLIES = (
     "drain.TLOG.foreign_cutoffs",
     "drain.TLOG.overdue",
     "drain.TLOG.passes",
+    "drain.UJSON.admits",
+    "drain.UJSON.readmits",
+    "drain.UJSON.demote_write",
+    "drain.UJSON.demote_overflow",
+    "drain.UJSON.demote_budget",
+    "drain.UJSON.resident_rows",
+    "drain.UJSON.foreign_deltas",
+    "drain.UJSON.device_deltas",
+    "drain.UJSON.host_deltas",
+    "drain.UJSON.host_walked",
+    "drain.UJSON.local_writes",
+    "drain.UJSON.row_deltas",
+    "drain.UJSON.row_rewrites",
+    "drain.UJSON.row_reads",
     "serving.ENGINE.reply_grows",
     "serving.ENGINE.reply_buffer_bytes",
     "serving.ENGINE.oversize_defers",

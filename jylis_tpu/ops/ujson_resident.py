@@ -350,6 +350,13 @@ def repack_narrow(batch: DocBatch, old_shift: int, new_shift: int) -> DocBatch:
 
 
 @jax.jit
+def gather_rows(batch: DocBatch, rows) -> DocBatch:
+    """The rows a read pulls to the host, in ONE program (plane by plane
+    an eager index is a handful of dispatches each)."""
+    return DocBatch(*(p[rows] for p in batch))
+
+
+@jax.jit
 def clear_rows(batch: DocBatch, mask) -> DocBatch:
     """Reset masked rows to the identity document (eviction)."""
     pad = _pad_of(batch.dots.dtype)
@@ -469,6 +476,14 @@ class ResidentStore:
         # seqs bounds every seq on device — which is what makes the
         # narrow->narrow repack on replica growth provably safe
         self._max_seq = 0
+        # `pin_shapes` (a deployment that keeps documents resident by
+        # size): plane-width floors the fold's output never goes under,
+        # and the fixed menu of grid shapes a fold pads to, so that a
+        # stream of small drains meets only programs compiled at boot.
+        # Unset (0 / None) every shape follows its data, as above
+        self._min_w = 0
+        self._min_c = 0
+        self._pinned = False
 
     # -- interners ----------------------------------------------------------
 
@@ -512,6 +527,11 @@ class ResidentStore:
         if self._batch is not None:
             jax.block_until_ready(self._batch.dots)
 
+    def busy(self) -> bool:
+        """True while a queued fold or placement has not finished on the
+        device: a read now would wait for it."""
+        return self._batch is not None and not _ready(self._batch.dots)
+
     def approx_bytes(self) -> int:
         """Projected resident plane footprint (current shapes)."""
         if self._batch is None:
@@ -522,6 +542,112 @@ class ResidentStore:
         """True when admission should stop (BYTE_BUDGET crossed): the
         serving repo keeps further keys on the host lattice."""
         return self.approx_bytes() >= self.BYTE_BUDGET
+
+    # -- pinned shapes ------------------------------------------------------
+
+    # (keys, deltas a key) of the fold programs a pinned store runs, by
+    # rising key count; the last entry's keys are every row. A fold costs
+    # the device keys x deltas^2 x slots membership probes (the flat fold
+    # rule), so the program that spans every row is shallow and the deep
+    # ones are narrow: a full drain folds every key's first MENU_ALL_D
+    # deltas in one dispatch and the few keys that hold more in passes of
+    # the smaller programs (my chip run, PR 39: every row x 32 deltas x 16
+    # slots took ~1.2 s of device time a dispatch)
+    MENU_PASSES = ((4, 64), (64, 32))
+    MENU_ALL_D = 4
+    MENU_W = 8  # entries / cloud dots a delta's grid row holds
+    CLOUD_MIN = 256  # cloud slots a row keeps (a fold's bound adds its
+    # group's cloud dots to the live width before compaction drops them)
+
+    def pin_shapes(self) -> None:
+        """Size the planes from what is resident NOW, with room, and fix
+        the grid shapes folds pad to: rows x slots become powers of two
+        with twice the widest row's slots, so a hot document's growth
+        does not regrow (and recompile) inside a serving window; a fold
+        then runs as passes of the few programs of `_passes`, each
+        delta's entries and cloud dots padded to MENU_W. A delta wider
+        than that does not fit the pinned grid (`fits`): the caller
+        rewrites its row from the decoded view instead (`rewrite`). On a
+        mesh the aligned grid spans every row whatever the drain, so
+        only the depth and the widths are pinned there."""
+        if self._batch is None:
+            return
+        self._min_w = 2 * bucket(self._base_w, 4)
+        self._min_c = self.CLOUD_MIN
+        bw, bc = self._batch.dots.shape[-1], self._batch.cloud.shape[-1]
+        if self._min_w > bw or self._min_c > bc:
+            self._batch = self._shard(slice_widths(
+                self._batch, max(bw, self._min_w), max(bc, self._min_c)
+            ))
+        self._pinned = True
+
+    def warm_pinned(self) -> None:
+        """Compile what a serving window meets at the pinned shapes: one
+        identity fold per program of `_passes` (rows stay as they are:
+        the join's neutral element), a one-row rewrite of a row with its
+        own document, and the one-row gather a read makes."""
+        from .ujson_host import UJSON
+
+        if not self._pinned or not self._rows:
+            return
+        keys = sorted(self._rows)
+        for n, depth in self._passes():
+            self.fold_in({k: [UJSON()] * depth for k in keys[:n]})
+        self.rewrite([(keys[0], self.read(keys[0]))])
+        self.block()
+
+    def _passes(self) -> list[tuple[int, int]]:
+        cap = self._row_axis()
+        if self._mesh is not None:
+            return [(cap, 2 * self.MENU_ALL_D)]
+        return [p for p in self.MENU_PASSES if p[0] < cap] + [(cap, self.MENU_ALL_D)]
+
+    def fits(self, delta) -> bool:
+        """Does a delta fit a row of the pinned grid? (True while no
+        shape is pinned: the grid then takes its widths from its data.)"""
+        if not self._pinned:
+            return True
+        n = getattr(delta, "n_entries", None)
+        if n is None:
+            n, c = len(delta.entries), len(delta.ctx.cloud)
+        else:
+            c = delta.n_cloud
+        return n <= self.MENU_W and c <= self.MENU_W
+
+    def rewrite(self, items: list[tuple[bytes, object]]) -> None:
+        """Replace resident rows by the encoding of the given documents
+        (the caller's decoded views, current with everything pending):
+        how a delta too wide for the pinned grid reaches its row without
+        a fold in a new shape. One program a row count (a pinned store
+        places them one at a time); rows wider than the planes widen
+        them, as an admission does."""
+        self._flush_broadcast()
+        items = [(k, d) for k, d in items if k in self._rows]
+        if not items:
+            return
+        if self._pinned and len(items) > 1:
+            # one program a row count, and the boot compiled the one-row
+            # placement only: a drain that rewrites two rows (my chip run,
+            # PR 39: one of seven windows) must not compile a second
+            for item in items:
+                self.rewrite([item])
+            return
+        self._note_seqs([d for _, d in items])
+        rows_np = self._encode_rows([d for _, d in items])
+        # the width bound takes the rows' LENGTHS, not their padded
+        # widths: a 1,300-leaf row encodes 2,048 wide, and a floor of
+        # 2,048 would make the next fold ask for planes of 4,096
+        pad = _pad_of(rows_np.dots.dtype)
+        self._floor_w = max(self._floor_w, int((rows_np.dots != pad).sum(axis=1).max()))
+        self._floor_c = max(self._floor_c, int((rows_np.cloud != pad).sum(axis=1).max()))
+        self._base_w = max(self._base_w, self._floor_w)
+        self._base_c = max(self._base_c, self._floor_c)
+        self._place(rows_np, np.array([self._rows[k] for k, _ in items], np.int32))
+
+    def plane_shape(self) -> tuple[int, int] | None:
+        """(rows, slots) of the resident dot plane, None before the first
+        admission."""
+        return None if self._batch is None else tuple(self._batch.dots.shape)
 
     # -- layout plumbing ----------------------------------------------------
 
@@ -577,8 +703,8 @@ class ResidentStore:
             return bucket(ub_w, 4), bucket(ub_c, 4)
         bw = self._batch.dots.shape[-1]
         bc = self._batch.cloud.shape[-1]
-        out_w = bucket(ub_w, 4)
-        out_c = bucket(ub_c, 4)
+        out_w = max(bucket(ub_w, 4), self._min_w)
+        out_c = max(bucket(ub_c, 4), self._min_c)
         # shape hysteresis: keep the current width unless it must grow
         # or can shrink 4x (no recompile thrash around a boundary)
         if out_w < bw and out_w * 4 > bw:
@@ -693,13 +819,20 @@ class ResidentStore:
             self._ensure_reps()
             return b
 
-    def _encode_grid(self, groups) -> DocBatch:
-        wire = self._grid_from_wire(groups)
+    def _encode_grid(self, groups, depth: int = 0) -> DocBatch:
+        """The (K, D, W) grid of a fold; ``depth`` is the pinned pass's
+        (0 while no shape is pinned: the grid follows its data)."""
+        wire = self._grid_from_wire(groups, depth)
         if wire is not None:
             return wire
+        encode = dev.encode_doc_groups
+        if depth:
+            encode = partial(
+                _encode_groups_menu, menu=(depth, self.MENU_W, self.MENU_W)
+            )
         while True:
             try:
-                g = dev.encode_doc_groups(
+                g = encode(
                     groups, self._rid_cols, self.pay, self._nrep,
                     shift=self._shift,
                 )
@@ -714,7 +847,7 @@ class ResidentStore:
             self._ensure_reps()
             return g
 
-    def _grid_from_wire(self, groups) -> DocBatch | None:
+    def _grid_from_wire(self, groups, depth: int = 0) -> DocBatch | None:
         """The native wire->planes grid encoder: when every delta in the
         drain is a WireUJSON (the cluster receive path), the (K, D, W)
         grid fills straight from the raw payload bytes — per-delta host
@@ -742,6 +875,9 @@ class ResidentStore:
         d_dim = bucket(max(len(g) for g in groups), 1)
         w = bucket(max(max(d.n_entries for d in flat), 1), 4)
         c = bucket(max(max(d.n_cloud for d in flat), 1), 4)
+        if depth:
+            d_dim = max(d_dim, depth)
+            w, c = max(w, self.MENU_W), max(c, self.MENU_W)
         rows = len(groups) * d_dim
         dest = np.fromiter(
             (
@@ -845,7 +981,16 @@ class ResidentStore:
             cap = self._capacity_for(old + need - len(self._free))
             self._batch = self._shard(grow_capacity(self._batch, cap))
             self._free = list(range(cap - 1, old - 1, -1)) + self._free
-        # harmonise widths between the resident planes and the new rows
+        idx = np.empty(need, np.int32)
+        for j, (key, _) in enumerate(items):
+            row = self._free.pop()
+            self._rows[key] = row
+            idx[j] = row
+        self._place(rows_np, idx)
+
+    def _place(self, rows_np: DocBatch, idx: np.ndarray) -> None:
+        """Write encoded rows into resident rows ``idx``, harmonising
+        widths first: wider rows widen the planes, narrower ones pad."""
         bw, bc = self._batch.dots.shape[-1], self._batch.cloud.shape[-1]
         rw, rc = rows_np.dots.shape[-1], rows_np.cloud.shape[-1]
         if rw > bw or rc > bc:
@@ -855,11 +1000,6 @@ class ResidentStore:
             bw, bc = max(rw, bw), max(rc, bc)
         if rw < bw or rc < bc:
             rows_np = _pad_planes_np(rows_np, bw, bc)
-        idx = np.empty(need, np.int32)
-        for j, (key, _) in enumerate(items):
-            row = self._free.pop()
-            self._rows[key] = row
-            idx[j] = row
         self._batch = self._shard(
             place_rows(self._batch, DocBatch(*(jnp.asarray(p) for p in rows_np)),
                        jnp.asarray(idx))
@@ -884,16 +1024,32 @@ class ResidentStore:
 
     # -- the drain ----------------------------------------------------------
 
-    def fold_in(self, pending: dict[bytes, list]) -> None:
+    def fold_in(self, pending: dict[bytes, list], mark=None) -> None:
         """Fold each key's pending deltas into its resident row — ONE
         device dispatch for every key in the drain, no host read-backs.
         Raises OverflowError (rows unchanged) when a delta exceeds the
         u64/32 layout; the caller demotes those keys to the host
-        lattice."""
+        lattice. ``mark`` (the repo's drain clock) is called where the
+        host's encode ends and the dispatch begins."""
         self._flush_broadcast()
         pending = {k: v for k, v in pending.items() if v and k in self._rows}
         if not pending:
             return
+        rows_n = depth = 0
+        if self._pinned:
+            # pinned shapes: the program that holds the drain's keys sets
+            # how many deltas a key folds now; what is left folds in
+            # further passes (the join is associative), never a new shape
+            rows_n, depth = next(
+                p for p in self._passes() if p[0] >= len(pending)
+            )
+            if any(len(v) > depth for v in pending.values()):
+                self.fold_in({k: v[:depth] for k, v in pending.items()}, mark)
+                self.fold_in(
+                    {k: v[depth:] for k, v in pending.items() if len(v) > depth},
+                    mark,
+                )
+                return
         self._note_seqs([d for lst in pending.values() for d in lst])
         # width bound: each row grows by at most its group's entry/cloud
         # counts (the join can only drop), so the batch max grows by at
@@ -918,9 +1074,9 @@ class ResidentStore:
             # single device: the subset fold's grid covers exactly the
             # drained keys (the aligned grid spans every capacity row —
             # only worth it when sharding forbids gathers/scatters)
-            self._fold_subset(pending, grow_w, grow_c)
+            self._fold_subset(pending, grow_w, grow_c, mark, rows_n, depth)
         else:
-            self._fold_aligned(pending, grow_w, grow_c)
+            self._fold_aligned(pending, grow_w, grow_c, mark, depth)
 
     # buffered broadcast deltas past this count force a flush, bounding
     # host memory and the single fold's delta axis. One large fold
@@ -979,30 +1135,40 @@ class ResidentStore:
         )
         self._batch = self._shard(self._note_fold(out, live, grow_w, grow_c))
 
-    def _fold_subset(self, pending, grow_w: int, grow_c: int) -> None:
+    def _fold_subset(
+        self, pending, grow_w: int, grow_c: int, mark, n: int, depth: int
+    ) -> None:
+        """``n`` x ``depth``: the pinned program's keys and deltas a key
+        (0: no shape is pinned, the grid follows the drain)."""
         ks = sorted(pending)
-        n = bucket(len(ks), 4)
+        n = n or bucket(len(ks), 4)
         groups = [pending[k] for k in ks] + [[] for _ in range(n - len(ks))]
-        grid = self._encode_grid(groups)
+        grid = self._encode_grid(groups, depth)
         out_w, out_c = self._budget_widths(grow_w, grow_c)
         idx = np.zeros(n, np.int32)  # pad slots -> scratch row 0
         for j, k in enumerate(ks):
             idx[j] = self._rows[k]
         grid = self._grid_to_device(grid)
+        if mark is not None:
+            mark()
         out, live = fold_join_subset(
             self._batch, grid, jnp.asarray(idx), shift=self._shift,
             out_w=out_w, out_c=out_c,
         )
         self._batch = self._note_fold(out, live, grow_w, grow_c)
 
-    def _fold_aligned(self, pending, grow_w: int, grow_c: int) -> None:
+    def _fold_aligned(
+        self, pending, grow_w: int, grow_c: int, mark, depth: int
+    ) -> None:
         cap = self._row_axis()
         groups: list[list] = [[] for _ in range(cap)]
         for k, lst in pending.items():
             groups[self._rows[k]] = lst
-        grid = self._encode_grid(groups)
+        grid = self._encode_grid(groups, depth)
         out_w, out_c = self._budget_widths(grow_w, grow_c)
         grid = self._shard(self._grid_to_device(grid))
+        if mark is not None:
+            mark()
         out, live = fold_join_aligned(
             self._batch, grid, shift=self._shift, out_w=out_w, out_c=out_c
         )
@@ -1019,7 +1185,7 @@ class ResidentStore:
         rows = jnp.asarray(
             np.array([self._rows[k] for k in keys], np.int32)
         )
-        sub = DocBatch(*(p[rows] for p in self._batch))
+        sub = gather_rows(self._batch, rows)
         np_sub = DocBatch(*jax.device_get(tuple(sub)))  # one transfer
         # full-read detection must reject duplicate keys: a duplicated
         # subset could pass the length check and re-tighten (then slice)
@@ -1032,8 +1198,8 @@ class ResidentStore:
             self._base_c = max(int((np_sub.cloud != pad).sum(axis=1).max()), 1)
             self._inflight.clear()  # the pull reflects every queued fold
             self._floor_w = self._floor_c = 1
-            w = bucket(self._base_w, 4)
-            c = bucket(self._base_c, 4)
+            w = max(bucket(self._base_w, 4), self._min_w)
+            c = max(bucket(self._base_c, 4), self._min_c)
             if (
                 w < self._batch.dots.shape[-1]
                 or c < self._batch.cloud.shape[-1]
@@ -1077,6 +1243,43 @@ class ResidentStore:
             return []
         keys = sorted(self._rows)
         return list(zip(keys, self.read_many(keys)))
+
+
+def _encode_groups_menu(
+    groups, rid_cols, pay_ids, n_rep: int, shift: int, menu
+) -> DocBatch:
+    """`ujson_device.encode_doc_groups` at pinned shapes: the (K, D, W)
+    grid padded up to the menu's depth and widths, as host numpy planes
+    (the pads are identity documents and pad slots, so the fold is the
+    same fold)."""
+    from .ujson_host import UJSON
+
+    d_min, w_min, c_min = menu
+    d = max(bucket(max((len(g) for g in groups), default=1), 1), d_min)
+    # encode the deltas that are there and place them: the grid is
+    # mostly identity slots (K rows x D deltas for a few deltas a key)
+    flat = [x for g in groups for x in g] or [UJSON()]
+    b = dev._encode_docs_np(flat, rid_cols, pay_ids, n_rep, shift=shift)
+    w = max(w_min, b.dots.shape[-1])
+    c = max(c_min, b.cloud.shape[-1])
+    dest = np.fromiter(
+        (k * d + j for k, g in enumerate(groups) for j in range(len(g))),
+        np.int64,
+    )
+    pad = _pad_of(b.dots.dtype)
+    rows = len(groups) * d
+    dots = np.full((rows, w), pad, b.dots.dtype)
+    pay = np.full((rows, w), -1, np.int32)
+    vv = np.zeros((rows, n_rep), np.uint32)
+    cloud = np.full((rows, c), pad, b.cloud.dtype)
+    if len(dest):
+        dots[dest, : b.dots.shape[-1]] = b.dots
+        pay[dest, : b.pay.shape[-1]] = b.pay
+        vv[dest] = b.vv
+        cloud[dest, : b.cloud.shape[-1]] = b.cloud
+    return DocBatch(
+        *(p.reshape((len(groups), d) + p.shape[1:]) for p in (dots, pay, vv, cloud))
+    )
 
 
 def _pad_planes_np(batch: DocBatch, w: int, c: int) -> DocBatch:

@@ -38,6 +38,12 @@ class Config:
     journal_fsync: str = "interval"
     journal_fsync_interval: float = 0.2
     journal_max_bytes: int = 64 << 20
+    # extension: UJSON residency by size (models/repo_ujson.py) — a
+    # document of this many leaves or more lives in the device-resident
+    # store from restore (or from the write that grows it there) on, and
+    # stays there under local writes; 0 (default) leaves promotion to
+    # the anti-entropy fan-in alone (docs/types/ujson.md, "On the TPU")
+    ujson_resident_min_leaves: int = 0
     # extension: peer dial lifecycle (cluster.py) — connect timeout in
     # seconds and the exponential-backoff ceiling in heartbeat ticks
     dial_timeout: float = 5.0
@@ -181,6 +187,17 @@ def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
         "--journal-max-bytes", type=int, default=64 << 20,
         help="Journal size that triggers compaction: a fresh snapshot is "
         "cut and the old journal segment retired (docs/durability.md).",
+    )
+    parser.add_argument(
+        "--ujson-resident-min-leaves", type=int,
+        default=Config.ujson_resident_min_leaves,
+        help="UJSON documents of this many leaves or more are kept in "
+        "the device-resident store: admitted when a snapshot or the "
+        "journal restores them or when a write grows them to the size, "
+        "the store sized and its fold programs compiled at boot, and "
+        "local INS/RM/SET/CLR applied as row deltas instead of sending "
+        "the document back to the host lattice. 0 (default) promotes by "
+        "anti-entropy fan-in only (docs/types/ujson.md, 'On the TPU').",
     )
     parser.add_argument(
         "--dial-timeout", type=float, default=Config.dial_timeout,
@@ -355,6 +372,9 @@ def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
     config.journal_fsync = args.journal_fsync
     config.journal_fsync_interval = args.journal_fsync_interval
     config.journal_max_bytes = args.journal_max_bytes
+    if args.ujson_resident_min_leaves < 0:
+        parser.error("--ujson-resident-min-leaves must be >= 0")
+    config.ujson_resident_min_leaves = args.ujson_resident_min_leaves
     config.dial_timeout = args.dial_timeout
     config.dial_backoff_cap = args.dial_backoff_cap
     config.delta_log_cap = args.delta_log_cap

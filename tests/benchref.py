@@ -20,6 +20,7 @@ def load(rel: str):
 gen = load("harness/gen.py")
 resp = load("harness/resp.py")
 TLOG = load("reference/TLOG.py")
+UJSON = load("reference/UJSON.py")
 
 TLOG_RECIPE = {"keys": 24, "entries": 20, "value_bytes": 48, "key_format": "thread%07d",
                "ts_epoch_ms": gen.TS_EPOCH_MS, "ts_shift": gen.TS_SHIFT, "base_days": 30}
@@ -29,6 +30,15 @@ def tlog_reference(seed: int, **sizes):
     recipe = dict(TLOG_RECIPE, **sizes)
     return TLOG.Reference(recipe, seed, 1, [], gen.hottest(recipe["keys"], recipe["keys"]),
                           gen.Values(seed))
+
+
+UJSON_RECIPE = {"keys": 12, "members": 40, "path": "members", "key_format": "doc%07d",
+                "id_base": 10**18}
+
+
+def ujson_reference(seed: int, **sizes):
+    recipe = dict(UJSON_RECIPE, **sizes)
+    return UJSON.Reference(recipe, seed, 1, [], gen.hottest(recipe["keys"], recipe["keys"]))
 
 
 class Replies:

@@ -213,7 +213,7 @@ def test_system_latency_and_trace_commands():
     db.apply(resp, [b"SYSTEM", b"LATENCY"])
     lines = resp.strings()
     # every declared seam reports, armed ones with non-zero percentiles
-    assert len([line for line in lines if line.startswith("drain.")]) == 7
+    assert len([line for line in lines if line.startswith("drain.")]) == 8
     (dispatch,) = [
         line for line in lines if line.startswith("server.py_dispatch ")
     ]

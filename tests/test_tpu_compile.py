@@ -85,3 +85,55 @@ def test_mesh_drain_is_the_same_local_program(topo, kind):
     assert moved == [] and temp < K * w  # under one device's block
     assert not re.search(r"all-reduce|all-gather|all-to-all|collective-permute",
                          compiled.as_text())
+
+
+@pytest.mark.parametrize("keys,depth", [(4, 64), (64, 32), (1024, 4)])
+def test_the_resident_ujson_fold_compiles_at_the_shapes_the_boot_pins(topo, keys, depth):
+    """`ycsb-ujson-1kx1k-r3`'s store (`UJSON 1024x2048`, `ResidentStore.
+    pin_shapes`): the subset fold at each of the three programs a pinned
+    store runs (few keys deep, every row shallow), 8 slots a delta, as the
+    chip's compiler builds it — it fits the chip by a wide margin (the
+    output is new planes: the fold is not donated)."""
+    from jylis_tpu.ops import ujson_device as dev
+    from jylis_tpu.ops.ujson_resident import ResidentStore, fold_join_subset
+
+    one = SingleDeviceSharding(topo.devices[0])
+    rows, slots, cloud, reps = 1024, 2048, ResidentStore.CLOUD_MIN, 8
+    w = ResidentStore.MENU_W
+    assert (keys, depth) in ResidentStore.MENU_PASSES + ((rows, ResidentStore.MENU_ALL_D),)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    resident = dev.DocBatch(s((rows, slots), jnp.int32), s((rows, slots), jnp.int32),
+                            s((rows, reps), jnp.uint32), s((rows, cloud), jnp.int32))
+    grid = dev.DocBatch(s((keys, depth, w), jnp.int32), s((keys, depth, w), jnp.int32),
+                        s((keys, depth, reps), jnp.uint32), s((keys, depth, w), jnp.int32))
+    compiled = fold_join_subset.lower(
+        resident, grid, s((keys,), jnp.int32), shift=dev.narrow_shift(reps),
+        out_w=slots, out_c=cloud).compile()
+    mem = compiled.memory_analysis()
+    planes = 4 * (2 * rows * slots + rows * reps + rows * cloud)
+    assert mem.output_size_in_bytes >= planes
+    assert mem.temp_size_in_bytes < 1 << 30  # 16 GB of HBM: no pressure
+
+
+def test_the_resident_ujson_read_gathers_one_row_in_one_program(topo):
+    """A read of a resident document that has no decoded view (never
+    decoded, evicted, or dropped by its key's last fold) is one gather of
+    its row from the four planes, `ResidentStore.read`'s one program, at
+    `ycsb-ujson-1kx1k-r3`'s shapes."""
+    from jylis_tpu.ops import ujson_device as dev
+    from jylis_tpu.ops.ujson_resident import ResidentStore, gather_rows
+
+    one = SingleDeviceSharding(topo.devices[0])
+    rows, slots, cloud, reps = 1024, 2048, ResidentStore.CLOUD_MIN, 8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    resident = dev.DocBatch(s((rows, slots), jnp.int32), s((rows, slots), jnp.int32),
+                            s((rows, reps), jnp.uint32), s((rows, cloud), jnp.int32))
+    compiled = gather_rows.lower(resident, s((1,), jnp.int32)).compile()
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert 4 * (2 * slots + reps + cloud) <= out < 1 << 16  # one row, tiled
