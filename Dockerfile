@@ -15,7 +15,7 @@ WORKDIR /src
 COPY native/ native/
 # the native codecs (RESP scanner, cluster codec, counter engine): one
 # shared object, no Python build step needed
-RUN g++ -O2 -std=c++17 -shared -fPIC -o native/libjylis_native.so native/*.cpp
+RUN g++ -O2 -std=c++17 -shared -fPIC -pthread -o native/libjylis_native.so native/*.cpp
 
 FROM python:3.11-slim
 ARG JAX_EXTRA="jax[cpu]"

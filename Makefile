@@ -48,7 +48,7 @@ lint-native:
 # un-imported (JYLIS_SANITIZE gates tests/conftest.py): jaxlib's pybind11
 # C++ exceptions abort under the preloaded ASAN interceptor.
 sanitize:
-	g++ -O1 -g -std=c++17 -shared -fPIC -fsanitize=address,undefined \
+	g++ -O1 -g -std=c++17 -shared -fPIC -pthread -fsanitize=address,undefined \
 	  -fno-sanitize-recover=all -Wall -Wextra -Werror \
 	  -o native/libjylis_native_san.so native/*.cpp
 	JYLIS_SANITIZE=1 JYLIS_NATIVE_SO=$(abspath native/libjylis_native_san.so) \
@@ -75,7 +75,7 @@ sanitize-threads:
 	  exit 0; \
 	fi; \
 	set -e; \
-	g++ -O1 -g -std=c++17 -shared -fPIC -fsanitize=thread \
+	g++ -O1 -g -std=c++17 -shared -fPIC -pthread -fsanitize=thread \
 	  -Wall -Wextra -Werror \
 	  -o native/libjylis_native_tsan.so native/*.cpp; \
 	JYLIS_SANITIZE=1 JYLIS_NATIVE_SO=$(abspath native/libjylis_native_tsan.so) \

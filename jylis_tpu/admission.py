@@ -32,7 +32,8 @@ Three design points worth naming:
   can't flap per command, and operators/drills can assert transitions.
 * **A hard queued-bytes bound.** Reply bytes parked on slow consumers
   (transport write buffers + the per-connection reply buffer) are
-  tracked per connection; past ``--admission-queue-bytes`` EVERY class
+  tracked per connection, and what the engine's reply sender holds is
+  read from it when the total is compared (``held_elsewhere``); past ``--admission-queue-bytes`` EVERY class
   is refused, so a slow-consumer burst can never OOM the loop. The
   server additionally caps each connection's transport buffer so
   ``drain()`` applies real per-connection backpressure.
@@ -239,10 +240,20 @@ class AdmissionController:
         self.shed: dict[str, int] = dict.fromkeys(CLASSES, 0)
         self.enters = 0
         self.exits = 0
-        self.queued_bytes = 0
+        self._noted_bytes = 0
         self._conn_q: dict[int, int] = {}
+        # reply bytes held where no connection's note sees them (the
+        # engine's reply sender), read when the total is: a callable
+        self.held_elsewhere = None
 
     # ---- the admit decision (hot path) ------------------------------------
+
+    @property
+    def queued_bytes(self) -> int:
+        """Reply bytes the node holds for its consumers, now: the
+        connections' notes plus what `held_elsewhere` reports."""
+        held = self.held_elsewhere
+        return self._noted_bytes + (held() if held is not None else 0)
 
     @property
     def armed(self) -> bool:
@@ -374,11 +385,13 @@ class AdmissionController:
         prev = self._conn_q.get(conn_id, 0)
         if nbytes != prev:
             self._conn_q[conn_id] = nbytes
-            self.queued_bytes += nbytes - prev
-            if self._reg is not None and self._reg.enabled:
-                self._reg.gauge_set(
-                    "serving.queued_bytes", float(self.queued_bytes)
-                )
+            self._noted_bytes += nbytes - prev
+        elif self.held_elsewhere is None:
+            return  # the total cannot have moved
+        if self._reg is not None and self._reg.enabled:
+            self._reg.gauge_set(
+                "serving.queued_bytes", float(self.queued_bytes)
+            )
 
     def drop_conn(self, conn_id: int) -> None:
         self.note_conn_queued(conn_id, 0)

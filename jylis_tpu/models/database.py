@@ -173,10 +173,7 @@ class Database:
         # default controller is unarmed (no policy, no byte bound) —
         # set_admission replaces it with the configured one and keeps
         # the OVERLOAD section of SYSTEM METRICS pointed at it.
-        from ..admission import AdmissionController
-
-        self.admission = AdmissionController(registry=self.metrics)
-        self.system.overload_fn = self.admission.metrics_totals
+        self.set_admission("", 0)
 
     def _served_totals(self) -> dict[str, int]:
         """Commands served per type on BOTH paths (SYSTEM METRICS)."""
@@ -327,6 +324,9 @@ class Database:
         self.admission = AdmissionController(
             policy, queue_bytes, registry=self.metrics
         )
+        if self.native_engine is not None:
+            # the byte bound counts what the reply sender holds too
+            self.admission.held_elsewhere = self.native_engine.sender_pending
         self.system.overload_fn = self.admission.metrics_totals
 
     def set_admission_cap(self, cap: int) -> None:

@@ -87,9 +87,11 @@ DRAIN_WIDTH_FLOOR = 16
 # the planes on the device until the next has read it (the drain is not
 # donated), so a rejoin's hundreds of passes must not all be in flight
 PASSES_IN_FLIGHT = 4
-# Compiling ahead: once the longest row passes WARM_FILL of len_cap the
-# programs of the NEXT len_cap are compiled, off the lock, so that the
-# `grow` that row will force meets them ready. Planes narrower than
+# Compiling ahead: the boot compiles the programs of its len_cap and the
+# next two; from then on, once the longest row passes WARM_FILL of
+# len_cap the programs of the NEXT len_cap are compiled (if they are not
+# yet), off the lock, so that the `grow` that row will force meets them
+# ready. Planes narrower than
 # WARM_MIN_LEN are left alone: rows that short double sooner than a
 # program compiles.
 WARM_FILL = 0.75
@@ -558,8 +560,11 @@ class RepoTLOG:
     def warm_drain_shapes(self) -> None:
         """Boot, after recovery has settled the capacity (single-threaded
         caller): compile the serving programs for the recovered len_cap
-        AND the next one, so that neither the first drains nor the first
-        `grow` compile with clients waiting. A longest row already past
+        AND the next two, so that neither the first drains nor the first
+        `grow` compile with clients waiting, and the warm thread's
+        compiles (`_warm_ahead`, which take host cores beside the
+        serving loop for tens of seconds) start only once a row has
+        grown to three times the boot's width. A longest row already past
         WARM_FILL regrows the planes first: it would overflow within the
         first seconds of serving, and nobody waits now. Planes under
         WARM_MIN_LEN (an empty or young keyspace: `Database.warmup`
@@ -573,7 +578,7 @@ class RepoTLOG:
             return
         if self._longest > WARM_FILL * self._len_cap:
             self._grow(self._key_cap, 2 * self._len_cap)
-        self._warm_levels(self._state, 0, 1)
+        self._warm_levels(self._state, 0, 2)
         self._bound_drains()
 
     def _warm_ahead(self) -> None:

@@ -89,7 +89,8 @@ def build() -> bool:
     tmp = f"{_BUILT_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp] + units,
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+             "-o", tmp] + units,
             check=True,
             capture_output=True,
             timeout=300,

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import ctypes
 import struct
+import weakref
 
 import numpy as np
 
@@ -151,31 +152,62 @@ def _declare(c: ctypes.CDLL) -> None:
             [vp, vp, i64, vp, i64, i64, pi64, pi64, vp, vp, i32, pi32, vp],
         ),
         "jy_eng_first_type": (i32, [u8p, i64]),
+        **_sender_sigs(),
     }
+    _apply_sigs(c, sigs)
+
+
+def _apply_sigs(c, sigs) -> None:
     for fn_name, (restype, argtypes) in sigs.items():
         fn = getattr(c, fn_name)
         fn.restype = restype
         fn.argtypes = argtypes
 
 
+def _sender_sigs() -> dict:
+    """The reply sender's entry points (reply_sender.cpp): declared on
+    the plain handle and on the one that keeps the GIL."""
+    vp, i32, i64 = ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64
+    return {
+        "jy_snd_open": (i64, [vp, i32, i64, i64]),
+        "jy_snd_send": (i64, [vp, i64, vp, i64]),
+        "jy_snd_behind": (i64, [vp, i64]),
+        "jy_snd_wait": (i32, [vp, i64]),
+        "jy_snd_notify_fd": (i32, [vp]),
+        "jy_snd_close": (i64, [vp, i64]),
+        "jy_snd_pending": (i64, [vp]),
+        "jy_snd_stats": (None, [vp, vp]),
+        "jy_snd_stop": (None, [vp]),
+    }
+
+
 _declared = False
+# the same library through a handle that KEEPS the GIL: a hand-off to the
+# sender is microseconds long and blocks on nothing, and releasing the
+# interpreter around it would let a drain thread's Python phase take it
+# between a burst and its hand-off
+_gil_lib: ctypes.PyDLL | None = None
 
 
 class ServeEngine:
     """One native engine instance = all five data-type tables of one node."""
 
     def __init__(self, cdll):
-        global _declared
+        global _declared, _gil_lib
         if not _declared:
             _declare(cdll)
+            _gil_lib = ctypes.PyDLL(cdll._name)
+            _apply_sigs(_gil_lib, _sender_sigs())
             _declared = True
         self._lib = cdll
+        self._gil = _gil_lib
         self._h = cdll.jy_eng_new()
         self._out = (ctypes.c_uint8 * _OUT_CAP)()
         self._offs = (ctypes.c_int64 * _MAX_ARGS)()
         self._lens = (ctypes.c_int64 * _MAX_ARGS)()
         self._changed = (ctypes.c_int32 * 5)()
         self._tlog_vals: list[bytes] = []  # native vid -> bytes mirror
+        self._sender_seen = [0] * 8  # what sender_tally last counted
 
     def __del__(self):
         if getattr(self, "_h", None):
@@ -758,30 +790,42 @@ class ServeEngine:
 
     def bind_metrics(self, registry) -> None:
         """Count this engine's reply buffer in ``registry`` (its
-        Database's): the bytes it holds now, then every grow."""
+        Database's): the bytes it holds now, then every grow. The
+        sender's counters live in the library and are read when the
+        registry reports."""
         self.metrics = registry
         registry.tally("serving.ENGINE.reply_buffer_bytes", len(self._out))
+        me = weakref.ref(self)
+        registry.sender_fn = lambda: (e := me()) and e.sender_tally()
 
     def _grow_out(self, need: int) -> None:
         """Replace the reply buffer by one of the next power of two that
-        holds ``need`` bytes. The loop is one thread and `scan_apply`
-        copies a burst's replies out before it returns, so nothing
-        still reads the old array."""
+        holds ``need`` bytes. The loop is one thread and a burst's
+        replies are copied out (`reply_bytes`, or into the sender's job)
+        before the next burst runs, so nothing still reads the old
+        array."""
         cap = 1 << (need - 1).bit_length()
         reg = resolve_registry(self)
         reg.tally("serving.ENGINE.reply_grows", 1)
         reg.tally("serving.ENGINE.reply_buffer_bytes", cap - len(self._out))
         self._out = (ctypes.c_uint8 * cap)()
 
+    def reply_bytes(self, n: int) -> bytes:
+        """A copy of the first ``n`` bytes of the reply array."""
+        return ctypes.string_at(self._out, n)
+
     def scan_apply(self, buf):
         """Apply a pipelined burst. Returns
-        (rc, consumed, replies: bytes, unhandled: list[bytes] | None,
-        changed: tuple of 5 per-type counts (G, PN, TREG, TLOG, UJSON));
-        rc as documented in serve_engine.cpp, but for its 3 (answered
-        here: the reply buffer grows to the reply and the burst runs
-        again) and its 4 (counted here, handed on as 1)."""
+        (rc, consumed, n: the replies' length, unhandled: list[bytes] |
+        None, changed: tuple of 5 per-type counts (G, PN, TREG, TLOG,
+        UJSON)); the replies are the first ``n`` bytes of the reply
+        array, which the next burst reuses: `reply_bytes` copies them
+        out, `sender_send` hands them to the sender. rc as
+        documented in serve_engine.cpp, but for its 3 (answered here:
+        the reply buffer grows to the reply and the burst runs again)
+        and its 4 (counted here, handed on as 1)."""
         if not buf:
-            return 0, 0, b"", None, (0, 0, 0, 0, 0)
+            return 0, 0, 0, None, (0, 0, 0, 0, 0)
         base = ctypes.addressof(ctypes.c_char.from_buffer(buf))
         out_len = ctypes.c_int64()
         consumed = ctypes.c_int64()
@@ -797,7 +841,6 @@ class ServeEngine:
             if rc != 3:
                 break
             self._grow_out(out_len.value)  # the bytes the reply needs
-        replies = ctypes.string_at(self._out, out_len.value)
         unhandled = None
         if rc == 4:
             resolve_registry(self).tally("serving.ENGINE.oversize_defers", 1)
@@ -809,8 +852,90 @@ class ServeEngine:
                 for i in range(n_args.value)
             ]
             del view
-        return rc, consumed.value, replies, unhandled, tuple(self._changed)
+        return (
+            rc, consumed.value, out_len.value, unhandled, tuple(self._changed)
+        )
 
+    # ---- the reply sender (native/reply_sender.cpp) ------------------------
+
+    def sender_open(self, fd: int, low: int, high: int) -> int:
+        """A door for the socket ``fd``: the connection's id (> 0, never
+        reused; the sender works on its own duplicate of the
+        descriptor), or -1 when it could not be had. ``low`` / ``high``
+        are the loop's water marks for a writer's buffer."""
+        return self._lib.jy_snd_open(self._h, fd, low, high)
+
+    def sender_send(self, conn: int, n: int, data: bytes | None = None) -> int:
+        """Hand the first ``n`` bytes of ``data`` to the sender (copied
+        there), or with no ``data`` of the reply array: the only copy a
+        burst's replies make on their way out. The bytes the
+        connection's consumer is behind by: its pending bytes once the
+        socket has refused some (or jobs pile up past the high-water
+        mark behind one not yet through), else 0; -1 for a connection
+        that is closed or dead."""
+        return self._gil.jy_snd_send(
+            self._h, conn, self._out if data is None else data, n
+        )
+
+    def sender_behind(self, conn: int) -> int:
+        """What `sender_send` answers, now (0: closed or dead)."""
+        return self._gil.jy_snd_behind(self._h, conn)
+
+    def sender_wait(self, conn: int) -> bool:
+        """Arm the sender's signal (`sender_notify_fd`) for the moment
+        ``conn`` is written down to its low-water mark; True while it
+        is armed and has not fired, False when there is nothing to wait
+        for (not behind by more than the high-water mark, closed or
+        dead)."""
+        return bool(self._gil.jy_snd_wait(self._h, conn))
+
+    def sender_notify_fd(self) -> int:
+        return self._lib.jy_snd_notify_fd(self._h)
+
+    def sender_close(self, conn: int) -> int:
+        """Close ``conn`` BEFORE its socket is closed: the sender takes
+        no more for it, writes out what it holds (the bytes still to go
+        are returned) and then closes its descriptor, so the peer reads
+        every reply and then the end of the stream. Idempotent."""
+        return self._lib.jy_snd_close(self._h, conn)
+
+    def sender_pending(self) -> int:
+        """The bytes the sender holds now, over all connections, the
+        closing ones too."""
+        return self._gil.jy_snd_pending(self._h)
+
+    def sender_stop(self) -> None:
+        """Join the sender's thread, if one runs; what closing
+        connections still hold is written as far as their sockets take
+        it at once and dropped beyond."""
+        self._lib.jy_snd_stop(self._h)
+
+    def sender_stats(self) -> list[int]:
+        """The sender's atomics, now: reply writes handed over, sends a
+        socket did not take whole, hand-offs that woke the thread, bytes
+        dropped at a close or reset, the most bytes ever held, busy
+        microseconds; then the bytes held now and 1 while the thread
+        runs."""
+        out = (ctypes.c_uint64 * 8)()
+        if getattr(self, "_h", None):
+            self._lib.jy_snd_stats(self._h, out)
+        stats = list(out)
+        stats[5] //= 1000
+        return stats
+
+    def sender_tally(self) -> None:
+        """Bring the registry's `serving.ENGINE.sender_*` tallies up to
+        the sender's atomics (the registry calls this before it reports
+        its tallies)."""
+        now = self.sender_stats()
+        seen, self._sender_seen = self._sender_seen, now
+        reg = resolve_registry(self)
+        reg.tally("serving.ENGINE.sender_sends", now[0] - seen[0])
+        reg.tally("serving.ENGINE.sender_partial", now[1] - seen[1])
+        reg.tally("serving.ENGINE.sender_wakes", now[2] - seen[2])
+        reg.tally("serving.ENGINE.sender_dropped_bytes", now[3] - seen[3])
+        reg.tally("serving.ENGINE.sender_pending_max_bytes", now[4] - seen[4])
+        reg.tally("serving.ENGINE.sender_busy_us", now[5] - seen[5])
 
     def first_type(self, head: bytes) -> int:
         """The type the first command of ``head`` addresses, as

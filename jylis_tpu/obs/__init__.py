@@ -143,6 +143,8 @@ SEAMS = (
 # reply_bytes: bytes of engine replies handed to writers. slept_bursts:
 # native bursts that found a repo lock held, slept for it holding
 # nothing and then ran in the engine (or were demoted by a shutdown).
+# loop_sends: reply writes the event loop made itself (`writer.write`:
+# a connection with no sender behind it; beside ENGINE sender_sends).
 SERVING = (
     "demotions",
     "busy_refusals",
@@ -151,6 +153,7 @@ SERVING = (
     "demoted_conn_cmds",
     "reply_bytes",
     "slept_bursts",
+    "loop_sends",
 )
 
 # Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`
@@ -185,7 +188,13 @@ SERVING = (
 # times the reply buffer was replaced by a larger one (a reply alone
 # outgrew it), the bytes it holds now (it only grows, so the sum of its
 # steps), and commands whose reply passed the buffer's ceiling and went
-# to the Python path.
+# to the Python path; and the reply sender's own atomics
+# (native/reply_sender.cpp), read when a surface reports: reply writes
+# handed to it, sends a socket did not take whole, hand-offs that found
+# its thread asleep and woke it, bytes dropped at a reset or at dispose, the
+# most bytes it ever held, and the microseconds its thread spent inside
+# `send` and the zero-timeout `poll` that looks for POLLOUT (not its idle
+# polling, not its sleep).
 TALLIES = (
     "drain.TREG.bulk_rows",
     "drain.TREG.tie_rows",
@@ -216,6 +225,12 @@ TALLIES = (
     "serving.ENGINE.reply_grows",
     "serving.ENGINE.reply_buffer_bytes",
     "serving.ENGINE.oversize_defers",
+    "serving.ENGINE.sender_sends",
+    "serving.ENGINE.sender_partial",
+    "serving.ENGINE.sender_wakes",
+    "serving.ENGINE.sender_dropped_bytes",
+    "serving.ENGINE.sender_pending_max_bytes",
+    "serving.ENGINE.sender_busy_us",
 )
 
 # Node-wide gauges (per-peer convergence lag lives on the Cluster and

@@ -116,7 +116,8 @@ def render(database) -> str:
         t = _esc(name)
         out.append(f'jylis_drain_total{{type="{t}",kind="batches"}} {drains}')
         out.append(f'jylis_drain_total{{type="{t}",kind="keys"}} {keys}')
-    for typ, kind, n in reg.tally_stats():
+    tallies = list(reg.tally_stats())
+    for typ, kind, n in tallies:
         out.append(f'jylis_drain_total{{type="{typ}",kind="{kind}"}} {n}')
 
     cluster = system.cluster_fn() if system.cluster_fn else {}
@@ -210,6 +211,17 @@ def render(database) -> str:
     )
     out.append("# TYPE jylis_loop_cpu_seconds_total counter")
     out.append(f"jylis_loop_cpu_seconds_total {reg.loop_cpu_s():.9f}")
+
+    # the reply sender thread's time inside send() and its zero-timeout
+    # poll() (ENGINE sender_busy_us, as seconds): how near that thread is
+    # to being the next single core
+    busy_us = next(n for _t, k, n in tallies if k == "sender_busy_us")
+    out.append(
+        "# HELP jylis_sender_busy_seconds_total Seconds of the native "
+        "reply sender's thread inside send() and poll() with work queued."
+    )
+    out.append("# TYPE jylis_sender_busy_seconds_total counter")
+    out.append(f"jylis_sender_busy_seconds_total {busy_us / 1e6:.6f}")
 
     out.append(
         "# HELP jylis_device_bytes Device memory of the fullest local "

@@ -80,6 +80,9 @@ class MetricsRegistry:
         # reads the event-loop thread's CPU clock, once a timing
         # selector is attached (obs/loop.py): see loop_cpu_s
         self.loop_cpu_fn = None
+        # brings the native reply sender's tallies up to its library's
+        # atomics (native/engine.py bind_metrics): see tally_stats
+        self.sender_fn = None
         self.gauges: dict[str, float] = {name: 0.0 for name in GAUGES}
         # exact per-type drain event counts (obs.TALLIES); each has one
         # writer, a drain under its repo's lock
@@ -172,7 +175,10 @@ class MetricsRegistry:
                 yield name, int(c["batches"]), int(c["keys"]), c["seconds"] * 1e3
 
     def tally_stats(self):
-        """(type, kind, n) per declared tally, TALLIES order."""
+        """(type, kind, n) per declared tally, TALLIES order; the reply
+        sender's are its library's atomics, read now."""
+        if self.sender_fn is not None:
+            self.sender_fn()
         for name in TALLIES:
             _, typ, kind = name.split(".")
             yield typ, kind, self.tallies[name]

@@ -24,6 +24,8 @@ flush) makes bursts sleep, and its release wakes them all at once.
 from __future__ import annotations
 
 import asyncio
+import os
+import socket
 from time import perf_counter
 
 from .. import admission as admission_mod
@@ -35,14 +37,126 @@ from ..utils.net import ipv4_port
 from .resp import Respond, RespError
 
 
+# dispose: how long the sender's closed connections may go without a
+# byte moving before what they still hold is given up on
+_LINGER_STALL_S = 1.0
+_LINGER_TICK_S = 0.01
+
+
+class _Door:
+    """The ONE way a connection's reply bytes leave, decided once from
+    what the connection is: a plain TCP socket on a database with a
+    native engine hands every reply to the engine's sender thread
+    (native/reply_sender.cpp), so the loop's part of a reply is a copy
+    and a queue push; anything else (a Database built with
+    engine="python" or on a host with no toolchain, a writer with no
+    socket) keeps `writer.write`, the oracle path. A connection that has
+    a sender NEVER writes its asyncio transport: engine replies, the
+    Python path's `out`, an error before a close all take this door, so
+    no second queue can overtake the first and the reply stream stays
+    in command order."""
+
+    __slots__ = ("writer", "_server", "_engine", "_conn", "_behind")
+
+    def __init__(self, server: "Server", writer, engine):
+        self.writer = writer
+        self._server = server
+        self._engine = engine
+        self._conn = -1  # the sender's id of this connection
+        self._behind = 0  # what the sender's last answer said
+        transport = writer.transport
+        sock = transport.get_extra_info("socket")
+        if (
+            engine is not None
+            and sock is not None
+            and sock.type == socket.SOCK_STREAM
+            and sock.family in (socket.AF_INET, socket.AF_INET6)
+            and transport.get_extra_info("sslcontext") is None
+        ):
+            low, high = transport.get_write_buffer_limits()
+            self._conn = engine.sender_open(sock.fileno(), low, high)
+
+    def write(self, data: bytes) -> None:
+        if self._conn >= 0:
+            self._behind = self._engine.sender_send(
+                self._conn, len(data), data
+            )
+        else:
+            self.writer.write(data)
+            self._server._reg.note_serving("loop_sends")
+
+    def write_held(self, n: int) -> None:
+        """`write` of the first ``n`` bytes of the engine's reply array
+        (a burst's replies, still where `scan_apply` left them)."""
+        if self._conn >= 0:
+            self._behind = self._engine.sender_send(self._conn, n)
+        else:
+            self.writer.write(self._engine.reply_bytes(n))
+            self._server._reg.note_serving("loop_sends")
+
+    def unsent(self) -> int:
+        """Bytes this connection's consumer is behind by. A transport's
+        are what a write left in its buffer (the socket did not take
+        the reply whole); the sender's are its pending bytes once the
+        socket has refused some (or replies pile up past the high-water
+        mark behind one the thread has not got through), as its last
+        answer said: a handler learns of a refusal at its NEXT hand-off,
+        and the answer is looked up again only while it was not 0 (so a
+        burst whose consumer keeps up pays no call here)."""
+        if self._conn < 0:
+            return self.writer.transport.get_write_buffer_size()
+        if self._behind > 0:
+            self._behind = self._engine.sender_behind(self._conn)
+        return max(self._behind, 0)
+
+    def buffered(self) -> int:
+        """What `--admission-queue-bytes` notes for this connection: the
+        bytes in its transport's buffer. A sender's door notes none: the
+        admission controller reads what the sender holds, for all of
+        its connections, from the sender itself when it compares
+        (`AdmissionController.held_elsewhere`), so a reply that was
+        handed over a microsecond ago and one a consumer has refused
+        for a minute both count, as long as they are held."""
+        if self._conn < 0:
+            return self.writer.transport.get_write_buffer_size()
+        return 0
+
+    async def drain(self) -> None:
+        """`writer.drain()` for either door: past the high-water mark the
+        handler sleeps until the connection is written down to the
+        low-water mark (the sender signals the loop), and a connection
+        error the reader saw is raised."""
+        if self._conn >= 0 and self._engine.sender_wait(self._conn):
+            await self._server._sender_wait(self._conn)
+        await self.writer.drain()
+
+    def close(self) -> None:
+        """Before the socket is closed: the sender takes no more for the
+        connection, writes out what it holds and THEN lets go of its
+        descriptor, so a client that pipelined commands and half-closed,
+        or that earned an error reply, reads every reply and then the
+        end of the stream, as it does behind a closing transport (which
+        flushes its buffer before it closes the socket). Only a peer's
+        reset, or the sender's stop, drops bytes. Later writes are
+        dropped and counted."""
+        if self._conn >= 0:
+            self._engine.sender_close(self._conn)
+            self._server._sender_woken(self._conn)
+
+
 class Server:
     def __init__(self, config, database: Database):
         self._config = config
         self._database = database
         self._log = config.log
         self._server: asyncio.base_events.Server | None = None
-        self._conns: set[asyncio.StreamWriter] = set()
+        self._conns: dict[asyncio.StreamWriter, _Door] = {}
         self._closing = False
+        # handlers asleep until the sender has written their connection
+        # down (serve.write_wait), by the sender's connection id; the
+        # sender's eventfd is read by the loop from the first such sleep
+        self._write_waiters: dict[int, asyncio.Future] = {}
+        self._notify_fd = -1
         # dispatch-latency seams (obs/): one histogram per serving path —
         # a native burst (one engine scan_apply call settling many
         # commands) vs one Python-path dispatch (deferred, demoted, or
@@ -154,7 +268,7 @@ class Server:
             if len(out) <= bound:
                 return 0.0
             t_w = perf_counter() if reg.enabled else 0.0
-            writer.write(bytes(out))
+            door.write(bytes(out))
             out.clear()
             if not t_w:
                 return 0.0
@@ -165,10 +279,8 @@ class Server:
         engine = getattr(self._database, "native_engine", None)
         use_native = engine is not None
         buf = bytearray()
-        # bytes a write left in the transport: the socket did not take
-        # the reply whole (bound once, read once per burst)
-        unsent = writer.transport.get_write_buffer_size
-        self._conns.add(writer)
+        door = self._conns[writer] = _Door(self, writer, engine)
+        unsent = door.unsent  # read once per burst
         try:
             adm_armed = self._database.admission.armed
             if t_acc:
@@ -245,7 +357,7 @@ class Server:
                     else:
                         buf += data
                         t_tail = await self._apply_native(
-                            engine, buf, parser, resp, flush, writer, out,
+                            engine, buf, parser, resp, flush, door, out,
                             t_arr, t_rt,
                         )
                         if t_tail is not None:
@@ -256,7 +368,7 @@ class Server:
                             if t_tail and wrote_s:
                                 t_tail += wrote_s
                             if unsent():
-                                t_tail = await self._write_wait(writer, t_tail)
+                                t_tail = await self._write_wait(door, t_tail)
                             else:
                                 await writer.drain()
                             continue
@@ -277,7 +389,7 @@ class Server:
                             self._h_parse.record(perf_counter() - t_ps)
                         if cmd is None:
                             break
-                        await self._dispatch_py(resp, cmd, writer, out, t_arr)
+                        await self._dispatch_py(resp, cmd, door, out, t_arr)
                         # the Python path's commands by cause (the third
                         # is the engine's hand-back, in _apply_native),
                         # counted once dispatched AND applied: a command
@@ -293,19 +405,20 @@ class Server:
                     flush()
                     break
                 flush()
-                await writer.drain()
+                await door.drain()
         except (ConnectionResetError, BrokenPipeError, asyncio.CancelledError):
             pass
         finally:
             self._database.admission.drop_conn(id(writer))
-            self._conns.discard(writer)
+            del self._conns[writer]
+            door.close()  # BEFORE the socket: the sender finishes it
             writer.close()
             try:
                 await writer.wait_closed()
             except (ConnectionResetError, BrokenPipeError):
                 pass
 
-    async def _dispatch_py(self, resp, cmd, writer, out, t_arr=0.0) -> None:
+    async def _dispatch_py(self, resp, cmd, door, out, t_arr=0.0) -> None:
         """ONE Python-path dispatch (demoted loop and the native path's
         deferred commands share it): the overload-armor admission gate
         (admission.py) in front of Database.apply_async. When armed it
@@ -326,10 +439,7 @@ class Server:
         see time-in-our-own-queue or the node never declares overload."""
         adm = self._database.admission
         if adm.armed:
-            adm.note_conn_queued(
-                id(writer),
-                writer.transport.get_write_buffer_size() + len(out),
-            )
+            adm.note_conn_queued(id(door.writer), door.buffered() + len(out))
             # pipeline.classify: the admission toll per command on an
             # armed node — classify plus the gate's token walk, timed
             # for refusals and admissions alike
@@ -373,25 +483,52 @@ class Server:
     def _engine_managers(self):
         return [self._database.manager(n) for n in self._ENGINE_TYPES]
 
-    async def _write_wait(self, writer, t_stage: float) -> float:
-        """``await writer.drain()`` with bytes still in the transport:
+    async def _write_wait(self, door, t_stage: float) -> float:
+        """``await door.drain()`` with bytes the consumer has not taken:
         the socket did not take the reply whole. serve.write_wait, NOT
         loop work — the caller's open stage (started at ``t_stage``)
         stops before it and goes on after it: its start stamp comes back
         moved forward by the wait. Below asyncio's high-water mark
         (64 KiB) the drain returns at once and the sample is the call's
-        own cost; past it the handler sleeps until the transport has
-        written down to the low-water mark."""
+        own cost; past it the handler sleeps until the transport, or the
+        sender, has written down to the low-water mark."""
         t0 = perf_counter() if t_stage else 0.0
-        await writer.drain()
+        await door.drain()
         if not t0:
             return 0.0
         seconds = perf_counter() - t0
         self._h_write_wait.record(seconds)
         return t_stage + seconds
 
+    def _sender_wait(self, conn: int) -> asyncio.Future:
+        """The future a handler sleeps on while the sender writes its
+        connection down: set from `_on_sender_notify` (the sender cannot
+        call the loop; it signals one eventfd the loop reads), or by the
+        door's close."""
+        loop = asyncio.get_running_loop()
+        if self._notify_fd < 0:
+            self._notify_fd = self._database.native_engine.sender_notify_fd()
+            loop.add_reader(self._notify_fd, self._on_sender_notify)
+        fut = self._write_waiters[conn] = loop.create_future()
+        return fut
+
+    def _on_sender_notify(self) -> None:
+        try:
+            os.read(self._notify_fd, 8)
+        except BlockingIOError:
+            pass
+        engine = self._database.native_engine
+        for conn in list(self._write_waiters):
+            if not engine.sender_wait(conn):  # fired, closed or dead
+                self._sender_woken(conn)
+
+    def _sender_woken(self, conn: int) -> None:
+        fut = self._write_waiters.pop(conn, None)
+        if fut is not None and not fut.done():
+            fut.set_result(None)
+
     async def _apply_native(
-        self, engine, buf, parser, resp, flush, writer, out, t_arr=0.0,
+        self, engine, buf, parser, resp, flush, door, out, t_arr=0.0,
         t_route=0.0,
     ):
         """Drain `buf` through the native serving engine; commands it
@@ -471,7 +608,7 @@ class Server:
                     if t_route:
                         t_held = perf_counter()
                         self._h_route.record(t_held - t_route)
-                    rc, consumed, replies, unhandled, changed = (
+                    rc, consumed, n_replies, unhandled, changed = (
                         engine.scan_apply(buf)
                     )
                     # the burst ends; the reply write, or with nothing
@@ -486,12 +623,14 @@ class Server:
                         self._h_dispatch.record(el)
                 except faults.FaultError:
                     return demote()
-                if replies:
+                if n_replies:
                     if out:  # deferred-command replies precede these
-                        writer.write(bytes(out))
+                        door.write(bytes(out))
                         out.clear()
-                    writer.write(replies)
-                    reg.note_serving("reply_bytes", len(replies))
+                    # a call of its own AFTER the burst: with a sender
+                    # the one copy the replies make, and a queue push
+                    door.write_held(n_replies)
+                    reg.note_serving("reply_bytes", n_replies)
                     if t_tail:
                         t_scan, t_tail = t_tail, perf_counter()
                         self._h_reply_write.record(t_tail - t_scan)
@@ -502,7 +641,7 @@ class Server:
                 RepoLock.release_all(locks)
             del buf[:consumed]
             # slow-consumer hard bound (--admission-queue-bytes): engine
-            # replies land straight in the transport buffer; once the
+            # replies land straight in the door's buffer; once the
             # node-wide queued total is past the cap, drain() here is
             # real per-connection backpressure — it parks only THIS
             # connection until its consumer catches up, outside the
@@ -510,15 +649,10 @@ class Server:
             # slowing healthy consumers
             adm = self._database.admission
             if adm.queue_bytes_cap:
-                adm.note_conn_queued(
-                    id(writer), writer.transport.get_write_buffer_size()
-                )
+                adm.note_conn_queued(id(door.writer), door.buffered())
                 if adm.queued_bytes > adm.queue_bytes_cap:
-                    t_tail = await self._write_wait(writer, t_tail)
-                    adm.note_conn_queued(
-                        id(writer),
-                        writer.transport.get_write_buffer_size(),
-                    )
+                    t_tail = await self._write_wait(door, t_tail)
+                    adm.note_conn_queued(id(door.writer), door.buffered())
             if rc == 0:  # consumed all complete commands
                 return t_tail
             if rc < 0:
@@ -536,7 +670,7 @@ class Server:
                 t_route = perf_counter()
                 self._h_tail.record(t_route - t_tail)
             if rc == 1:
-                await self._dispatch_py(resp, unhandled, writer, out, t_arr)
+                await self._dispatch_py(resp, unhandled, door, out, t_arr)
                 reg.note_serving("deferred_cmds")
                 # a burst of repeatedly deferring reads (e.g. rows whose
                 # drained base the host lacks, or replies past the
@@ -555,6 +689,26 @@ class Server:
         self._closing = True  # handlers not yet in _conns self-close
         if self._server is not None:
             self._server.close()
-            for w in list(self._conns):
+            for w, door in list(self._conns.items()):
+                door.close()
                 w.close()
             await self._server.wait_closed()
+        engine = self._database.native_engine
+        if engine is not None:
+            if self._notify_fd >= 0:
+                asyncio.get_running_loop().remove_reader(self._notify_fd)
+                self._notify_fd = -1
+            # the closed connections' last replies: `wait_closed` above
+            # waited for the transports to flush theirs, and the sender
+            # writes its own out the same; it is given up on once no
+            # byte has moved for _LINGER_STALL_S (a consumer that reads
+            # slowly gets everything, one that does not read is cut)
+            left, stalled = engine.sender_pending(), 0.0
+            while left and stalled < _LINGER_STALL_S:
+                await asyncio.sleep(_LINGER_TICK_S)
+                now = engine.sender_pending()
+                stalled = 0.0 if now < left else stalled + _LINGER_TICK_S
+                left = now
+            # a blocking join, microseconds long: the thread is awake or
+            # in a poll() the stop wakes
+            engine.sender_stop()

@@ -21,6 +21,7 @@
 #pragma once
 
 #include <algorithm>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
 #include <string>
@@ -1097,6 +1098,11 @@ struct UjsonQueue {
 
 // ---- the engine ------------------------------------------------------------
 
+// the reply sender (reply_sender.cpp): made at the first connection that
+// is opened on it, its thread at the first hand-off
+struct Sender;
+void sender_destroy(Sender* s);
+
 struct Engine {
     Table t[2];  // 0 = GCOUNT, 1 = PNCOUNT
     TregTable treg;
@@ -1108,6 +1114,12 @@ struct Engine {
     // (models/manager.py _apply_core's per-Database tally). SYSTEM
     // METRICS reports the sum.
     uint64_t served[5] = {0, 0, 0, 0, 0};
+    // atomic: a counters' read from another thread may meet its making
+    std::atomic<Sender*> sender{nullptr};
+
+    ~Engine() {
+        if (Sender* s = sender.load()) sender_destroy(s);
+    }
 };
 
 // ---- shared formatting / parsing helpers -----------------------------------

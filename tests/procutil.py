@@ -37,6 +37,13 @@ _PORT_BASE, _PORT_SPAN, _PORT_SLOTS = 20000, 700, 12
 _draws = itertools.count()
 
 
+def scan_bytes(eng, buf):
+    """`ServeEngine.scan_apply` with the burst's replies copied out of
+    the engine's reply array as bytes (the loop door's two calls)."""
+    rc, consumed, n, unhandled, changed = eng.scan_apply(buf)
+    return rc, consumed, eng.reply_bytes(n), unhandled, changed
+
+
 def free_port() -> int:
     # "gw3" -> slot 4; a run without xdist takes slot 0
     slot = int(os.environ.get("PYTEST_XDIST_WORKER", "gw-1")[2:]) + 1

@@ -15,6 +15,8 @@ import jylis_tpu  # noqa: F401
 from jylis_tpu.models import Database
 from jylis_tpu.server.resp import Respond
 
+from procutil import scan_bytes
+
 
 class Out:
     """Byte-collecting Respond sink (the reference's _ExpectRespond seam)."""
@@ -468,8 +470,7 @@ def test_system_metrics_counts_served_commands(db):
     assert total == 2  # per-instance tally: exactly this test's commands
     eng = db.native_engine
     if eng is not None:
-        rc, _, replies, _, _ = eng.scan_apply(
-            bytearray(b"GCOUNT INC m:srv 1\r\nGCOUNT GET m:srv\r\n")
+        rc, _, replies, _, _ = scan_bytes(eng, bytearray(b"GCOUNT INC m:srv 1\r\nGCOUNT GET m:srv\r\n")
         )
         assert rc == 0 and replies == b"+OK\r\n:2\r\n"
         assert eng.served_counts()["GCOUNT"] == 2

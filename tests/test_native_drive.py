@@ -24,6 +24,8 @@ from jylis_tpu.native import lib
 from jylis_tpu.native.engine import ServeEngine
 from jylis_tpu.obs.registry import MetricsRegistry
 
+from procutil import scan_bytes
+
 
 @pytest.fixture
 def eng() -> ServeEngine:
@@ -58,7 +60,7 @@ def drain_native(eng, burst: bytes):
     deferred = []
     rc = 0
     while True:
-        rc, consumed, out, unhandled, _changed = eng.scan_apply(buf)
+        rc, consumed, out, unhandled, _changed = scan_bytes(eng, buf)
         replies += out
         del buf[:consumed]
         if rc == 1:
@@ -183,7 +185,7 @@ def test_small_and_oversize_replies_keep_command_order(eng):
         + resp(b"GCOUNT", b"GET", b"c")
     )
     buf = bytearray(burst)
-    rc, consumed, replies, unhandled, _ = eng.scan_apply(buf)
+    rc, consumed, replies, unhandled, _ = scan_bytes(eng, buf)
     assert rc == 2 and unhandled is None  # flush what settled, re-enter
     assert replies == b":200\r\n" + _render(entries, 3)
     assert reply_tallies(eng) == (0, 1 << 16, 0)  # not while replies wait
@@ -455,11 +457,11 @@ def test_split_burst_resumes_mid_command(eng):
     for cut in (1, 7, len(whole) // 2, len(whole) - 2):
         e = ServeEngine(lib())
         buf = bytearray(whole[:cut])
-        rc, consumed, out, _, _ = e.scan_apply(buf)
+        rc, consumed, out, _, _ = scan_bytes(e, buf)
         assert rc == 0
         del buf[:consumed]
         buf += whole[cut:]
-        rc, consumed, out2, _, _ = e.scan_apply(buf)
+        rc, consumed, out2, _, _ = scan_bytes(e, buf)
         assert rc == 0
         assert (out + out2) == b"+OK\r\n:3\r\n"
 

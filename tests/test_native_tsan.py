@@ -18,6 +18,12 @@ libtsan LD_PRELOADed. Two invariants are certified:
   the critical section (e.g. an unsynchronized static scratch buffer
   would race even under the mutex between release/acquire pairs).
 
+* **The reply sender** (native/reply_sender.cpp) — the one piece of the
+  library with a thread of its own: two producers hand replies to it
+  (the hand-off keeps the GIL, as the server's does; open, close and
+  the counters' read drop it) while it sends, meets sockets that do
+  not take a job whole, and loses connections under its hands.
+
 In the regular suite this doubles as a plain concurrency smoke (the
 invariants hold under the GIL too — assertion failures here mean
 cross-engine state leaked regardless of the data-race question).
@@ -29,6 +35,9 @@ tests/conftest.py).
 
 from __future__ import annotations
 
+import ctypes
+import select
+import socket
 import threading
 
 import numpy as np
@@ -62,8 +71,8 @@ def drain_native(eng, burst: bytes):
     replies = b""
     deferred = []
     while True:
-        rc, consumed, out, unhandled, _changed = eng.scan_apply(buf)
-        replies += out
+        rc, consumed, n, unhandled, _changed = eng.scan_apply(buf)
+        replies += eng.reply_bytes(n)
         del buf[:consumed]
         if rc == 1:
             deferred.append(unhandled)
@@ -342,3 +351,103 @@ def test_interner_compaction_under_load(cdll):
         for n in range(3):
             r = eng.tlog_find(b"cold-%d" % n)
             assert eng.tlog_size(r) == N_ROUNDS
+
+
+def test_two_producers_hand_off_to_the_reply_sender(cdll):
+    """Two producers, four connections each, against one engine's
+    sender thread: every connection's bytes arrive in hand-off order
+    (small replies from the reply array, large ones that a socket pair's
+    buffer cannot take whole), a third thread reads the counters all
+    the while, a connection closed with bytes pending has them written
+    out before the sender lets go of it, and the stop drops what a
+    closed connection's socket still refuses. Under TSAN: the queue,
+    the counters, the close and the stop are race-free."""
+    eng = ServeEngine(cdll)
+    n_conns, n_msgs = 4, 60
+    done = threading.Event()
+    out_mu = threading.Lock()
+
+    def message(tag: int, c: int, i: int) -> bytes:
+        body = b"%d/%d/%d;" % (tag, c, i)
+        return body * (20_000 if i % 20 == 7 else 3)
+
+    def producer(tag: int):
+        pairs = [socket.socketpair() for _ in range(n_conns)]
+        conns = [eng.sender_open(a.fileno(), 16 << 10, 64 << 10) for a, _ in pairs]
+        assert all(c > 0 for c in conns)
+        want = [b"".join(message(tag, c, i) for i in range(n_msgs)) for c in range(n_conns)]
+        got = [bytearray() for _ in range(n_conns)]
+
+        def read_all():
+            by_sock = {b: got[c] for c, (_, b) in enumerate(pairs)}
+            while any(len(got[c]) < len(want[c]) for c in range(n_conns)):
+                ready, _, _ = select.select(list(by_sock), [], [], 10)
+                assert ready, "the sender stalled"
+                for b in ready:
+                    by_sock[b] += b.recv(1 << 16)
+
+        reader = threading.Thread(target=read_all)
+        reader.start()
+        for i in range(n_msgs):
+            for c, conn in enumerate(conns):
+                data = message(tag, c, i)
+                if len(data) < 1024:
+                    # as a burst's replies leave: from the engine's reply
+                    # array, which the producers take turns at as bursts
+                    # do on the loop
+                    with out_mu:
+                        ctypes.memmove(eng._out, data, len(data))
+                        assert eng.sender_send(conn, len(data)) >= 0
+                else:
+                    assert eng.sender_send(conn, len(data), data) >= 0
+        reader.join(30)
+        assert not reader.is_alive()
+        assert [bytes(g) for g in got] == want
+        for conn in conns:
+            assert eng.sender_close(conn) == 0 and eng.sender_behind(conn) == 0
+            assert eng.sender_send(conn, 4, b"late") == -1  # closed: never sent
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+    def watcher():
+        while not done.is_set():
+            st = eng.sender_stats()
+            assert st[0] >= st[1] and st[6] <= st[4]
+
+    w = threading.Thread(target=watcher)
+    w.start()
+    try:
+        _run_threads([lambda t=t: producer(t) for t in (1, 2)])
+    finally:
+        done.set()
+        w.join(10)
+    # a connection closed with bytes its socket will not take yet: the
+    # thread writes them out as the peer reads, THEN lets go of its
+    # descriptor, and the peer reads the end of the stream
+    big = 4 << 20
+    a, b = socket.socketpair()
+    conn = eng.sender_open(a.fileno(), 16 << 10, 64 << 10)
+    assert eng.sender_send(conn, big, b"x" * big) >= 0
+    assert 0 < eng.sender_close(conn) <= big
+    assert eng.sender_send(conn, 4, b"late") == -1
+    a.close()  # the caller's descriptor goes first: the sender's stands
+    b.settimeout(10)
+    got = 0
+    while chunk := b.recv(1 << 20):
+        got += len(chunk)
+    b.close()
+    st = eng.sender_stats()
+    assert got == big and st[3] == (2 * n_conns + 1) * 4 and st[6] == 0
+    # one whose peer never reads: the stop drops what its socket refuses
+    a, b = socket.socketpair()
+    conn = eng.sender_open(a.fileno(), 16 << 10, 64 << 10)
+    assert eng.sender_send(conn, big, b"y" * big) >= 0
+    left = eng.sender_close(conn)
+    assert 0 < left <= big and eng.sender_stats()[7] == 1
+    eng.sender_stop()
+    st2 = eng.sender_stats()
+    assert 0 < st2[3] - st[3] <= left and st2[6] == 0 and st2[7] == 0
+    assert st2[0] == 2 * n_conns * n_msgs + 2
+    a.close()
+    b.close()

@@ -648,7 +648,7 @@ def test_a_slept_burst_is_counted_on_every_surface():
     assert 'jylis_serving_total{kind="slept_bursts"} 1\n' in prom.render(db)
     assert db.metrics.report().endswith("; SERVING: 0 demotions, 0 busy_refusals, "
         "0 busy_routed_cmds, 0 deferred_cmds, 0 demoted_conn_cmds, "
-        f"{serving['reply_bytes']} reply_bytes, 1 slept_bursts")
+        f"{serving['reply_bytes']} reply_bytes, 1 slept_bursts, 0 loop_sends")
 
 
 def test_engine_reply_bytes_are_counted_per_burst():

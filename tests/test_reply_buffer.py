@@ -24,6 +24,7 @@ from jylis_tpu.native.engine import make_engine
 from jylis_tpu.obs import prom
 from jylis_tpu.server.resp import Respond
 from jylis_tpu.utils.metrics import metric_lines
+from procutil import scan_bytes
 from test_native_drive import TS0, post, resp
 from test_serve_tables import _oracle_reply as oracle_reply
 
@@ -39,7 +40,7 @@ pytestmark = pytest.mark.skipif(
 
 def engine_reply(eng, *args: bytes) -> bytes:
     """One command through the native burst; it must settle there."""
-    rc, consumed, replies, unhandled, _ = eng.scan_apply(bytearray(resp(*args)))
+    rc, consumed, replies, unhandled, _ = scan_bytes(eng, bytearray(resp(*args)))
     assert rc == 0 and unhandled is None, (rc, unhandled)
     return replies
 
@@ -195,9 +196,11 @@ def test_the_three_counts_are_on_the_scrape_in_system_metrics_and_in_the_shutdow
     assert "ENGINE reply_grows 1" in lines
     assert "ENGINE reply_buffer_bytes 131072" in lines
     assert "ENGINE oversize_defers 0" in lines
-    assert db.metrics.report().endswith(
-        "ENGINE: 1 reply_grows, 131072 reply_buffer_bytes, 0 oversize_defers"
-    )
+    # the buffer's three lead the ENGINE part; the reply sender's follow
+    assert (
+        "ENGINE: 1 reply_grows, 131072 reply_buffer_bytes, 0 oversize_defers, "
+        "0 sender_sends, "
+    ) in db.metrics.report()
     # a node on the Python tables has no such buffer: explicit zeros
     assert "ENGINE reply_buffer_bytes 0" in metric_lines(
         registry=Database(identity=1, engine="python").metrics

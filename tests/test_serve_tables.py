@@ -24,6 +24,8 @@ from jylis_tpu.models.repo_treg import RepoTREG
 from jylis_tpu.models.repo_ujson import RepoUJSON
 from jylis_tpu.native.engine import make_engine
 
+from procutil import scan_bytes
+
 from test_tlog_tallies import lose_base
 
 
@@ -338,13 +340,13 @@ def test_tlog_native_value_interner_stays_flat_under_churn():
     # the drain carries the base and the post-drain GET serves natively.
     repo.apply(r, [b"INS", b"cold", b"keepme", b"1"])
     repo.apply(r, [b"INS", b"cold", b"andme", b"2"])
-    rc, _, _, _, _ = eng.scan_apply(bytearray(b"TLOG GET cold\r\n"))
+    rc, _, _, _, _ = scan_bytes(eng, bytearray(b"TLOG GET cold\r\n"))
     assert rc == 0
     repo.drain()
     cold_expect = (
         b"*2\r\n*2\r\n$5\r\nandme\r\n:2\r\n*2\r\n$6\r\nkeepme\r\n:1\r\n"
     )
-    rc, _, cold_before, _, _ = eng.scan_apply(bytearray(b"TLOG GET cold\r\n"))
+    rc, _, cold_before, _, _ = scan_bytes(eng, bytearray(b"TLOG GET cold\r\n"))
     assert rc == 0 and cold_before == cold_expect
     ts = 0
     keep = 4
@@ -370,7 +372,7 @@ def test_tlog_native_value_interner_stays_flat_under_churn():
     assert out.vals[5].startswith(b"g5-0-")
     # ... and the cold row's native GET still renders the original
     # values: the pre-remap sorted cache was dropped, not reused
-    rc, _, cold_after, _, _ = eng.scan_apply(bytearray(b"TLOG GET cold\r\n"))
+    rc, _, cold_after, _, _ = scan_bytes(eng, bytearray(b"TLOG GET cold\r\n"))
     assert rc == 0 and cold_after == cold_expect
 
 
@@ -410,8 +412,7 @@ def test_scan_apply_tlog_get_and_cutoff_byte_match_oracle():
         [b"CUTOFF", b"missing"],
     )
     burst = b"".join(b"TLOG " + b" ".join(a) + b"\r\n" for a in gets)
-    rc, consumed, replies, unhandled, changed = native.engine.scan_apply(
-        bytearray(burst)
+    rc, consumed, replies, unhandled, changed = scan_bytes(native.engine, bytearray(burst)
     )
     assert rc == 0 and consumed == len(burst) and unhandled is None
     assert changed == (0, 0, 0, 0, 0)  # reads change nothing
@@ -421,8 +422,7 @@ def test_scan_apply_tlog_get_and_cutoff_byte_match_oracle():
     # the quiescent serving path against the oracle
     native.drain()
     oracle.drain()
-    rc, _, replies, _, _ = native.engine.scan_apply(
-        bytearray(b"TLOG GET k\r\nTLOG CUTOFF k\r\n")
+    rc, _, replies, _, _ = scan_bytes(native.engine, bytearray(b"TLOG GET k\r\nTLOG CUTOFF k\r\n")
     )
     assert rc == 0
     assert replies == _oracle_reply(oracle, [b"GET", b"k"]) + _oracle_reply(
@@ -441,13 +441,11 @@ def test_scan_apply_tlog_get_defers_when_base_unknown():
     # a converge + drain keeps the base: only a failed guard loses it
     assert native._tbl.base_valid(native._tbl.find(b"k"))
     lose_base(native, b"k")
-    rc, consumed, replies, unhandled, _ = native.engine.scan_apply(
-        bytearray(b"TLOG GET k\r\n")
+    rc, consumed, replies, unhandled, _ = scan_bytes(native.engine, bytearray(b"TLOG GET k\r\n")
     )
     assert rc == 1 and unhandled == [b"TLOG", b"GET", b"k"]
     assert replies == b""
-    rc, _, replies, _, _ = native.engine.scan_apply(
-        bytearray(b"TLOG SIZE k\r\n")
+    rc, _, replies, _, _ = scan_bytes(native.engine, bytearray(b"TLOG SIZE k\r\n")
     )
     assert rc == 0 and replies == b":1\r\n"
     # the Python path (where the server routes the defer) serves it
@@ -456,8 +454,7 @@ def test_scan_apply_tlog_get_defers_when_base_unknown():
     )
     # and REPAIRS the drained base while at it (ADVICE round 5): the next
     # GET settles natively again instead of deferring forever
-    rc, _, replies, unhandled, _ = native.engine.scan_apply(
-        bytearray(b"TLOG GET k\r\n")
+    rc, _, replies, unhandled, _ = scan_bytes(native.engine, bytearray(b"TLOG GET k\r\n")
     )
     assert rc == 0 and unhandled is None
     assert replies == b"*1\r\n*2\r\n$1\r\nv\r\n:7\r\n"
@@ -471,11 +468,11 @@ def test_scan_apply_tlog_get_big_reply_flushes_then_grows():
     native, oracle = _tlog_pair()
     both(native, oracle, [b"INS", b"k", b"x" * 70000, b"1"])
     burst = bytearray(b"TLOG SIZE k\r\nTLOG GET k\r\n")
-    rc, consumed, replies, unhandled, _ = native.engine.scan_apply(burst)
+    rc, consumed, replies, unhandled, _ = scan_bytes(native.engine, burst)
     assert rc == 2 and replies == b":1\r\n"
     assert consumed == len(b"TLOG SIZE k\r\n")
     del burst[:consumed]
-    rc, consumed, replies, unhandled, _ = native.engine.scan_apply(burst)
+    rc, consumed, replies, unhandled, _ = scan_bytes(native.engine, burst)
     assert rc == 0 and unhandled is None
     assert replies == _oracle_reply(oracle, [b"GET", b"k"])
     assert consumed == len(b"TLOG GET k\r\n")
@@ -503,7 +500,7 @@ def test_ujson_queue_flush_order_and_replies():
         b'UJSON RM u nums 1.5\r\n'
         b"UJSON CLR u deep\r\n"
     )
-    rc, consumed, replies, unhandled, changed = eng.scan_apply(wire)
+    rc, consumed, replies, unhandled, changed = scan_bytes(eng, wire)
     assert rc == 0 and consumed == len(wire)
     assert replies == b"+OK\r\n" * 9
     assert changed == (0, 0, 0, 0, 9)
@@ -550,7 +547,7 @@ def test_ujson_engine_bounces_unsafe_values():
         # RESP array framing: exact tokens (inline would split/eat spaces)
         parts = [b"UJSON", b"INS", b"u", b"p", bad]
         wire = _resp_array(parts)
-        rc, _consumed, replies, unhandled, _ch = eng.scan_apply(wire)
+        rc, _consumed, replies, unhandled, _ch = scan_bytes(eng, wire)
         assert rc == 1 and replies == b"", bad
         assert unhandled[0] == b"UJSON"
     assert eng.uq_count() == 0
@@ -562,7 +559,7 @@ def test_ujson_engine_bounces_unsafe_values():
     ):
         parts = [b"UJSON", b"SET", b"u", b"p", doc]
         wire = _resp_array(parts)
-        rc, _c, replies, _u, _ch = eng.scan_apply(wire)
+        rc, _c, replies, _u, _ch = scan_bytes(eng, wire)
         if ok:
             good += 1
             assert rc == 0 and replies == b"+OK\r\n", doc
@@ -580,8 +577,7 @@ def test_ujson_engine_bounces_huge_ints_and_bad_utf8_paths():
     eng = make_engine()
     native = RepoUJSON(identity=1, engine=eng)
     big = b"1" * 5000
-    rc, _, replies, unh, _ = eng.scan_apply(
-        _resp_array([b"UJSON", b"INS", b"u", b"p", big])
+    rc, _, replies, unh, _ = scan_bytes(eng, _resp_array([b"UJSON", b"INS", b"u", b"p", big])
     )
     assert rc == 1 and replies == b""  # bounced: the apply would raise
     # both stacks turn the oversized int into ParseError (-> help reply
@@ -593,8 +589,7 @@ def test_ujson_engine_bounces_huge_ints_and_bad_utf8_paths():
         with pytest.raises(ParseError):
             repo.apply(R(), [b"INS", b"u", b"p", big])
     # a float with as many digits parses fine (no int() limit): banks
-    rc, _, replies, _, _ = eng.scan_apply(
-        _resp_array([b"UJSON", b"INS", b"u", b"p", b"1." + b"1" * 5000])
+    rc, _, replies, _, _ = scan_bytes(eng, _resp_array([b"UJSON", b"INS", b"u", b"p", b"1." + b"1" * 5000])
     )
     assert rc == 0 and replies == b"+OK\r\n"
     # invalid-UTF-8 path component: b"\xff" decodes to U+FFFD, the SAME
@@ -602,15 +597,13 @@ def test_ujson_engine_bounces_huge_ints_and_bad_utf8_paths():
     # not bank it (its raw-byte invalidation would miss the memo key)
     native.apply(R(), [b"INS", b"u2", b"\xef\xbf\xbd", b"1"])
     before = _oracle_reply(native, [b"GET", b"u2", b"\xef\xbf\xbd"])
-    rc, _, replies, unh, _ = eng.scan_apply(
-        _resp_array([b"UJSON", b"INS", b"u2", b"\xff", b"2"])
+    rc, _, replies, unh, _ = scan_bytes(eng, _resp_array([b"UJSON", b"INS", b"u2", b"\xff", b"2"])
     )
     assert rc == 1 and replies == b""  # bank refused: path not UTF-8
     native.apply(R(), unh[1:])  # the deferred apply canonicalises
     after = _oracle_reply(native, [b"GET", b"u2", b"\xef\xbf\xbd"])
     assert after != before
-    rc, _, replies, _, _ = eng.scan_apply(
-        bytearray(b"UJSON GET u2 \xef\xbf\xbd\r\n")
+    rc, _, replies, _, _ = scan_bytes(eng, bytearray(b"UJSON GET u2 \xef\xbf\xbd\r\n")
     )
     assert rc == 0 and replies == after  # fresh render, not a stale memo
 
@@ -628,28 +621,25 @@ def test_ujson_native_get_serves_memo_and_invalidates_precisely():
     ):
         native.apply(R(), args)
     # never rendered: the native GET defers
-    rc, _, replies, unhandled, _ = eng.scan_apply(bytearray(b"UJSON GET u profile\r\n"))
+    rc, _, replies, unhandled, _ = scan_bytes(eng, bytearray(b"UJSON GET u profile\r\n"))
     assert rc == 1 and unhandled == [b"UJSON", b"GET", b"u", b"profile"]
     # Python renders (and repairs the memo)...
     want = _oracle_reply(native, [b"GET", b"u", b"profile"])
     want_root = _oracle_reply(native, [b"GET", b"u"])
     # ...and the same GETs now settle natively on those exact bytes
-    rc, _, replies, _, _ = eng.scan_apply(
-        bytearray(b"UJSON GET u profile\r\nUJSON GET u\r\n")
+    rc, _, replies, _, _ = scan_bytes(eng, bytearray(b"UJSON GET u profile\r\nUJSON GET u\r\n")
     )
     assert rc == 0 and replies == want + want_root
     served = eng.served_counts()["UJSON"]
     # a write at a DISJOINT path keeps the profile memo (still native)
     # but drops the root render (() is a prefix of every write path)
-    rc, _, replies, unhandled, _ = eng.scan_apply(
-        bytearray(b"UJSON INS u tags 2\r\nUJSON GET u profile\r\n")
+    rc, _, replies, unhandled, _ = scan_bytes(eng, bytearray(b"UJSON INS u tags 2\r\nUJSON GET u profile\r\n")
     )
     assert rc == 0 and replies == b"+OK\r\n" + want
-    rc, _, _, unhandled, _ = eng.scan_apply(bytearray(b"UJSON GET u\r\n"))
+    rc, _, _, unhandled, _ = scan_bytes(eng, bytearray(b"UJSON GET u\r\n"))
     assert rc == 1 and unhandled == [b"UJSON", b"GET", b"u"]
     # a write AT the memoised path invalidates it
-    rc, _, replies, unhandled, _ = eng.scan_apply(
-        bytearray(b'UJSON RM u profile "p1"\r\nUJSON GET u profile\r\n')
+    rc, _, replies, unhandled, _ = scan_bytes(eng, bytearray(b'UJSON RM u profile "p1"\r\nUJSON GET u profile\r\n')
     )
     assert rc == 1 and replies == b"+OK\r\n"
     assert unhandled == [b"UJSON", b"GET", b"u", b"profile"]
@@ -657,15 +647,15 @@ def test_ujson_native_get_serves_memo_and_invalidates_precisely():
     # banked INS+RM are visible) and repairs the memo again
     after = _oracle_reply(native, [b"GET", b"u", b"profile"])
     assert after == b"$0\r\n\r\n"  # p1 removed
-    rc, _, replies, _, _ = eng.scan_apply(bytearray(b"UJSON GET u profile\r\n"))
+    rc, _, replies, _, _ = scan_bytes(eng, bytearray(b"UJSON GET u profile\r\n"))
     assert rc == 0 and replies == after
     assert eng.served_counts()["UJSON"] > served
     # absent keys defer and NEVER memoise (a read-only scan over
     # missing keys must not grow engine rows without bound)
-    rc, _, _, unhandled, _ = eng.scan_apply(bytearray(b"UJSON GET nope\r\n"))
+    rc, _, _, unhandled, _ = scan_bytes(eng, bytearray(b"UJSON GET nope\r\n"))
     assert rc == 1 and unhandled == [b"UJSON", b"GET", b"nope"]
     assert _oracle_reply(native, [b"GET", b"nope"]) == b"$0\r\n\r\n"
-    rc, _, _, unhandled, _ = eng.scan_apply(bytearray(b"UJSON GET nope\r\n"))
+    rc, _, _, unhandled, _ = scan_bytes(eng, bytearray(b"UJSON GET nope\r\n"))
     assert rc == 1 and unhandled == [b"UJSON", b"GET", b"nope"]
     assert eng.uj_memo_len(b"nope") == 0
 
@@ -680,17 +670,17 @@ def test_ujson_memo_invalidated_by_cluster_converge():
     native = RepoUJSON(identity=1, engine=eng)
     native.apply(R(), [b"INS", b"u", b"tags", b"1"])
     before = _oracle_reply(native, [b"GET", b"u", b"tags"])
-    rc, _, replies, _, _ = eng.scan_apply(bytearray(b"UJSON GET u tags\r\n"))
+    rc, _, replies, _, _ = scan_bytes(eng, bytearray(b"UJSON GET u tags\r\n"))
     assert rc == 0 and replies == before
     remote = UJSON()
     d = UJSON()
     remote.ins(7, ("tags",), "2", delta=d)
     native.converge(b"u", d)
-    rc, _, _, unhandled, _ = eng.scan_apply(bytearray(b"UJSON GET u tags\r\n"))
+    rc, _, _, unhandled, _ = scan_bytes(eng, bytearray(b"UJSON GET u tags\r\n"))
     assert rc == 1 and unhandled == [b"UJSON", b"GET", b"u", b"tags"]
     after = _oracle_reply(native, [b"GET", b"u", b"tags"])
     assert after != before
-    rc, _, replies, _, _ = eng.scan_apply(bytearray(b"UJSON GET u tags\r\n"))
+    rc, _, replies, _, _ = scan_bytes(eng, bytearray(b"UJSON GET u tags\r\n"))
     assert rc == 0 and replies == after
 
 
@@ -700,7 +690,7 @@ def _native_serve(native, eng, args) -> bytes:
     the repo (which repairs the memo) otherwise. Returns reply bytes."""
     parts = [b"UJSON", *args]
     wire = _resp_array(parts)
-    rc, consumed, replies, unhandled, _ = eng.scan_apply(wire)
+    rc, consumed, replies, unhandled, _ = scan_bytes(eng, wire)
     assert consumed == len(wire)
     if rc == 1:
         return replies + _oracle_reply(native, unhandled[1:])

@@ -25,6 +25,7 @@ from jylis_tpu.models.database import Database
 from jylis_tpu.obs import SEAMS, loop as loop_mod, prom, span
 from jylis_tpu.obs.registry import MetricsRegistry
 from jylis_tpu.utils import metrics
+from jylis_tpu.server.server import _Door
 
 from procutil import REPO, SPAWN_CPU, connect_client, free_port, stop_node
 from test_async_serving import SLOW, make_server, slow_down_drain
@@ -539,6 +540,9 @@ class _Writer:
     def get_write_buffer_size(self):
         return 0
 
+    def get_extra_info(self, name):
+        return None  # no socket: its door is `write`, not the sender
+
     async def drain(self):
         pass
 
@@ -563,7 +567,8 @@ def test_a_bursts_sleep_is_the_lock_waits_and_not_the_routes(held):
             await lock.acquire()
         burst = asyncio.create_task(server._apply_native(
             db.native_engine, bytearray(b"GCOUNT INC x 1\r\n"), make_parser(),
-            Respond(out.extend), lambda bound=0: 0.0, writer, out, 0.0,
+            Respond(out.extend), lambda bound=0: 0.0,
+            _Door(server, writer, db.native_engine), out, 0.0,
             time.perf_counter()))
         if held:
             await asyncio.sleep(0.2)
@@ -583,12 +588,20 @@ def test_a_bursts_sleep_is_the_lock_waits_and_not_the_routes(held):
     asyncio.run(main())
 
 
-@pytest.mark.parametrize("size,waits", [(1000, 0), (4 << 20, 1)])
-def test_a_reply_the_socket_does_not_take_whole_is_one_write_wait(size, waits):
-    """A reply past the transport's high-water mark to a client that
-    does not read: the handler waits in drain(), one serve.write_wait
-    sample as long as the client kept it waiting, and none of it in the
-    tail. A 1 KB reply goes out whole: no sample."""
+@pytest.mark.parametrize(
+    "size,commands,waits", [(1000, 2, 0), (4 << 20, 2, 1), (4 << 20, 1, 0)]
+)
+def test_a_reply_the_socket_does_not_take_whole_is_one_write_wait(
+    size, commands, waits
+):
+    """A reply past the high-water mark to a client that does not read,
+    and a second command behind it: the handler waits in drain(), one
+    serve.write_wait sample as long as the client kept it waiting, and
+    none of it in the tail. A 1 KB reply goes out whole: no sample.
+    ONE such command and nothing after it: the reply is with the sender
+    (which the byte bound counts at once), and the handler, whose next
+    hand-off would have told it of the refusal, sleeps for no consumer:
+    it is back in its read, and there is no sample."""
     import socket
 
     async def main():
@@ -609,11 +622,21 @@ def test_a_reply_the_socket_does_not_take_whole_is_one_write_wait(size, waits):
             sock.setblocking(False)
             await loop.sock_connect(sock, ("127.0.0.1", server.port))
             await loop.sock_sendall(sock, b"TREG GET k\r\n")
+            await asyncio.sleep(0.05)
+            end = b"\r\n:1\r\n"
+            if commands == 2:
+                # a second command: its reply's hand-off is where a
+                # handler behind the sender learns that the socket
+                # refused the first
+                await loop.sock_sendall(sock, b"GCOUNT GET none\r\n")
+                end += b":0\r\n"
             await asyncio.sleep(0.3)  # does not read
+            if size > 1 << 20:  # held, and counted by the byte bound
+                assert db.admission.queued_bytes > size // 4
             wait = reg.hist("serve.write_wait")
             assert wait.count == 0  # a waiting handler has recorded nothing yet
             got = b""
-            while not got.endswith(b"\r\n:1\r\n"):
+            while not got.endswith(end):
                 got += await asyncio.wait_for(loop.sock_recv(sock, 1 << 20), 5)
             assert value in got
             sock.close()

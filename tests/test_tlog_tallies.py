@@ -13,6 +13,8 @@ from jylis_tpu.models.repo_tlog import RepoTLOG
 from jylis_tpu.obs.registry import MetricsRegistry
 from jylis_tpu.utils.metrics import metric_lines
 
+from procutil import scan_bytes
+
 ENGINES = ["auto", "python"]
 KINDS = ("entries", "trims", "grows", "bases_lost", "row_gathers", "view_sorts")
 
@@ -192,7 +194,7 @@ def test_foreign_entries_with_duplicates_and_a_cutoff_fold_into_the_base(engine)
     want = reference(foreign + [(b"mine", 7), (b"w", 8)], 5)
     assert repo._tbl.base_valid(row) and repo._tbl.len_cache(row) == len(want) == 4
     if repo.engine is not None:  # the native burst serves it: nothing deferred to Python
-        rc, _, replies, unhandled, _ = repo.engine.scan_apply(bytearray(b"TLOG GET k\r\n"))
+        rc, _, replies, unhandled, _ = scan_bytes(repo.engine, bytearray(b"TLOG GET k\r\n"))
         assert rc == 0 and unhandled is None
         assert replies.startswith(b"*4\r\n*2\r\n$1\r\ny\r\n:9\r\n")
     assert get(repo, b"k") == want

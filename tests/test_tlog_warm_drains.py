@@ -3,8 +3,9 @@ warming, a seeded run of the cell's mix (`ycsb-tlog-1kx1k.e`: GET with a
 count, INS, TRIMAT, Zipfian keys; the INS share raised so that the hot row
 outgrows len_cap twice inside a test) compiles nothing on the serving
 path, every `grow` lands on a plane shape whose programs were compiled
-before it, and the programs of the len_cap after that are compiled on the
-warm thread once the longest row passes WARM_FILL."""
+before it (the boot compiles its width and the next two), and the programs
+of a len_cap beyond those are compiled on the warm thread once the longest
+row passes WARM_FILL of the width before it."""
 
 import numpy as np
 import pytest
@@ -72,33 +73,35 @@ def test_nothing_compiles_while_serving_after_the_warm_up(engine):
     repo = mix.repo
     mix.boot()  # drains what recovery buffered; 200 of 256 slots: regrows, then compiles
     assert repo._len_cap == 512 and repo._longest == 200
-    assert repo._warmed == {(1024, 512), (1024, 1024)} and mix.grown_to == []
+    boot = {(1024, 512), (1024, 1024), (1024, 2048)}
+    assert repo._warmed == boot and mix.grown_to == []
     ready = compiled()
 
-    # to the first grow (a row passes 512), short of WARM_FILL of the new width
-    mix.run_until(600)
+    # to the first grow (a row passes 512), then past WARM_FILL of the new
+    # width: the boot compiled the width after it too, no warm thread starts
+    mix.run_until(int(WARM_FILL * 1024) + 8)
     assert repo._len_cap == 1024 and mix.grown_to == [(1024, 1024)]
     assert compiled() == ready, "a program compiled with clients waiting"
-    assert repo._warming is None
+    assert repo._warming is None and repo._warmed == boot
 
-    # past WARM_FILL of 1,024: the next width's programs compile on the warm thread
-    mix.run_until(int(WARM_FILL * 1024) + 8)
+    # the second grow meets its programs ready
+    mix.run_until(1100)
+    assert repo._len_cap == 2048 and mix.grown_to == [(1024, 1024), (1024, 2048)]
+    assert compiled() == ready and repo._warming is None
+
+    # past WARM_FILL of 2,048: the next width's programs compile on the warm thread
+    mix.run_until(int(WARM_FILL * 2048) + 8)
     assert repo._warming is not None
     repo._warming.result(timeout=300)
-    assert (1024, 2048) in repo._warmed
+    assert (1024, 4096) in repo._warmed
     ahead = compiled()
     # (one more drain program, unless an earlier test of this process left it compiled)
     assert ahead["_drain_tlog"] - ready["_drain_tlog"] in (0, 1)
     assert ahead["_drain_tlog_dense"] == ready["_drain_tlog_dense"]
-
-    # the second grow meets them ready
-    mix.run_until(1100)
-    assert repo._len_cap == 2048 and mix.grown_to == [(1024, 1024), (1024, 2048)]
-    assert compiled() == ahead
     reg = repo_tlog.resolve_registry(repo)
     assert reg.tallies["drain.TLOG.grows"] >= 3  # the boot's, and the two above
     hot = max(range(64), key=lambda k: repo._tbl.len_cache(repo._tbl.find(mix.ref.key(k))))
-    assert len(mix.wire.call(repo, b"GET", mix.ref.key(hot))) >= 1100
+    assert len(mix.wire.call(repo, b"GET", mix.ref.key(hot))) >= 1536
 
 
 def test_a_young_keyspace_and_the_mesh_are_left_to_their_first_drain():
