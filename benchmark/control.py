@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """The control of `correct`: the reference put in the program's place and
 computed in the nearest precision below the one the configuration states
-(float64 in place of exact 64-bit integers). It has to come out NOT correct.
+(float64 in place of exact 64-bit integers). It has to come out NOT correct:
+for each type the configuration states in turn, that type's answers in the
+lower precision (the others exact) must fail the comparison.
 
     python3 benchmark/control.py --workload <name> --seed <n> [--rehearse]
 
@@ -27,9 +29,10 @@ sys.path.insert(0, ROOT)
 from benchmark.harness import check, gen, manifest  # noqa: E402
 
 
-def synthetic_logs(traffic: dict, recipe: dict, seed: int, writes: int) -> list[dict]:
+def synthetic_logs(traffic: dict, recipes: dict, seed: int, writes: int) -> list[dict]:
     """Acknowledged writes as the workers would log them: ``writes`` in
-    all, split over the mix's write ops by their shares."""
+    all, split over the mix's write ops by their shares. ``recipes``: type
+    name -> its state recipe."""
     rng = np.random.default_rng([seed, 0x43544C])
     logs = []
     for si, stream in enumerate(traffic["streams"]):
@@ -38,7 +41,8 @@ def synthetic_logs(traffic: dict, recipe: dict, seed: int, writes: int) -> list[
         if "write" not in classes:
             continue
         ops = rng.choice(len(probs), writes, p=probs).astype(np.uint8)
-        keys = gen.KeyDist(stream["keys"], recipe["keys"]).draw(rng, writes)
+        keys = gen.draw_keys(rng, stream["keys"],
+                             [recipes[t.type_name]["keys"] for t in templates], ops)
         lo, hi = stream.get("amount", [1, 1])
         a = rng.integers(lo, hi + 1, writes, dtype=np.uint64)
         b = np.zeros(writes, np.uint64)
@@ -50,27 +54,34 @@ def synthetic_logs(traffic: dict, recipe: dict, seed: int, writes: int) -> list[
             b = (conn.astype(np.uint64) << np.uint64(40)) | np.arange(writes, dtype=np.uint64)
         logs.append({"op": ops, "key": keys, "a": a, "b": b,
                      "acked": np.ones(writes, bool), "classes": classes,
-                     "verbs": [t.verb for t in templates]})
+                     "types": [t.type_name for t in templates],
+                     "verbs": [t.verb for t in templates],
+                     "texts": [t.text for t in templates]})
     return logs
 
 
-def control(workload: str, seed: int, rehearse: bool, writes: int) -> dict:
-    cell = manifest.Cell(workload)
+def control(workload: str, seed: int, rehearse: bool, writes: int,
+            root: str = manifest.ROOT) -> dict:
+    cell = manifest.Cell(workload, root)
     config = manifest.sized(cell.config, rehearse)
-    recipe = config["state"]
+    types = manifest.types_of(config, rehearse)
+    recipes = {t["type"]: t["state"] for t in types}
     traffic = manifest.sized(cell.traffic, rehearse)
-    hot = gen.hottest(recipe["keys"], recipe["keys"])
-    ref = cell.reference_module().Reference(recipe, seed, 1, [2, 3, 4][: config["peers"]],
-                                            hot, gen.Values(seed))
-    written, doubtful = check.feed_reference(ref, synthetic_logs(traffic, recipe, seed, writes))
-    keys = check.choose_keys(ref, seed, recipe["keys"], config["check"]["sample"], written,
-                             doubtful, hot[: recipe.get("foreign_keys", 4096)])
-    exact = ref.expected(keys)
-    lower = ref.expected_lower_precision(keys)
-    bad = sum(1 for e, g in zip(exact, lower) if e != g)
-    return {"workload": workload, "seed": seed, "compared": len(keys),
-            "control_mismatched": bad, "limit": check.LIMIT,
-            "control_correct": bad <= check.LIMIT}
+    refs, hot = check.references(cell, recipes, seed, 1, [2, 3, 4][: config["peers"]])
+    fed = check.feed_reference(refs, synthetic_logs(traffic, recipes, seed, writes))
+    by_type = {}
+    for block in types:
+        name = block["type"]
+        keys = check.choose_keys(seed, block, *fed[name], hot[name])
+        exact = refs[name].expected(keys)
+        lower = refs[name].expected_lower_precision(keys)
+        by_type[name] = {"compared": len(keys),
+                         "control_mismatched": sum(1 for e, g in zip(exact, lower) if e != g)}
+    # the control must fail for EACH type: the weakest one is the reading
+    weakest = min(by_type.values(), key=lambda r: r["control_mismatched"])
+    return {"workload": workload, "seed": seed, **weakest, "limit": check.LIMIT,
+            "control_correct": weakest["control_mismatched"] <= check.LIMIT,
+            "by_type": by_type}
 
 
 def main() -> int:

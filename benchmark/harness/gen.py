@@ -4,8 +4,10 @@ under ``benchmark/traffic/``; nothing here knows a mix or a type by name.
 
 Template fields (``"TREG SET {key} {value:1000} {ts}"``):
 
-``{key}``       the key's bytes: ``key_format % index``, the index drawn
-                from the stream's ``keys`` distribution;
+``{key}``       the key's bytes: ``key_format % index`` with the
+                ``key_format`` of the template's type (its first word),
+                the index drawn from the stream's ``keys`` distribution
+                over THAT type's keyspace (`draw_keys`);
 ``{amount}``    a whole number drawn uniformly from the stream's
                 ``amount`` range (both ends included);
 ``{value:N}``   N bytes made from a per-operation nonce (`Values.make`):
@@ -66,6 +68,28 @@ class KeyDist:
             return rng.integers(0, self.n, count)
         ranks = np.searchsorted(self.cdf, rng.random(count))
         return self.perm[np.minimum(ranks, self.n - 1)]
+
+
+def draw_keys(rng: np.random.Generator, spec: dict, sizes: list[int],
+              ops: np.ndarray) -> np.ndarray:
+    """The key index of each drawn operation, over its OWN type's keyspace:
+    ``sizes[i]`` is the number of keys of the type that op ``i`` names.
+    One draw of ``len(ops)`` indices per distinct size, in the order the
+    ops first name it, so a stream of one type draws as it always did, and
+    types that state the same ``keys`` share the drawn index: operation
+    ``j`` meets the same entity whichever of them it names (user 17's
+    timeline and user 17's follower set)."""
+    drawn: dict[int, np.ndarray] = {}
+    for n in sizes:
+        if n not in drawn:
+            drawn[n] = KeyDist(spec, n).draw(rng, len(ops))
+    if len(drawn) == 1:
+        return drawn[sizes[0]]
+    keys = np.empty(len(ops), np.int64)
+    for i, n in enumerate(sizes):
+        mine = ops == i
+        keys[mine] = drawn[n][mine]
+    return keys
 
 
 class Values:

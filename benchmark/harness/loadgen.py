@@ -154,10 +154,12 @@ class Draws:
         self.templates, probs = gen.op_table(cfg["ops"])
         self.count = count
         self.ops = rng.choice(len(probs), count, p=probs).astype(np.uint8)
-        self.keys = gen.KeyDist(cfg["keys"], cfg["n_keys"]).draw(rng, count)
+        # an op's key lies in ITS type's keyspace and takes its key format
+        spaces = [cfg["keyspaces"][t.type_name] for t in self.templates]
+        self.keys = gen.draw_keys(rng, cfg["keys"], [s["keys"] for s in spaces], self.ops)
         lo, hi = cfg.get("amount", [1, 1])
         self.amounts = rng.integers(lo, hi + 1, count, dtype=np.uint64)
-        self.key_format = cfg["key_format"].encode()
+        self.key_formats = [s["key_format"].encode() for s in spaces]
         self.values = gen.Values(cfg["seed"])
         self.i = 0
         self.t_begin = cfg["t_begin"]
@@ -178,7 +180,7 @@ class Draws:
         if "value" in tpl.fields:
             b = (link.ident << 40) | link.seq
             value = self.values.make(b, tpl.value_size)
-        return tpl.render(self.key_format % key, a, a, value), op, key, a, b
+        return tpl.render(self.key_formats[op] % key, a, a, value), op, key, a, b
 
 
 def sleep_until(t: float) -> None:
@@ -284,6 +286,20 @@ def run_open(cfg: dict, log: Log) -> dict:
     return {"generator_cpu_share": busy}
 
 
+def probe_draws(cfg: dict, count: int):
+    """(key indices, amounts, write template, read template, key format)
+    of the first ``count`` probes: the probes' type is their write
+    template's, and its keyspace is the one they are drawn over."""
+    p = cfg["probe"]
+    rng = np.random.default_rng([cfg["seed"], cfg["stream_index"], cfg["worker"]])
+    write_tpl, read_tpl = gen.Template(p["write"]), gen.Template(p["read"])
+    space = cfg["keyspaces"][write_tpl.type_name]
+    keys = gen.KeyDist(p["keys"], space["keys"]).draw(rng, count)
+    lo, hi = p["amount"]
+    amounts = rng.integers(lo, hi + 1, count, dtype=np.uint64)
+    return keys, amounts, write_tpl, read_tpl, space["key_format"].encode()
+
+
 def run_probe(cfg: dict, log: Log) -> dict:
     """Probe j is due at ``t_begin + j / rate``: GET at the reader target
     (the value before), write at writer target ``j mod writers``, then GET
@@ -295,15 +311,10 @@ def run_probe(cfg: dict, log: Log) -> dict:
     reader = Link(sel, *cfg["read_targets"][0], cfg["conn_base"])
     writers = [Link(sel, host, port, cfg["conn_base"] + 1 + i)
                for i, (host, port) in enumerate(cfg["write_targets"])]
-    rng = np.random.default_rng([cfg["seed"], cfg["stream_index"], cfg["worker"]])
     rate = float(p["rate_per_s"])
     t_begin, t1 = cfg["t_begin"], cfg["t1"]
     total = int((t1 - t_begin) * rate)
-    keys = gen.KeyDist(p["keys"], cfg["n_keys"]).draw(rng, max(total, 1))
-    lo, hi = p["amount"]
-    amounts = rng.integers(lo, hi + 1, max(total, 1), dtype=np.uint64)
-    write_tpl, read_tpl = gen.Template(p["write"]), gen.Template(p["read"])
-    key_format = cfg["key_format"].encode()
+    keys, amounts, write_tpl, read_tpl, key_format = probe_draws(cfg, max(total, 1))
     threshold = int(p["visible_delta_at_least"])
     poll = p["poll_ms"] / 1000.0
     timeout = float(p["timeout_s"])

@@ -40,9 +40,12 @@ class Cell:
     def _mine(self, metric: dict) -> bool:
         return "workloads" not in metric or self.name in metric["workloads"]
 
-    def reference_module(self):
-        return load_module(os.path.join(self.bench_dir, "reference",
-                                        self.config["type"] + ".py"))
+    def reference_module(self, type_name: str | None = None):
+        """``reference/<TYPE>.py`` of one of the configuration's types (of
+        its only type, where it states one)."""
+        if type_name is None:
+            type_name = self.config["type"]
+        return load_module(os.path.join(self.bench_dir, "reference", type_name + ".py"))
 
     def layer_spec(self, metric_name: str) -> dict:
         return load_json(os.path.join(self.bench_dir, "layer_metrics", metric_name + ".json"))
@@ -54,6 +57,21 @@ def load_module(path: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def types_of(config: dict, rehearse: bool) -> list[dict]:
+    """The data types a `sized` configuration states, each as its
+    ``{"type", "state", "check"}`` block: the file's ``types`` list (a block
+    may carry ``rehearse`` overrides of its own), or the one type that
+    ``type``, ``state`` and ``check`` at the top of the file describe."""
+    if "types" in config:
+        blocks = [sized(b, rehearse) for b in config["types"]]
+    else:
+        blocks = [{k: config[k] for k in ("type", "state", "check")}]
+    names = [b["type"] for b in blocks]
+    if len(set(names)) != len(names):
+        raise ValueError(f"a configuration states a type once: {names}")
+    return blocks
 
 
 def sized(block: dict, rehearse: bool) -> dict:

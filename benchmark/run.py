@@ -3,11 +3,13 @@
 
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-State from the seed -> snapshot -> boot the node (``python -m jylis_tpu``,
-the only child not pinned to the CPU) and its peers through their own boot
-recovery -> warm up with the cell's own mix -> measure ``--seconds`` ->
-read back and compare with the plain reference -> stop. The LAST line of
-stdout is the result object; everything else is on earlier lines or under
+State of every data type the configuration states, from the seed -> one
+snapshot -> boot the node (``python -m jylis_tpu``, the only child not
+pinned to the CPU) and its peers through their own boot recovery -> warm up
+with the cell's own mix -> measure ``--seconds`` -> read every type back and
+compare with its plain reference -> stop. The LAST line of stdout is the
+result object (its last key, ``compared``, holds each number compared beside
+its limit; the same on the last lines of stderr); everything else is on earlier lines or under
 ``benchmark/out/``. No fallback: a node on another platform than ``tpu``,
 on the Python tables, with an error-level log line, or a failed phase ends
 the run non-zero with no result line. ``--rehearse`` (tests, debugging)
@@ -108,29 +110,38 @@ def load_log(cfg: dict) -> dict:
         lg = {k: z[k] for k in z.files}
     lg["kind"], lg["counted"], lg["stream"] = cfg["kind"], cfg["counted"], cfg["stream"]
     if cfg["kind"] == "probe":
-        tpl = gen.Template(cfg["probe"]["write"])
-        lg["verbs"], lg["classes"] = [tpl.verb], ["write"]
+        templates = [gen.Template(cfg["probe"]["write"])]
+        lg["classes"] = ["write"]
         lg["timeout_s"] = float(cfg["probe"]["timeout_s"])
         # a probe's write is acknowledged once the worker stamped ``sched``
         lg["acked"] = lg["sched"] > 0
     else:
-        lg["verbs"] = [gen.Template(op["cmd"]).verb for op in cfg["ops"]]
+        templates = [gen.Template(op["cmd"]) for op in cfg["ops"]]
         lg["classes"] = [op["class"] for op in cfg["ops"]]
         lg["acked"] = lg["status"] == 1
+    # per op index: its type (whose reference follows it), verb and template
+    lg["types"] = [t.type_name for t in templates]
+    lg["verbs"] = [t.verb for t in templates]
+    lg["texts"] = [t.text for t in templates]
     return lg
 
 
 class Run:
-    """One booted deployment: the node under test, its peers, the reference
-    that follows every acknowledged write. `boot`, then `drive` one or more
+    """One booted deployment: the node under test, its peers, and behind
+    every data type the configuration states the reference that follows
+    that type's acknowledged writes. `boot`, then `drive` one or more
     windows, then `verify` and `stop`; `close` always."""
 
-    def __init__(self, args):
+    def __init__(self, args, root: str = ROOT):
+        """``root``: where ``BENCHMARK.json`` and the files it names are
+        read from (a test's copy with a cell laid over); the program, the
+        load workers and ``benchmark/out`` are this checkout's."""
         self.args = args
-        self.cell = cell = manifest.Cell(args.workload)
+        self.cell = cell = manifest.Cell(args.workload, root)
         self.rehearse = rehearse = args.rehearse
         self.config = config = manifest.sized(cell.config, rehearse)
-        self.recipe = config["state"]
+        self.types = manifest.types_of(config, rehearse)
+        self.recipes = {t["type"]: t["state"] for t in self.types}
         self.traffic = manifest.sized(cell.traffic, rehearse)
         src_hash = ensure_native()
         say(f"cell {cell.name}: config {config['name']}, traffic {self.traffic['name']}, "
@@ -162,6 +173,9 @@ class Run:
         self.workers: list[subprocess.Popen] = []
         self.logs: list[dict] = []
         self.n_windows = 0
+        self.refs: dict = {}  # type name -> its reference
+        self.hot: dict = {}  # type name -> its key indices, hottest first
+        self.compared: dict = {}  # "<node>.<TYPE>" -> [mismatched reads, limit]
         # node name -> port the LOAD dials in that node's place (the
         # read-back always dials the node itself): lets a test put a
         # tampering proxy under the timed path
@@ -170,16 +184,7 @@ class Run:
     def boot(self) -> None:
         """State from the seed through the program's snapshot format, then
         peers and node together, each through its own boot recovery."""
-        args, recipe, config = self.args, self.recipe, self.config
-        t = time.monotonic()
-        self.hot = gen.hottest(recipe["keys"], recipe["keys"])
-        self.ref = self.cell.reference_module().Reference(
-            recipe, args.seed, replica_id(self.node.addr),
-            [replica_id(p.addr) for p in self.peers], self.hot, gen.Values(args.seed))
-        size = state.write_snapshots(self.ref, config["type"],
-                                     [n.data_dir for n in self.everyone])
-        say(f"state: {recipe['keys']} {config['type']} keys from the seed, snapshot "
-            f"{size / 1e6:.1f} MB in {time.monotonic() - t:.1f}s")
+        self.write_state()
         for n in self.everyone:
             n.spawn()
         for n in self.everyone:
@@ -226,28 +231,60 @@ class Run:
         say("cluster: join sync done on every node (digests matched)")
         self.warm_shapes()
 
+    def write_state(self) -> int:
+        """A reference for every stated type, all from the one seed, and
+        their full states as ONE snapshot in every node's data dir."""
+        t = time.monotonic()
+        self.refs, self.hot = check.references(
+            self.cell, self.recipes, self.args.seed, replica_id(self.node.addr),
+            [replica_id(p.addr) for p in self.peers])
+        size = state.write_snapshots(self.refs, [n.data_dir for n in self.everyone])
+        keys = " + ".join(f"{r['keys']} {name} keys" for name, r in self.recipes.items())
+        say(f"state: {keys} from the seed, snapshot "
+            f"{size / 1e6:.1f} MB in {time.monotonic() - t:.1f}s")
+        return size
+
+    def keyspaces(self) -> dict:
+        """Per type, what a load worker needs to draw and render a key."""
+        return {name: {"keys": r["keys"], "key_format": r["key_format"]}
+                for name, r in self.recipes.items()}
+
+    def warm_bursts(self):
+        """(entry of the mix's ``warm_bursts``, its write template, the
+        burst's index in the entry, key indices, amounts, commands) of every
+        burst, from the seed. ``warm_bursts`` is one object or a list of
+        them, an entry a type: the type is the entry's write template's."""
+        spec = self.traffic.get("warm_bursts") or []
+        for j, entry in enumerate([spec] if isinstance(spec, dict) else spec):
+            write_tpl = gen.Template(entry["write"])
+            recipe = self.recipes[write_tpl.type_name]
+            rng = np.random.default_rng([self.args.seed, 0x5742] + ([j] if j else []))
+            fmt = recipe["key_format"].encode()
+            for i, size in enumerate(entry["sizes"]):
+                size = min(size, recipe["keys"])
+                keys = rng.choice(recipe["keys"], size, replace=False)
+                amounts = rng.integers(1, 1000, size, dtype=np.uint64)
+                cmds = [write_tpl.render(fmt % int(k), int(a)) for k, a in zip(keys, amounts)]
+                yield entry, write_tpl, i, keys, amounts, cmds
+
     def warm_shapes(self) -> None:
         """Drain batches pad to powers of two, and every new size is a new
         compiled program: before the mix starts, bursts of exactly those
         sizes go in at the ``write_at`` nodes and one read at the node
         drains each, so that the window meets no new shape. The bursts are
         acknowledged writes like any other and reach the reference."""
-        spec = self.traffic.get("warm_bursts")
-        if not spec:
-            return
+        if not self.traffic.get("warm_bursts"):
+            return  # nothing to warm: no connection is opened at the node
         t = time.monotonic()
         targets = {"node": [self.node], "peers": self.peers}
-        write_tpl, read_tpl = gen.Template(spec["write"]), gen.Template(spec["read"])
-        rng = np.random.default_rng([self.args.seed, 0x5742])
-        fmt = self.recipe["key_format"].encode()
-        counter = spec["drain_counter"]
         conn = resp.Conn(HOST, self.node.port, timeout=120)
-        nodes = targets[spec["write_at"]]
-        for i, size in enumerate(spec["sizes"]):
-            size = min(size, self.recipe["keys"])
-            keys = rng.choice(self.recipe["keys"], size, replace=False)
-            amounts = rng.integers(1, 1000, size, dtype=np.uint64)
-            cmds = [write_tpl.render(fmt % int(k), int(a)) for k, a in zip(keys, amounts)]
+        n = 0
+        for n, (spec, write_tpl, i, keys, amounts, cmds) in enumerate(self.warm_bursts(), 1):
+            size = len(keys)
+            read_tpl = gen.Template(spec["read"])
+            fmt = self.recipes[write_tpl.type_name]["key_format"].encode()
+            counter = spec["drain_counter"]
+            nodes = targets[spec["write_at"]]
             before = self.node.prom().get(counter, 0)
             # a writer ships its deltas when a write finds the last flush
             # over 500 ms old (else at the 10 s heartbeat): all but one row
@@ -260,7 +297,8 @@ class Run:
             self.logs.append({"kind": "burst", "counted": False, "op": np.zeros(size, np.uint8),
                               "key": keys.astype(np.int64), "a": amounts,
                               "b": np.zeros(size, np.uint64), "acked": acked,
-                              "verbs": [write_tpl.verb], "classes": ["write"]})
+                              "types": [write_tpl.type_name], "verbs": [write_tpl.verb],
+                              "texts": [write_tpl.text], "classes": ["write"]})
             deadline = time.monotonic() + 30
             while True:  # read until the burst has arrived and a drain has run
                 time.sleep(spec["settle_ms"] / 1000.0)
@@ -268,21 +306,27 @@ class Run:
                 if self.node.prom().get(counter, 0) > before:
                     break
                 if time.monotonic() > deadline:
-                    raise RunFailure(f"warm burst of {size} rows drained nothing")
+                    raise RunFailure(f"warm burst of {size} {write_tpl.type_name} rows "
+                                     f"drained nothing")
         conn.close()
-        say(f"warm bursts: {len(spec['sizes'])} drain shapes in {time.monotonic() - t:.1f}s")
+        say(f"warm bursts: {n} drain shapes in {time.monotonic() - t:.1f}s")
+
+    def worker_cfgs(self, traffic: dict, t_begin: float, t1: float) -> list[dict]:
+        """The load workers' configurations for the next window of a mix."""
+        node = self.node
+        targets = {"node": [self.load_ports.get(node.name, node.port)],
+                   "peers": [self.load_ports.get(p.name, p.port) for p in self.peers]}
+        base = {"seed": self.args.seed + 7919 * self.n_windows, "keyspaces": self.keyspaces(),
+                "t_begin": t_begin, "t1": t1}
+        return worker_configs(traffic, targets, base)
 
     def drive(self, traffic: dict, seconds: float) -> dict:
         """Warm up with the mix, then one measured window of it."""
         node, work = self.node, self.work
-        targets = {"node": [self.load_ports.get(node.name, node.port)],
-                   "peers": [self.load_ports.get(p.name, p.port) for p in self.peers]}
         t_begin = time.monotonic() + LEAD_S
         t0 = t_begin + traffic["warm_seconds"]
         t1 = t0 + seconds
-        base = {"seed": self.args.seed + 7919 * self.n_windows, "n_keys": self.recipe["keys"],
-                "key_format": self.recipe["key_format"], "t_begin": t_begin, "t1": t1}
-        cfgs = worker_configs(traffic, targets, base)
+        cfgs = self.worker_cfgs(traffic, t_begin, t1)
         self.workers = []
         for i, cfg in enumerate(cfgs):
             cfg["out"] = os.path.join(work, f"worker{self.n_windows}-{i}.npz")
@@ -318,20 +362,26 @@ class Run:
                 "before": before, "after": after, "wall0": wall0, "wall1": wall1}
 
     def verify(self) -> bool:
-        """`correct`: read back at the node and at every live peer."""
+        """`correct`: every stated type read back at the node and at every
+        live peer, each against its own reference; true only if every
+        (node, type) is within the limit."""
         t = time.monotonic()
-        config, recipe = self.config, self.recipe
-        written, doubtful = check.feed_reference(self.ref, self.logs)
-        keys = check.choose_keys(self.ref, self.args.seed, recipe["keys"],
-                                 config["check"]["sample"], written, doubtful,
-                                 self.hot[: recipe.get("foreign_keys", 4096)])
-        expected = self.ref.expected(keys)
+        fed = check.feed_reference(self.refs, self.logs)
         where = {n.name: n.port for n in self.everyone}
-        verdicts = check.compare(where, self.ref, keys, expected,
-                                 config["check"]["settle_seconds"], say)
-        correct = all(v["mismatched"] <= v["limit"] for v in verdicts.values())
-        say(f"correct: {correct} ({len(keys)} keys at {len(where)} node(s), "
-            f"{len(doubtful)} doubtful keys left out, check took "
+        n_keys = n_doubtful = 0
+        for block in self.types:
+            name, ref = block["type"], self.refs[block["type"]]
+            written, doubtful = fed[name]
+            keys = check.choose_keys(self.args.seed, block, written, doubtful, self.hot[name])
+            verdicts = check.compare(where, ref, keys, ref.expected(keys),
+                                     block["check"]["settle_seconds"], say, name)
+            for node, v in verdicts.items():
+                self.compared[f"{node}.{name}"] = [v["mismatched"], v["limit"]]
+            n_keys += len(keys)
+            n_doubtful += len(doubtful)
+        correct = all(bad <= limit for bad, limit in self.compared.values())
+        say(f"correct: {correct} ({n_keys} keys of {len(self.types)} type(s) at {len(where)} "
+            f"node(s), {n_doubtful} doubtful keys left out, check took "
             f"{time.monotonic() - t:.1f}s)")
         return correct
 
@@ -423,6 +473,9 @@ def run_cell(args) -> dict:
         result["compiles_in_window"] = d["compiles"]
         if run.rehearse:
             result["rehearsal"] = True
+        # each number compared beside its limit, last in the line
+        result["compared"] = {k: {"mismatched_reads": bad, "limit": limit}
+                              for k, (bad, limit) in run.compared.items()}
         failed = False
         return result
     finally:
@@ -445,6 +498,9 @@ def main(argv: list[str] | None = None) -> int:
     except RunFailure as e:
         print(f"run failed, no result: {e}", file=sys.stderr)
         return 1
+    for name, c in result["compared"].items():
+        print(f"compared {name}: mismatched reads {c['mismatched_reads']}, limit {c['limit']}",
+              file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -1,4 +1,6 @@
-"""Writes ``data/tpu_like.xplane.pb``: a tiny trace in the profiler's file
+"""Writes ``data/tpu_like.xplane.pb`` and ``data/host_only.xplane.pb``
+(the trace of a node that served its window from the host: no device
+plane at all): a tiny trace in the profiler's file
 format (XSpace, hand-encoded in protobuf wire format: no generated bindings
 are installed here) with the planes and lines a TPU run writes. The times
 are chosen so that the reduction's answers can be worked by hand; the file
@@ -53,6 +55,17 @@ def plane(name: str, lines: dict, stats: dict | None = None) -> bytes:
     return field(1, body)
 
 
+def host_and_env() -> bytes:
+    ms = lambda t: int(t * MS)
+    host = plane("/host:CPU", {
+        "python": [("drain_PNCOUNT", ms(290), ms(30)), ("drain_PNCOUNT", ms(480), ms(60)),
+                   ("something_else", ms(0), ms(5))],
+    })
+    env = plane("Task Environment", {}, {"profile_start_time": START_NS,
+                                         "profile_stop_time": START_NS + ms(1000)})
+    return host + env
+
+
 def build() -> bytes:
     ms = lambda t: int(t * MS)
     device = plane("/device:TPU:0", {
@@ -63,17 +76,13 @@ def build() -> bytes:
                     ("fusion.3", ms(900), ms(1))],
         "Steps": [("0", ms(0), ms(1000))],
     })
-    host = plane("/host:CPU", {
-        "python": [("drain_PNCOUNT", ms(290), ms(30)), ("drain_PNCOUNT", ms(480), ms(60)),
-                   ("something_else", ms(0), ms(5))],
-    })
-    env = plane("Task Environment", {}, {"profile_start_time": START_NS,
-                                         "profile_stop_time": START_NS + ms(1000)})
-    return device + host + env
+    return device + host_and_env()
 
 
 if __name__ == "__main__":
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "tpu_like.xplane.pb")
-    with open(path, "wb") as f:
-        f.write(build())
-    print(path, os.path.getsize(path))
+    data = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+    for name, blob in (("tpu_like", build()), ("host_only", host_and_env())):
+        path = os.path.join(data, name + ".xplane.pb")
+        with open(path, "wb") as f:
+            f.write(blob)
+        print(path, os.path.getsize(path))

@@ -18,6 +18,13 @@ RECIPE = {"keys": 40, "value_bytes": 48, "key_format": "u%02d",
 KEYS = list(range(RECIPE["keys"]))
 
 
+
+def feed(ref, logs):
+    """`check.feed_reference` for logs of the one type TREG."""
+    for lg in logs:
+        lg.setdefault("types", ["TREG"] * len(lg["verbs"]))
+    return check.feed_reference({"TREG": ref}, logs)["TREG"]
+
 def reference(seed: int):
     return TR.Reference(RECIPE, seed, 0, [1, 2], gen.hottest(40, 40), gen.Values(seed))
 
@@ -51,7 +58,7 @@ def test_every_order_of_three_writers_logs_gives_the_same_expected(seed):
     answers = []
     for order in itertools.permutations(range(3)):
         ref = reference(seed)
-        written, doubtful = check.feed_reference(ref, [logs[i] for i in order])
+        written, doubtful = feed(ref, [logs[i] for i in order])
         answers.append((ref.expected(KEYS), written.tolist(), doubtful.tolist()))
     assert all(a == answers[0] for a in answers[1:])
     # and it is the plain rule: per key the greatest acknowledged timestamp, base included
@@ -83,7 +90,7 @@ def test_a_stale_write_from_another_node_loses():
     log_b = {"op": np.zeros(2, np.uint8), "key": np.array([7, 8]),
              "a": np.array([b_old, b_new], np.uint64), "b": np.array([702, 802], np.uint64),
              "acked": np.ones(2, bool), "verbs": ["SET"], "classes": ["write"]}
-    check.feed_reference(ref, [log_a, log_b])
+    feed(ref, [log_a, log_b])
     assert ref.expected([7, 8]) == [[ref.values.make(701, 48), a_new],
                                     [ref.values.make(802, 48), b_new]]
     # the control (timestamps through float64) loses the connection id in the low bits

@@ -19,6 +19,13 @@ RECIPE = {"keys": 16, "entries": 30, "value_bytes": 40, "key_format": "t%02d",
 KEYS = list(range(RECIPE["keys"]))
 
 
+
+def feed(ref, logs):
+    """`check.feed_reference` for logs of the one type TLOG."""
+    for lg in logs:
+        lg.setdefault("types", ["TLOG"] * len(lg["verbs"]))
+    return check.feed_reference({"TLOG": ref}, logs)["TLOG"]
+
 def reference(seed: int):
     return TL.Reference(RECIPE, seed, 0, [1, 2], gen.hottest(16, 16), gen.Values(seed))
 
@@ -59,7 +66,7 @@ def test_every_order_of_the_three_nodes_logs_gives_the_same_expected(seed):
     answers = []
     for order in itertools.permutations(range(4)):
         ref = reference(seed)
-        written, doubtful = check.feed_reference(ref, [logs[i] for i in order])
+        written, doubtful = feed(ref, [logs[i] for i in order])
         answers.append((ref.expected(KEYS), written.tolist(), doubtful.tolist()))
     assert all(a == answers[0] for a in answers[1:])
     # and it is the plain rule: union of base and acknowledged posts, at or above the greatest cutoff
@@ -99,6 +106,6 @@ def test_a_cutoff_from_another_node_trims_a_post_taken_here_before_it():
              "acked": np.ones(2, bool), "verbs": ["TRIMAT"], "classes": ["write"]}
     for order in ([posts, trims], [trims, posts]):
         ref = reference(1)
-        check.feed_reference(ref, order)
+        feed(ref, order)
         assert ref.expected([3]) == [[[ref.values.make(32, 40), new]]]
         assert len(ref.expected([4])[0]) == 30

@@ -49,6 +49,31 @@ def test_rehearsal_of_each_cell(workload, trace):
         assert all(m["value"] > 0 for m in result["metrics"].values())
 
 
+def test_a_traced_run_with_no_device_plane_prints_its_line(monkeypatch, capsys):
+    """What a new cell's PARENT meets on the chip: a node that serves its
+    window from the host writes a trace with no device plane. Here the CPU
+    rehearsal's trace is read as a chip run's would be (its host plane not
+    taken for a device): busy 0 s, `device.idle_share` 100, no roofline,
+    the result line printed, exit code 0 (PR 38 was refused over the raise)."""
+    from benchmark import run as bench
+
+    real = bench.readers.Context
+    monkeypatch.setattr(bench.readers, "Context",
+                        lambda *a: real(*a[:7], False, *a[8:]))  # rehearse=False: no host-as-device
+    rc = bench.main(["--workload", "ycsb-treg-1m.a", "--seed", str(2**31 + 4141), "--seconds", "3",
+                     "--trace", "1", "--rehearse"])
+    assert rc == 0
+    result = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["device"]["busy_s"] == 0.0 and result["device"]["window_s"] > 2.9
+    assert result["metrics"]["device.idle_share"]["value"] == 100.0
+    assert "kernel.treg_drain_roofline" not in result["metrics"]
+    assert result["breakdown"]["device_ops"] == []
+    assert sum(s for _n, s in result["breakdown"]["idle_gaps"]) == pytest.approx(
+        result["device"]["window_s"], rel=1e-6)
+    assert "server.dispatch_us_per_cmd" in result["metrics"]
+
+
 def test_a_measuring_run_without_a_tpu_names_the_platform_and_prints_no_result(tmp_path):
     """Without ``--rehearse`` the CPU platform is refused. A copy of the
     benchmark with the state cut down keeps the test short; the program is
@@ -83,13 +108,15 @@ class TamperingProxy:
     on its way in (an INC/DEC amount grows by one; a SET value's last byte
     flips a bit; hot keys are soon overwritten, so one write would not
     do): the node acknowledges what it got, the reference follows what was
-    sent, so answers are wrong where they are produced."""
+    sent, so answers are wrong where they are produced. ``only_type``
+    leaves the writes of every other data type alone."""
 
-    def __init__(self, port: int):
+    def __init__(self, port: int, only_type: bytes | None = None):
         import socket
         import threading
 
         self.port_to = port
+        self.only_type = only_type
         self.tampered = self.writes = 0
         self.lock = threading.Lock()
         self.listener = socket.socket()
@@ -118,7 +145,8 @@ class TamperingProxy:
                 parser.feed(chunk)
                 while (cmd := parser.pop()) is not resp.Parser.MORE:
                     with self.lock:
-                        write = cmd[1] in (b"INC", b"DEC", b"SET")
+                        write = cmd[1] in (b"INC", b"DEC", b"SET") and (
+                            self.only_type in (None, cmd[0]))
                         self.writes += write
                         if write and self.writes % 50 == 1:
                             self.tampered += 1

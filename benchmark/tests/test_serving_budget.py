@@ -17,7 +17,8 @@ EVERYWHERE = {
     "server.tail_us_per_cmd", "server.py_apply_us_per_cmd", "server.handler_share",
     "server.busy_routed_frac", "server.deferred_frac", "server.reply_bytes_per_cmd",
     "server.write_wait_us_per_cmd", "models.lock_hold_serve_share"}
-CLUSTERED = {"pncount-1m-r64.fanin", "ycsb-treg-1m-r3.a", "ycsb-tlog-1kx1k-r3.e"}
+# the cells whose configuration has live peers: only there is a cluster hold to read
+CLUSTERED = {w for w in CELLS if manifest.Cell(w).config["peers"] > 0}
 STAGES = ["server.route_us_per_cmd", "server.engine_us_per_cmd",
           "server.reply_write_us_per_cmd", "server.tail_us_per_cmd"]
 

@@ -48,6 +48,32 @@ def test_breakdown_names_top_ops_and_labels_idle_gaps_by_host_span(trace):
     assert gaps["(no host span)"] == pytest.approx(0.969 - 0.064)
 
 
+@pytest.mark.parametrize("path,w0", [
+    (os.path.join(os.path.dirname(PATH), "host_only.xplane.pb"), W0),  # no device plane
+    (PATH, START_NS + 2000 * MS),  # a device plane, no op of it inside the window
+])
+def test_a_window_with_no_device_op_is_a_reading_not_a_crash(path, w0):
+    """A node that served its window from the host (a new cell's PARENT):
+    busy 0 s, no device op, the whole window idle, `device.idle_share` 100,
+    no roofline; the traced run then prints its result line (PR 38 was
+    refused over `ValueError: no device plane`)."""
+    tr = trace_reduce.Trace(path)
+    w1 = w0 + 1000 * MS
+    assert tr.busy_s(w0, w1) == 0.0
+    assert tr.program_s(["jit__drain_pn*"], w0, w1) == (0.0, 0)
+    b = tr.breakdown(w0, w1)
+    assert b["device_ops"] == []
+    assert sum(s for _name, s in b["idle_gaps"]) == pytest.approx(1.0)
+    rows = 'jylis_drain_total{type="PNCOUNT",kind="keys"}'
+    ctx = readers.Context(None, {rows: 0.0}, {rows: 500.0}, w0, w1, _Node(), "", False, "")
+    ctx._trace = tr
+    assert readers.trace_idle(ctx, {}) == 100.0
+    assert ctx.trace()["busy_s"] == 0.0 and ctx.trace()["window_s"] == pytest.approx(1.0)
+    spec = {"type": "PNCOUNT", "bytes": "pncount", "programs": ["jit__drain_pn"],
+            "dense_programs": ["jit__drain_pn_dense"], "rows": [rows]}
+    assert readers.trace_roofline(ctx, spec) is None
+
+
 def test_bytes_a_drain_must_move_and_the_share_of_the_roofline():
     assert roofline.pncount_sparse_bytes(1000, 64) == 1000 * (12 * 64 * 4 + 16)
     assert roofline.pncount_dense_bytes(1 << 20, 64) == (1 << 20) * (12 * 64 * 4 + 8)
