@@ -559,7 +559,10 @@ class Database:
     @asynccontextmanager
     async def all_locks(self):
         """Async context holding every repo lock (fixed order): the
-        shutdown snapshot dumps under it so nothing mutates mid-dump."""
+        shutdown snapshot dumps under it so nothing mutates mid-dump.
+        A native burst takes only the locks its commands name, and is
+        shut out all the same: its set is a subset of these (RepoLock,
+        "Why a holder of EVERY lock still excludes every burst")."""
         async with AsyncExitStack() as stack:
             for mgr in self._map.values():
                 await stack.enter_async_context(mgr.hold_sync())

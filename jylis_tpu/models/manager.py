@@ -83,13 +83,15 @@ class RepoLock:
       dump, snapshot, shutdown). First come, first served: it goes
       straight in only when nobody holds the lock and nobody is in line;
       otherwise it sleeps at the end of the line.
-    * ``RepoLock.acquire_all(locks)`` — a holder that releases before it
-      yields (the server's native burst). It takes every lock at once
-      whenever nobody HOLDS one, whoever is in line, and holds nothing
-      while it sleeps: on the first held lock it waits its turn in that
-      lock's line and starts over. (``RepoManager.apply_async``'s inline
-      fast path is the same kind with no take at all: it runs whenever
-      ``locked()`` is false.)
+    * ``RepoLock.take_all(locks)`` / ``acquire_all(locks)`` — a holder
+      that releases before it yields (the server's native burst, over
+      the locks of the types its commands name: any SUBSET of a
+      database's locks, most often one). It takes every lock of its set
+      at once whenever nobody HOLDS one of them, whoever is in line, and
+      holds nothing while it sleeps: on the first held lock of the set
+      it waits its turn in that lock's line and starts over.
+      (``RepoManager.apply_async``'s inline fast path is the same kind
+      with no take at all: it runs whenever ``locked()`` is false.)
 
     ``release()`` wakes EVERY sleeper, in arrival order; each retries
     when it runs, and one that finds the lock held again (a sleeper
@@ -112,7 +114,22 @@ class RepoLock:
     holders that yield, and retries on each of their releases. (The one
     burst that yields while holding is a drill's: an armed
     ``native.scan_apply`` sleep. It adds its injected sleep to the
-    bound, nothing else.)
+    bound, nothing else.) The argument is made lock by lock and never
+    speaks of the size of a burst's set: two bursts whose sets overlap
+    cannot both be inside a take, a burst asleep on one lock holds none
+    of the others of its set, and a lock outside a burst's set is
+    neither read nor written by it.
+
+    Why a holder of EVERY lock still excludes every burst
+    (``Database.all_locks``: the shutdown snapshot, a joining peer's
+    first digest). It takes the locks one by one, each the long way,
+    and keeps them across yields; a burst takes its set only when all
+    of the set is free and holds nothing while it waits, so it can
+    neither sit on a lock the snapshot waits for while waiting for one
+    the snapshot has (no deadlock, whatever locks the bursts asleep at
+    that moment sleep on), nor run on a type whose lock the snapshot
+    holds; and once all are held no burst's set, being a subset, is
+    free.
 
     A sleeper that leaves the line without taking (cancelled) wakes the
     rest if the lock is free: a taker that lined up behind it after the
@@ -415,8 +432,8 @@ class RepoManager:
     # under the same lock keeps liveness traffic flowing with identical
     # lattice results. The yield is by TIME, checked after each slice: a
     # lock held across a yield is one every client command of the type
-    # then meets held (its native burst sleeps in the lock's line, and a
-    # burst of another type leaves the engine for the Python path), so
+    # then meets held (its native burst sleeps in the lock's line; a
+    # burst of another type runs beside it), so
     # a fold that is over in a couple of milliseconds — a peer's 500 ms
     # flush of 1 KB registers — runs through, and only a fold that
     # would hold the loop longer yields.
@@ -464,13 +481,12 @@ class RepoManager:
     def busy(self) -> bool:
         """True while a (possibly threaded) repo access holds the lock.
         The server's route (server.py `_handle_client`) reads it of the
-        engine's five managers when a chunk arrives: a chunk whose first
-        command names THIS type stays native and sleeps for the lock
-        (the Python path would sleep in the same line); a chunk of
-        another type, or one whose type cannot be told, takes the
-        per-repo Python path and never meets this lock; with
-        `admission_cap` set this type's chunks take it too, so the wait
-        counts in `_inflight`."""
+        engine's managers only under `admission_cap`: a chunk that
+        arrives while a capped lock is held takes the per-repo Python
+        path, so that its wait counts in `_inflight`. Without a cap a
+        native burst reads the locks themselves (`RepoLock.take_all`):
+        a round whose first command names THIS type sleeps for the lock,
+        a round of another type never meets it."""
         return self._lock.locked()
 
     async def clean_shutdown_async(self) -> None:

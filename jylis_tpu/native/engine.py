@@ -32,6 +32,8 @@ PN = 1
 _OUT_CAP = 1 << 16
 _OUT_CEIL = 1 << 24
 _MAX_ARGS = 1024
+# `scan_apply`'s ``held`` with every engine type in it
+ALL_TYPES = 0b11111
 
 # jy_tlog_export_merged's "view unavailable" sentinel (serve_engine.cpp)
 _TLOG_UNAVAILABLE = -1 - (1 << 40)
@@ -149,9 +151,9 @@ def _declare(c: ctypes.CDLL) -> None:
         # batch applier
         "jy_eng_scan_apply2": (
             i32,
-            [vp, vp, i64, vp, i64, i64, pi64, pi64, vp, vp, i32, pi32, vp],
+            [vp, vp, i64, i32, vp, i64, i64, pi64, pi64, vp, vp, i32, pi32, vp],
         ),
-        "jy_eng_first_type": (i32, [u8p, i64]),
+        "jy_eng_types_ahead": (i32, [vp, i64, vp, vp, i32]),
         **_sender_sigs(),
     }
     _apply_sigs(c, sigs)
@@ -814,11 +816,15 @@ class ServeEngine:
         """A copy of the first ``n`` bytes of the reply array."""
         return ctypes.string_at(self._out, n)
 
-    def scan_apply(self, buf):
-        """Apply a pipelined burst. Returns
+    def scan_apply(self, buf, held: int = ALL_TYPES):
+        """Apply a pipelined burst under the repo locks of the types in
+        ``held`` (bit i: type i of the order G, PN, TREG, TLOG, UJSON;
+        the default is a caller that owns the engine alone): the run of
+        commands ahead up to the first that names another of the five
+        types, which is left where it is (rc 5). Returns
         (rc, consumed, n: the replies' length, unhandled: list[bytes] |
-        None, changed: tuple of 5 per-type counts (G, PN, TREG, TLOG,
-        UJSON)); the replies are the first ``n`` bytes of the reply
+        None, changed: tuple of 5 per-type counts in that order); the
+        replies are the first ``n`` bytes of the reply
         array, which the next burst reuses: `reply_bytes` copies them
         out, `sender_send` hands them to the sender. rc as
         documented in serve_engine.cpp, but for its 3 (answered here:
@@ -832,7 +838,7 @@ class ServeEngine:
         n_args = ctypes.c_int32()
         while True:
             rc = self._lib.jy_eng_scan_apply2(
-                self._h, ctypes.c_void_p(base), len(buf),
+                self._h, ctypes.c_void_p(base), len(buf), held,
                 self._out, len(self._out), _OUT_CEIL, ctypes.byref(out_len),
                 ctypes.byref(consumed),
                 self._offs, self._lens, _MAX_ARGS, ctypes.byref(n_args),
@@ -937,13 +943,21 @@ class ServeEngine:
         reg.tally("serving.ENGINE.sender_pending_max_bytes", now[4] - seen[4])
         reg.tally("serving.ENGINE.sender_busy_us", now[5] - seen[5])
 
-    def first_type(self, head: bytes) -> int:
-        """The type the first command of ``head`` addresses, as
-        `scan_apply` would see it: an index into the `changed` order
-        (G, PN, TREG, TLOG, UJSON), 5 for any other first word, -1 when
-        that cannot be told (incomplete or malformed). Reads the first
-        command only."""
-        return self._lib.jy_eng_first_type(head, len(head))
+    def types_ahead(self, buf) -> int:
+        """What the run of commands at the head of ``buf`` names, as
+        `scan_apply` would see it: the low five bits are the set of
+        engine types (the ``held`` order) named by the complete commands
+        up to the first that names none of them or cannot be read (64
+        commands ahead at most);
+        ``>> 8`` is the FIRST command's type, 5 for another first word
+        (SYSTEM, MAP, ...), 7 where no complete command names one.
+        Reads only."""
+        if not buf:
+            return 7 << 8
+        base = ctypes.addressof(ctypes.c_char.from_buffer(buf))
+        return self._lib.jy_eng_types_ahead(
+            base, len(buf), self._offs, self._lens, _MAX_ARGS
+        )
 
 
 # the counter-only name the round-3 engine shipped under; kept for callers

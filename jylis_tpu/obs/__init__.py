@@ -102,7 +102,7 @@ SEAMS = (
     # a served burst's stages (server.py, docs/observability.md "A
     # served burst's stages"): with server.native_burst,
     # pipeline.reply_write and pipeline.parse they tile the handler's
-    # share of loop.busy. route = read returned -> the five locks held;
+    # share of loop.busy. route = read returned -> the round's locks held;
     # tail = reply with the transport -> the next read is called;
     # write_wait = a drain() called with bytes still in the transport
     # (NOT loop work); py_apply = RepoManager._apply_core on the loop
@@ -137,14 +137,21 @@ SEAMS = (
 # lines. demotions: whole connections moved off the native engine for
 # good. busy_refusals: commands refused by a per-class admission cap.
 # The next three partition what the server's Python path dispatches, by
-# cause: commands of a chunk the busy() rule routed (a repo lock was
-# held when its bytes arrived), commands the engine handed back (rc 1),
+# cause: commands of a chunk the busy() rule routed (under
+# --admission-cap, a repo lock was held when its bytes arrived: 0
+# without a cap), commands the engine handed back (rc 1),
 # commands of a connection with no engine or demoted for good.
 # reply_bytes: bytes of engine replies handed to writers. slept_bursts:
-# native bursts that found a repo lock held, slept for it holding
-# nothing and then ran in the engine (or were demoted by a shutdown).
+# native bursts that found the repo lock their next command names held,
+# slept for it holding nothing and then ran in the engine (or were
+# demoted by a shutdown); by the type of that lock in
+# `registry.slept_by_type` (jylis_slept_bursts_total{type=...}).
 # loop_sends: reply writes the event loop made itself (`writer.write`:
 # a connection with no sender behind it; beside ENGINE sender_sends).
+# native_bursts: rounds, one call of the engine's scan_apply each.
+# burst_locks: repo locks those rounds took, summed (a round takes the
+# locks of the types its commands name). bursts_beside_hold: rounds that
+# ran while a lock they did not need was held by somebody else.
 SERVING = (
     "demotions",
     "busy_refusals",
@@ -154,6 +161,9 @@ SERVING = (
     "reply_bytes",
     "slept_bursts",
     "loop_sends",
+    "native_bursts",
+    "burst_locks",
+    "bursts_beside_hold",
 )
 
 # Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`

@@ -76,6 +76,10 @@ def render(database) -> str:
             f'jylis_serving_total{{kind="{key}"}} {serving.get(key, 0)}'
         )
 
+    out.append("# TYPE jylis_slept_bursts_total counter")
+    for name, n in sorted(reg.slept_by_type.items()):
+        out.append(f'jylis_slept_bursts_total{{type="{_esc(name)}"}} {n}')
+
     overload = system.overload_fn() if system.overload_fn else {}
     if overload.get("armed"):
         # overload armor (admission.py): same split discipline as the

@@ -67,6 +67,8 @@ class MetricsRegistry:
         # (manager.py), Python-path commands by cause and the engine's
         # reply bytes (server.py)
         self.serving_counters: dict[str, int] = dict.fromkeys(SERVING, 0)
+        # slept_bursts by the type whose lock the burst slept for
+        self.slept_by_type: dict[str, int] = {}
         self.hists: dict[str, Histogram] = {name: Histogram() for name in SEAMS}
         # the three phases of a drain (utils/metrics.timed_drain), in
         # DRAIN_PHASES order: recorded WITH their parent drain.<TYPE>
@@ -124,6 +126,12 @@ class MetricsRegistry:
 
     def note_serving(self, counter: str, n: int = 1) -> None:
         self.serving_counters[counter] += n
+
+    def note_slept(self, type_name: str) -> None:
+        """One native burst slept for ``type_name``'s repo lock: the
+        `type` label of slept_bursts."""
+        by = self.slept_by_type
+        by[type_name] = by.get(type_name, 0) + 1
 
     def note_write_heat(self, name: str, bucket: int, n: int = 1) -> None:
         """One emitted delta batch touched ``bucket`` of ``name``'s

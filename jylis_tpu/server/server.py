@@ -13,12 +13,14 @@ slow drain stalls neither other connections nor the heartbeat. Within one
 connection commands complete strictly in order (RESP replies must match
 request order), which each connection's sequential await provides.
 
-A native burst takes the engine's five repo locks all at once
-(RepoLock.acquire_all) and lets go before it yields, so it never waits
-for a lock that nobody holds and never makes anyone queue: one loop
-iteration settles every connection whose bytes are ready. Only a holder
-that keeps a lock across a yield (a threaded drain, a cluster apply, a
-flush) makes bursts sleep, and its release wakes them all at once.
+A native burst takes the repo locks of the types its commands name,
+all at once (RepoLock.take_all) and no other, and lets go before it
+yields, so it never waits for a lock that nobody holds and never makes
+anyone queue: one loop iteration settles every connection whose bytes
+are ready. Only a holder that keeps a lock across a yield (a threaded
+drain, a cluster apply, a flush) makes bursts sleep, and only the
+bursts whose next command names ITS type: its release wakes them all at
+once, and a client of another type is served in the engine beside it.
 """
 
 from __future__ import annotations
@@ -192,13 +194,32 @@ class Server:
         self._h_route = self._reg.hist("serve.route")
         self._h_tail = self._reg.hist("serve.tail")
         self._h_write_wait = self._reg.hist("serve.write_wait")
-        # lock.wait_serve (obs/span.py): wanting the engine's five repo
-        # locks to holding them — what pipeline.dispatch and
+        # lock.wait_serve (obs/span.py): wanting the repo lock a round's
+        # first command names to holding it — what pipeline.dispatch and
         # server.py_dispatch INCLUDE for every command that queues behind
         # a drain, summed over connections; server.native_burst does not.
         # A burst records it only when it slept: a take of free locks
         # is the route's, and costs neither clock read nor annotation
         self._s_lock_wait = self._reg.seam("lock.wait_serve")
+        # what a native round takes, by the set of types it names (the
+        # engine's `held` bits): (locks, their managers each with its
+        # type's bit number, every OTHER engine lock). Locks in DATABASE
+        # MAP order (TREG, TLOG, G, PN, UJSON), the order
+        # database.all_locks takes them in
+        self._mgrs = self._rounds = ()
+        if database.native_engine is not None:
+            self._mgrs = mgrs = tuple(
+                database.manager(n) for n in self._ENGINE_TYPES
+            )
+            order = (2, 3, 0, 1, 4)
+            self._rounds = tuple(
+                (
+                    tuple(mgrs[i]._lock for i in order if held >> i & 1),
+                    tuple((mgrs[i], i) for i in order if held >> i & 1),
+                    tuple(mgrs[i]._lock for i in order if not held >> i & 1),
+                )
+                for held in range(1 << len(mgrs))
+            )
 
     async def start(self) -> None:
         try:
@@ -310,47 +331,23 @@ class Server:
                 t_arr = (t_rt or perf_counter()) if adm_armed else 0.0
                 routed = False  # this chunk took the Python path for busy()
                 if use_native:
-                    mgrs = self._engine_managers()
-                    go_native = not any(m.busy() for m in mgrs)
-                    if not go_native and not parser.has_pending():
-                        # a repo lock is held. The chunk's first command
-                        # names its type, and when THAT type's lock is
-                        # the one held the Python path would only sleep
-                        # in its line until the same release: the burst
-                        # sleeps for it instead (_apply_native), holding
-                        # nothing, and runs in the engine. Under an
-                        # admission cap the wait must count in the
-                        # manager's _inflight (its typed BUSY): routed
-                        which = engine.first_type(
-                            bytes(buf) + data if buf else data
-                        )
-                        go_native = (
-                            0 <= which < len(mgrs)
-                            and mgrs[which].busy()
-                            and not mgrs[which].admission_cap
-                        )
+                    # under --admission-cap a wait for a repo lock must
+                    # count in its manager's _inflight (the typed BUSY):
+                    # a chunk that arrives while a capped lock is held,
+                    # whatever it names, takes the per-repo Python path
+                    go_native = not any(
+                        m.admission_cap and m.busy() for m in self._mgrs
+                    )
                     if go_native and parser.has_pending():
-                        # a previous burst was routed through the Python
+                        # a previous chunk was routed through the Python
                         # parser and left a split command's head behind:
-                        # reclaim it so the stream returns to the engine.
-                        # Without this, one mid-command chunk boundary
-                        # (near-certain once a saturated connection fills
-                        # 64 KiB reads) exiles the connection to the
-                        # per-command Python path for as long as the
-                        # backlog lasts — the engine abandoned exactly
-                        # when its throughput matters most.
+                        # reclaim it so the stream returns to the engine
                         tail = parser.take_tail()
                         if tail is None:
                             go_native = False  # malformed/unserved: stay
                         else:
                             buf += tail
                     if not go_native:
-                        # every held lock is ANOTHER type's than the one
-                        # this chunk names (or the type cannot be told):
-                        # route THIS chunk through the per-repo Python
-                        # path, which takes its own repo's lock alone,
-                        # so an unrelated repo never waits on the
-                        # engine's five-lock boundary
                         routed = True
                         parser.append(bytes(buf))
                         buf.clear()
@@ -477,11 +474,9 @@ class Server:
             self._h_py.record(el)
             self._h_dispatch.record(el)
 
-    # the engine's changed-counter order (serve_engine.cpp scan_apply2)
+    # the engine's type order (serve_engine.cpp scan_apply2: `held`'s
+    # bits, `changed`'s cells)
     _ENGINE_TYPES = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON")
-
-    def _engine_managers(self):
-        return [self._database.manager(n) for n in self._ENGINE_TYPES]
 
     async def _write_wait(self, door, t_stage: float) -> float:
         """``await door.drain()`` with bytes the consumer has not taken:
@@ -549,8 +544,8 @@ class Server:
         off), or None (demote this connection to the Python path; tail
         moved into `parser` — on malformed input the Python parser then
         renders its specific error and the connection drops)."""
-        mgrs = self._engine_managers()
         reg = self._reg
+        rounds = self._rounds
 
         def demote() -> None:
             # the whole connection moves to the Python dispatch path for
@@ -562,35 +557,47 @@ class Server:
             parser.append(bytes(buf))
             buf.clear()
 
-        # DATABASE MAP order (TREG, TLOG, G, PN, UJSON), the order
-        # database.all_locks takes them in
-        locks = [mgrs[i]._lock for i in (2, 3, 0, 1, 4)]
         while True:
-            if any(m._shutdown for m in mgrs):
-                return demote()
-            # all five type tables can mutate inside one native call: hold
-            # every engine-backed repo lock, exactly the boundary
-            # apply_async enforces per repo — a threaded drain holding any
-            # one of them keeps the engine out entirely. All five or
-            # none: a burst holds nothing while it sleeps, so it can
-            # never deadlock against the shutdown snapshot (all_locks),
-            # and it takes free locks whoever is in line (RepoLock).
-            slept = not RepoLock.take_all(locks)
-            if slept:
-                # the sleep is lock.wait_serve's, not the route's
-                t_wait = self._s_lock_wait.begin()
-                await RepoLock.acquire_all(locks)
-                waited_s = self._s_lock_wait.end(t_wait)
-                reg.note_serving("slept_bursts")
-                if t_route:
-                    t_route += waited_s
+            # a round holds what it names: the locks of the types the run
+            # of commands ahead addresses, taken together when all of
+            # them are free — exactly the boundary apply_async enforces
+            # per repo, so a threaded drain of ANOTHER type runs beside
+            # the round and one of a type it names keeps it out. All of
+            # them or none, and nothing while it sleeps: it can never
+            # deadlock against the shutdown snapshot (all_locks), and it
+            # takes free locks whoever is in line (RepoLock). A round
+            # that names no engine type (SYSTEM, an unfinished command)
+            # takes none.
+            ahead = engine.types_ahead(buf)
+            held = ahead & 31
+            locks, mgrs, others = rounds[held]
+            if not RepoLock.take_all(locks):
+                # a lock the run names is held. Its FIRST command's type
+                # alone, then: served now if that lock is free (the
+                # engine stops before the next command of another type),
+                # else the burst sleeps in that lock's line, holding
+                # nothing — the Python path would sleep in the same line
+                which = ahead >> 8
+                held = 1 << which
+                locks, mgrs, others = rounds[held]
+                if not RepoLock.take_all(locks):
+                    # the sleep is lock.wait_serve's, not the route's
+                    t_wait = self._s_lock_wait.begin()
+                    await RepoLock.acquire_all(locks)
+                    waited_s = self._s_lock_wait.end(t_wait)
+                    reg.note_serving("slept_bursts")
+                    reg.note_slept(self._ENGINE_TYPES[which])
+                    if t_route:
+                        t_route += waited_s
             try:
-                if slept and any(m._shutdown for m in mgrs):
-                    # a burst that slept behind a drain may wake after a
-                    # repo's final flush: applying now would acknowledge
-                    # a write that never replicates (apply_async looks
-                    # again under its lock for the same reason)
-                    return demote()
+                for mgr, _i in mgrs:
+                    if mgr._shutdown:
+                        # its final flush is spoken for (a burst that
+                        # slept behind a drain may wake after it):
+                        # applying now would acknowledge a write that
+                        # never replicates (apply_async looks again
+                        # under its lock for the same reason)
+                        return demote()
                 try:
                     # native.scan_apply: a failure AT the FFI burst
                     # boundary must demote this connection to the Python
@@ -603,13 +610,19 @@ class Server:
                     # freeze that idle-evicts our peer connections
                     # (caught by jlint's interprocedural JL101)
                     await faults.async_point("native.scan_apply")
+                    reg.note_serving("native_bursts")
+                    reg.note_serving("burst_locks", len(locks))
+                    for lock in others:
+                        if lock._held:  # and the round runs beside it
+                            reg.note_serving("bursts_beside_hold")
+                            break
                     # serve.route ends, server.native_burst begins
                     t_held = 0.0
                     if t_route:
                         t_held = perf_counter()
                         self._h_route.record(t_held - t_route)
                     rc, consumed, n_replies, unhandled, changed = (
-                        engine.scan_apply(buf)
+                        engine.scan_apply(buf, held)
                     )
                     # the burst ends; the reply write, or with nothing
                     # to write the tail, begins
@@ -634,8 +647,9 @@ class Server:
                     if t_tail:
                         t_scan, t_tail = t_tail, perf_counter()
                         self._h_reply_write.record(t_tail - t_scan)
-                for mgr, ch in zip(mgrs, changed):
-                    if ch:
+                # only a type in `held` can have changed: its lock is ours
+                for mgr, i in mgrs:
+                    if changed[i]:
                         mgr._maybe_proactive_flush()
             finally:
                 RepoLock.release_all(locks)
@@ -663,8 +677,9 @@ class Server:
                 # this connection from here on.
                 return demote()
             # another round: this one's tail ends here. rc 2: the reply
-            # buffer was flushed, the next round's route begins at once.
-            # rc 1: one command for the Python path first, in order
+            # buffer was flushed; rc 5: the next command names a type
+            # this round did not hold: the next round's route begins at
+            # once. rc 1: one command for the Python path first, in order
             t_route = 0.0
             if t_tail:
                 t_route = perf_counter()

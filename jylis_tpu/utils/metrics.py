@@ -200,6 +200,10 @@ def metric_lines(
         if total:
             frac = serving.get("demoted_cmds", 0) / total
             lines.append(f"SERVING fallback_frac {frac:.4f}")
+        lines.extend(
+            f"SERVING slept_bursts.{name} {n}"
+            for name, n in sorted(reg.slept_by_type.items())
+        )
     if session is not None and any(session.values()):
         # session-guarantee counters (sessions.py): tokens minted,
         # reads served/waited, typed STALE/BADTOKEN refusals, adoption

@@ -23,6 +23,17 @@ namespace {
 // tests/test_serve_tables.py)
 constexpr int64_t TREG_PENDING_DRAIN = 4096;
 
+// Which of the engine's five types a command's first word names, in the
+// changed[] order (G, PN, TREG, TLOG, UJSON); 5: any other word (SYSTEM,
+// MAP, ...: no table of the engine's).
+inline int32_t type_of(const uint8_t* buf, int64_t off, int64_t len) {
+    static const char* const names[5] = {"GCOUNT", "PNCOUNT", "TREG", "TLOG",
+                                         "UJSON"};
+    for (int32_t i = 0; i < 5; i++)
+        if (word_is(buf, off, len, names[i])) return i;
+    return 5;
+}
+
 }  // namespace
 
 extern "C" {
@@ -599,14 +610,29 @@ int64_t jy_uj_memo_len(void* e, const uint8_t* k, int64_t n) {
 //      so no caller of ServeEngine.scan_apply sees this code)
 //   4  as 1, and the reason is a reply of more than out_ceil bytes
 //      (engine.py counts it and hands the caller a 1)
+//   5  stopped BEFORE a command of a type that is not in `held`: nothing
+//      of it is consumed and *n_args is its type (0..4)
 //  -1  protocol error at the stop point (serve replies, drop connection)
 //  -2  a command has more than max_args arguments (grow and retry)
 // changed[5] counts state-changing applies per type
 // (G, PN, TREG, TLOG, UJSON) for the caller's on-change notifications.
+//
+// `held` is the set of types (bit i: type i of that order) whose repo
+// lock the caller holds: a run of commands is applied under it, and a
+// command of any other of the five ends the run untouched (code 5). The
+// boundary is per TYPE because the state is: a command of type X reads
+// and writes X's own table and nothing of another type's — the counters
+// `t[which]`, TREG `treg`, TLOG `tlog` (its value interner `vals` is a
+// member of the table), UJSON `uq` and `uj` (the write queue and the
+// render memo) — plus its own cells of `changed[]` and `served[]`. What
+// every command shares is the caller's: `out`, `offs`, `lens`, one burst
+// at a time on the loop thread. A command of no engine type is handed
+// back (code 1) whatever is held: it touches no table here.
 int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
-                           uint8_t* out, int64_t out_cap, int64_t out_ceil,
-                           int64_t* out_len, int64_t* consumed, int64_t* offs,
-                           int64_t* lens, int32_t max_args, int32_t* n_args,
+                           int32_t held, uint8_t* out, int64_t out_cap,
+                           int64_t out_ceil, int64_t* out_len,
+                           int64_t* consumed, int64_t* offs, int64_t* lens,
+                           int32_t max_args, int32_t* n_args,
                            int32_t* changed) {
     Engine* eng = static_cast<Engine*>(ev);
     *out_len = 0;
@@ -630,6 +656,11 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
         if (inline_blank) {  // oracle parser skips blank inline lines
             *consumed += sub_consumed;
             continue;
+        }
+        int32_t ty = argc >= 1 ? type_of(buf, offs[0], lens[0]) : 5;
+        if (ty < 5 && !((held >> ty) & 1)) {
+            *n_args = ty;  // the caller takes that lock and comes again
+            return 5;
         }
         // bounce THIS command to the Python path, consumed
         auto defer = [&]() -> int32_t {
@@ -919,23 +950,39 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
     }
 }
 
-// Which type does the FIRST command of `buf` address, as scan_apply
-// would see it? 0..4: the engine's five, in the changed[] order
-// (G, PN, TREG, TLOG, UJSON); 5: another first word (SYSTEM, MAP, ...);
-// -1: cannot tell (incomplete, malformed, a blank inline line, more than
-// 64 arguments). Reads the first command only, changes nothing: the
-// server asks it of a chunk that arrived while a repo lock was held.
-int32_t jy_eng_first_type(const uint8_t* buf, int64_t len) {
-    int64_t consumed = 0, offs[64], lens[64];
-    int32_t argc = 0;
-    if (resp_scan(buf, len, &consumed, offs, lens, 64, &argc) != 1 ||
-        argc == 0)
-        return -1;
-    static const char* const names[5] = {"GCOUNT", "PNCOUNT", "TREG", "TLOG",
-                                         "UJSON"};
-    for (int32_t i = 0; i < 5; i++)
-        if (word_is(buf, offs[0], lens[0], names[i])) return i;
-    return 5;
+// The types the run of commands AHEAD names, as scan_apply would see
+// them: bits 0..4 are the set of the engine's types (changed[] order)
+// that the complete commands of `buf` name, up to the first that names
+// no engine type, is incomplete or malformed or has more than max_args
+// arguments (blank inline lines skipped, as there); bits 8.. are the
+// type of the FIRST of them (0..4), 5 for another first word, 7 when
+// there is no complete command to name one. Reads only, changes nothing:
+// the server asks it before a round, takes the locks of the set when all
+// of them are free, and else the first command's alone. It looks
+// AHEAD_CMDS commands ahead and no further, so that a chunk of thousands
+// of commands that ends a round at every one of them (a reply buffer's
+// worth each, a hand-back each) is not scanned to its end every round;
+// what lies beyond meets `held` in scan_apply like any command.
+int32_t jy_eng_types_ahead(const uint8_t* buf, int64_t len, int64_t* offs,
+                           int64_t* lens, int32_t max_args) {
+    constexpr int32_t AHEAD_CMDS = 64;
+    int32_t mask = 0, first = 7;
+    int64_t at = 0;
+    for (int32_t n = 0; n < AHEAD_CMDS; n++) {
+        int64_t consumed = 0;
+        int32_t argc = 0;
+        if (resp_scan(buf + at, len - at, &consumed, offs, lens, max_args,
+                      &argc) != 1)
+            break;
+        bool inline_blank = argc == 0 && buf[at] != '*';
+        int32_t ty = argc >= 1 ? type_of(buf + at, offs[0], lens[0]) : 5;
+        at += consumed;
+        if (inline_blank) continue;
+        if (first == 7) first = ty;
+        if (ty == 5) break;
+        mask |= 1 << ty;
+    }
+    return mask | (first << 8);
 }
 
 }  // extern "C"

@@ -18,6 +18,14 @@ libtsan LD_PRELOADed. Two invariants are certified:
   the critical section (e.g. an unsynchronized static scratch buffer
   would race even under the mutex between release/acquire pairs).
 
+* **One lock a TYPE, not one lock an engine** — the server's rounds
+  hold the repo locks of the types their commands name and no other
+  (server.py `_apply_native`, `scan_apply`'s ``held``), so a round of
+  TREG commands on the loop thread runs WHILE another type's drain
+  works on its own table in a worker thread, with no lock in common.
+  TSAN proves a command of type X touches no state of type Y inside the
+  library (tables, memo, queue, interner, `served[]`).
+
 * **The reply sender** (native/reply_sender.cpp) — the one piece of the
   library with a thread of its own: two producers hand replies to it
   (the hand-off keeps the GIL, as the server's does; open, close and
@@ -257,6 +265,61 @@ def test_treg_bulk_drain_under_mutex_while_serving(cdll):
                 if (n + i) % n_keys == j
             )
             assert (ts, val) == want
+
+
+def test_a_round_of_one_type_runs_beside_the_holders_of_the_others(cdll):
+    """No mutex in common: the "loop" thread serves rounds that hold ONE
+    type (`scan_apply(buf, held)`: the only user of the engine's reply
+    and argument scratch) while a "TLOG drain" thread and a "UJSON fold"
+    thread, each the one holder of ITS type's lock, work on their own
+    tables through the calls a drain makes. A round stops before the
+    first command of a type it does not hold (rc 5), so nothing of the
+    loop's ever reaches the tables the other two are in."""
+    eng = ServeEngine(cdll)
+    treg, counters = 1 << 2, 1 << 0 | 1 << 1
+    stopped = []
+
+    def loop():
+        for i in range(N_ROUNDS * 4):
+            k = b"reg-%d" % (i % 8)
+            buf = bytearray(
+                resp(b"TREG", b"SET", k, b"v%d" % i, b"%d" % (i + 1))
+                + resp(b"TREG", b"GET", k)
+                + resp(b"TLOG", b"INS", b"log-0", b"never", b"1")
+            )
+            rc, consumed, n, _u, changed = eng.scan_apply(buf, treg)
+            assert rc == 5 and changed[2] == 1 and changed[3] == changed[4] == 0
+            assert eng.reply_bytes(n).startswith(b"+OK\r\n*2\r\n")
+            del buf[:consumed]
+            stopped.append(bytes(buf[:4]))
+            buf = bytearray(resp(b"GCOUNT", b"INC", k, b"1") + resp(b"PNCOUNT", b"DEC", k, b"1"))
+            assert eng.scan_apply(buf, counters)[:2] == (0, len(buf))
+
+    def tlog_drain():
+        for i in range(N_ROUNDS * 4):
+            row = eng.tlog_upsert(b"log-%d" % (i % 4))
+            eng.tlog_conv_entry(row, i + 1, b"foreign-%d" % i)
+            eng.tlog_ins(row, 10_000 + i, b"local-%d" % i)
+            assert eng.tlog_pend_total() >= 1
+            eng.tlog_export_pend_bulk([row])
+            eng.tlog_merged_entries(row)
+            eng.tlog_flush_deltas()
+
+    def ujson_fold():
+        for i in range(N_ROUNDS * 4):
+            key = b"doc-%d" % (i % 4)
+            eng.uj_memo_put(key, [b"members"], b"$1\r\n1\r\n")
+            assert eng.uj_memo_len(key) >= 1
+            eng.uj_invalidate(key, [b"members"], False)
+            eng.uq_drain()
+
+    _run_threads([loop, tlog_drain, ujson_fold])
+    assert set(stopped) == {b"*5\r\n"}  # the TLOG INS, untouched every time
+    assert eng.tlog_find(b"log-0") >= 0 and eng.treg_rows() == 8
+    pend = eng.tlog_export_pend(eng.tlog_find(b"log-0"))
+    assert len(pend) == 2 * N_ROUNDS and all(v != b"never" for _ts, v in pend)
+    served = eng.served_counts()
+    assert served["TREG"] == N_ROUNDS * 8 and served["TLOG"] == 0
 
 
 def test_memo_install_invalidate_under_mutex(cdll):

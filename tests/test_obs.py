@@ -582,8 +582,9 @@ _CAUSES = ("busy_routed_cmds", "deferred_cmds", "demoted_conn_cmds")
 @pytest.mark.parametrize("cause", _CAUSES)
 def test_python_path_commands_are_counted_by_cause(cause):
     """The three causes partition what the server's Python path
-    dispatches: a chunk that met a held repo lock (busy()), a command
-    the engine handed back, a connection with no engine. Their sum is
+    dispatches: a chunk that met a held repo lock under an admission
+    cap (busy()), a command the engine handed back, a connection with no
+    engine. Their sum is
     demoted_cmds; all three are on SYSTEM METRICS and the scrape."""
     from jylis_tpu.obs import prom
 
@@ -595,8 +596,11 @@ def test_python_path_commands_are_counted_by_cause(cause):
             pytest.skip("no native engine on this host")
         burst = b"TREG SET k v 1\r\n" + b"TREG GET k\r\n" * (n - 1)
         if cause == "busy_routed_cmds":
-            # somebody holds ANOTHER type's lock across a yield: the
-            # whole chunk takes the Python path, and applies inline
+            # under --admission-cap, somebody holds a type's lock across
+            # a yield: the whole chunk takes the Python path, and (of
+            # another type) applies inline. Without a cap it would be
+            # served in the engine beside the hold (test_repo_lock.py)
+            db.set_admission_cap(8)
             lock = db.manager("GCOUNT")._lock
             await lock.acquire()
             await _drive_server(db, burst, n)
@@ -644,11 +648,17 @@ def test_a_slept_burst_is_counted_on_every_surface():
     assert db.metrics.hist("lock.wait_serve").count == 1
     resp = _Resp()
     db.apply(resp, [b"SYSTEM", b"METRICS"])
-    assert "SERVING slept_bursts 1" in [str(s) for s in resp.strings()]
-    assert 'jylis_serving_total{kind="slept_bursts"} 1\n' in prom.render(db)
+    lines = [str(s) for s in resp.strings()]
+    assert "SERVING slept_bursts 1" in lines and "SERVING slept_bursts.TREG 1" in lines
+    assert "SERVING native_bursts 1" in lines and "SERVING burst_locks 1" in lines
+    scrape = prom.render(db)
+    assert 'jylis_serving_total{kind="slept_bursts"} 1\n' in scrape
+    assert 'jylis_slept_bursts_total{type="TREG"} 1\n' in scrape  # the type label
+    assert 'jylis_serving_total{kind="bursts_beside_hold"} 0\n' in scrape
     assert db.metrics.report().endswith("; SERVING: 0 demotions, 0 busy_refusals, "
         "0 busy_routed_cmds, 0 deferred_cmds, 0 demoted_conn_cmds, "
-        f"{serving['reply_bytes']} reply_bytes, 1 slept_bursts, 0 loop_sends")
+        f"{serving['reply_bytes']} reply_bytes, 1 slept_bursts, 0 loop_sends, "
+        "1 native_bursts, 1 burst_locks, 0 bursts_beside_hold")
 
 
 def test_engine_reply_bytes_are_counted_per_burst():

@@ -8,7 +8,11 @@ served, so none starves; a cancelled sleeper strands nobody.
 
 Served: after a drain lets go, the connections that slept behind it are
 settled together, and the loop goes back to many commands per iteration
-— pinned by COUNTING loop iterations, not by timing them.
+— pinned by COUNTING loop iterations, not by timing them. A native burst
+takes the locks of the types its commands name and no other: beside a
+held lock a client of another type is served in the engine, a client of
+the held type sleeps for it, and the holder of every lock (the shutdown
+snapshot) still shuts every burst out.
 """
 
 import asyncio
@@ -215,6 +219,94 @@ def test_a_burst_sleeps_holding_nothing_and_says_that_it_slept():
     run(main())
 
 
+def test_a_burst_reads_and_writes_the_locks_of_its_set_alone():
+    """Subsets: a burst over [a] runs while b is held and leaves b as it
+    was; a burst over [a, b] sleeps in b's line holding neither; two
+    bursts asleep on DIFFERENT locks each wake at their own release."""
+
+    async def main():
+        a, b, c, log = RepoLock(), RepoLock(), RepoLock(), []
+        gates = {"b": asyncio.Event(), "c": asyncio.Event()}
+        drains = [asyncio.create_task(hold(lock, log, name, gates[name]))
+                  for lock, name in ((b, "b"), (c, "c"))]
+        await steps()
+        assert RepoLock.take_all([a])  # beside two holds
+        assert a.locked() and b.locked() and not b._line
+        RepoLock.release_all([a])
+        assert not RepoLock.take_all([a, b]) and not a.locked()  # all or none
+        on_b = asyncio.create_task(RepoLock.acquire_all([a, b]))
+        on_c = asyncio.create_task(RepoLock.acquire_all([c]))
+        await steps()
+        assert len(b._line) == 1 and len(c._line) == 1 and not a.locked()
+        gates["c"].set()
+        assert await on_c is True and not on_b.done()
+        RepoLock.release_all([c])
+        gates["b"].set()
+        assert await on_b is True and a.locked() and b.locked()
+        RepoLock.release_all([a, b])
+        await asyncio.gather(*drains)
+
+    run(main())
+
+
+def test_the_holder_of_every_lock_shuts_out_bursts_asleep_on_different_locks():
+    """`Database.all_locks` beside subsets: bursts sleep on two different
+    locks when the snapshot lines up for all of them, one by one. No
+    deadlock (a sleeping burst holds nothing the snapshot waits for), the
+    snapshot gets every lock in ONE release each, and while it holds
+    them no burst runs, whatever its set."""
+
+    async def main():
+        locks = [RepoLock() for _ in range(5)]
+        log, ran = [], []
+        gates = [asyncio.Event() for _ in range(2)]
+        drains = [asyncio.create_task(hold(locks[i], log, f"drain{i}", gates[n]))
+                  for n, i in enumerate((1, 4))]
+        await steps()
+
+        async def burst(name, mine):
+            await RepoLock.acquire_all(mine)
+            ran.append(name)
+            RepoLock.release_all(mine)
+
+        bursts = [asyncio.create_task(burst("on1", [locks[1]])),
+                  asyncio.create_task(burst("on4", [locks[0], locks[4]])),
+                  asyncio.create_task(burst("free", [locks[2]]))]
+        await steps()
+        assert ran == ["free"]  # a set with no held lock runs beside both
+        inside = asyncio.Event()
+        leave = asyncio.Event()
+
+        async def snapshot():
+            for lock in locks:  # the fixed order, each the long way
+                await lock.acquire()
+            inside.set()
+            await leave.wait()
+            for lock in locks:
+                lock.release()
+
+        snap = asyncio.create_task(snapshot())
+        await steps()
+        assert not inside.is_set()
+        late = asyncio.create_task(burst("late", [locks[0]]))  # lock 0 is the snapshot's
+        await steps()
+        gates[0].set()
+        gates[1].set()
+        await asyncio.wait_for(inside.wait(), 5)
+        # the bursts that slept behind the drains woke first and ran (they
+        # were ahead in line); nobody runs while the snapshot holds
+        before = list(ran)
+        assert set(before) >= {"free", "on1"}
+        await steps(5)
+        assert ran == before and all(lock.locked() for lock in locks)
+        leave.set()
+        await asyncio.wait_for(asyncio.gather(snap, late, *bursts, *drains), 5)
+        assert sorted(ran) == ["free", "late", "on1", "on4"]
+        assert not any(lock.locked() or lock._line for lock in locks)
+
+    run(main())
+
+
 def test_cancelling_a_sleeper_leaves_the_lock_usable():
     async def main():
         lock, log = RepoLock(), []
@@ -398,8 +490,11 @@ def test_a_chunk_of_the_held_type_sleeps_for_the_lock_and_stays_native(held, for
         assert got == want
         serving = db.serving_totals()
         assert serving["slept_bursts"] == len(lines)
+        assert reg.slept_by_type == {held: len(lines)}  # slept_bursts{type}
         assert serving["busy_routed_cmds"] == 0 and serving["demoted_cmds"] == 0
         assert serving["native_cmds"] == len(lines)
+        assert serving["native_bursts"] == serving["burst_locks"] == len(lines)
+        assert serving["bursts_beside_hold"] == 0  # the held lock was their own
         assert reg.hist("lock.wait_serve").count == len(lines)
         assert reg.hist("serve.py_apply").count == 0
 
@@ -407,38 +502,46 @@ def test_a_chunk_of_the_held_type_sleeps_for_the_lock_and_stays_native(held, for
 
 
 @pytest.mark.parametrize("form", ["inline", "array"])
-def test_a_chunk_of_another_type_is_answered_before_the_release(form):
-    """What the busy() rule is kept for: beside a long hold of the TLOG
-    lock a TREG client is served at once, on the Python path, which
-    takes the TREG lock alone."""
+@pytest.mark.parametrize("held", ["TLOG", "UJSON"])
+def test_a_chunk_of_another_type_is_served_in_the_engine_beside_the_hold(held, form):
+    """A round holds what it names: beside a long hold of the TLOG (or
+    the UJSON) lock a TREG client is served at once AND natively, under
+    the TREG lock alone; nothing is routed to the Python path, and
+    `bursts_beside_hold` counts the round."""
     lines = [b"TREG SET t v 1", b"TREG GET t"]
 
     async def main():
         want = await python_path(lines)
         server, db = await native_server()
-        lock = db.manager("TLOG")._lock
+        mgr = db.manager(held)
         try:
-            await lock.acquire()
-            wire = b"".join(l + b"\r\n" if form == "inline" else array(l) for l in lines)
-            got = await send_recv(server.port, wire, len(b"".join(want)))
-            assert lock.locked()  # answered while it was held
-            lock.release()
+            async with mgr.hold_sync():  # a `_Hold`: a digest's, a dump's
+                wire = b"".join(l + b"\r\n" if form == "inline" else array(l) for l in lines)
+                got = await send_recv(server.port, wire, len(b"".join(want)))
+                assert mgr.busy()  # answered while it was held
         finally:
             await server.dispose()
         assert got == b"".join(want)
         serving = db.serving_totals()
-        assert serving["busy_routed_cmds"] == 2 and serving["slept_bursts"] == 0
+        assert serving["native_cmds"] == 2 and serving["demoted_cmds"] == 0
+        assert serving["busy_routed_cmds"] == 0 and serving["slept_bursts"] == 0
+        assert serving["native_bursts"] == serving["burst_locks"] == 1
+        assert serving["bursts_beside_hold"] == 1
         assert db.metrics.hist("lock.wait_serve").count == 0
+        assert db.metrics.hist("serve.py_apply").count == 0
 
     run(main())
 
 
 @pytest.mark.parametrize("case", ["split", "python_only", "malformed"])
-def test_a_chunk_whose_type_cannot_be_told_takes_the_python_path(case):
-    """The parent's route, for a chunk that names none of the engine's
-    types under a held (TLOG) lock: a first command split across two
-    reads (its head, and then its rest behind the parser's pending
-    bytes), a type only Python serves, a protocol error."""
+def test_a_chunk_whose_type_cannot_be_told_needs_no_lock_and_no_route(case):
+    """Under a held (TLOG) lock, a chunk that names none of the engine's
+    types stays with the engine all the same, which takes no lock for
+    it: the head of a command split across two reads waits for its rest
+    (and the whole command then sleeps for its own type's lock), a type
+    only Python serves is handed back (deferred) and answered while the
+    lock is held, a protocol error demotes the connection to the parser
+    that words the error."""
 
     async def main():
         server, db = await native_server()
@@ -450,8 +553,8 @@ def test_a_chunk_whose_type_cannot_be_told_takes_the_python_path(case):
             if case == "split":
                 writer.write(b"TLOG GE")
                 await asyncio.sleep(0.05)
+                assert not lock._line  # an unfinished command waits for nobody
                 writer.write(b"T k\r\n")
-                # the Python path sleeps in the TLOG line until the release
                 await asyncio.wait_for(asleep(lock, 1), 5)
                 assert not reader._buffer
                 lock.release()
@@ -471,16 +574,20 @@ def test_a_chunk_whose_type_cannot_be_told_takes_the_python_path(case):
             await server.dispose()
         assert got == want if case == "split" else got.startswith(want or b"$")
         serving = db.serving_totals()
-        assert serving["slept_bursts"] == 0
-        assert serving["busy_routed_cmds"] == (0 if case == "malformed" else 1)
+        assert serving["busy_routed_cmds"] == 0
+        assert serving["slept_bursts"] == (1 if case == "split" else 0)
+        assert serving["deferred_cmds"] == (1 if case == "python_only" else 0)
+        assert serving["demotions"] == (1 if case == "malformed" else 0)
 
     run(main())
 
 
 def test_a_pipelined_chunk_behind_its_first_commands_hold_answers_in_order():
     """[TLOG INS, TREG GET, TLOG GET] in one chunk behind a TLOG hold:
-    the first command names the type, the whole burst sleeps and the
-    replies come back in command order."""
+    the first command names the held type, so the burst sleeps for it
+    and wakes holding that lock alone: a round of the one TLOG command,
+    then the run ahead names two types, both free: one round under two
+    locks. The replies in command order."""
     lines = [b"TLOG INS k a 1", b"TREG GET t", b"TLOG GET k"]
 
     async def main():
@@ -499,6 +606,82 @@ def test_a_pipelined_chunk_behind_its_first_commands_hold_answers_in_order():
         serving = db.serving_totals()
         assert serving["slept_bursts"] == 1 and serving["native_cmds"] == 3
         assert serving["busy_routed_cmds"] == 0 and serving["demoted_cmds"] == 0
+        assert serving["native_bursts"] == 2 and serving["burst_locks"] == 1 + 2
+
+    run(main())
+
+
+def test_a_pipelined_chunk_is_served_up_to_the_command_that_names_the_held_type():
+    """[TREG SET, TREG GET, TLOG INS, TREG GET, UJSON GET] in one chunk
+    beside a TLOG hold: the run ahead names a held type, so the first
+    round is the first command's type alone and stops before the TLOG
+    command: its two replies arrive WHILE the lock is held. The rest
+    sleeps for TLOG, then runs as one round over what it names; replies
+    in command order, byte for byte the Python path's."""
+    lines = [b"TREG SET t v 1", b"TREG GET t", b"TLOG INS k a 1", b"TREG GET t",
+             b"UJSON GET d"]
+
+    async def main():
+        want = await python_path(lines)
+        server, db = await native_server()
+        lock = db.manager("TLOG")._lock
+        try:
+            await lock.acquire()
+            reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+            writer.write(b"".join(l + b"\r\n" for l in lines))
+            await asyncio.wait_for(asleep(lock, 1), 5)
+            head = await asyncio.wait_for(reader.read(1 << 16), 5)
+            assert head == b"".join(want[:2]) and lock.locked()
+            lock.release()
+            rest = b""
+            while len(rest) < len(b"".join(want[2:])):
+                rest += await asyncio.wait_for(reader.read(1 << 16), 5)
+            writer.close()
+        finally:
+            await server.dispose()
+        assert rest == b"".join(want[2:])
+        serving = db.serving_totals()
+        assert serving["slept_bursts"] == 1 and db.metrics.slept_by_type == {"TLOG": 1}
+        assert serving["busy_routed_cmds"] == 0 and serving["demotions"] == 0
+        # UJSON GET of a document never rendered is the engine's hand-back
+        assert serving["native_cmds"] == 4 and serving["deferred_cmds"] == 1
+        assert serving["bursts_beside_hold"] == 1  # the first round, beside TLOG's
+
+    run(main())
+
+
+@pytest.mark.parametrize("own", [True, False])
+def test_under_an_admission_cap_a_chunk_that_meets_a_held_lock_is_routed_and_counted(own):
+    """`--admission-cap`: the wait for a repo lock must count in its
+    manager's `_inflight`, so while a capped lock is held a chunk takes
+    the per-repo Python path whatever it names: of the held type, one
+    command queues and the next is refused with the typed BUSY; of
+    another type, it is served at once (its own lock is free)."""
+
+    async def main():
+        server, db = await native_server()
+        db.set_admission_cap(1)
+        lock = db.manager("TLOG")._lock
+        try:
+            await lock.acquire()
+            if own:
+                first = asyncio.create_task(send_recv(server.port, b"TLOG INS k a 1\r\n"))
+                await asyncio.wait_for(asleep(lock, 1), 5)
+                refused = await send_recv(server.port, b"TLOG INS k b 2\r\n")
+                assert refused.startswith(b"-BUSY (TLOG admission cap 1")
+                assert lock.locked()
+                lock.release()
+                assert await first == b"+OK\r\n"
+            else:
+                assert await send_recv(server.port, b"TREG SET t v 1\r\n") == b"+OK\r\n"
+                assert lock.locked()
+                lock.release()
+        finally:
+            await server.dispose()
+        serving = db.serving_totals()
+        assert serving["busy_refusals"] == (1 if own else 0)
+        assert serving["busy_routed_cmds"] == (2 if own else 1)
+        assert serving["slept_bursts"] == 0 and serving["native_cmds"] == 0
 
     run(main())
 
