@@ -204,23 +204,23 @@ class RepoUJSON:
 
     def _host_fold(self, doc: UJSON, deltas) -> None:
         """Converge pending deltas into a host document or a resident
-        row's decoded view, one walk of the document each: counted, and
-        each walk a ujson.host_fold span."""
+        row's decoded view: counted, with the entries each join examined
+        for removal (`UJSON.walked`: the delta's own size, not the
+        document's), and each join a ujson.host_fold span."""
         reg = resolve_registry(self)
         if not reg.enabled:
             for d in deltas:
                 doc.converge(d)
             return
         seam = reg.seam("ujson.host_fold")
-        walked = n = 0
+        walked, n = doc.walked, 0
         for d in deltas:
-            walked += len(doc.entries)
             n += 1
             t0 = seam.begin()
             doc.converge(d)
             seam.end(t0)
         reg.tally("drain.UJSON.host_deltas", n)
-        reg.tally("drain.UJSON.host_walked", walked)
+        reg.tally("drain.UJSON.host_walked", doc.walked - walked)
 
     def _mutate(self, doc: UJSON, op: bytes, path, value, delta: UJSON) -> None:
         if op == b"INS":
@@ -359,10 +359,16 @@ class RepoUJSON:
             doc = self._view(key)
             text = ""
             if doc is not None:
-                seam = resolve_registry(self).seam("ujson.render")
+                reg = resolve_registry(self)
+                seam = reg.seam("ujson.render")
+                sorts = doc.sorts
                 t0 = seam.begin()
                 text = doc.render(path)
                 seam.end(t0)
+                if doc.sorts != sorts:
+                    # the render read no kept token order: it sorted one
+                    # (a view's first render of the path)
+                    reg.tally("drain.UJSON.render_sorts", 1)
             resp.string(text)
             if self.engine is not None and doc is not None:
                 body = text.encode()

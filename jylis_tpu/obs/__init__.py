@@ -122,10 +122,11 @@ SEAMS = (
     "lock.hold_sync",
     # the UJSON repo's two host costs per document (models/
     # repo_ujson.py): the render of a GET that reached the repo (the
-    # walk of the leaves, the sort and the join; a GET the engine's
+    # join of the path's kept token order, and on a view's first
+    # render the index's build and the sort; a GET the engine's
     # memo answers renders nothing), and the host fold of ONE pending
     # delta into a document or a resident row's decoded view
-    # (UJSON.converge, a walk of the whole document)
+    # (UJSON.converge, at the cost of the delta's own size)
     "ujson.render",
     "ujson.host_fold",
 )
@@ -191,10 +192,14 @@ SERVING = (
 # budget), rows resident now (admits less demotions), deltas `converge`
 # buffered (a peer's, a restore's, a journal replay's), of those the ones
 # that reached their document by a device fold alone and the ones a host
-# fold walked in, the leaves those host folds walked, local writes
+# fold joined in, the entries those joins examined for removal (the
+# delta's context's size, not the document's), local writes
 # applied, of those the ones that became a delta for a resident row
-# (--ujson-resident-min-leaves), and rows rewritten from their decoded
-# view because a delta was too wide for the store's pinned grid. ENGINE:
+# (--ujson-resident-min-leaves), rows rewritten from their decoded
+# view because a delta was too wide for the store's pinned grid, rows
+# gathered and decoded for a document with no decoded view, and renders
+# that had to sort a path's tokens because the view kept no order for
+# it yet (a view's first render of the path). ENGINE:
 # times the reply buffer was replaced by a larger one (a reply alone
 # outgrew it), the bytes it holds now (it only grows, so the sum of its
 # steps), and commands whose reply passed the buffer's ceiling and went
@@ -232,6 +237,7 @@ TALLIES = (
     "drain.UJSON.row_deltas",
     "drain.UJSON.row_rewrites",
     "drain.UJSON.row_reads",
+    "drain.UJSON.render_sorts",
     "serving.ENGINE.reply_grows",
     "serving.ENGINE.reply_buffer_bytes",
     "serving.ENGINE.oversize_defers",

@@ -29,6 +29,8 @@ reference.
 from __future__ import annotations
 
 import json
+from bisect import bisect_left, insort
+from collections import defaultdict
 
 Dot = tuple[int, int]  # (replica-id, seq)
 Path = tuple[str, ...]
@@ -131,6 +133,39 @@ def parse_value(doc: str) -> str:
     return json.dumps(data)
 
 
+class _Index:
+    """What `UJSON` keeps beside its dot-store so that an operation costs
+    what it changes and not the whole document (see the class)."""
+
+    __slots__ = ("of", "paths", "order", "seqs")
+
+    def __init__(self, entries: dict[Dot, tuple[Path, str]]):
+        self.of = entries  # the dict this index describes, by identity
+        # path -> token -> the dots that carry (path, token): a tuple,
+        # one dot long but for a pair inserted concurrently
+        paths: dict[Path, dict[str, tuple[Dot, ...]]] = {}
+        last = toks = None
+        for d, (p, t) in entries.items():
+            if p is not last:  # a set's leaves share their path object
+                last = p
+                toks = paths.get(p)
+                if toks is None:
+                    toks = paths[p] = {}
+            if t in toks:
+                toks[t] += (d,)
+            else:
+                toks[t] = (d,)
+        # replica -> the seqs of its live dots
+        seqs: defaultdict[int, set[int]] = defaultdict(set)
+        for r, s in entries:
+            seqs[r].add(s)
+        self.paths = paths
+        self.seqs = seqs
+        # path -> its distinct tokens in sorted order, from the path's
+        # first render on (kept by ordered insert and delete after it)
+        self.order: dict[Path, list[str]] = {}
+
+
 class UJSON:
     """One document: dot-store + causal context, with delta-mutators.
 
@@ -138,56 +173,95 @@ class UJSON:
     joinable state of the mutation (the reference's delta-accumulator
     pattern, repo_ujson.pony:53-66); deltas for the same document within a
     flush window coalesce by join.
+
+    ``walked`` and ``sorts`` count work, for whoever serves the document
+    (models/repo_ujson.py): entries `converge` examined for removal, and
+    token orders `render` had to sort instead of reading them.
     """
 
-    __slots__ = ("entries", "ctx", "_by_path", "_idx_of")
+    __slots__ = ("entries", "ctx", "_idx", "walked", "sorts")
 
     def __init__(self):
         self.entries: dict[Dot, tuple[Path, str]] = {}
         self.ctx = CausalContext()
-        self._by_path: dict[Path, set[Dot]] | None = None
-        self._idx_of: dict | None = None
+        self._idx: _Index | None = None
+        self.walked = 0
+        self.sorts = 0
 
-    # -- per-path index over the dot-store ----------------------------------
+    # -- the index over the dot-store ---------------------------------------
     #
-    # set_doc/rm/clr observe (then remove) the dots at or under a path;
-    # scanning every entry per write made write-hot documents quadratic —
-    # the floor of an all-commands serving mix. The index maps
-    # path -> dots, built lazily at the first observe and maintained by
-    # the internal mutators; it is keyed on the entries dict's IDENTITY,
-    # so consumers that install a fresh entries dict wholesale
-    # (LazyWireUJSON._materialize, test fixtures) invalidate it by
-    # construction. Code outside this class must never mutate an
-    # existing entries dict in place after the doc has served a write —
-    # decode paths populate entries only at construction, before any
-    # index exists.
+    # A set of 1,000 members takes writes, foreign deltas and reads one
+    # member at a time, and a walk of every entry for each of them is the
+    # floor of a serving mix ("Big(ger) Sets", arXiv:1605.06424: a write's
+    # cost should be flat in the set's cardinality). The index has three
+    # levels, built together at the first operation that needs one and
+    # maintained by the internal mutators:
+    #
+    # * path -> token -> dots: set_doc/rm/clr observe (then remove) the
+    #   dots at or under a path, rm those of one token; render reads the
+    #   tokens AT a path and finds the children under it among the path
+    #   keys;
+    # * path -> sorted distinct tokens: what render joins. Sorted at the
+    #   path's first render, then kept by ordered insert and delete;
+    # * replica -> live seqs: converge's candidates for removal under the
+    #   other side's version vector.
+    #
+    # It is keyed on the entries dict's IDENTITY, so consumers that
+    # install a fresh entries dict wholesale (WireUJSON._materialize, test
+    # fixtures) invalidate it by construction. Code outside this class
+    # must never mutate an existing entries dict in place after the doc
+    # has served an operation — decode paths populate entries only at
+    # construction, before any index exists. (getattr: a WireUJSON is
+    # made without __init__, on the receive hot path.)
 
-    def _index(self) -> dict[Path, set[Dot]]:
-        if getattr(self, "_idx_of", None) is not self.entries:
-            idx: dict[Path, set[Dot]] = {}
-            for d, (p, _) in self.entries.items():
-                s = idx.get(p)
-                if s is None:
-                    s = idx[p] = set()
-                s.add(d)
-            self._by_path = idx
-            self._idx_of = self.entries
-        return self._by_path
+    def _kept(self) -> _Index | None:
+        """The index, if one was built over the entries dict that stands."""
+        idx = getattr(self, "_idx", None)
+        return idx if idx is not None and idx.of is self.entries else None
 
-    def _idx_add(self, dot: Dot, path: Path) -> None:
-        if getattr(self, "_idx_of", None) is self.entries:
-            s = self._by_path.get(path)
-            if s is None:
-                s = self._by_path[path] = set()
-            s.add(dot)
+    def _index(self) -> _Index:
+        idx = self._kept()
+        if idx is None:
+            idx = self._idx = _Index(self.entries)
+        return idx
 
-    def _idx_drop(self, dot: Dot, path: Path) -> None:
-        if getattr(self, "_idx_of", None) is self.entries:
-            s = self._by_path.get(path)
-            if s is not None:
-                s.discard(dot)
-                if not s:
-                    del self._by_path[path]
+    def _idx_add(self, dot: Dot, path: Path, token: str) -> None:
+        idx = self._kept()
+        if idx is None:
+            return
+        toks = idx.paths.get(path)
+        if toks is None:
+            toks = idx.paths[path] = {}
+        have = toks.get(token)
+        if have is None:
+            toks[token] = (dot,)
+            order = idx.order.get(path)
+            if order is not None:
+                insort(order, token)
+        else:
+            toks[token] = have + (dot,)
+        idx.seqs[dot[0]].add(dot[1])
+
+    def _idx_drop(self, dot: Dot, path: Path, token: str) -> None:
+        idx = self._kept()
+        if idx is None:
+            return
+        toks = idx.paths[path]
+        left = tuple(d for d in toks[token] if d != dot)
+        if left:
+            toks[token] = left
+        else:
+            del toks[token]
+            order = idx.order.get(path)
+            if not toks:
+                del idx.paths[path]
+                idx.order.pop(path, None)
+            elif order is not None:
+                del order[bisect_left(order, token)]
+        live = idx.seqs[dot[0]]
+        live.discard(dot[1])
+        if not live:
+            del idx.seqs[dot[0]]
 
     def __eq__(self, other) -> bool:
         """Representational equality (see CausalContext.__eq__): used by
@@ -205,9 +279,10 @@ class UJSON:
     def _under(self, path: Path) -> list[Dot]:
         n = len(path)
         out: list[Dot] = []
-        for p, dots in self._index().items():
+        for p, toks in self._index().paths.items():
             if p[:n] == path:
-                out.extend(dots)
+                for dots in toks.values():
+                    out.extend(dots)
         return out
 
     def is_empty(self) -> bool:
@@ -217,27 +292,22 @@ class UJSON:
         """Render the subtree at path as compact JSON; "" when absent
         (ujson.md:34-38). Set/map member order is unspecified by the
         semantics; we emit a deterministic sorted order."""
+        idx = self._index()
         n = len(path)
-        values: set[str] = set()
-        children: dict[str, bool] = {}
-        for p, token in self.entries.values():
-            if p[:n] != path:
-                continue
-            if len(p) == n:
-                values.add(token)
-            else:
-                children[p[n]] = True
-        if not values and not children:
-            return ""
-        rendered_map = None
-        if children:
-            items = sorted(children)
-            rendered_map = (
-                "{" + ",".join(json.dumps(k) + ":" + self.render(path + (k,)) for k in items) + "}"
-            )
-        vals = sorted(values)
-        if rendered_map is None:
+        children = sorted({p[n] for p in idx.paths if len(p) > n and p[:n] == path})
+        vals: list[str] = []
+        if path in idx.paths:
+            vals = idx.order.get(path)
+            if vals is None:
+                vals = idx.order[path] = sorted(idx.paths[path])
+                self.sorts = getattr(self, "sorts", 0) + 1
+        if not children:
+            if not vals:
+                return ""
             return vals[0] if len(vals) == 1 else "[" + ",".join(vals) + "]"
+        rendered_map = (
+            "{" + ",".join(json.dumps(k) + ":" + self.render(path + (k,)) for k in children) + "}"
+        )
         if not vals:
             return rendered_map
         return "[" + ",".join(vals + [rendered_map]) + "]"
@@ -255,21 +325,21 @@ class UJSON:
         for d in dots:
             pv = self.entries.pop(d, None)
             if pv is not None:
-                self._idx_drop(d, pv[0])
+                self._idx_drop(d, *pv)
             self.ctx.add(d)
             if delta is not None:
                 dpv = delta.entries.pop(d, None)
                 if dpv is not None:
-                    delta._idx_drop(d, dpv[0])
+                    delta._idx_drop(d, *dpv)
                 delta.ctx.add(d)
 
     def _add_leaf(self, replica: int, path: Path, token: str, delta) -> None:
         dot = self.ctx.next_dot(replica)
         self.entries[dot] = (path, token)
-        self._idx_add(dot, path)
+        self._idx_add(dot, path, token)
         if delta is not None:
             delta.entries[dot] = (path, token)
-            delta._idx_add(dot, path)
+            delta._idx_add(dot, path, token)
             delta.ctx.add(dot)
 
     def set_doc(self, replica: int, path: Path, doc: str, delta=None) -> None:
@@ -288,12 +358,7 @@ class UJSON:
         """RM: remove the observed dots of one exact (path, value) pair
         (ujson.md:91-103)."""
         token = parse_value(value)
-        dots = [
-            d
-            for d in self._index().get(path, ())
-            if self.entries[d][1] == token
-        ]
-        self._remove_dots(dots, delta)
+        self._remove_dots(self._index().paths.get(path, {}).get(token, ()), delta)
 
     def clr(self, replica: int, path: Path, delta=None) -> None:
         """CLR: remove all observed dots at or under path (ujson.md:63-75)."""
@@ -302,22 +367,39 @@ class UJSON:
     # ---- lattice ----------------------------------------------------------
 
     def converge(self, other: "UJSON") -> bool:
-        """ORSWOT join; returns True if local state changed."""
+        """ORSWOT join; returns True if local state changed. Its cost
+        follows the OTHER side: the dots of its context's cloud, the live
+        seqs here of each replica in its version vector, its entries."""
+        mine, theirs, octx = self.entries, other.entries, other.ctx
+        # entries present only here, observed (covered) by other -> removed:
+        # only a dot of the cloud, or one at or under the version vector's
+        # seq for its replica, can be covered
+        gone = [d for d in octx.cloud if d in mine and d not in theirs]
+        walked = len(octx.cloud)
+        if octx.vv:
+            seqs = self._index().seqs
+            for r, top in octx.vv.items():
+                live = seqs.get(r)
+                if live:
+                    walked += len(live)
+                    gone.extend(
+                        (r, s) for s in live if s <= top and (r, s) not in theirs
+                    )
+        self.walked = getattr(self, "walked", 0) + walked
         changed = False
-        # entries present only here, observed (covered) by other -> removed
-        for d in list(self.entries):
-            if d not in other.entries and other.ctx.contains(d):
-                pv = self.entries.pop(d)
-                self._idx_drop(d, pv[0])
+        for d in gone:
+            pv = mine.pop(d, None)
+            if pv is not None:
+                self._idx_drop(d, *pv)
                 changed = True
         # entries present only there, not covered by us -> added
-        for d, pv in other.entries.items():
-            if d not in self.entries and not self.ctx.contains(d):
-                self.entries[d] = pv
-                self._idx_add(d, pv[0])
+        for d, pv in theirs.items():
+            if d not in mine and not self.ctx.contains(d):
+                mine[d] = pv
+                self._idx_add(d, *pv)
                 changed = True
         before = (dict(self.ctx.vv), set(self.ctx.cloud))
-        self.ctx.join(other.ctx)
+        self.ctx.join(octx)
         if (self.ctx.vv, self.ctx.cloud) != before:
             changed = True
         return changed

@@ -184,6 +184,14 @@ def test_the_senders_counters_are_on_the_three_surfaces(node):
     for i in range(20):
         s.sendall(resp(b"GCOUNT", b"INC", b"k", b"1"))
         assert read_exactly(s, 5) == b"+OK\r\n"
+    # the client can hold the last reply before either thread has counted
+    # it: the hand-off adds to `sends` after it has queued the job (the
+    # sender may send it first), and the sender takes the job's bytes off
+    # `pending` and adds its `busy` only after `send` has returned
+    wait_for(
+        lambda: node.stats()[SENDS] == 20 and node.stats()[PENDING] == 0,
+        "the sender's counts to settle",
+    )
     st = node.stats()
     assert st[SENDS] == 20 and st[RUNNING] == 1 and st[PARTIAL] == 0
     assert 1 <= st[WAKES] <= 20 and st[BUSY_US] > 0

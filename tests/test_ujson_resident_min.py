@@ -470,10 +470,13 @@ def test_which_path_a_foreign_delta_took_and_what_it_walked_is_counted():
     peer = _Peer(PEER_A)
     for i in range(3):
         repo.converge(b"k", peer.ins(str(3 * 10**18 + i)))
-    _get(repo, b"k")  # the read path's trickle: three host folds of a 50-leaf view
+    _get(repo, b"k")  # the read path's trickle: three host folds into a 50-leaf view
     assert _tally(db, "foreign_deltas") - base["foreign_deltas"] == 3
     assert _tally(db, "host_deltas") - base["host_deltas"] == 3
-    assert _tally(db, "host_walked") - base["host_walked"] == 50 + 51 + 52
+    # entries EXAMINED, the deltas' size and not the view's (50 + 51 + 52
+    # until PR 43): the peer's seq 1 ships as a version vector over a
+    # replica with no live dot here (0), seqs 2 and 3 as a cloud dot each
+    assert _tally(db, "host_walked") - base["host_walked"] == 0 + 1 + 1
     assert _tally(db, "device_deltas") == 0
     assert db.metrics.hist("ujson.host_fold").snapshot()["count"] >= 3
     assert db.metrics.hist("ujson.render").snapshot()["count"] == 1
@@ -492,13 +495,15 @@ def test_the_counters_and_spans_are_on_the_metrics_endpoint_and_the_shutdown_lin
     text = render(db)
     for kind in ("admits", "readmits", "demote_write", "demote_overflow", "demote_budget",
                  "resident_rows", "foreign_deltas", "device_deltas", "host_deltas",
-                 "host_walked", "local_writes", "row_deltas", "row_rewrites", "row_reads"):
+                 "host_walked", "local_writes", "row_deltas", "row_rewrites", "row_reads",
+                 "render_sorts"):
         assert f'jylis_drain_total{{type="UJSON",kind="{kind}"}}' in text, kind
     assert 'jylis_drain_total{type="UJSON",kind="row_deltas"} 1' in text
     for seam in ("drain.UJSON", "ujson.render", "ujson.host_fold"):
         assert f'jylis_seam_latency_seconds_count{{seam="{seam}"}}' in text
     line = db.metrics.report()
     assert "1 admits" in line and "1 local_writes" in line and "1 row_deltas" in line
+    assert "1 render_sorts" in line
 
 
 def test_a_snapshot_of_resident_documents_restores_to_the_same_answers(tmp_path):
