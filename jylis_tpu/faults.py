@@ -171,6 +171,13 @@ def hits(name: str) -> int:
         return _hits.get(name, 0)
 
 
+def armed(name: str) -> bool:
+    """True while ``name`` is armed: for a synchronous seam that leaves
+    an armed point's firing to a coroutine (the server's read callback:
+    `async_point` there, so an injected sleep stalls one connection)."""
+    return name in _armed
+
+
 def armed_points() -> dict[str, str]:
     """{name: action} snapshot of what is currently armed."""
     with _lock:

@@ -480,7 +480,7 @@ class RepoManager:
 
     def busy(self) -> bool:
         """True while a (possibly threaded) repo access holds the lock.
-        The server's route (server.py `_handle_client`) reads it of the
+        The server's route (server.py `_capped_busy`) reads it of the
         engine's managers only under `admission_cap`: a chunk that
         arrives while a capped lock is held takes the per-repo Python
         path, so that its wait counts in `_inflight`. Without a cap a

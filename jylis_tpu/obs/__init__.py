@@ -153,6 +153,10 @@ SEAMS = (
 # burst_locks: repo locks those rounds took, summed (a round takes the
 # locks of the types its commands name). bursts_beside_hold: rounds that
 # ran while a lock they did not need was held by somebody else.
+# inline_bursts: rounds settled with no task (server.py `_Conn.serve`,
+# every chunk of a loop iteration taken up in one callback); the rest of
+# native_bursts are a slow-path task's (a sleep for a lock, a command
+# handed back, a consumer that is behind, the byte bound).
 SERVING = (
     "demotions",
     "busy_refusals",
@@ -165,6 +169,7 @@ SERVING = (
     "native_bursts",
     "burst_locks",
     "bursts_beside_hold",
+    "inline_bursts",
 )
 
 # Exact event counters beside a type's drain totals, `drain.<TYPE>.<kind>`

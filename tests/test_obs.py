@@ -658,7 +658,7 @@ def test_a_slept_burst_is_counted_on_every_surface():
     assert db.metrics.report().endswith("; SERVING: 0 demotions, 0 busy_refusals, "
         "0 busy_routed_cmds, 0 deferred_cmds, 0 demoted_conn_cmds, "
         f"{serving['reply_bytes']} reply_bytes, 1 slept_bursts, 0 loop_sends, "
-        "1 native_bursts, 1 burst_locks, 0 bursts_beside_hold")
+        "1 native_bursts, 1 burst_locks, 0 bursts_beside_hold, 0 inline_bursts")
 
 
 def test_engine_reply_bytes_are_counted_per_burst():

@@ -182,6 +182,10 @@ def test_a_node_on_the_python_tables_starts_no_thread_and_counts_loop_sends():
 def test_the_senders_counters_are_on_the_three_surfaces(node):
     s = node.connect()
     for i in range(20):
+        if i == 10:
+            # well past the thread's idle spin (200 us): it is asleep at
+            # the next hand-off, whatever a round trip takes
+            time.sleep(0.02)
         s.sendall(resp(b"GCOUNT", b"INC", b"k", b"1"))
         assert read_exactly(s, 5) == b"+OK\r\n"
     # the client can hold the last reply before either thread has counted

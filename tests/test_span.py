@@ -25,7 +25,6 @@ from jylis_tpu.models.database import Database
 from jylis_tpu.obs import SEAMS, loop as loop_mod, prom, span
 from jylis_tpu.obs.registry import MetricsRegistry
 from jylis_tpu.utils import metrics
-from jylis_tpu.server.server import _Door
 
 from procutil import REPO, SPAWN_CPU, connect_client, free_port, stop_node
 from test_async_serving import SLOW, make_server, slow_down_drain
@@ -516,57 +515,36 @@ def test_a_served_bursts_stages_tile_the_loops_busy_time(conns):
     asyncio.run(main(), loop_factory=loop_mod.new_event_loop)
 
 
-class _Writer:
-    """What `_apply_native` needs of a stream writer."""
-
-    def __init__(self):
-        self.data = bytearray()
-        self.transport = self
-
-    def write(self, data):
-        self.data += data
-
-    def get_write_buffer_size(self):
-        return 0
-
-    def get_extra_info(self, name):
-        return None  # no socket: its door is `write`, not the sender
-
-    async def drain(self):
-        pass
-
-
 @pytest.mark.parametrize("held", [False, True])
 def test_a_bursts_sleep_is_the_lock_waits_and_not_the_routes(held):
     """An unslept burst leaves lock.wait_serve where it was; one that
     slept behind a held lock adds the sleep to it and NOT to
     serve.route, which stops where the sleep began and goes on after."""
-    from jylis_tpu.native.resp import make_parser
-    from jylis_tpu.server.resp import Respond
 
     async def main():
         server, db = make_server()
         if db.native_engine is None:
             pytest.skip("no native engine on this host")
+        await server.start()
         reg = db.metrics
         lock = db.manager("GCOUNT")._lock  # the one lock the burst names
-        out = bytearray()
-        writer = _Writer()
-        if held:
-            await lock.acquire()
-        burst = asyncio.create_task(server._apply_native(
-            db.native_engine, bytearray(b"GCOUNT INC x 1\r\n"), make_parser(),
-            Respond(out.extend), lambda bound=0: 0.0,
-            _Door(server, writer, db.native_engine), out, 0.0,
-            time.perf_counter()))
-        if held:
-            await asyncio.sleep(0.2)
-            assert not burst.done()
-            lock.release()
-        t_tail = await burst
-        assert t_tail and bytes(writer.data) == b"+OK\r\n"
+        try:
+            if held:
+                await lock.acquire()
+            burst = asyncio.create_task(
+                send_recv(server.port, b"GCOUNT INC x 1\r\n", 5))
+            if held:
+                while not lock._line:  # the clock starts where the sleep does
+                    await asyncio.sleep(0.001)
+                await asyncio.sleep(0.2)
+                assert not burst.done()
+                lock.release()
+            assert await burst == b"+OK\r\n"
+        finally:
+            await server.dispose()
         wait, route = reg.hist("lock.wait_serve"), reg.hist("serve.route")
         assert route.count == 1 and reg.hist("server.native_burst").count == 1
+        assert reg.serving_counters["inline_bursts"] == (0 if held else 1)
         if held:
             assert wait.count == 1 and wait.total >= 0.19
             assert route.total < 0.1, route.total
