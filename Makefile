@@ -21,11 +21,11 @@ PY ?= python
 ci: native lint lint-native test chaos model-smoke check-graft \
     metrics-smoke sanitize sanitize-threads
 
-# the eleven jlint passes + the hygiene rules (broad-except, suppression
+# the ten jlint passes (1-5, 7-11) + the hygiene rules (broad-except, suppression
 # reasons/staleness), against the committed baseline
 # (scripts/jlint/baseline.json — every entry justified in-line, stale
 # entries fail). The manifest checks (RESP parity, failpoints, metrics,
-# lane shared-state, codec symmetry, lattice discipline, protocol
+# codec symmetry, lattice discipline, protocol
 # atlas, cross-language RESP semantics) re-extract
 # their surfaces on every run and fail on uncommitted drift; regenerate
 # with `$(PY) -m scripts.jlint --write-manifest` (then `--write-corpus`
@@ -85,9 +85,7 @@ sanitize-threads:
 
 # boot a real node with --metrics-port, scrape it, validate the
 # Prometheus exposition grammar + presence of every histogram/gauge in
-# scripts/jlint/metrics_manifest.json; then boot a --lanes 4 node and
-# validate the supervisor's AGGREGATED scrape (per-lane labels +
-# lane-less counter sums) — neither surface can rot
+# scripts/jlint/metrics_manifest.json
 metrics-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/metrics_smoke.py
 
@@ -96,22 +94,20 @@ test:
 
 # tiny fault-injection drill smoke: a curated subset of the drill
 # matrix — dial backoff/reset/timeout drills, an FFI fault served via
-# demotion, the CLUSTER metrics surface, and the LANE-CRASH cell
-# (SIGKILL one lane of a spawned --lanes 2 node mid-traffic; surviving
-# lanes serve throughout, the respawn replays its journal segment,
-# per-lane digests re-match) — per commit via `make ci`. The FULL
+# demotion, the CLUSTER metrics surface, the region and bridge drills
+# — per commit via `make ci`. The FULL
 # {error,sleep,corrupt,drop,crash} x {every registered failpoint}
-# matrix plus the 3-node lane drills run nightly behind `-m soak`.
+# matrix runs nightly behind `-m soak`.
 chaos:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_drill_matrix.py -m chaos -q
 
-# jmodel: bounded explicit-state exploration of the cluster + lane-bus
+# jmodel: bounded explicit-state exploration of the cluster
 # protocol (scripts/jmodel). Drives the REAL Cluster handler code over
 # an in-memory deterministic network (virtual clock + pipe transport
 # through cluster.py's injectable clock/connect seams), enumerating
 # delivery schedules — reorder across conns, drop (conn kill),
 # duplicate, partition, crash-reboot-from-journal — over the 2-node,
-# 3-node and 2-lane-bus configs with state-hash dedup and sleep-set
+# 3-node and 3-node-2-region configs with state-hash dedup and sleep-set
 # partial-order reduction. Asserts, per state: lattice monotonicity,
 # held-queue FIFO + bound, dial-backoff monotonicity; at quiescence:
 # digest match on every replica, no stranded rtt stamps, nothing in

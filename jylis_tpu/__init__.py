@@ -28,7 +28,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 # Persistent compile cache, set here because every entry point (the node,
-# lanes, the smokes, the tests) imports this package first.
+# the smokes, the tests) imports this package first.
 # JAX_COMPILATION_CACHE_DIR, when set, places the cache and jax reads it
 # itself; otherwise a FIXED directory beside the package (gitignored),
 # never a temp/pid/time-derived one — a cache that moves never hits.

@@ -429,8 +429,6 @@ class Cluster:
         self,
         config,
         database,
-        drive_flush: bool = True,
-        register_system: bool = True,
         clock: Clock | None = None,
         connect=None,
     ):
@@ -444,44 +442,11 @@ class Cluster:
         # checker.
         self._clock = clock or REAL_CLOCK
         self._connect = connect or tcp_connect
-        # multi-lane bridge hooks (lanes.py). A node running N serving
-        # lanes has TWO Cluster instances on lane 0 — the external mesh
-        # on config.addr and the loopback lane bus — sharing ONE
-        # Database whose delta buffer must drain exactly once per
-        # flush: `drive_flush=False` makes this instance's heartbeat
-        # skip the database flush (dials/eviction/announce/sync still
-        # run), and `flush_sink` (when set on the driving instance)
-        # replaces broadcast_deltas as the flush sink so one drain can
-        # tee to both meshes. `on_push` is called after every converged
-        # MsgPushDeltas with (name, batch) — the bridge relays inbound
-        # deltas to the OTHER mesh there (converge never re-exports, so
-        # relaying cannot echo). `register_system=False` keeps this
-        # instance from claiming the SYSTEM METRICS CLUSTER section.
-        self._drive_flush = drive_flush
-        self.flush_sink = None
-        self.on_push = None
-        # ---- provenance spans (schema v11, obs/jtrace.py) --------------
-        # 1-in-N sequenced flushes get a trace span minted at
-        # broadcast_deltas (0 disables). `last_span` exposes the span of
-        # the most recent broadcast so the lane tee (lanes.py) can carry
-        # the SAME chain onto the external mesh without widening the
-        # broadcast_deltas signature tests and jlint pin. `relay_hop` is
-        # the hop tag relay_deltas stamps — HOP_RELAY for a plain
-        # bridge, overridden by lanes.py/main.py wiring so the bus and
-        # the external cluster legs are distinguishable in a chain.
+        # provenance spans (schema v11, obs/jtrace.py): 1-in-N sequenced
+        # flushes get a trace span minted at broadcast_deltas (0
+        # disables)
         self._trace_sample = max(0, getattr(config, "trace_sample", 0))
         self._trace_n = 0
-        self.last_span = b""
-        self.relay_hop = jtrace.HOP_RELAY
-        # the node's PRIMARY cluster view owns the shared observability
-        # names (cluster.rtt histogram, converge_lag_ms/backlog_ms
-        # gauges, SYSTEM METRICS CLUSTER section). On lane 0 the
-        # loopback bus instance is secondary (register_system=False):
-        # letting it record would drown the external mesh's
-        # microsecond-loopback-free rtt/lag signal — the exact
-        # cross-node staleness surface the gauges exist to expose —
-        # and flap the gauges last-writer-wins between the instances.
-        self._obs_primary = register_system
         self._addr: Address = config.addr
         # ---- sessions & regions (schema v10) ---------------------------
         # boot epoch: the incarnation stamp of this instance's sequenced
@@ -531,14 +496,12 @@ class Cluster:
         self._relay_queue: deque = deque()
         self._relay_queue_bytes = 0
         self._relay_inflight = False
-        # the node's session index (sessions.SessionIndex) — owned by
-        # the Database and SHARED by every cluster instance of the node
-        # (bus + external on lane 0): applied-vector advances and
-        # digest-match adoptions feed it from any mesh; only the
-        # DRIVING instance binds its rid + flush hook for token minting
+        # the node's session index (sessions.SessionIndex), owned by
+        # the Database: applied-vector advances and digest-match
+        # adoptions feed it; this instance binds its rid + flush hook
+        # for token minting
         self._sessions = getattr(database, "sessions", None)
-        self._owns_session = drive_flush and self._sessions is not None
-        if self._owns_session:
+        if self._sessions is not None:
             self._sessions.bind(self._srid, self.flush_now)
         self._known_addrs: P2Set = P2Set([self._addr])
         for seed in config.seed_addrs:
@@ -689,7 +652,7 @@ class Cluster:
         # instance (wired here, not in main, so in-process test nodes
         # get the same observability as spawned ones)
         system = getattr(database, "system", None)
-        if system is not None and register_system:
+        if system is not None:
             system.cluster_fn = self.metrics_totals
             system.lag_fn = self.lag_snapshot
             system.topology_fn = self.topology_lines
@@ -818,17 +781,12 @@ class Cluster:
         # flush as a task taking each repo's lock: a repo mid-drain delays
         # only its own flush, never the tick (eviction/announce/dial
         # above). Hold a strong reference — asyncio keeps only weak task
-        # refs — and surface exceptions through the log. On a lane-0
-        # bridge the non-driving instance skips this (the driving
-        # instance's flush_sink tees the one drain to both meshes).
-        if self._drive_flush:
-            task = asyncio.get_running_loop().create_task(
-                self._database.flush_deltas_async(
-                    self.flush_sink or self.broadcast_deltas
-                )
-            )
-            self._flush_tasks.add(task)
-            task.add_done_callback(self._flush_task_done)
+        # refs — and surface exceptions through the log.
+        task = asyncio.get_running_loop().create_task(
+            self._database.flush_deltas_async(self.broadcast_deltas)
+        )
+        self._flush_tasks.add(task)
+        task.add_done_callback(self._flush_task_done)
         self._sync_actives()
 
     def metrics_totals(self) -> dict[str, int]:
@@ -902,8 +860,8 @@ class Cluster:
     LAG_ALPHA = 0.5
 
     def _note_lag(self, peer: str, lag_ms: float) -> None:
-        if not self._reg.enabled or not self._obs_primary:
-            return  # obs kill switch / secondary (lane-bus) instance
+        if not self._reg.enabled:
+            return  # obs kill switch
         old = self._lag_ms.get(peer)
         self._lag_ms[peer] = (
             lag_ms if old is None
@@ -926,7 +884,7 @@ class Cluster:
         if st.interval_dirty == dirty:
             return
         st.interval_dirty = dirty
-        if self._reg.enabled and self._obs_primary:
+        if self._reg.enabled:
             self._reg.gauge_set(
                 "cluster.interval_dirty_peers", float(self._dirty_count())
             )
@@ -1036,7 +994,7 @@ class Cluster:
                     f"{self._bridge_seen} -> {b}"
                 )
             self._bridge_seen = b
-        if self._reg.enabled and self._obs_primary:
+        if self._reg.enabled:
             self._reg.gauge_set(
                 "cluster.bridge_is_self",
                 1.0 if b == str(self._addr) else 0.0,
@@ -1417,7 +1375,7 @@ class Cluster:
         a silent ignore would hide forever)."""
         if conn.pong_sent:
             dt = self._clock.perf() - conn.pong_sent.popleft()
-            if self._reg.enabled and self._obs_primary:
+            if self._reg.enabled:
                 self._h_rtt.record(dt)
         else:
             self._drop_msg(conn, unmatched_reason)
@@ -1496,15 +1454,11 @@ class Cluster:
             # push — the join is idempotent, so overlap with live
             # deltas is harmless. Unsequenced, so it advances no
             # session watermark (the digest-match adoption is the sync
-            # path's session heal); the lane bridge still relays it
-            # (origin None) so siblings converge within the proactive
-            # cadence instead of a bus sync period.
+            # path's session heal).
             self._sync_rx_tick = self._tick  # mid-heal: defer serving dumps
             self._stats["sync_bytes_recv"] += nbytes
             await self._database.converge_async((msg.name, list(msg.batch)))
             self._record_push_lag(conn, origin_ms)
-            if self.on_push is not None:
-                self.on_push(None, 0, msg.name, list(msg.batch))
             # cross-bridge repair relay (PR 15): a region bridge that
             # just converged sync/repair data pulled ACROSS the WAN
             # re-exports it into its intra-region mesh through the
@@ -1584,8 +1538,7 @@ class Cluster:
             # SeqPush from this conn's sender (acked, interval-tracked,
             # retransmittable), but the session watermark advances for
             # the ORIGIN incarnation carried in the message — which is
-            # what lets a token minted in another region (or on another
-            # lane) verify here
+            # what lets a token minted in another region verify here
             self._stats["relays_recv"] += 1
             self._send(conn, MsgDeltaAck(self._track_seq(conn, msg.seq)))
             await self._database.converge_async((msg.name, list(msg.batch)))
@@ -1668,8 +1621,6 @@ class Cluster:
             self._send(conn, MsgPong())
             await self._database.converge_async((msg.name, list(msg.batch)))
             self._record_push_lag(conn, origin_ms)
-            if self.on_push is not None:
-                self.on_push(None, 0, msg.name, list(msg.batch))
             return
         if isinstance(msg, MsgAnnounceAddrs):
             self._converge_addrs(msg.known_addrs)
@@ -1853,9 +1804,7 @@ class Cluster:
         converge completes (the chain measures applied, not received).
         A malformed span counts and is dropped — it rides inside the
         CRC-covered frame, so garbage here means a peer bug, and the
-        frame's deltas have already converged regardless. Every lane
-        folds into the shared registry (SpanStats is locked), so the
-        node-level SLO covers all lanes without aggregator math."""
+        frame's deltas have already converged regardless."""
         if not self._reg.enabled:
             return
         worst = self._reg.spans.ingest(
@@ -1868,9 +1817,8 @@ class Cluster:
         self, fresh: bool, origin: str | None, oseq: int, name: str, batch,
         span: bytes = b"",
     ) -> None:
-        """Bridge re-export of one first-sight sequenced batch. Lane
-        bridge: the on_push hook hands it to the sibling mesh instance.
-        Region bridge: this instance re-broadcasts it into its own
+        """Region-bridge re-export of one first-sight sequenced batch:
+        this instance re-broadcasts it into its own
         conns (intra peers + other regions' bridges; receivers' own
         first-sight checks stop echo loops). The dedup is BEST-EFFORT
         at-most-once: a seq evicted from the bounded park (PARK_CAP
@@ -1884,9 +1832,7 @@ class Cluster:
         amplification tradeoff is documented in operations.md."""
         if not fresh or not origin:
             return
-        relay_lane = self.on_push is not None
-        relay_region = bool(self._region) and self._is_bridge()
-        if not (relay_lane or relay_region):
+        if not (self._region and self._is_bridge()):
             return
         try:
             # cluster.relay: the WAN seam. sleep injects inter-region
@@ -1896,10 +1842,7 @@ class Cluster:
             await faults.async_point("cluster.relay")
         except faults.FaultError:
             return
-        if relay_lane:
-            self.on_push(origin, oseq, name, list(batch), span)
-        if relay_region:
-            self.relay_deltas(origin, oseq, (name, list(batch)), span)
+        self.relay_deltas(origin, oseq, (name, list(batch)), span)
 
     async def flush_now(self) -> None:
         """Token minting's flush barrier (sessions.SessionIndex.bind):
@@ -1907,9 +1850,7 @@ class Cluster:
         heartbeat uses, awaited — every prior local write is sequenced
         (and note_local'd) before SESSION TOKEN reads the vector, so
         the minted token provably covers the client's writes."""
-        await self._database.flush_deltas_async(
-            self.flush_sink or self.broadcast_deltas
-        )
+        await self._database.flush_deltas_async(self.broadcast_deltas)
 
     def _session_svec(self) -> tuple:
         """The vector as sorted wire pairs — snapshotted BEFORE the sync
@@ -2306,7 +2247,7 @@ class Cluster:
         stamp behind the seam's back."""
         return wire_frame(body, origin_ms=self._clock.now_ms())
 
-    def broadcast_deltas(self, deltas):
+    def broadcast_deltas(self, deltas) -> None:
         """The _SendDeltasFn sink (cluster.pony:209-213), schema v8:
         serialise the batch once, write to every established active
         connection. Content-carrying batches are SEQUENCED (MsgSeqPush
@@ -2317,9 +2258,7 @@ class Cluster:
         Anything already held ships FIRST (strict FIFO: a late-joining
         peer sees pre-join writes in flush order, never a fresh batch
         jumping the queue), and a fresh batch that cannot ship queues
-        behind them. Returns (own srid, assigned seq) for sequenced
-        content — the lane bridge's tee relays the SAME batch into the
-        sibling mesh under that origin — or (None, 0) for keepalives."""
+        behind them."""
         name, batch = deltas
         if batch and name != "SYSTEM":
             # outbound data deltas exist only for LOCAL applies: the
@@ -2331,14 +2270,13 @@ class Cluster:
             self._flush_held()
             if not self._held:
                 self._send_to_actives(data, expect_pong=True)
-            return None, 0
+            return
         self._delta_seq += 1
         self._own_seq += 1
         seq = self._delta_seq
         # provenance sampling (schema v11): every Nth sequenced flush
         # carries a span minted here — the chain every later hop
-        # appends to. `last_span` stays set (or cleared) until the next
-        # sequenced flush so the lane tee can read it synchronously.
+        # appends to.
         span = b""
         if self._trace_sample > 0:
             self._trace_n += 1
@@ -2348,13 +2286,12 @@ class Cluster:
                     b"", jtrace.HOP_ORIGIN, self._srid, self._region,
                     self._clock.now_ms(),
                 )
-        self.last_span = span
         data = self._wire(
             codec.encode(
                 MsgSeqPush(seq, self._own_seq, name, tuple(batch), span)
             )
         )
-        if self._owns_session:
+        if self._sessions is not None:
             # every local write in this batch is now sequenced: the
             # vector's own entry advances, which is what a token minted
             # after the flush barrier reads (sessions.py). The vector
@@ -2363,27 +2300,24 @@ class Cluster:
             # relay-hops away) see a gapless stream per origin.
             self._sessions.note_local(self._srid, self._own_seq)
         self._ship_sequenced(seq, data, len(batch))
-        return self._srid, self._own_seq
 
     def relay_deltas(self, origin: str, oseq: int, deltas,
                      span: bytes = b"") -> None:
         """Re-export one first-sight sequenced batch into THIS mesh
-        with origin attribution preserved (lane bridge: called by the
-        sibling instance's on_push / the tee; region bridge:
+        with origin attribution preserved (the region bridge's
         _relay_fresh). Transport-wise identical to broadcast_deltas'
         sequenced path — the frame takes this sender's next seq, rides
         the delta log, is acked and retransmitted — so receivers'
         per-sender contiguity survives bridge fan-out; only the session
         watermark semantics differ (the ORIGIN's, carried verbatim).
-        A sampled span gets this hop's stamp appended (`relay_hop` —
-        bus/cluster/relay depending on which leg this instance is)."""
+        A sampled span gets this hop's stamp appended (HOP_RELAY)."""
         name, batch = deltas
         self._delta_seq += 1
         seq = self._delta_seq
         self._stats["relays_sent"] += 1
         if span:
             span = jtrace.append_hop(
-                span, self.relay_hop, self._srid, self._region,
+                span, jtrace.HOP_RELAY, self._srid, self._region,
                 self._clock.now_ms(),
             )
         data = self._wire(
@@ -2392,20 +2326,6 @@ class Cluster:
             )
         )
         self._ship_sequenced(seq, data, len(batch))
-
-    def push_unsequenced(self, deltas) -> None:
-        """Best-effort unsequenced content push (MsgPushDeltas) to the
-        established actives — the lane bridge's carrier for relayed
-        SYNC data (origin None). Deliberately outside the seq/ack/
-        retransmit machinery AND the session surface: re-originating
-        sync data as this instance's own sequenced stream would mint
-        own-content ordinals that one side of the bridge can never
-        observe, stranding every token that references them (review
-        find). Loss is healed by the receivers' own periodic digest
-        syncs, exactly like any sync-dump frame."""
-        name, batch = deltas
-        data = self._wire(codec.encode(MsgPushDeltas(name, tuple(batch))))
-        self._send_to_actives(data, expect_pong=True)
 
     def _queue_repair_relay(self, name: str, batch, nbytes: int) -> None:
         """Enqueue one cross-WAN sync/repair batch for re-export into
@@ -2425,7 +2345,7 @@ class Cluster:
             return
         self._relay_queue.append((name, batch, nbytes))
         self._relay_queue_bytes += nbytes
-        if self._reg.enabled and self._obs_primary:
+        if self._reg.enabled:
             self._reg.gauge_set(
                 "cluster.relay_queue_bytes", float(self._relay_queue_bytes)
             )
@@ -2445,14 +2365,14 @@ class Cluster:
         buffering' means at this seam). Frames ride as unsequenced
         MsgPushDeltas exactly like the sync data they re-export:
         re-originating them as our own sequenced stream would mint
-        own-content ordinals one side can never observe (the lane
-        bridge's push_unsequenced lesson). cluster.relay fires per
+        own-content ordinals one side can never observe, stranding
+        every token that references them. cluster.relay fires per
         batch — the WAN seam's failpoint paces/drops here too."""
         try:
             while self._relay_queue:
                 name, batch, nbytes = self._relay_queue.popleft()
                 self._relay_queue_bytes -= nbytes
-                if self._reg.enabled and self._obs_primary:
+                if self._reg.enabled:
                     self._reg.gauge_set(
                         "cluster.relay_queue_bytes",
                         float(self._relay_queue_bytes),
@@ -2752,14 +2672,11 @@ class Cluster:
         if tracked:
             # the lag gauge tracks LIVE peers: a departed conn's EWMA
             # must not pin the node-wide max forever (a rejoin restarts
-            # sampling immediately). Secondary (lane-bus) instances
-            # never own the gauge — writing their always-empty max
-            # here would zero the primary's value on every bus drop.
+            # sampling immediately).
             self._lag_ms.pop(self._peer_key(conn), None)
-            if self._obs_primary:
-                self._reg.gauge_set(
-                    "cluster.converge_lag_ms", self._worst_lag_ms()
-                )
+            self._reg.gauge_set(
+                "cluster.converge_lag_ms", self._worst_lag_ms()
+            )
         self._last_activity.pop(conn, None)
         self._passives.discard(conn)
         if conn.active_addr is not None:

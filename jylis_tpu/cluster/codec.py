@@ -147,7 +147,7 @@ SCHEMA_VERSION = 11
 # origin-preserving relay (transport-sequenced like msg7, its name+batch
 # bytes msg3's after the prefix, with the ORIGIN incarnation's rid+seq
 # carried verbatim hop to hop — how a session token minted in one
-# region or lane verifies in another). msg12 gossips {addr -> region}
+# region verifies in another). msg12 gossips {addr -> region}
 # on the announce cadence so dial policy can classify addresses it
 # never met.
 # v11: provenance spans — transport-only like v8/v10 (delta lines

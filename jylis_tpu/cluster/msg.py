@@ -188,9 +188,8 @@ class MsgSyncRequest:
 @dataclass(frozen=True)
 class MsgRelayPush:
     """Schema v10 origin-preserving relay: a MsgSeqPush whose content
-    ORIGINATED at another replica, re-exported by a bridge (a region
-    bridge between WAN meshes, or lane 0 between the lane bus and the
-    external mesh). ``seq`` is the RELAYING sender's transport seq —
+    ORIGINATED at another replica, re-exported by a region bridge
+    between WAN meshes. ``seq`` is the RELAYING sender's transport seq —
     the frame rides its delta log, is acked by MsgDeltaAck and
     retransmitted on reconnect exactly like a SeqPush, so transport
     contiguity per sender is preserved even though bridges fan subsets

@@ -8,9 +8,9 @@ from .journal import (  # noqa: F401
     Journal,
     JournalError,
     MAGIC,
+    SEGMENT_NAME,
     list_segments,
     recover,
     recover_all,
-    segment_name,
     replay_journal,
 )

@@ -607,9 +607,8 @@ def replay_journal(database, path: str, truncate_tail: bool = True) -> int:
         # first, or new-type frames would land in a file whose header
         # promises the old delta encodings (a rolled-back build would
         # then classify the whole segment as corrupt mid-replay instead
-        # of refusing it cleanly at the header). Foreign lane segments
-        # (truncate_tail=False) belong to live siblings and are never
-        # touched.
+        # of refusing it cleanly at the header). Lane-named segments
+        # (truncate_tail=False) are never touched.
         _migrate_legacy_segment(path, msgs)
     # fully validated: only now touch the database. load_state (not bare
     # converge) for the same reason snapshots use it: this node's own
@@ -658,24 +657,21 @@ def _db_registry(database):
     return metrics.resolve_registry(database)
 
 
-def segment_name(lane_id: int | None) -> str:
-    """The journal segment filename for a lane (None / lane-less nodes
-    keep the classic ``journal.jylis``; lane k writes
-    ``journal.lane<k>.jylis`` so N lanes append independently)."""
-    if lane_id is None:
-        return "journal.jylis"
-    return f"journal.lane{lane_id}.jylis"
+# the one segment a node writes; `journal.lane<k>.jylis` files are what
+# a multi-lane node (a mode retired in PR 45) left: read at boot, never
+# written (docs/durability.md)
+SEGMENT_NAME = "journal.jylis"
 
 
 def list_segments(data_dir: str) -> list[str]:
-    """Every journal segment path in ``data_dir``, ANY lane naming —
-    the classic ``journal.jylis`` plus every ``journal.lane<k>.jylis``
+    """Every journal segment path in ``data_dir``: the node's own
+    ``journal.jylis`` plus every lane-named ``journal.lane<k>.jylis``
     (``.retiring``/``.unreadable`` variants are handled by recover,
     not listed here). Sorted for deterministic replay order (order is
     a formality: replay is lattice join)."""
     out = []
     for fname in sorted(os.listdir(data_dir)):
-        if fname == "journal.jylis" or (
+        if fname == SEGMENT_NAME or (
             fname.startswith("journal.lane") and fname.endswith(".jylis")
         ):
             out.append(os.path.join(data_dir, fname))
@@ -683,21 +679,16 @@ def list_segments(data_dir: str) -> list[str]:
 
 
 def recover_all(database, data_dir: str, own_path: str, log=None) -> int:
-    """Boot-path MERGE replay for multi-lane nodes: every lane's
-    segment (and its ``.retiring`` sibling) converges into this
-    database. Lattice join makes cross-segment overlap harmless, and a
-    node rebooted with a DIFFERENT lane count (or ``--lanes 1``) still
-    recovers every lane's accepted writes — segments are disjoint by
-    acceptance (each lane journals only batches its own serving path
-    flushed), and their union is the node's whole journaled state.
+    """Boot-path MERGE replay: the node's own segment and every
+    lane-named one beside it (each with its ``.retiring`` sibling)
+    converge into this database, so a directory an older multi-lane
+    node wrote boots whole. Lattice join makes overlap between segments
+    harmless.
 
-    Only the lane's OWN segment (``own_path``) gets the mutating
-    recovery (torn-tail truncation, ``.unreadable`` move-aside): a lane
-    restarting while its siblings are still serving reads THEIR
-    segments mid-append, so a foreign segment's torn tail is the
-    owner's live write, not a crash artifact — foreign segments replay
-    best-effort with no truncation and no rename, and whatever the
-    read missed converges in over the lane bus sync instead."""
+    Only the OWN segment (``own_path``) gets the mutating recovery
+    (torn-tail truncation, ``.unreadable`` move-aside, the legacy
+    re-stamp): the others replay best-effort with no truncation and no
+    rename — they are never this node's to write."""
     # the own segment recovers unconditionally (its .retiring sibling
     # can exist even when the active file does not — a crash between
     # rotate_begin's rename and the fresh open)
@@ -713,9 +704,7 @@ def recover_all(database, data_dir: str, own_path: str, log=None) -> int:
             try:
                 total += replay_journal(database, p, truncate_tail=False)
             except JournalError as e:
-                # a foreign lane's problem (or its live mid-write tail):
-                # never mutate another lane's file; the owner heals it
-                # and the bus sync heals us
+                # never mutate a file this node does not write
                 if log is not None:
                     log.warn() and log.w(
                         f"foreign journal segment skipped ({p}): {e}"

@@ -167,56 +167,10 @@ class Dispose:
 
 async def run(argv: list[str] | None = None) -> None:
     config = config_from_cli(argv)
-    if config.lanes > 1 and config.lane_id is None:
-        # multi-lane node: THIS process becomes the lane supervisor —
-        # it spawns one worker per lane (SO_REUSEPORT on the RESP port,
-        # loopback delta bus between them), restarts crashed lanes, and
-        # aggregates their metrics endpoints (lanes.py)
-        from . import lanes as lanes_mod
-
-        print(LOGO)
-        # argv=None means "parsed from sys.argv" (python -m jylis_tpu):
-        # the supervisor re-spawns workers from the SAME flag list, so
-        # it must see what argparse saw
-        await lanes_mod.run_supervisor(
-            config, sys.argv[1:] if argv is None else argv
-        )
-        return
-    if config.lane_id is not None and config.lanes > 1:
-        # one process per chip: lanes are processes, and an accelerator
-        # belongs to the first process that initialises it — a sibling
-        # lane would crash or hang at backend init and be respawned
-        # forever. Only the lane can see the platform (a supervisor that
-        # looked would itself hold the chip), so it reports with an exit
-        # code the supervisor treats as fatal (lanes.LANE_FATAL_EXIT).
-        try:
-            platform = jax.default_backend()
-        except RuntimeError as e:
-            # backend init refused: on an accelerator host the sibling
-            # lane holds the chip (or its lockfile) — the same wall
-            platform = f"unavailable ({str(e).splitlines()[0][:120]}…)"
-        if platform != "cpu":
-            from . import lanes as lanes_mod
-
-            config.log.err() and config.log.e(
-                f"--lanes {config.lanes} on platform {platform}: lanes are "
-                "processes and cannot share an accelerator; run --lanes 1 "
-                "(one process per chip)"
-            )
-            sys.exit(lanes_mod.LANE_FATAL_EXIT)
     if config.failpoints:
         # flag arming lands on top of any JYLIS_FAILPOINTS env arming
         # (faults.py parses the env at import); same spec syntax
         faults.arm_spec(config.failpoints)
-    lane_id = config.lane_id
-    if lane_id is not None:
-        from . import lanes as lanes_mod
-
-        # each lane is a distinct CRDT replica with a RESTART-STABLE
-        # identity (advertised address + lane ordinal, lanes.py)
-        identity = lanes_mod.lane_identity(config, lane_id)
-    else:
-        identity = config.addr.hash64()
     system = System(config)
     t_boot = time.perf_counter()
     jax.devices()  # backend init (seconds on a TPU), timed apart from ...
@@ -234,7 +188,9 @@ async def run(argv: list[str] | None = None) -> None:
     # globals it cleared)
     # jlint: blocking-ok — pre-serving boot; warmup above already built
     # and memoised the native lib, so this resolves from cache
-    database = Database(identity=identity, system_repo=system.repo)
+    database = Database(
+        identity=config.addr.hash64(), system_repo=system.repo
+    )
     # the loop's own time (loop.busy, jylis_loop_cpu_seconds_total)
     # records into this node's registry from here on (obs/loop.py)
     loop_mod.attach(database.metrics)
@@ -258,11 +214,6 @@ async def run(argv: list[str] | None = None) -> None:
         if s.strip()
     )
     log = config.log
-    if lane_id is not None:
-        # SYSTEM METRICS' LANE section: which lane this connection
-        # landed on, out of how many (clients pin lane-affine reads by
-        # reconnecting until the id matches)
-        system.repo.lane_fn = lambda: {"id": lane_id, "count": config.lanes}
 
     snapshot_path = ""
     journal = None
@@ -271,20 +222,14 @@ async def run(argv: list[str] | None = None) -> None:
     # has no clients to stall, and sequencing recovery before serving is
     # the point — each site carries its own suppression
     if config.data_dir:
-        from . import lanes as lanes_mod
-
         # jlint: blocking-ok — pre-serving boot, no clients on the loop
         os.makedirs(config.data_dir, exist_ok=True)
-        snapshot_path = os.path.join(
-            config.data_dir, lanes_mod.snapshot_name(lane_id)
-        )
-        # restore EVERY snapshot present (own lane's plus any sibling
-        # or previous-lane-count file): restore is lattice convergence,
-        # so overlap is a no-op and a changed --lanes never strands
-        # state. Only the OWN file is moved aside when unreadable — a
-        # sibling lane may be alive and writing its own.
-        # jlint: blocking-ok — pre-serving boot, no clients on the loop
-        for spath in lanes_mod.list_snapshots(config.data_dir):
+        snapshot_path = os.path.join(config.data_dir, persist.SNAPSHOT_NAME)
+        # restore EVERY snapshot present (the node's own plus any
+        # `snapshot.lane<k>.jylis` an older multi-lane node left):
+        # restore is lattice convergence, so overlap is a no-op. Only
+        # the OWN file is ever written or moved aside.
+        for spath in persist.list_snapshots(config.data_dir):
             try:
                 n = persist.load_snapshot(database, spath)
                 log.info() and log.i(
@@ -308,10 +253,10 @@ async def run(argv: list[str] | None = None) -> None:
             # recovery ordering: snapshot first, then the journal tail —
             # though lattice join makes the order a formality (overlap
             # between snapshot and journal converges to the same state).
-            # Merge replay: every lane segment converges (the own one
-            # with truncation/move-aside, live siblings' read-only).
+            # Merge replay: the own segment with truncation/move-aside,
+            # any lane-named segment an older node left read-only.
             journal_path = os.path.join(
-                config.data_dir, journal_mod.segment_name(lane_id)
+                config.data_dir, journal_mod.SEGMENT_NAME
             )
             n = journal_mod.recover_all(
                 database, config.data_dir, journal_path, log
@@ -338,54 +283,14 @@ async def run(argv: list[str] | None = None) -> None:
     )
 
     server = Server(config, database)
-    lane_tick_task = None
-    if lane_id is None:
-        # jlint: blocking-ok — Cluster construction reads/writes the
-        # tiny boot-epoch sidecar (pre-serving boot, no clients on the
-        # loop yet; cluster.py Cluster._boot_epoch)
-        cluster = Cluster(config, database)
-    else:
-        from . import lanes as lanes_mod
-
-        # the lane bus: the existing cluster engine on loopback — wire
-        # framing, CRC, delta broadcast, digest-checked rejoin sync and
-        # dial backoff all inherited. Lane 0 additionally runs the
-        # node's ONE external cluster identity and bridges the meshes.
-        # jlint: blocking-ok — Cluster construction reads/writes the
-        # tiny boot-epoch sidecar (pre-serving boot, no clients yet)
-        bus = Cluster(
-            lanes_mod.bus_config(config, lane_id),
-            database,
-            register_system=(lane_id != 0),
-        )
-        external = None
-        if lane_id == 0:
-            # jlint: blocking-ok — same pre-serving epoch-sidecar I/O
-            external = Cluster(config, database, drive_flush=False)
-            lanes_mod.wire_bridge(bus, external)
-        cluster = lanes_mod.LaneClusters(bus, external)
-
-        async def _lane_tick() -> None:
-            # the lane-crash drill seam: arming `lane.tick=crash` in ONE
-            # lane's env (supervisor: JYLIS_LANE_FAILPOINTS="1:lane.tick
-            # =crash:1") kills that worker mid-traffic, deterministically.
-            # error degrades to a log line, sleep just delays the tick.
-            while True:
-                await asyncio.sleep(0.25)
-                try:
-                    await faults.async_point("lane.tick")
-                except faults.FaultError:
-                    log.warn() and log.w("lane.tick failpoint fired")
-
-        lane_tick_task = asyncio.create_task(_lane_tick())
+    # jlint: blocking-ok — Cluster construction reads/writes the tiny
+    # boot-epoch sidecar (pre-serving boot, no clients on the loop yet;
+    # cluster.py Cluster._boot_epoch)
+    cluster = Cluster(config, database)
     await server.start()
     # SYSTEM TOPOLOGY advertises the node's RESP port (cluster-aware
-    # client discovery, client.py) — known only after listen, pushed
-    # onto whichever cluster object registered the system hooks (the
-    # single-node Cluster, or the lane bus + lane 0's external identity)
-    for sub in getattr(cluster, "clusters", [cluster]):
-        if hasattr(sub, "resp_port"):
-            sub.resp_port = int(server.port)
+    # client discovery, client.py) — known only after listen
+    cluster.resp_port = int(server.port)
     await cluster.start()
     metrics_http = None
     if config.metrics_port:
@@ -408,9 +313,7 @@ async def run(argv: list[str] | None = None) -> None:
             )
         )
 
-    if lane_id is None:
-        print(LOGO)  # lane workers skip it: one logo per NODE, not per lane
-    log = config.log
+    print(LOGO)
     from . import __version__
 
     log.info() and log.i(f"jylis-tpu version: {__version__}")
@@ -424,8 +327,6 @@ async def run(argv: list[str] | None = None) -> None:
             "serving engine: python tables (native library unavailable)"
         )
     log.info() and log.i(f"cluster address: {config.addr}")
-    if lane_id is not None:
-        log.info() and log.i(f"serving lane {lane_id}/{config.lanes}")
     log.info() and log.i(f"serving clients on port: {server.port}")
     if metrics_http is not None:
         log.info() and log.i(f"metrics endpoint on port: {metrics_http.port}")
@@ -438,8 +339,6 @@ async def run(argv: list[str] | None = None) -> None:
         _dump_trace(database, log)
         raise
     finally:
-        if lane_tick_task is not None:
-            lane_tick_task.cancel()
         if metrics_http is not None:
             await metrics_http.dispose()
 
@@ -579,7 +478,7 @@ async def _snapshot_loop(
 def main(argv: list[str] | None = None) -> None:
     try:
         # the node's loop is a selector loop with a timing selector
-        # (obs/loop.py); a lane worker enters here too
+        # (obs/loop.py)
         asyncio.run(run(argv), loop_factory=loop_mod.new_event_loop)
     except KeyboardInterrupt:
         pass

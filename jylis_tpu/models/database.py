@@ -449,7 +449,7 @@ class Database:
             # the digest takes every DATA repo's lock in turn, which
             # only the async path can await. The hex of the combined
             # per-type digest — equal bytes on converged replicas, so
-            # "are these nodes (or lanes) converged?" is answerable
+            # "are these nodes converged?" is answerable
             # from any Redis client.
             digest = await self.sync_digest_async()
             resp.string(digest.hex().encode())

@@ -120,10 +120,6 @@ class RepoSYSTEM:
         # wired as `metrics` like every repo. None (a standalone
         # RepoSYSTEM) reads the process DEFAULT via resolve_registry.
         self.metrics = None
-        # main.py wires this on lane workers: {"id": k, "count": n} for
-        # the LANE section of SYSTEM METRICS (which lane a connection
-        # landed on); None on single-lane nodes — no section
-        self.lane_fn = None
         # the owning Database wires this to its single-threaded digest
         # computation (the async serving path intercepts SYSTEM DIGEST
         # in Database.apply_async instead — it must await repo locks)
@@ -168,7 +164,6 @@ class RepoSYSTEM:
                 self.serving_fn() if self.serving_fn else None,
                 self.cluster_fn() if self.cluster_fn else None,
                 registry=self.metrics,
-                lane=self.lane_fn() if self.lane_fn else None,
                 session=self.session_fn() if self.session_fn else None,
                 overload=self.overload_fn() if self.overload_fn else None,
             )

@@ -81,7 +81,7 @@ def built_hash() -> str | None:
 def build() -> bool:
     """Compile native/*.cpp into the in-checkout .so and record the source
     hash beside it. Both files land by rename, so a concurrent loader
-    (lanes, parallel tests) sees the old pair or the new one."""
+    (a second node, parallel tests) sees the old pair or the new one."""
     units = [p for p in _sources() if p.endswith(".cpp")]
     if not units:
         return False

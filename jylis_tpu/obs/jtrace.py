@@ -4,7 +4,7 @@ A span is a tiny append-only byte string carried on SEQUENCED cluster
 frames (schema v11's transport-only ``span`` field — delta signatures
 untouched). The origin node mints one for 1-in-N sequenced flushes
 (``--trace-sample``); every hop the frame crosses appends a stamp
-(origin lane, lane bus, external cluster, bridge relay), and the final
+(origin, bridge relay), and the final
 receiver appends its apply stamp and folds the whole chain into
 convergence-latency histograms — per hop transition, and end-to-end per
 (origin region, apply region) pair. The worst chains seen are kept as
@@ -41,8 +41,11 @@ from .hist import Histogram
 
 # hop tags, in the order a write crosses them
 HOP_ORIGIN = 1  # minted where broadcast_deltas sequenced the flush
-HOP_BUS = 2  # the lane bus (intra-node fan-out between lanes)
-HOP_CLUSTER = 3  # the external WAN cluster leg (lane 0's bridge tee)
+# 2 and 3 are RESERVED wire tags: the multi-lane mode (retired in PR 45)
+# stamped them on its loopback bus and its external leg. No code stamps
+# them now; a chain minted by an older node still decodes and renders.
+HOP_BUS = 2
+HOP_CLUSTER = 3
 HOP_RELAY = 4  # a bridge relayed it onward (origin-preserving)
 HOP_APPLY = 5  # the receiving replica applied it (appended at fold)
 
@@ -149,8 +152,8 @@ class SpanStats:
     pass 5 rightly refuses dynamic names through hist()/gauge_set().
     This class IS the declared surface — prom.py renders it wholesale.
 
-    Thread-safe under a lock: lanes fold on their own loop threads, and
-    SYSTEM TRACE SPANS / the scrape read from another.
+    Thread-safe under a lock: the fold and the readers (SYSTEM TRACE
+    SPANS, the scrape) need not share a thread.
     """
 
     def __init__(self, slo_ms: tuple[int, ...] = DEFAULT_SLO_MS):

@@ -70,7 +70,7 @@ class TimingSelector(selectors.DefaultSelector):
 
 
 def new_event_loop() -> asyncio.AbstractEventLoop:
-    """The node's loop (main.py, every lane worker): a plain selector
+    """The node's loop (main.py): a plain selector
     loop whose selector is a `TimingSelector`."""
     selector = TimingSelector()
     loop = asyncio.SelectorEventLoop(selector)

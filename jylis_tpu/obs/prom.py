@@ -152,7 +152,7 @@ def render(database) -> str:
     # the same seams as REAL cumulative histograms (satellite of the
     # jtrace round): quantile gauges above are convenient but opaque to
     # PromQL — histogram_quantile()/Grafana need `_bucket` series, and
-    # cumulative bucket counters sum correctly across lanes where a
+    # cumulative bucket counters sum correctly across nodes where a
     # quantile never does. Distinct family name: one family cannot be
     # both summary and histogram.
     out.append(
@@ -186,8 +186,8 @@ def render(database) -> str:
 
     # fleet convergence SLOs (obs/jtrace.py): the fraction of sampled
     # deltas fully applied within each --converge-slo-ms threshold,
-    # plus the raw counters the lane aggregator re-derives node-wide
-    # fractions from (fractions are not summable; counts are)
+    # plus the raw counters a fleet-wide fraction is re-derived from
+    # (fractions are not summable; counts are)
     out.append(
         "# HELP jylis_converge_slo Fraction of sampled deltas applied "
         "within le milliseconds end to end."
@@ -249,22 +249,13 @@ def render(database) -> str:
 
 class MetricsHTTP:
     """GET /metrics on ``port`` (0 = ephemeral; the bound port is
-    `.port`). Anything else is a 404; malformed requests just close.
+    `.port`). Anything else is a 404; malformed requests just close."""
 
-    ``render_async`` swaps the body producer (an async () -> str): the
-    lane supervisor's aggregated endpoint (lanes.py) reuses this whole
-    responder — request parse, bounded header drain, status handling —
-    with its own multi-lane render."""
-
-    def __init__(self, database, port: int, log=None, render_async=None):
+    def __init__(self, database, port: int, log=None):
         self._database = database
         self._want_port = port
         self._log = log
         self._server: asyncio.base_events.Server | None = None
-        self._render = render_async or self._render_default
-
-    async def _render_default(self) -> str:
-        return render(self._database)
 
     async def start(self) -> None:
         self._server = await asyncio.start_server(
@@ -293,7 +284,7 @@ class MetricsHTTP:
             if len(parts) >= 2 and parts[0] == b"GET" and (
                 parts[1] == b"/metrics" or parts[1].startswith(b"/metrics?")
             ):
-                body = (await self._render()).encode()
+                body = render(self._database).encode()
                 head = (
                     b"HTTP/1.1 200 OK\r\n"
                     b"Content-Type: text/plain; version=0.0.4; charset=utf-8\r\n"

@@ -28,6 +28,11 @@ from .cluster.msg import MsgPushDeltas
 
 MAGIC = b"JYLSNAP1"
 
+# the one snapshot a node writes; `snapshot.lane<k>.jylis` files are what
+# a multi-lane node (a mode retired in PR 45) left: restored at boot,
+# never written (docs/durability.md)
+SNAPSHOT_NAME = "snapshot.jylis"
+
 # how many type batches a snapshot of each legacy era actually wrote:
 # the v1-v3 full-signature era and the v4-v6 delta-signature era both
 # had five data types + SYSTEM; the v7/v8 era added TENSOR. Keyed by
@@ -36,6 +41,19 @@ MAGIC = b"JYLSNAP1"
 _LEGACY_TYPE_BATCHES = dict(
     zip(codec.legacy_snapshot_signatures(), (6, 6, 6, 6, 7))
 )
+
+
+def list_snapshots(data_dir: str) -> list[str]:
+    """Every snapshot file in ``data_dir``, the node's own and any
+    lane-named one, sorted — boot restores all of them (restore is
+    lattice convergence; overlap is a no-op)."""
+    out = []
+    for fname in sorted(os.listdir(data_dir)):
+        if fname == SNAPSHOT_NAME or (
+            fname.startswith("snapshot.lane") and fname.endswith(".jylis")
+        ):
+            out.append(os.path.join(data_dir, fname))
+    return out
 
 
 def save_snapshot(database, path: str) -> None:

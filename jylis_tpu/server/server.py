@@ -534,26 +534,15 @@ class Server:
 
     async def start(self) -> None:
         try:
-            if getattr(self._config, "lanes", 1) > 1:
-                # multi-lane serving: every lane binds the SAME port
-                # with SO_REUSEPORT and the kernel shards accepted
-                # connections across the lane processes — no userspace
-                # acceptor, no fd passing. IPv4-only in this mode (each
-                # family would otherwise need its own shared socket).
-                sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
-                sock.bind(("0.0.0.0", int(self._config.port)))
-                where = {"sock": sock}
-            else:
-                where = {"host": None, "port": int(self._config.port)}
+            port = int(self._config.port)
             # which listener is decided once, from what the database is
             if self._engine is not None:
                 self._server = await asyncio.get_running_loop().create_server(
-                    lambda: _Conn(self), **where
+                    lambda: _Conn(self), host=None, port=port
                 )
             else:
                 self._server = await asyncio.start_server(
-                    self._handle_client, **where
+                    self._handle_client, host=None, port=port
                 )
         except OSError as e:
             self._log.err() and self._log.e(f"server listen failed: {e}")

@@ -1,7 +1,7 @@
 """Session guarantees: read-your-writes / monotonic-reads tokens.
 
 The paper's store is eventually consistent: a client that writes on one
-replica (or one serving lane) and reads on another can observe its own
+replica and reads on another can observe its own
 write missing — fine for a single LAN socket, disqualifying for a
 system serving one logical session across many replicas. This module
 cashes in the schema-v8 delta-interval machinery for a client-visible
@@ -40,10 +40,10 @@ ways). Adoption is also what heals a rebooted origin: its seq counter
 restarts, so each boot mints a fresh rid (address + boot epoch) and the
 old incarnation's entries survive on peers, frozen and adoptable.
 
-Tokens survive a client bouncing across lanes because the lane bus IS a
-cluster (each lane's vector tracks its siblings' bus streams), and
-across replicas/regions because bridges relay foreign streams with
-origin attribution preserved (``MsgRelayPush``). docs/sessions.md has
+Tokens survive a client bouncing across replicas because each node's
+vector tracks its peers' streams, and across regions because bridges
+relay foreign streams with origin attribution preserved
+(``MsgRelayPush``). docs/sessions.md has
 the token format, the guarantee matrix, and the STALE/BUSY contracts.
 """
 
@@ -157,8 +157,7 @@ def dominates(vec: dict[str, int], token: dict[str, int]) -> bool:
 # bytes on every read of a session, so the serving path pays the full
 # decode+CRC once per distinct token instead of once per command.
 # Bounded by wholesale clear; values are treated as immutable by every
-# caller (declared in scripts/jlint/lanes_manifest.json — a pure
-# derived-data cache, so per-lane copies are trivially correct).
+# caller (a pure derived-data cache).
 _DECODE_MEMO: dict[bytes, dict[str, int]] = {}
 _DECODE_MEMO_CAP = 128
 
@@ -213,7 +212,7 @@ def _r_varint(data: bytes, pos: int) -> tuple[int, int]:
 
 
 class SessionIndex:
-    """One node's (or lane's) applied-interval vector + waiter queue.
+    """One node's applied-interval vector + waiter queue.
 
     Owned by the Database; fed by the cluster engine: ``note_local``
     after every flush that sequenced own batches, ``note_applied`` after

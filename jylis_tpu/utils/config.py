@@ -97,15 +97,6 @@ class Config:
     # sampled deltas fully applied within each of these milliseconds
     # bounds, exported as the jylis_converge_slo gauge family
     converge_slo_ms: str = "50,250,1000"
-    # extension: multi-lane serving (lanes.py) — N worker processes
-    # sharing the RESP port via SO_REUSEPORT, converging over a loopback
-    # delta bus. lanes=1 is the classic single-process node; lane_id is
-    # set ONLY in spawned lane workers (None = supervisor / single-lane);
-    # lane_bus is the comma-joined list of every lane's bus port.
-    lanes: int = 1
-    lane_id: int | None = None
-    lane_bus: list[int] = field(default_factory=list)
-    lane_bus_heartbeat: float = 0.25
     log: Log = field(default_factory=Log.create_none)
 
     def normalize(self) -> None:
@@ -114,17 +105,8 @@ class Config:
             self.addr = Address(self.addr.host, self.addr.port, generate_name(rng))
 
 
-def resolve_auto_lanes(cpus: int | None = None) -> int:
-    """``--lanes auto``: 1 below 4 host cores (a lane split would just
-    contend), else the core count capped at 8 (past that the loopback
-    bus and the shared accelerator dominate)."""
-    import os
-
-    n = cpus if cpus is not None else (os.cpu_count() or 1)
-    return 1 if n < 4 else min(n, 8)
-
-
-def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
+def build_parser() -> argparse.ArgumentParser:
+    """Every flag the node takes (docs/operations.md, "Flags")."""
     parser = argparse.ArgumentParser(
         prog="jylis-tpu",
         description="TPU-native distributed in-memory database for CRDTs",
@@ -308,7 +290,7 @@ def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
         "--trace-sample", type=int, default=Config.trace_sample,
         help="Delta provenance tracing (docs/observability.md): one "
         "sequenced delta frame in N carries a trace span stamped at "
-        "every hop (origin lane, lane bus, cluster, bridge relay); the "
+        "every hop (origin, bridge relay); the "
         "applying node folds it into per-hop and per-region-pair "
         "convergence-latency histograms (SYSTEM TRACE SPANS) and the "
         "convergence SLO gauges. Schema v11 transport field — v10 "
@@ -323,29 +305,6 @@ def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
         "that bound end to end (jylis_converge_slo, SYSTEM OBSERVE).",
     )
     parser.add_argument(
-        "--lanes", default="1",
-        help="Serving lanes: N worker processes each owning a full "
-        "ServeEngine/Database/journal-segment/metrics stack, sharing "
-        "the RESP port via SO_REUSEPORT and converging over a loopback "
-        "delta bus (the same wire-delta plumbing the cluster uses — "
-        "CRDT join makes the lanes coordination-free). 'auto' picks "
-        "from the host core count (1 on hosts with < 4 cores, else "
-        "cores capped at 8); 1 (default) is the classic single-process "
-        "node. See docs/operations.md, 'Serving and host cores'.",
-    )
-    parser.add_argument(
-        "--lane-id", type=int, default=None, help=argparse.SUPPRESS,
-    )  # internal: set by the lane supervisor on spawned workers
-    parser.add_argument(
-        "--lane-bus", default="", help=argparse.SUPPRESS,
-    )  # internal: comma-joined bus ports, one per lane, supervisor-set
-    parser.add_argument(
-        "--lane-bus-heartbeat", type=float, default=0.25,
-        help="Heartbeat seconds for the intra-node lane bus (cross-lane "
-        "convergence cadence; the proactive flush still ships deltas "
-        "within 500 ms of a write). Only meaningful with --lanes > 1.",
-    )
-    parser.add_argument(
         "-L", "--log-level", default="info",
         help="Maximum level of detail for logging (error, warn, info, or debug).",
     )
@@ -354,6 +313,11 @@ def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
     parser.add_argument(
         "--version", action="version", version=f"jylis-tpu {__version__}",
     )
+    return parser
+
+
+def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
+    parser = build_parser()
     args = parser.parse_args(argv)
     if args.snapshot_interval > 0 and not args.data_dir:
         parser.error("--snapshot-interval requires --data-dir")
@@ -407,20 +371,6 @@ def config_from_cli(argv: list[str] | None = None, log_out=None) -> Config:
             f"milliseconds: {args.converge_slo_ms!r}"
         )
     config.converge_slo_ms = args.converge_slo_ms
-    if args.lanes == "auto":
-        config.lanes = resolve_auto_lanes()
-    else:
-        try:
-            config.lanes = int(args.lanes)
-        except ValueError:
-            parser.error(f"--lanes must be an integer or 'auto': {args.lanes}")
-        if config.lanes < 1:
-            parser.error("--lanes must be >= 1")
-    config.lane_id = args.lane_id
-    config.lane_bus = [int(p) for p in args.lane_bus.split(",") if p]
-    config.lane_bus_heartbeat = args.lane_bus_heartbeat
-    if config.lane_id is not None and len(config.lane_bus) != config.lanes:
-        parser.error("--lane-id requires --lane-bus with one port per lane")
 
     level = {"error": "err", "warn": "warn", "info": "info", "debug": "debug"}.get(
         args.log_level

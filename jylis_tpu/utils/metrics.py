@@ -165,7 +165,6 @@ def metric_lines(
     serving: dict[str, int] | None = None,
     cluster: dict[str, int] | None = None,
     registry: MetricsRegistry | None = None,
-    lane: dict[str, int] | None = None,
     session: dict[str, int] | None = None,
     overload: dict[str, int] | None = None,
 ) -> list[str]:
@@ -186,13 +185,6 @@ def metric_lines(
     lines = [
         f"{name} cmds {n}" for name, n in sorted((served or {}).items()) if n
     ]
-    if lane is not None:
-        # multi-lane nodes lead with which lane this connection landed
-        # on (SO_REUSEPORT picked it) — the one fact a client needs to
-        # interpret every per-lane counter below, and what the lane
-        # drills use to address a specific worker
-        lines.insert(0, f"LANE count {lane.get('count', 0)}")
-        lines.insert(0, f"LANE id {lane.get('id', 0)}")
     if serving and any(serving.values()):
         for k in ("native_cmds", "demoted_cmds") + SERVING:
             lines.append(f"SERVING {k} {serving.get(k, 0)}")

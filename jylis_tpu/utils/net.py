@@ -6,8 +6,8 @@ import socket
 
 
 def free_port() -> int:
-    """Reserve-and-release an ephemeral loopback port (the lane
-    supervisor's bus/metrics port picks). The tiny race
+    """Reserve-and-release an ephemeral loopback port (chip_smoke.py's
+    node ports). The tiny race
     — another process binding it before the intended owner does — is
     the standard trade every spawning test in this repo already
     makes."""

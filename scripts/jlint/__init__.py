@@ -34,10 +34,9 @@ re-record, and each gets a dedicated analysis pass:
   the committed ``scripts/jlint/metrics_manifest.json`` AND
   pre-registered in ``jylis_tpu/obs/__init__.py``; stale entries and
   dead declarations fail, so the scrapeable surface stays reviewed.
-* **Pass 6 — cross-lane shared-state discipline** (`pass_lanes`, rules
-  JL6xx): every module-level mutable in ``jylis_tpu/`` is per-LANE
-  state under ``--lanes N`` and must be declared in the committed
-  ``lanes_manifest.json`` with why per-process copies are correct.
+* Pass 6 (rules JL6xx) is RETIRED: it declared the module-level
+  mutables that the multi-lane mode copied per process, and went with
+  that mode in PR 45. The other passes keep their numbers.
 
 jlint v2 adds a shared INTERPROCEDURAL core (``core.py`` +
 ``graph.py``: per-project module/call graph with no-false-edge
@@ -147,8 +146,6 @@ RULES = {
     "JL402": (None, "failpoints manifest entry stale, missing, or undescribed"),
     "JL501": (None, "metric name non-literal, not declared in metrics_manifest.json, or not pre-registered in obs"),
     "JL502": (None, "metrics manifest / obs declaration stale, missing, or undescribed"),
-    "JL601": ("lane-shared-ok", "module-level mutable (per-LANE state under --lanes N) not declared in lanes_manifest.json"),
-    "JL602": (None, "lanes manifest entry stale, missing, or undescribed"),
     "JL701": (None, "codec encoder/decoder field sequences diverge (order/width/endianness drift)"),
     "JL702": (None, "codec field written but never consumed, or decoder reads past the wire shape"),
     "JL703": (None, "codec manifest drift or missing (--write-manifest regenerates)"),

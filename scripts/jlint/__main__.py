@@ -3,11 +3,11 @@
 Exit 0 only when every pass is clean: no unsuppressed finding, no stale
 baseline entry or inline suppression, no manifest drift. One semantic
 core (scripts/jlint/core.py) is built per run — content-hash-cached
-ASTs, call graph, per-function summaries — and all eleven passes
+ASTs, call graph, per-function summaries — and all ten passes (1-5, 7-11)
 consume it.
 
 * ``--write-manifest`` regenerates every committed manifest (parity,
-  failpoints, metrics, lanes, codec, lattice + the generated lattice
+  failpoints, metrics, codec, lattice + the generated lattice
   property harness, protocol atlas, semantics + the generated
   differential fuzz harness) in place and exits: commit the diff.
 * ``--write-corpus`` regenerates the golden codec corpus
@@ -19,7 +19,7 @@ consume it.
   line, message, suppressed) plus per-pass wall times — the CI artifact
   finding-count drift is diffed across.
 * ``--budget`` enforces the recorded wall-time bound in
-  scripts/jlint/budget.json: eleven passes must not erode the commit
+  scripts/jlint/budget.json: ten passes must not erode the commit
   loop, so `make lint` fails if the run blows the budget.
 """
 
@@ -43,7 +43,6 @@ from . import (
     pass_codec,
     pass_failpoints,
     pass_jax,
-    pass_lanes,
     pass_lattice,
     pass_locks,
     pass_metrics,
@@ -61,7 +60,7 @@ JAX_SCOPE = ("jylis_tpu/ops",)
 
 BUDGET_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "budget.json")
 
-N_PASSES = 11
+N_PASSES = 10  # 1-5 and 7-11: pass 6 went with the multi-lane mode
 
 
 def run_all(
@@ -104,7 +103,6 @@ def run_all(
     findings = timed("1:async", pass_async.run, async_sources)
     findings += timed("1:async", pass_async.run_interprocedural, project)
     findings += timed("2:jax", pass_jax.run, jax_sources)
-    findings += timed("6:lanes", pass_lanes.check)
     findings += timed("8:lattice", pass_lattice.run, project)
     findings += timed("9:locks", pass_locks.run, project)
     by_rel = project.by_rel
@@ -211,12 +209,6 @@ def write_manifests(project: Project | None = None) -> None:
         f"metrics manifest written: {len(mets)} metrics"
         + (f" ({todo} need descriptions)" if todo else "")
     )
-    lns = pass_lanes.write_manifest()
-    todo = sum(1 for d in lns.values() if d == pass_lanes.PLACEHOLDER)
-    print(
-        f"lanes manifest written: {len(lns)} module-level mutables"
-        + (f" ({todo} need descriptions)" if todo else "")
-    )
     cdc = pass_codec.write_manifest()
     print(
         f"codec manifest written: {len(cdc['units'])} units, "
@@ -268,7 +260,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--write-manifest", action="store_true",
         help="regenerate every committed manifest (parity, failpoints, "
-        "metrics, lanes, codec, lattice + property harness, protocol, "
+        "metrics, codec, lattice + property harness, protocol, "
         "semantics + fuzz harness; descriptions preserved) and exit",
     )
     ap.add_argument(

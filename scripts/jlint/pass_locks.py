@@ -1,7 +1,7 @@
 """Pass 9 — cross-thread lock-order analysis (rules JL901/JL902/JL903).
 
 The node is one asyncio loop + a journal writer thread + to_thread
-drain workers + lane worker processes, coordinating through a handful
+drain workers, coordinating through a handful
 of threading locks and condition variables. The three failure shapes
 this pass mechanises are the ones reviews kept having to re-derive by
 hand from multi-function context:
@@ -14,7 +14,7 @@ hand from multi-function context:
 * **JL902 — lock-acquisition cycle**: the global lock graph — an edge
   A→B whenever B is acquired while A is held, in one function or
   through any resolved call chain — must be acyclic, across the
-  thread/loop/lane seams. A cycle is a potential deadlock the drill
+  thread/loop seams. A cycle is a potential deadlock the drill
   matrix can only hit probabilistically; here it is structural.
   Lock identity is class-scoped (``Journal._cv``); acquiring the SAME
   attribute on several instances (the ordered ``Database.all_locks``

@@ -1,8 +1,7 @@
 """Pass 10 — protocol atlas (rules JL1001/JL1002/JL1003).
 
 The cluster protocol is ~6 message kinds × an active/passive role split
-× a per-address dial state machine × the sync-serve machinery — and the
-lane bus/bridge rides the same engine. Until this pass its full
+× a per-address dial state machine × the sync-serve machinery. Until this pass its full
 transition relation lived only in the heads of whoever last read
 ``cluster.py``; the drill matrix samples behaviours, it does not pin
 them. This pass extracts, statically, what every handler is PERMITTED

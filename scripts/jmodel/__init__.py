@@ -4,7 +4,7 @@ jlint pass 10 (the protocol atlas) pins what the protocol *is*; this
 package exhaustively explores what it *does*. It drives the REAL
 ``jylis_tpu.cluster.Cluster`` handler code — dial state machine,
 handshake, read loop, every message handler, the sync-serve machinery,
-the held queue, and the lane bus/bridge — over an in-memory
+the held queue, and the region bridge — over an in-memory
 deterministic network (``net.py``): a virtual clock that advances only
 when the explorer says so, and an in-memory pipe transport injected
 through the ``clock=`` / ``connect=`` seams ``Cluster`` grew for
@@ -15,7 +15,7 @@ substitutions are the wall clock, the TCP socket, and the Database
 
 The explorer (``explore.py``) enumerates delivery schedules — reorder
 across connections, drop (connection kill), duplicate, partition,
-crash-reboot-from-journal — over 2-node, 3-node and 2-lane-bus
+crash-reboot-from-journal — over 2-node, 3-node and 3-node-2-region
 configurations to a bounded depth, with state-hash deduplication and a
 sleep-set partial-order reduction (independent actions on distinct
 receiving instances are explored in one order, not all orders).
@@ -28,7 +28,7 @@ Invariants checked at every distinct state:
 and at quiescence (deliver everything, heal everything, tick until
 stable):
 
-* digest match on every replica (nodes or lanes — the convergence
+* digest match on every replica (the convergence
   guarantee the periodic digest exchange promises);
 * no stranded rtt stamps (every Pong-soliciting send on a live conn
   eventually matched);

@@ -3,7 +3,7 @@
 Modes:
 
 * ``--smoke`` — the per-commit gate: bounded exploration of all three
-  configurations (2-node, 3-node, 2-lane-bus) at the committed depths,
+  configurations (2-node, 3-node, 3-node-2-region) at the committed depths,
   asserting every invariant AND the recorded coverage floor
   (``model_min_states`` in scripts/jlint/budget.json — a refactor that
   silently collapses the explored space fails loudly). ``--budget``
@@ -38,7 +38,7 @@ BUDGET_PATH = os.path.join(
 )
 
 # committed smoke parameters (depth, quiesce-every): deep enough that
-# the four frontiers together clear the recorded model_min_states
+# the three frontiers together clear the recorded model_min_states
 # floor (budget.json), shallow enough for the per-commit budget. The
 # v10 sessions/regions axes (a mint action per group, the regions3
 # config with its bridge relays and session invariants) grow the
@@ -47,12 +47,11 @@ BUDGET_PATH = os.path.join(
 # nodes2 drops from depth 6 to 5 with the v10 mint axis: the sessions
 # action roughly doubled its per-depth branching, and depth 6 alone ran
 # 112k states / 305s — past the whole budget. Depth 5 keeps the config
-# at ~23k states while the three NEW-coverage configs (lane bus,
-# regions, plus nodes3's gossip discovery) spend the rest of the box.
+# at ~23k states while the two NEW-coverage configs (regions, plus
+# nodes3's gossip discovery) spend the rest of the box.
 SMOKE_PARAMS = {
     "nodes2": (5, 24),
     "nodes3": (4, 16),
-    "lanes2": (4, 16),
     "regions3": (4, 16),
 }
 

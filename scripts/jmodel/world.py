@@ -1,6 +1,6 @@
 """The explorable world: real Clusters over the model network.
 
-A ``World`` is one configuration (2-node, 3-node, or 2-lane-bus) of
+A ``World`` is one configuration (2-node, 3-node, or 3-node-2-region) of
 REAL ``Cluster`` instances wired to ``net.py``'s in-memory transport
 and virtual clock, each over a ``ModelDatabase`` — a minimal host-side
 GCOUNT lattice (pointwise-max join, the paper's canonical delta CRDT)
@@ -42,7 +42,6 @@ from concurrent.futures import ThreadPoolExecutor
 from jylis_tpu import sessions as sessions_mod
 from jylis_tpu.cluster import cluster as cluster_mod
 from jylis_tpu.cluster.cluster import Cluster
-from jylis_tpu.lanes import wire_bridge
 from jylis_tpu.obs.registry import MetricsRegistry
 from jylis_tpu.ops import compose
 from jylis_tpu.ops.bcount import BCount
@@ -53,7 +52,7 @@ from jylis_tpu.utils.log import Log
 
 from .net import Network, VirtualClock
 
-CONFIG_NAMES = ("nodes2", "nodes3", "lanes2", "regions3")
+CONFIG_NAMES = ("nodes2", "nodes3", "regions3")
 
 TICK_MS = 100  # virtual ms per heartbeat action
 
@@ -123,8 +122,9 @@ class ModelDatabase:
     surface the Cluster consumes, producing real codec-shaped deltas.
     GCOUNT is the scalar delta payload; TENSOR (element-wise-max mode,
     dim-2 vectors — ops/tensor_host.Tensor, the REAL wire object) makes
-    every explored schedule also carry a non-scalar binary payload over
-    the bus and bridge. One ``write`` action mutates both lattices (the
+    every explored schedule also carry a non-scalar binary payload
+    over the wire and the region bridge. One ``write`` action mutates
+    both lattices (the
     tensor cell is a deterministic function of the counter write), so
     the frontier does not grow a second write axis. ``journal`` is the
     WAL analog: local writes survive a crash-reboot (the tensor write
@@ -509,8 +509,8 @@ class ModelDatabase:
 
 class Instance:
     """One Cluster's place in the world. ``group`` is the
-    crash/partition granularity (a lane-split node is one group with
-    two instances: the bus and the external cluster)."""
+    crash/partition granularity (one instance a group in every
+    configuration left)."""
 
     def __init__(self, key: str, group: str, addr: Address):
         self.key = key
@@ -734,15 +734,12 @@ class World:
 
     # ---- construction ------------------------------------------------------
 
-    def _spawn(self, key, group, addr, seeds, db, drive_flush=True,
-               register_system=True, region="") -> Instance:
+    def _spawn(self, key, group, addr, seeds, db, region="") -> Instance:
         inst = Instance(key, group, addr)
         inst.database = db
         inst.cluster = Cluster(
             _mk_config(addr, seeds, region, self.bridge_unsafe),
             db,
-            drive_flush=drive_flush,
-            register_system=register_system,
             clock=self.clock,
             connect=self.net.connect_fn(inst),
         )
@@ -772,15 +769,6 @@ class World:
             self._node_group("foo", addrs["foo"], [], rid=1)
             self._node_group("bar", addrs["bar"], [addrs["foo"]], rid=2)
             self._node_group("baz", addrs["baz"], [addrs["foo"]], rid=3)
-        elif self.config_name == "lanes2":
-            # external node E + a 2-lane node N (bus + bridge)
-            e_addr = Address("10.0.0.9", "7001", "E")
-            n_addr = Address("10.0.0.1", "7001", "N")
-            bus0 = Address("127.0.0.1", "7101", "N#lane0")
-            bus1 = Address("127.0.0.1", "7102", "N#lane1")
-            self._node_group("E", e_addr, [n_addr], rid=9)
-            self._lane_group("L0", 0, n_addr, bus0, [bus1], e_addr, rid=1)
-            self._lane_group("L1", 1, n_addr, bus1, [bus0], None, rid=2)
         else:  # regions3: two regions, one deterministic bridge each.
             # foo+bar form region ra's intra mesh (foo, the smaller
             # address, is its bridge); baz alone is region rb (its own
@@ -816,35 +804,6 @@ class World:
         self.bdecs_left[name] = self.budgets["bdecs"]
         self.mints_left[name] = self.budgets["mints"]
         self.group_rids[name] = rid
-        build()
-
-    def _lane_group(self, group, lane_id, n_addr, bus_addr, bus_seeds,
-                    e_addr, rid) -> None:
-        def build(journal=None):
-            db = ModelDatabase(group, rid, journal,
-                               escrow_unsafe=self.escrow_unsafe,
-                               session_unsafe=self.session_unsafe)
-            self.dbs[group] = db
-            # main.py's exact wiring: every lane runs a bus instance
-            # (lane 0's does not own the SYSTEM metrics section); lane 0
-            # additionally runs the external cluster with
-            # drive_flush=False and bridges the meshes
-            bus = self._spawn(
-                f"{group}.bus", group, bus_addr, bus_seeds, db,
-                register_system=(lane_id != 0),
-            )
-            if lane_id == 0:
-                ext = self._spawn(
-                    f"{group}.ext", group, n_addr, [e_addr], db,
-                    drive_flush=False,
-                )
-                wire_bridge(bus.cluster, ext.cluster)
-
-        self._group_builders[group] = build
-        self.writes_left[group] = self.budgets["writes"]
-        self.bdecs_left[group] = self.budgets["bdecs"]
-        self.mints_left[group] = self.budgets["mints"]
-        self.group_rids[group] = rid
         build()
 
     # ---- event-loop stepping ----------------------------------------------
@@ -939,15 +898,14 @@ class World:
                 for gto in self._groups():
                     if gto != gfrom and self._group_alive(gto):
                         acts.append(("bxfer", gfrom, gto))
-        if self.config_name != "lanes2":
-            groups = self._groups()
-            for i, a in enumerate(groups):
-                for b in groups[i + 1:]:
-                    pair = frozenset((a, b))
-                    if pair in self.net.partitions:
-                        acts.append(("heal", a, b))
-                    elif self.used["partitions"] < self.budgets["partitions"]:
-                        acts.append(("part", a, b))
+        groups = self._groups()
+        for i, a in enumerate(groups):
+            for b in groups[i + 1:]:
+                pair = frozenset((a, b))
+                if pair in self.net.partitions:
+                    acts.append(("heal", a, b))
+                elif self.used["partitions"] < self.budgets["partitions"]:
+                    acts.append(("part", a, b))
         return acts
 
     def _group_alive(self, group: str) -> bool:
@@ -1033,8 +991,7 @@ class World:
             )
         if kind == "part":
             return (
-                self.config_name != "lanes2"
-                and action[1] in self._group_builders
+                action[1] in self._group_builders
                 and action[2] in self._group_builders
                 and action[1] != action[2]
                 and frozenset((action[1], action[2]))
@@ -1110,10 +1067,7 @@ class World:
         the writes the token's self entry covers. The session_ryw
         invariant then holds the floor against every replica whose
         vector ever dominates the token."""
-        inst = self.instances.get(group) or self.instances.get(
-            f"{group}.bus"
-        )
-        self._run(inst.cluster.flush_now)
+        self._run(self.instances[group].cluster.flush_now)
         db = self.dbs[group]
         vec = dict(db.sessions.vector())
         rid = self.group_rids[group]

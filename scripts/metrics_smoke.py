@@ -4,18 +4,9 @@ gauge and counter declared in scripts/jlint/metrics_manifest.json is
 present from boot (zero counts included — the observability surface must not depend
 on traffic having happened).
 
-Then boot a MULTI-LANE node (`--lanes N`, N from JYLIS_SMOKE_LANES,
-default 4) and scrape the supervisor's AGGREGATED endpoint: every
-manifest histogram must be present per lane (`lane="k"` labels for
-every k), the counter families must also appear as aggregate
-(lane-less) sums, every lane must report `jylis_lane_up 1`, and the
-whole body must still be grammatically valid exposition — the per-lane
-and aggregate metric surfaces can't rot independently.
-
-Run via `make metrics-smoke` (part of `make ci`). Exit 0 = both
-scrapes valid and complete, with non-trivial serving activity (the
-script issues a few RESP commands first, so at least one seam has
-samples).
+Run via `make metrics-smoke` (part of `make ci`). Exit 0 = the scrape
+is valid and complete, with non-trivial serving activity (the script
+issues a few RESP commands first, so at least one seam has samples).
 """
 
 from __future__ import annotations
@@ -98,7 +89,7 @@ def resp_traffic(port: int, timeout_s: float = 180.0) -> None:
     s.close()
 
 
-def _boot_and_scrape(lanes: int) -> str:
+def _boot_and_scrape() -> str:
     resp_port = free_port()
     mport = free_port()
     args = [
@@ -108,23 +99,10 @@ def _boot_and_scrape(lanes: int) -> str:
         "--metrics-port", str(mport),
         "--log-level", "warn",
     ]
-    if lanes > 1:
-        args += ["--lanes", str(lanes), "-T", "0.5"]
     proc = subprocess.Popen(args, cwd=ROOT, stdout=subprocess.DEVNULL)
     try:
         resp_traffic(resp_port)
-        body = scrape(mport)
-        # the aggregator answers as soon as IT is up, with whatever
-        # lanes answer — re-scrape until every lane reports in (the
-        # slowest lane can still be importing jax for a while on a
-        # loaded CI host), then validate the complete surface
-        deadline = time.time() + 240
-        while lanes > 1 and time.time() < deadline and not all(
-            f'jylis_lane_up{{lane="{k}"}} 1' in body for k in range(lanes)
-        ):
-            time.sleep(2.0)
-            body = scrape(mport)
-        return body
+        return scrape(mport)
     finally:
         proc.terminate()
         try:
@@ -141,14 +119,12 @@ _HIST_LINE_RE = re.compile(
 _LE_RE = re.compile(r'(?:^|,)le="([^"]+)"')
 
 
-def _check_histograms(body: str, failures: list, tag: str,
-                      hists: list[str]) -> int:
+def _check_histograms(body: str, failures: list, hists: list[str]) -> int:
     """Validate the real-histogram exposition grammar: every manifest
     seam exposes a `_bucket` series whose counts are CUMULATIVE in le
     order, ends at le="+Inf", and whose `_count` equals the +Inf bucket
     — the invariants histogram_quantile() silently miscomputes without.
-    Applies per series (so per-lane AND aggregated lane-less series on
-    a lanes scrape are each checked). Returns the series count."""
+    Returns the series count."""
     series: dict[str, list[tuple[float, int]]] = {}
     counts: dict[str, int] = {}
     for line in body.splitlines():
@@ -161,40 +137,40 @@ def _check_histograms(body: str, failures: list, tag: str,
             continue
         le = _LE_RE.search(labels)
         if le is None:
-            failures.append(f"  [{tag}] _bucket without le: {line!r}")
+            failures.append(f"  _bucket without le: {line!r}")
             continue
         key = _LE_RE.sub("", labels)
         series.setdefault(key, []).append((float(le.group(1)), v))
     for key, pts in series.items():
         pts.sort()  # by le; float("+Inf") orders it last
         if pts[-1][0] != float("inf"):
-            failures.append(f"  [{tag}] no le=\"+Inf\" bucket: {key}")
+            failures.append(f"  no le=\"+Inf\" bucket: {key}")
             continue
         vals = [v for _, v in pts]
         if any(b < a for a, b in zip(vals, vals[1:])):
             failures.append(
-                f"  [{tag}] non-cumulative _bucket series: {key}"
+                f"  non-cumulative _bucket series: {key}"
             )
         if counts.get(key) != vals[-1]:
             failures.append(
-                f"  [{tag}] _count != +Inf bucket for: {key}"
+                f"  _count != +Inf bucket for: {key}"
             )
     for name in hists:
         want = f'seam="{name}"'
         if not any(want in key for key in series):
             failures.append(
-                f"  [{tag}] manifest seam has no _bucket series: {name}"
+                f"  manifest seam has no _bucket series: {name}"
             )
     return len(series)
 
 
-def _check_exposition(body: str, failures: list, tag: str) -> int:
+def _check_exposition(body: str, failures: list) -> int:
     n_samples = 0
     for line in body.splitlines():
         if not line or line.startswith("#"):
             continue
         if not SAMPLE_RE.match(line):
-            failures.append(f"  [{tag}] bad exposition line: {line!r}")
+            failures.append(f"  bad exposition line: {line!r}")
         else:
             n_samples += 1
     return n_samples
@@ -207,11 +183,11 @@ def main() -> int:
     counters = sorted(n[8:] for n in manifest if n.startswith("counter:"))
     serving = sorted(n[8:] for n in manifest if n.startswith("serving:"))
 
-    body = _boot_and_scrape(lanes=1)
+    body = _boot_and_scrape()
 
     failures = []
-    n_samples = _check_exposition(body, failures, "single")
-    n_hist_series = _check_histograms(body, failures, "single", hists)
+    n_samples = _check_exposition(body, failures)
+    n_hist_series = _check_histograms(body, failures, hists)
     for name in hists:
         if f'seam="{name}"' not in body:
             failures.append(f"  manifest histogram absent from scrape: {name}")
@@ -240,45 +216,6 @@ def main() -> int:
     if "jylis_cmds_total" not in body:
         failures.append("  jylis_cmds_total family missing")
 
-    # ---- the multi-lane aggregated scrape ----------------------------------
-    lanes = int(os.environ.get("JYLIS_SMOKE_LANES", "4"))
-    lane_body = _boot_and_scrape(lanes=lanes)
-    n_lane_samples = _check_exposition(lane_body, failures, f"lanes={lanes}")
-    n_lane_hist = _check_histograms(
-        lane_body, failures, f"lanes={lanes}", hists
-    )
-    # the aggregator must ALSO sum buckets into lane-less series
-    # (cumulative bucket counters sum correctly; quantiles never do)
-    if not any(
-        line.startswith(f"{_HIST_FAMILY}_bucket{{seam=")
-        and 'lane="' not in line
-        for line in lane_body.splitlines()
-    ):
-        failures.append(
-            "  no aggregate (lane-less) _bucket series on the lanes scrape"
-        )
-    for k in range(lanes):
-        if f'jylis_lane_up{{lane="{k}"}} 1' not in lane_body:
-            failures.append(f"  lane {k} not up in the aggregated scrape")
-        for name in hists:
-            if f'lane="{k}",seam="{name}"' not in lane_body:
-                failures.append(
-                    f"  manifest histogram absent for lane {k}: {name}"
-                )
-        for name in gauges:
-            if f'lane="{k}",name="{name}"' not in lane_body:
-                failures.append(
-                    f"  manifest gauge absent for lane {k}: {name}"
-                )
-    # counter families must ALSO exist as lane-less aggregate sums
-    for family in ("jylis_cmds_total", "jylis_serving_total"):
-        agg = [
-            line for line in lane_body.splitlines()
-            if line.startswith(family) and 'lane="' not in line
-        ]
-        if not agg:
-            failures.append(f"  no aggregate (lane-less) {family} series")
-
     if failures:
         print("metrics-smoke FAILED:")
         print("\n".join(failures))
@@ -286,9 +223,7 @@ def main() -> int:
     print(
         f"metrics-smoke: {n_samples} valid samples; {len(hists)} histograms"
         f" + {len(gauges)} gauges + {len(counters) + len(serving)} counters all present; "
-        f"{n_hist_series} cumulative _bucket series valid; lanes={lanes} aggregate scrape: "
-        f"{n_lane_samples} samples, {n_lane_hist} _bucket series, "
-        f"per-lane + aggregate series ok"
+        f"{n_hist_series} cumulative _bucket series valid"
     )
     return 0
 

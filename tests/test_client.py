@@ -8,9 +8,8 @@ Two layers, matching docs/client.md's contract:
   failover + MTTR accounting, and the token-join monotonicity are all
   deterministic (injected sleep/clock/rng — no sockets, no timing).
 * Spawned-node integration: REAL node processes for the parts a stub
-  cannot vouch for — token monotonicity across a SIGKILL failover,
-  topology re-discovery after a node leaves, and the loopback-bus
-  lane-bounce read on a --lanes 2 node.
+  cannot vouch for — token monotonicity across a SIGKILL failover and
+  topology re-discovery after a node leaves.
 """
 
 import time
@@ -321,26 +320,3 @@ def test_topology_rediscovery_after_node_leaves():
             cc.close()
         stop_node(na)
         stop_node(nb)
-
-
-def test_lane_bounce_read_on_multilane_node():
-    """--lanes 2: SO_REUSEPORT shards fresh connections across lane
-    processes, so reconnect-per-op write/read pairs bounce between
-    lanes; the auto-threaded token keeps every read read-your-writes
-    whichever lane serves it (the loopback bus carries the deltas)."""
-    port, cport = free_port(), free_port()
-    proc = spawn_node(port, cport, "el", "--lanes", "2")
-    cc = None
-    try:
-        connect_client(port, proc=proc).close()
-        cc = ClusterClient([("127.0.0.1", port)], timeout=30)
-        for i in range(1, 9):
-            assert cc.write("GCOUNT", "INC", "lk", "1") == b"OK"
-            cc.close()  # drop the connection: the next op redials and
-            # may land on the other lane (kernel's accept sharding)
-            assert cc.read("GCOUNT", "GET", "lk") == i
-        assert cc.token is not None
-    finally:
-        if cc is not None:
-            cc.close()
-        stop_node(proc)

@@ -45,12 +45,7 @@ MANIFEST = os.path.join(
 )
 
 with open(MANIFEST, encoding="utf-8") as _f:
-    _ALL_SITES = sorted(json.load(_f)["failpoints"])
-
-# lane.* seams live in spawned lane WORKERS (the lane.tick task only
-# runs in lane mode) — the in-process generic drill can never fire
-# them; they get their own spawned cells below instead
-SITES = [s for s in _ALL_SITES if not s.startswith("lane.")]
+    SITES = sorted(json.load(_f)["failpoints"])
 
 CLASSES = ("error", "sleep", "corrupt", "drop", "crash")
 
@@ -980,356 +975,6 @@ def test_chaos_cluster_metrics_surface():
             await a.stop()
 
     asyncio.run(main())
-
-
-# ---- lane drills (spawned: supervisor + SO_REUSEPORT workers) ---------------
-
-
-def _lane_call(port: int, cmds: list[bytes], timeout=5.0) -> bytes:
-    """One fresh connection (so SO_REUSEPORT re-shards it), pipelined
-    newline commands, read until one reply line per command."""
-    import socket as _socket
-
-    s = _socket.create_connection(("127.0.0.1", port), timeout=timeout)
-    try:
-        s.sendall(b"".join(c + b"\r\n" for c in cmds))
-        s.settimeout(timeout)
-        out = b""
-        while out.count(b"\r\n") < len(cmds):
-            chunk = s.recv(1 << 16)
-            if not chunk:
-                break
-            out += chunk
-        return out
-    finally:
-        s.close()
-
-
-def _lane_of_conn(port: int) -> tuple[int, bytes]:
-    """(lane id, raw reply) for a fresh connection, via the LANE
-    section of SYSTEM METRICS."""
-    out = _lane_call(port, [b"SYSTEM METRICS"], timeout=10.0)
-    for line in out.split(b"\r\n"):
-        if line.startswith(b"LANE id "):
-            return int(line.split()[-1]), out
-    return -1, out
-
-
-def _lane_digest(port: int) -> bytes | None:
-    out = _lane_call(port, [b"SYSTEM DIGEST"], timeout=10.0)
-    if out.startswith(b"$64\r\n"):
-        return out.split(b"\r\n")[1]
-    return None
-
-
-def _values_and_lane(port: int, *keys: bytes) -> tuple[list[bytes], int]:
-    """(GCOUNT GET reply lines for ``keys``, lane id) from ONE
-    connection — probing lane and values over separate connections
-    would race SO_REUSEPORT's shard."""
-    import socket as _socket
-
-    s = _socket.create_connection(("127.0.0.1", port), timeout=10.0)
-    try:
-        s.sendall(
-            b"".join(b"GCOUNT GET %s\r\n" % k for k in keys)
-            + b"SYSTEM METRICS\r\n"
-        )
-        s.settimeout(10.0)
-        out = b""
-        # the values are the first len(keys) lines; `LANE id` leads the
-        # METRICS array (metric_lines inserts it first) shortly after
-        while b"LANE id " not in out and out.count(b"\r\n") < 16 + len(keys):
-            chunk = s.recv(1 << 16)
-            if not chunk:
-                break
-            out += chunk
-    finally:
-        s.close()
-    lines = out.split(b"\r\n")
-    vals = lines[: len(keys)]
-    lane = -1
-    for line in lines:
-        if line.startswith(b"LANE id "):
-            lane = int(line.split()[-1])
-    return vals, lane
-
-
-def _wait_serving(port: int, proc, timeout_s: float = 120.0) -> None:
-    deadline = time.time() + timeout_s
-    while time.time() < deadline:
-        if proc.poll() is not None:
-            raise RuntimeError("lane supervisor died during startup")
-        try:
-            if _lane_call(port, [b"GCOUNT GET boot"]).startswith(b":"):
-                return
-        except OSError:
-            time.sleep(0.3)
-    raise RuntimeError(f"lanes on :{port} never came up")
-
-
-@pytest.mark.chaos
-def test_chaos_lane_crash_smoke(tmp_path):
-    """The lane-crash drill (acceptance: SIGKILL one lane mid-traffic):
-    surviving lanes keep serving throughout, the supervisor respawns
-    the dead lane, the respawn replays its journal segment, and every
-    lane's SYSTEM DIGEST converges back to equality."""
-    import signal as _signal
-
-    from procutil import SPAWN_CPU, free_port
-
-    data_dir = str(tmp_path / "lanenode")
-    port = free_port()
-    proc = subprocess.Popen(
-        [
-            sys.executable, "-c", SPAWN_CPU,
-            "--lanes", "2", "--port", str(port),
-            "--addr", f"127.0.0.1:{free_port()}:lanedrill",
-            "--data-dir", data_dir, "--log-level", "warn",
-            "--journal-fsync", "always", "-T", "0.5",
-        ],
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    try:
-        _wait_serving(port, proc)
-        # land writes on EVERY lane. _wait_serving returns when ANY
-        # lane serves; under CI contention the sibling can still be
-        # importing jax for many seconds, and until it binds its
-        # SO_REUSEPORT socket every fresh connection lands on lane 0 —
-        # so keep probing (fresh conns re-shard) until both lane ids
-        # have answered. These drill writes are deliberately NOT
-        # exact-counted: a write acked by the victim inside its
-        # documented ack→flush window (≤ 500 ms + journal-writer lag)
-        # dies with the SIGKILL on every replica — by design — so the
-        # exact-total invariant belongs to the post-heal phase below.
-        lanes_written = set()
-        deadline = time.time() + 180
-        while time.time() < deadline:
-            try:
-                lane, _out = _lane_of_conn(port)
-            except OSError:
-                time.sleep(0.3)
-                continue
-            out = _lane_call(port, [b"GCOUNT INC drill 1"])
-            assert out == b"+OK\r\n", out
-            lanes_written.add(lane)
-            if lanes_written >= {0, 1}:
-                break
-        assert lanes_written >= {0, 1}, lanes_written
-
-        manifest = json.load(open(os.path.join(data_dir, "lanes.json")))
-        victim = next(lane for lane in manifest["lanes"] if lane["id"] == 1)
-        os.kill(victim["pid"], _signal.SIGKILL)
-
-        # surviving lanes serve THROUGHOUT the dead window: the dead
-        # socket closes with the process, so fresh conns land live —
-        # acks must keep arriving with at most transient hiccups (a
-        # loaded CI host can time out an individual call without the
-        # node having a serving gap)
-        deadline = time.time() + 90
-        served_after_kill = 0
-        fail_streak = max_fail_streak = 0
-        while time.time() < deadline and served_after_kill < 10:
-            try:
-                if (
-                    _lane_call(port, [b"GCOUNT INC drill 1"], timeout=10.0)
-                    == b"+OK\r\n"
-                ):
-                    served_after_kill += 1
-                    fail_streak = 0
-            except OSError:
-                fail_streak += 1
-                max_fail_streak = max(max_fail_streak, fail_streak)
-            time.sleep(0.05)
-        assert served_after_kill >= 10, served_after_kill
-        assert max_fail_streak <= 5, max_fail_streak
-
-        # the supervisor respawns lane 1 (lanes.json shows a new pid),
-        # its journal segments merge-replay, and the bus sync heals it
-        # back into the mesh: wait for the respawn to SERVE (respawn =
-        # jax import + warmup + replay + sync; generous under CI load)
-        deadline = time.time() + 300
-        reborn = False
-        while time.time() < deadline:
-            try:
-                m2 = json.load(open(os.path.join(data_dir, "lanes.json")))
-                pid2 = next(
-                    lane["pid"] for lane in m2["lanes"] if lane["id"] == 1
-                )
-                if pid2 != victim["pid"]:
-                    _vals, lane = _values_and_lane(port, b"drill")
-                    if lane == 1:
-                        reborn = True
-                        break
-            except (OSError, StopIteration, json.JSONDecodeError):
-                pass
-            time.sleep(0.3)
-        assert reborn, "lane 1 never respawned into serving"
-
-        # post-heal: exact-total writes on a FRESH key — no process
-        # dies from here on, so every ack must converge to every lane
-        # (serve-after-converge across the bus), and the two lanes'
-        # drill values and digests must agree (replay ⊔ bus sync made
-        # them one replica set again, whatever survived the kill)
-        for _ in range(5):
-            assert _lane_call(port, [b"GCOUNT INC heal 1"]) == b"+OK\r\n"
-        deadline = time.time() + 240
-        healed = False
-        last: dict[int, tuple] = {}
-        while time.time() < deadline:
-            try:
-                vals, lane = _values_and_lane(port, b"heal", b"drill")
-                if lane >= 0:
-                    last[lane] = tuple(vals)
-            except OSError:
-                pass
-            if (
-                set(last) == {0, 1}
-                and all(v[0] == b":5" for v in last.values())
-                and len({v[1] for v in last.values()}) == 1
-            ):
-                healed = True
-                break
-            time.sleep(0.3)
-        assert healed, f"lanes never reconverged: {last}"
-
-        # quiesced: every lane's digest equal (both ids seen)
-        deadline = time.time() + 120
-        matched = False
-        while time.time() < deadline:
-            digs = {}
-            for _ in range(12):
-                try:
-                    lane, _ = _lane_of_conn(port)
-                    d = _lane_digest(port)
-                    if lane >= 0 and d:
-                        digs[lane] = d
-                except OSError:
-                    pass
-            if set(digs) == {0, 1} and len(set(digs.values())) == 1:
-                matched = True
-                break
-            time.sleep(0.5)
-        assert matched, digs
-    finally:
-        if proc.poll() is None:
-            proc.terminate()
-            try:
-                proc.wait(timeout=60)
-            except subprocess.TimeoutExpired:
-                proc.kill()
-                proc.wait(timeout=10)
-
-
-@pytest.mark.soak
-@pytest.mark.slow  # nightly (`make soak`), not per-commit
-@pytest.mark.parametrize("action", ("crash", "drop"))
-def test_lane_drill_three_node_digest_match(action, tmp_path):
-    """{crash, drop} × lane worker over a REAL 3-node cluster where one
-    node runs 2 lanes: the faulted lane heals (respawn via the
-    lane.tick=crash failpoint, or budget-exhausted bus-write drops),
-    post-heal writes reach every node, and all three nodes' SYSTEM
-    DIGESTs match."""
-    from procutil import SPAWN_CPU, free_port, spawn_node, stop_node
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    data_dir = str(tmp_path / "bee")
-    p_a, p_b, p_c = free_port(), free_port(), free_port()
-    c_a, c_b, c_c = free_port(), free_port(), free_port()
-    a = spawn_node(p_a, c_a, "aye", "-T", "0.5")
-    env = dict(os.environ)
-    if action == "crash":
-        # the lane-crash FAILPOINT: lane 1's periodic tick kills the
-        # worker deterministically ~a second into serving
-        env["JYLIS_LANE_FAILPOINTS"] = "1:lane.tick=crash:1"
-    else:
-        # silent bus-write loss from lane 1, healed by budget
-        # exhaustion + the periodic digest sync (the budget burns
-        # slowly — dropped handshakes churn the bus conns — so keep it
-        # small enough that the heal lands inside the drill window)
-        env["JYLIS_LANE_FAILPOINTS"] = "1:cluster.write=drop:10"
-    b = subprocess.Popen(
-        [
-            sys.executable, "-c", SPAWN_CPU,
-            "--lanes", "2", "--port", str(p_b),
-            "--addr", f"127.0.0.1:{c_b}:bee",
-            "--seed-addrs", f"127.0.0.1:{c_a}:aye",
-            "--data-dir", data_dir, "--log-level", "warn", "-T", "0.5",
-        ],
-        cwd=repo, env=env,
-    )
-    c = spawn_node(
-        p_c, c_c, "sea", "--seed-addrs", f"127.0.0.1:{c_a}:aye", "-T", "0.5"
-    )
-    procs = [a, b, c]
-    try:
-        for port, proc in ((p_a, a), (p_b, b), (p_c, c)):
-            _wait_serving(port, proc)
-        # drill traffic on every node (fire-and-forget counts: a write
-        # acked by the crashing lane inside its documented unflushed
-        # window dies WITH it on every replica, so exact totals are not
-        # the invariant here — digest equality below is), until node B
-        # has been through its fault: for crash, lanes.json shows a new
-        # pid for lane 1 (the supervisor clears the one-shot injected
-        # spec, so the respawn comes up clean); for drop, the budget
-        # just runs out under traffic
-        deadline = time.time() + 180
-        first_pid = pid = None
-        rounds = 0
-        while time.time() < deadline:
-            for port in (p_a, p_b, p_c):
-                try:
-                    _lane_call(port, [b"GCOUNT INC drill 1"])
-                except OSError:
-                    pass
-            rounds += 1
-            try:
-                manifest = json.load(
-                    open(os.path.join(data_dir, "lanes.json"))
-                )
-                pid = next(
-                    lane["pid"] for lane in manifest["lanes"]
-                    if lane["id"] == 1
-                )
-                if first_pid is None:
-                    first_pid = pid
-                if action == "crash" and pid != first_pid:
-                    break  # the failpoint fired and the respawn landed
-            except (OSError, StopIteration, json.JSONDecodeError):
-                pass
-            if action == "drop" and rounds > 30:
-                break
-            time.sleep(0.2)
-        if action == "crash":
-            assert first_pid is not None
-            assert pid != first_pid, "lane.tick=crash never recycled lane 1"
-        # post-heal writes on every node: these MUST all survive
-        for port in (p_a, p_b, p_c):
-            assert _lane_call(port, [b"GCOUNT INC heal 1"]) == b"+OK\r\n"
-        # convergence: every node reads heal == 3 and the three SYSTEM
-        # DIGESTs (node B's answered by whichever lane) match
-        deadline = time.time() + 240
-        ok = False
-        vals = digs = None
-        while time.time() < deadline:
-            try:
-                vals = {
-                    _lane_call(p, [b"GCOUNT GET heal"]) for p in (p_a, p_b, p_c)
-                }
-                digs = [_lane_digest(p) for p in (p_a, p_b, p_c)]
-                if (
-                    vals == {b":3\r\n"}
-                    and all(d is not None for d in digs)
-                    and len(set(digs)) == 1
-                ):
-                    ok = True
-                    break
-            except OSError:
-                pass
-            time.sleep(0.5)
-        assert ok, (vals, digs)
-    finally:
-        for proc in procs:
-            stop_node(proc)
 
 
 # ---- the full matrix (nightly) ---------------------------------------------

@@ -22,7 +22,6 @@ from scripts.jlint import (  # noqa: E402
     pass_async,
     pass_failpoints,
     pass_jax,
-    pass_lanes,
     pass_metrics,
     pass_parity,
     pass_protocol,
@@ -733,103 +732,6 @@ def test_real_metrics_manifest_matches_sites():
     assert {n[6:] for n in manifest if n.startswith("gauge:")} == gauges
     assert {n[8:] for n in manifest if n.startswith("counter:")} == tallies
     assert {n[8:] for n in manifest if n.startswith("serving:")} == serving
-
-
-# ---- pass 6: cross-lane shared-state manifest (JL601/JL602) -----------------
-
-FAKE_LANEY = """
-TABLE = {}
-CACHE = dict()
-ITEMS: list = []
-FROZEN = frozenset({1})
-SCALAR = 7
-__all__ = ["TABLE"]
-
-def touch():
-    TABLE["k"] = 1
-"""
-
-
-def _lanes_manifest(tmp_path, entries):
-    p = tmp_path / "lanes.json"
-    p.write_text(json.dumps({"globals": entries}))
-    return str(p)
-
-
-def _lanes_found(tmp_path):
-    d = tmp_path / "jylis_tpu"
-    d.mkdir()
-    (d / "mod.py").write_text(FAKE_LANEY)
-    return pass_lanes.extract_globals(str(tmp_path), ("jylis_tpu",))
-
-
-def test_lane_extraction_finds_mutables_only(tmp_path):
-    found = _lanes_found(tmp_path)
-    rel = os.path.join("jylis_tpu", "mod.py")
-    assert set(found) == {
-        f"{rel}:TABLE", f"{rel}:CACHE", f"{rel}:ITEMS"
-    }  # frozenset/int constants and __all__ are out of scope
-
-
-def test_undeclared_lane_global_fails(tmp_path):
-    found = _lanes_found(tmp_path)
-    rel = os.path.join("jylis_tpu", "mod.py")
-    path = _lanes_manifest(
-        tmp_path, {f"{rel}:TABLE": "fine", f"{rel}:CACHE": "fine"}
-    )
-    findings = pass_lanes.check(path, found)
-    assert any(
-        f.rule == "JL601" and "`ITEMS`" in f.msg for f in findings
-    )
-    assert not any("TABLE" in f.msg for f in findings)
-
-
-def test_stale_and_placeholder_lane_entries_fail(tmp_path):
-    found = _lanes_found(tmp_path)
-    rel = os.path.join("jylis_tpu", "mod.py")
-    path = _lanes_manifest(
-        tmp_path,
-        {
-            f"{rel}:TABLE": pass_lanes.PLACEHOLDER,  # undescribed
-            f"{rel}:CACHE": "fine",
-            f"{rel}:ITEMS": "fine",
-            f"{rel}:GONE": "no binding matches",  # stale
-        },
-    )
-    findings = pass_lanes.check(path, found)
-    assert any(f.rule == "JL602" and "GONE" in f.msg for f in findings)
-    assert any(
-        f.rule == "JL602" and "no description" in f.msg for f in findings
-    )
-
-
-def test_missing_lanes_manifest_fails(tmp_path):
-    found = _lanes_found(tmp_path)
-    findings = pass_lanes.check(str(tmp_path / "nope.json"), found)
-    assert any(f.rule == "JL602" and "missing" in f.msg for f in findings)
-
-
-def test_lane_inline_suppression_works(tmp_path):
-    d = tmp_path / "jylis_tpu"
-    d.mkdir()
-    (d / "mod.py").write_text(
-        "GUARDED = {}  # jlint: lane-shared-ok — guarded by the flurm lock\n"
-    )
-    found = pass_lanes.extract_globals(str(tmp_path), ("jylis_tpu",))
-    path = _lanes_manifest(tmp_path, {"unrelated.py:X": "keep non-empty"})
-    findings = pass_lanes.check(path, found)
-    src = jlint.Source.load(str(d / "mod.py"), root=str(tmp_path))
-    jlint.apply_suppressions(findings, {src.rel: src})
-    assert all(f.suppressed for f in findings if f.rule == "JL601")
-
-
-def test_real_lanes_manifest_matches_bindings():
-    """Every module-level mutable in the product tree is declared and
-    described; no stale entries — `make lint` is clean."""
-    assert pass_lanes.check() == []
-    manifest = pass_lanes.load_manifest()
-    found = pass_lanes.extract_globals()
-    assert sorted(manifest) == sorted(found)
 
 
 # ---- the real repo ----------------------------------------------------------

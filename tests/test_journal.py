@@ -25,7 +25,7 @@ from jylis_tpu.models.database import Database
 from jylis_tpu.server.resp import Respond
 from jylis_tpu.utils import metrics
 
-from test_persist import READS, Cap, call, populate
+from test_persist import READS, Cap, call, journal_write, populate
 
 
 def flush_all(db, journal) -> None:
@@ -659,3 +659,72 @@ def test_shutdown_survives_journal_close_failure(tmp_path):
 
     assert asyncio.run(drive()) is True
     assert disposed == ["cluster", "server"]
+
+
+# ---- lane-named segments: read at boot, never written ----------------------
+#
+# A multi-lane node (a mode retired in PR 45) wrote journal.lane<k>.jylis
+# beside or instead of journal.jylis. The boot still converges every one
+# of them and mutates only its own segment.
+
+
+def test_list_segments_names_own_and_lane_files_only(tmp_path):
+    assert journal_mod.SEGMENT_NAME == "journal.jylis"
+    for name in (
+        "journal.jylis", "journal.lane0.jylis", "journal.lane12.jylis",
+        "journal.jylis.retiring", "journal.lane1.jylis.unreadable",
+        "snapshot.lane0.jylis", "journal.lane.txt", "lanes.json",
+    ):
+        (tmp_path / name).write_bytes(b"")
+    assert journal_mod.list_segments(str(tmp_path)) == [
+        str(tmp_path / n)
+        for n in ("journal.jylis", "journal.lane0.jylis", "journal.lane12.jylis")
+    ]
+
+
+def test_recover_all_merges_every_lane_segment(tmp_path):
+    d = str(tmp_path)
+    journal_write(
+        os.path.join(d, "journal.lane0.jylis"), "GCOUNT", [(b"a", {1: 5})]
+    )
+    journal_write(
+        os.path.join(d, "journal.lane1.jylis"), "GCOUNT", [(b"b", {2: 7})]
+    )
+    journal_write(
+        os.path.join(d, "journal.jylis"), "GCOUNT", [(b"c", {3: 9})]
+    )
+    db = Database(identity=42)
+    n = journal_mod.recover_all(db, d, os.path.join(d, "journal.jylis"))
+    assert n == 3
+    for key, want in ((b"a", b":5\r\n"), (b"b", b":7\r\n"), (b"c", b":9\r\n")):
+        assert call(db, "GCOUNT", "GET", key) == want, key
+
+
+def test_recover_all_never_mutates_foreign_torn_tail(tmp_path):
+    d = str(tmp_path)
+    own = os.path.join(d, "journal.jylis")
+    foreign = os.path.join(d, "journal.lane1.jylis")
+    journal_write(own, "GCOUNT", [(b"a", {1: 5})])
+    journal_write(foreign, "GCOUNT", [(b"b", {2: 7})], torn=True)
+    size_before = os.path.getsize(foreign)
+    db = Database(identity=42)
+    n = journal_mod.recover_all(db, d, own)
+    assert n == 2  # both complete batches converged
+    # the foreign file was not truncated and not moved aside
+    assert os.path.getsize(foreign) == size_before
+    assert not os.path.exists(foreign + ".unreadable")
+
+
+def test_recover_all_skips_corrupt_foreign_segment(tmp_path):
+    d = str(tmp_path)
+    own = os.path.join(d, "journal.jylis")
+    foreign = os.path.join(d, "journal.lane1.jylis")
+    journal_write(own, "GCOUNT", [(b"a", {1: 5})])
+    with open(foreign, "wb") as f:
+        f.write(b"not a journal at all")
+    db = Database(identity=42)
+    n = journal_mod.recover_all(db, d, own)
+    assert n == 1
+    # never mutate a file this node does not write, even an unreadable one
+    assert os.path.exists(foreign)
+    assert not os.path.exists(foreign + ".unreadable")

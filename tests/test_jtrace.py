@@ -280,25 +280,6 @@ def _mk_cluster(trace_sample: int) -> Cluster:
     return Cluster(cfg, _Db())
 
 
-def test_broadcast_mints_one_span_in_n():
-    c = _mk_cluster(trace_sample=3)
-    spans = []
-    for _ in range(6):
-        c.broadcast_deltas(("GCOUNT", [(b"k", {1: 1})]))
-        spans.append(c.last_span)
-    assert [bool(s) for s in spans] == [False, False, True] * 2
-    hops = decode_span(spans[2])
-    assert len(hops) == 1
-    assert hops[0][0] == HOP_ORIGIN and hops[0][2] == "r1"
-
-
-def test_trace_sample_zero_never_mints():
-    c = _mk_cluster(trace_sample=0)
-    for _ in range(5):
-        c.broadcast_deltas(("GCOUNT", [(b"k", {1: 1})]))
-        assert c.last_span == b""
-
-
 def _last_logged_msg(c: Cluster):
     """Decode the newest delta-log frame back to its codec message."""
     _seq, data = c._delta_log[-1]
@@ -312,23 +293,43 @@ def _last_logged_msg(c: Cluster):
     return codec.decode(payload)
 
 
+def test_broadcast_mints_one_span_in_n():
+    c = _mk_cluster(trace_sample=3)
+    spans = []
+    for _ in range(6):
+        c.broadcast_deltas(("GCOUNT", [(b"k", {1: 1})]))
+        spans.append(_last_logged_msg(c).span)
+    assert [bool(s) for s in spans] == [False, False, True] * 2
+    hops = decode_span(spans[2])
+    assert len(hops) == 1
+    assert hops[0][0] == HOP_ORIGIN and hops[0][2] == "r1"
+
+
+def test_trace_sample_zero_never_mints():
+    c = _mk_cluster(trace_sample=0)
+    for _ in range(5):
+        c.broadcast_deltas(("GCOUNT", [(b"k", {1: 1})]))
+        assert _last_logged_msg(c).span == b""
+
+
 def test_broadcast_wires_span_into_seq_push_frame():
     c = _mk_cluster(trace_sample=1)
     c.broadcast_deltas(("GCOUNT", [(b"k", {1: 1})]))
     msg = _last_logged_msg(c)
     assert isinstance(msg, MsgSeqPush)
-    assert msg.span == c.last_span and msg.span
+    hops = decode_span(msg.span)
+    assert [h[0] for h in hops] == [HOP_ORIGIN]
+    assert hops[0][1] == c._srid and hops[0][2] == "r1"
 
 
-def test_relay_appends_hop_with_configured_tag():
+def test_relay_appends_its_hop_to_the_chain():
     c = _mk_cluster(trace_sample=1)
-    c.relay_hop = HOP_BUS  # what lanes.py sets on the bus instance
     span = append_hop(b"", HOP_ORIGIN, "o!1", "r0", 7)
     c.relay_deltas("o!1", 1, ("GCOUNT", [(b"k", {1: 1})]), span)
     msg = _last_logged_msg(c)
     assert isinstance(msg, MsgRelayPush)
     hops = decode_span(msg.span)
-    assert [h[0] for h in hops] == [HOP_ORIGIN, HOP_BUS]
+    assert [h[0] for h in hops] == [HOP_ORIGIN, HOP_RELAY]
     assert hops[0] == (HOP_ORIGIN, "o!1", "r0", 7)  # original untouched
     assert hops[1][2] == "r1"  # this instance's stamp
 

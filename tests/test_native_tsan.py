@@ -9,8 +9,7 @@ libtsan LD_PRELOADed. Two invariants are certified:
   interner). ctypes releases the GIL around every FFI call, so the
   per-thread bursts below genuinely run concurrently inside the
   library; any cross-engine write TSAN sees is a product bug, because
-  the lane supervisor runs one engine per process and the single-node
-  server runs one per asyncio loop.
+  a node runs one engine per asyncio loop.
 * **External-mutex discipline** — a single engine shared across
   threads is race-free when every call is serialized by one lock
   (the product's implicit contract: the owning event loop is that

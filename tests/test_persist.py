@@ -292,3 +292,127 @@ def test_legacy_v2_snapshot_header_loads(tmp_path):
         assert persist.load_snapshot(db2, str(old_path)) > 0
         for args, want in READS.items():
             assert call(db2, *args) == want, (v, args)
+
+
+# ---- lane-named files: restored at boot, never written ---------------------
+#
+# A multi-lane node (a mode retired in PR 45) wrote snapshot.lane<k>.jylis
+# and journal.lane<k>.jylis. A data directory that holds them still boots
+# whole, and the node writes only its own snapshot.jylis / journal.jylis.
+
+
+def test_list_snapshots_names_own_and_lane_files_only(tmp_path):
+    assert persist.SNAPSHOT_NAME == "snapshot.jylis"
+    for name in (
+        "snapshot.jylis", "snapshot.lane0.jylis", "snapshot.lane3.jylis",
+        "snapshot.jylis.tmp", "snapshot.lane1.jylis.unreadable",
+        "journal.lane0.jylis", "lanes.json",
+    ):
+        (tmp_path / name).write_bytes(b"")
+    assert persist.list_snapshots(str(tmp_path)) == [
+        str(tmp_path / n)
+        for n in ("snapshot.jylis", "snapshot.lane0.jylis", "snapshot.lane3.jylis")
+    ]
+
+
+def _lane_snapshot(path, identity: int, key: str, n: int) -> None:
+    db = Database(identity=identity)
+    call(db, "GCOUNT", "INC", key, str(n))
+    persist.save_snapshot(db, str(path))
+
+
+def journal_write(path, name: str, batch, torn: bool = False) -> None:
+    """One batch in a fresh segment at ``path``; ``torn`` leaves a torn
+    trailing frame behind it."""
+    from jylis_tpu.journal import Journal
+
+    j = Journal(str(path), fsync="off")
+    j.open()
+    j.append(name, batch)
+    j.flush()
+    j.close()
+    if torn:
+        with open(path, "ab") as f:
+            f.write(b"\x00\x01\x02")
+
+
+@pytest.mark.parametrize(
+    "snapshots,segments",
+    [(True, False), (False, True), (True, True)],
+    ids=["snapshots-only", "segments-only", "both"],
+)
+def test_node_boots_a_multilane_data_dir_whole(tmp_path, snapshots, segments):
+    """`main.run` on a directory a multi-lane node left: every
+    lane-named snapshot and segment (one with a torn tail) converges
+    beside the node's own journal.jylis, the lane files stay
+    byte-identical, and a clean shutdown writes only snapshot.jylis."""
+    import asyncio
+    import os
+    import signal
+
+    from jylis_tpu import main as main_mod
+    from procutil import free_port
+    from test_cluster import resp_call
+
+    want = {b"own": 2}
+    journal_write(tmp_path / "journal.jylis", "GCOUNT", [(b"own", {7: 2})])
+    if snapshots:
+        _lane_snapshot(tmp_path / "snapshot.lane0.jylis", 11, "s0", 3)
+        _lane_snapshot(tmp_path / "snapshot.lane1.jylis", 12, "s1", 4)
+        want.update({b"s0": 3, b"s1": 4})
+    if segments:
+        journal_write(
+            tmp_path / "journal.lane1.jylis", "GCOUNT", [(b"j1", {13: 5})],
+            torn=True,
+        )
+        want[b"j1"] = 5
+    lane_files = {
+        p.name: p.read_bytes() for p in tmp_path.iterdir() if ".lane" in p.name
+    }
+    assert len(lane_files) == 2 * snapshots + segments
+    port, cport = free_port(), free_port()
+
+    async def drive():
+        node = asyncio.create_task(
+            main_mod.run([
+                "--port", str(port), "--addr", f"127.0.0.1:{cport}:lanedir",
+                "--data-dir", str(tmp_path), "--log-level", "error",
+            ])
+        )
+        got = {}
+        try:
+            deadline = asyncio.get_running_loop().time() + 240
+            while True:
+                assert not node.done(), node.exception()
+                try:
+                    for key in want:
+                        got[key] = await resp_call(
+                            port,
+                            b"*3\r\n$6\r\nGCOUNT\r\n$3\r\nGET\r\n$%d\r\n%s\r\n"
+                            % (len(key), key),
+                        )
+                    break
+                except OSError:
+                    assert asyncio.get_running_loop().time() < deadline
+                    await asyncio.sleep(0.1)
+        finally:
+            # the node's own SIGTERM handler (Dispose.on_signal) is on
+            # this loop by the time the port answers: a clean shutdown
+            os.kill(os.getpid(), signal.SIGTERM)
+            await asyncio.wait_for(node, 120)
+        return got
+
+    got = asyncio.run(drive())
+    assert got == {k: b":%d\r\n" % n for k, n in want.items()}
+    after = {p.name: p for p in tmp_path.iterdir()}
+    for name, blob in lane_files.items():
+        assert after[name].read_bytes() == blob, name
+    written = {
+        n for n in after if n not in lane_files and not n.startswith("epoch.")
+    }
+    assert written == {"snapshot.jylis", "journal.jylis"}
+    # the shutdown snapshot holds the union: a second boot needs no lane file
+    db = Database(identity=1)
+    persist.load_snapshot(db, str(after["snapshot.jylis"]))
+    for key, n in want.items():
+        assert call(db, "GCOUNT", "GET", key) == b":%d\r\n" % n

@@ -4,9 +4,9 @@ Three layers: the token codec's robustness (truncation at every byte,
 CRC, u64 bounds, empty vector — a client-held artifact must fail typed,
 never misread), the SessionIndex contiguity/adoption rules (the
 watermark discipline read-your-writes rests on), and the end-to-end
-guarantee over real sockets: tokens minted on one replica or lane
+guarantee over real sockets: tokens minted on one replica
 verify on another (bounded wait), go typed-STALE when uncovered, and
-reply tokens stay monotone across a lane bounce and a node failover.
+reply tokens stay monotone across a replica bounce and a node failover.
 """
 
 import asyncio
@@ -290,20 +290,20 @@ async def _failover():
         await bar.stop()
 
 
-def test_session_token_bounces_across_lanes():
-    """Two in-process 'lanes' (two Databases converging over a real
-    loopback bus, the lanes.py pattern): a token minted on lane 0
-    verifies on lane 1 once the bus delivers — the same vector, no
-    lane-specific state in the token."""
-    asyncio.run(_lane_bounce())
+def test_session_token_bounces_across_two_databases():
+    """Two plain Databases converging over a real loopback cluster: a
+    token minted on one verifies on the other once the cluster
+    delivers — the same vector, no replica-specific state in the
+    token."""
+    asyncio.run(_replica_bounce())
 
 
-async def _lane_bounce():
+async def _replica_bounce():
     p0, p1 = grab_ports(2)
-    a0 = Address("127.0.0.1", str(p0), "n#lane0")
-    a1 = Address("127.0.0.1", str(p1), "n#lane1")
+    a0 = Address("127.0.0.1", str(p0), "n0")
+    a1 = Address("127.0.0.1", str(p1), "n1")
 
-    def lane(addr, seeds, ident):
+    def replica(addr, seeds, ident):
         cfg = Config()
         cfg.port = "0"
         cfg.addr = addr
@@ -314,8 +314,8 @@ async def _lane_bounce():
         cl = Cluster(cfg, db)
         return cfg, db, cl
 
-    _, db0, cl0 = lane(a0, [a1], 1)
-    _, db1, cl1 = lane(a1, [a0], 2)
+    _, db0, cl0 = replica(a0, [a1], 1)
+    _, db1, cl1 = replica(a1, [a0], 2)
     await cl0.start()
     await cl1.start()
     try:
@@ -341,7 +341,7 @@ async def _lane_bounce():
                 break
             await asyncio.sleep(TICK / 2)
         assert db1.sessions.dominated(vec)
-        # the bounce: SESSION READ on the OTHER lane serves immediately
+        # the bounce: SESSION READ on the OTHER replica serves immediately
         r2 = _Resp()
         await db1.apply_async(
             r2, [b"SESSION", b"READ", tok, b"GCOUNT", b"GET", b"lk"]

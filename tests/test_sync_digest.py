@@ -667,3 +667,63 @@ def test_sync_streams_only_mismatched_types():
             await b.stop()
 
     asyncio.run(main())
+
+
+# ---- SYSTEM DIGEST ----------------------------------------------------------
+
+
+def test_system_digest_async_path_and_convergence():
+    """SYSTEM DIGEST over a real RESP connection: equal on converged
+    replicas, different when they diverge."""
+
+    async def main():
+        p_a, p_b = free_port(), free_port()
+        a = Node("aye", p_a)
+        b = Node("bee", p_b, seeds=[a.config.addr])
+        await a.start()
+        await b.start()
+        try:
+            digest_cmd = b"*2\r\n$6\r\nSYSTEM\r\n$6\r\nDIGEST\r\n"
+            empty_a = await resp_call(a.server.port, digest_cmd)
+            empty_b = await resp_call(b.server.port, digest_cmd)
+            assert empty_a.startswith(b"$64\r\n"), empty_a
+            assert empty_a == empty_b  # both empty: equal digests
+            out = await resp_call(
+                a.server.port,
+                b"*4\r\n$6\r\nGCOUNT\r\n$3\r\nINC\r\n$1\r\nk\r\n$1\r\n2\r\n",
+            )
+            assert out == b"+OK\r\n"
+
+            async def matched():
+                da = await resp_call(a.server.port, digest_cmd)
+                db = await resp_call(b.server.port, digest_cmd)
+                return da == db and da != empty_a
+
+            deadline = asyncio.get_event_loop().time() + 300 * TICK
+            while asyncio.get_event_loop().time() < deadline:
+                if await matched():
+                    break
+                await asyncio.sleep(TICK)
+            assert await matched()
+        finally:
+            await b.stop()
+            await a.stop()
+
+    asyncio.run(main())
+
+
+def test_system_digest_sync_path_matches_async():
+    from jylis_tpu.models.database import Database
+
+    db = Database(identity=9)
+    resp = _CollectResp()
+    db.apply(resp, [b"GCOUNT", b"INC", b"k", b"4"])
+    resp.vals.clear()
+    db.apply(resp, [b"SYSTEM", b"DIGEST"])
+    assert resp.vals[0] == "string"
+    sync_hex = resp.vals[1]
+
+    async def async_digest():
+        return (await db.sync_digest_async()).hex().encode()
+
+    assert asyncio.run(async_digest()) == sync_hex

@@ -2,12 +2,16 @@
 config CLI, log dual-sink (reference test analogs: test_address.pony,
 test_name_generator.pony)."""
 
+import functools
+import glob
+import os
 import random
+import re
 
 import pytest
 
 from jylis_tpu.utils.address import Address
-from jylis_tpu.utils.config import config_from_cli
+from jylis_tpu.utils.config import build_parser, config_from_cli
 from jylis_tpu.utils.log import Log
 from jylis_tpu.utils.namegen import generate_name
 
@@ -112,3 +116,87 @@ def test_config_version_flag_exits():
             assert e.code == 0
     assert raised
     assert pkg.__version__ in out.getvalue()
+
+
+# ---- the flags: the parser, and what the documents say of it ---------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+LONG_FLAGS = sorted(
+    opt
+    for action in build_parser()._actions
+    for opt in action.option_strings
+    if opt.startswith("--") and opt != "--help"
+)
+
+# the multi-lane mode's four (PR 45): refused by argparse itself, no
+# shim that takes and ignores them
+REMOVED_FLAGS = {
+    "--lanes": "2",
+    "--lane-id": "0",
+    "--lane-bus": "7001,7002",
+    "--lane-bus-heartbeat": "0.25",
+}
+
+# flags of OTHER programs that the documents name, with the source that
+# defines each (the test holds them to it, so this table cannot rot)
+OTHER_PROGRAMS = {
+    "--workload": "benchmark/run.py",
+    "--seed": "benchmark/run.py",
+    "--seconds": "benchmark/run.py",
+    "--rehearse": "benchmark/run.py",
+    "--write-manifest": "scripts/jlint/__main__.py",
+    "--write-corpus": "scripts/jlint/__main__.py",
+    "--out": "scripts/jlint/__main__.py",
+    "--budget": "scripts/jlint/__main__.py",
+    "--replay": "scripts/jmodel/__main__.py",
+    "--build-arg": None,  # docker build's own
+}
+
+_FLAG_RE = re.compile(r"(?<![\w-])--[a-z][a-z0-9-]*")
+
+
+@functools.lru_cache(maxsize=None)
+def _read(rel: str) -> str:
+    with open(os.path.join(REPO, rel), encoding="utf-8") as f:
+        return f.read()
+
+
+def _documents() -> list[str]:
+    docs = glob.glob(os.path.join(REPO, "docs", "**", "*.md"), recursive=True)
+    return ["README.md"] + sorted(os.path.relpath(p, REPO) for p in docs)
+
+
+def test_the_parser_defines_28_flags():
+    assert len(LONG_FLAGS) == 28, LONG_FLAGS
+
+
+@pytest.mark.parametrize("flag", sorted(REMOVED_FLAGS))
+def test_removed_lane_flag_is_refused_by_the_parser(flag, capsys):
+    with pytest.raises(SystemExit) as ei:
+        config_from_cli([flag, REMOVED_FLAGS[flag]])
+    assert ei.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("flag", LONG_FLAGS)
+def test_flag_is_documented_and_documents_name_only_flags(flag):
+    """Each flag of the parser has its line in docs/operations.md, and
+    a document that names a flag of the node names one the parser
+    takes (`--journal-*` style prefixes must open at least one)."""
+    assert _FLAG_RE.findall(_read("docs/operations.md")).count(flag), (
+        f"{flag} is not named in docs/operations.md"
+    )
+    flags = set(LONG_FLAGS) | {"--help"}
+    for rel in _documents():
+        for tok in set(_FLAG_RE.findall(_read(rel))):
+            if tok in flags:
+                continue
+            if tok.endswith("-"):
+                assert any(f.startswith(tok) for f in flags), (rel, tok)
+                continue
+            assert tok in OTHER_PROGRAMS, (
+                f"{rel} names {tok}: not a flag of the node"
+            )
+            src = OTHER_PROGRAMS[tok]
+            assert src is None or f'"{tok}"' in _read(src), (tok, src)
