@@ -2049,7 +2049,7 @@ class Cluster:
         a digest-matched peer still recovers log lines it missed)."""
         dump = await self._database.dump_state_async(names=("SYSTEM",))
         return [
-            self._wire(codec.encode(MsgPushDeltas(name, tuple(batch))))
+            self._wire(codec.encode(MsgPushDeltas(name, batch)))
             for name, batch in dump
         ]
 
@@ -2266,7 +2266,7 @@ class Cluster:
             self._local_writes_seen = True
         if not self._worth_holding(name, batch):
             # keepalive: best-effort liveness traffic, never held
-            data = self._wire(codec.encode(MsgPushDeltas(name, tuple(batch))))
+            data = self._wire(codec.encode(MsgPushDeltas(name, batch)))
             self._flush_held()
             if not self._held:
                 self._send_to_actives(data, expect_pong=True)
@@ -2288,7 +2288,7 @@ class Cluster:
                 )
         data = self._wire(
             codec.encode(
-                MsgSeqPush(seq, self._own_seq, name, tuple(batch), span)
+                MsgSeqPush(seq, self._own_seq, name, batch, span)
             )
         )
         if self._sessions is not None:
@@ -2322,7 +2322,7 @@ class Cluster:
             )
         data = self._wire(
             codec.encode(
-                MsgRelayPush(seq, origin, oseq, name, tuple(batch), span)
+                MsgRelayPush(seq, origin, oseq, name, batch, span)
             )
         )
         self._ship_sequenced(seq, data, len(batch))
@@ -2386,7 +2386,7 @@ class Cluster:
                     continue
                 data = self._wire(
                     await asyncio.to_thread(
-                        codec.encode, MsgPushDeltas(name, tuple(batch))
+                        codec.encode, MsgPushDeltas(name, batch)
                     )
                 )
                 self._stats["repair_relays"] += 1

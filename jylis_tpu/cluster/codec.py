@@ -378,10 +378,88 @@ def batch_has_content(name: str, batch) -> bool:
     return True
 
 
+class WireBatch:
+    """A MAP batch kept as its wire bytes: ``count`` (packed key, unit)
+    pairs exactly as a push message carries them, written or checked by
+    the native field table (every inner type TREG) or by `of_units`. A
+    flush of a few thousand fields, a state dump of 10^7 and the restore
+    of one move as ONE buffer between the table, the journal, the wire
+    and the file (models/repo_map.py), with no object a unit; anything
+    else that iterates it gets the oracle's (key, delta) tuples, decoded
+    as it goes. ``starts``: where each unit begins in ``payload``, when
+    the writer knows (`keys` then reads no unit)."""
+
+    __slots__ = ("count", "payload", "starts")
+
+    def __init__(self, count: int, payload, starts=None):
+        self.count = count
+        self.payload = payload
+        self.starts = starts
+
+    @classmethod
+    def of_units(cls, units) -> "WireBatch":
+        """(packed key, unit) tuples through the oracle's encoder."""
+        out = bytearray()
+        for key, unit in units:
+            _w_bytes(out, key)
+            _w_map(out, unit)
+        return cls(len(units), bytes(out))
+
+    def __add__(self, other: "WireBatch") -> "WireBatch":
+        return WireBatch(
+            self.count + other.count,
+            bytes(self.payload) + bytes(other.payload),
+        )
+
+    def __len__(self) -> int:
+        return self.count
+
+    def __iter__(self):
+        r = _Reader(bytes(self.payload))
+        for _ in range(self.count):
+            yield r.bytes_(), _r_map(r)
+
+    def keys(self) -> list[bytes]:
+        """The packed (key, field) wire keys, in the batch's order."""
+        if self.starts is None:
+            return [key for key, _unit in self]
+        r = _Reader(self.payload)
+        out = []
+        for at in self.starts:
+            r.pos = at
+            out.append(bytes(r.bytes_()))
+        return out
+
+
+def keys_of(batch) -> list:
+    """A batch's keys, in its order; a `WireBatch` reads them off its
+    bytes and decodes no unit."""
+    if isinstance(batch, WireBatch):
+        return batch.keys()
+    return [key for key, _delta in batch]
+
+
+def _w_batch(out: bytearray, name: str, batch) -> None:
+    # a batch already in wire form (``len`` its units, ``payload`` their
+    # bytes: a `WireBatch`, or a state made in bulk outside this module,
+    # which load-time validation checks as it checks any file)
+    payload = getattr(batch, "payload", None)
+    _w_varint(out, len(batch))
+    if payload is not None:
+        out += payload
+        return
+    for key, delta in batch:
+        _w_bytes(out, key)
+        _w_delta(out, name, delta)
+
+
 # ---- primitive writers ----------------------------------------------------
 
 
 def _w_varint(out: bytearray, v: int) -> None:
+    if 0 <= v < 0x80:  # one byte: most lengths, counts and small ids
+        out.append(v)
+        return
     if v < 0:
         raise CodecError(f"negative varint: {v}")
     while True:
@@ -513,7 +591,7 @@ def _r_p2set(r: _Reader) -> P2Set:
 
 def _w_gcount_dict(out: bytearray, d: dict) -> None:
     _w_varint(out, len(d))
-    for rid in sorted(d):
+    for rid in sorted(d) if len(d) > 1 else d:
         _w_varint(out, rid)
         _w_varint(out, d[rid])
 
@@ -811,10 +889,7 @@ def _encode_oracle(msg: Msg) -> bytes:
     elif isinstance(msg, MsgPushDeltas):
         out.append(_TAG_PUSH)
         _w_str(out, msg.name)
-        _w_varint(out, len(msg.batch))
-        for key, delta in msg.batch:
-            _w_bytes(out, key)
-            _w_delta(out, msg.name, delta)
+        _w_batch(out, msg.name, msg.batch)
     elif isinstance(msg, MsgSyncRequest):
         out.append(_TAG_SYNC_REQ)
         _w_varint(out, len(msg.digests))
@@ -830,10 +905,7 @@ def _encode_oracle(msg: Msg) -> bytes:
         _w_varint(out, msg.oseq)
         _w_bytes(out, msg.span)
         _w_str(out, msg.name)
-        _w_varint(out, len(msg.batch))
-        for key, delta in msg.batch:
-            _w_bytes(out, key)
-            _w_delta(out, msg.name, delta)
+        _w_batch(out, msg.name, msg.batch)
     elif isinstance(msg, MsgDigestTree):
         out.append(_TAG_DIGEST_TREE)
         _w_str(out, msg.name)
@@ -857,10 +929,7 @@ def _encode_oracle(msg: Msg) -> bytes:
         _w_varint(out, msg.oseq)
         _w_bytes(out, msg.span)
         _w_str(out, msg.name)
-        _w_varint(out, len(msg.batch))
-        for key, delta in msg.batch:
-            _w_bytes(out, key)
-            _w_delta(out, msg.name, delta)
+        _w_batch(out, msg.name, msg.batch)
     elif isinstance(msg, MsgRegionGossip):
         out.append(_TAG_REGION_GOSSIP)
         _w_varint(out, len(msg.regions))
@@ -873,13 +942,41 @@ def _encode_oracle(msg: Msg) -> bytes:
     return bytes(out)
 
 
-def decode(body: bytes) -> Msg:
+def _decode_map_wire(body: bytes) -> Msg | None:
+    """A MsgPushDeltas of MAP whose units the native field table can
+    read in place (well-formed, every inner type TREG), its batch left
+    as a `WireBatch`; None for anything else (the oracle decodes it, or
+    raises on it)."""
+    from ..native.engine import map_wire_ok
+
+    head = b"\x03\x03MAP"  # _TAG_PUSH, str "MAP"
+    if not body.startswith(head):
+        return None
+    r = _Reader(body)
+    r.pos = len(head)
+    try:
+        count = r.varint()
+    except WireError:
+        return None
+    payload = memoryview(body)[r.pos :]
+    if not map_wire_ok(payload, count):
+        return None
+    return MsgPushDeltas("MAP", WireBatch(count, payload))
+
+
+def decode(body: bytes, lazy: bool = False) -> Msg:
+    """``lazy`` (a snapshot's reader): a MAP batch may come back as its
+    checked wire bytes (`WireBatch`), not as a tuple of units."""
     if body and body[0] == _TAG_PUSH:
         from ..native import codec as ncodec
 
         fast = ncodec.decode_push(body)
         if fast is not None:
             return fast
+        if lazy:
+            fast = _decode_map_wire(body)
+            if fast is not None:
+                return fast
     elif body and body[0] == _TAG_SEQ_PUSH:
         # strip the seq prefix, decode the remainder as msg3 (native
         # fast path or oracle — byte-identical by schema), re-tag

@@ -26,6 +26,18 @@ class MsgPong:
     pass
 
 
+class _Batched:
+    """A message that carries a batch holds it as given to it where it
+    is a tuple or already in wire form (``payload``: codec.WireBatch),
+    and as a tuple of anything else, so a sender passes what its repo
+    flushed or dumped, unchanged."""
+
+    def __post_init__(self):
+        batch = self.batch
+        if not (isinstance(batch, tuple) or hasattr(batch, "payload")):
+            object.__setattr__(self, "batch", tuple(batch))
+
+
 @dataclass(frozen=True)
 class MsgSyncDone:
     """Reply closing a MsgSyncRequest: sent after the dump stream (or
@@ -57,7 +69,7 @@ class MsgAnnounceAddrs:
 
 
 @dataclass(frozen=True)
-class MsgPushDeltas:
+class MsgPushDeltas(_Batched):
     """(data-type name, [(key, delta)]) — the _SendDeltasFn payload shape
     (_send_deltas_fn.pony:1-2)."""
 
@@ -66,7 +78,7 @@ class MsgPushDeltas:
 
 
 @dataclass(frozen=True)
-class MsgSeqPush:
+class MsgSeqPush(_Batched):
     """Schema v8 delta-interval broadcast: a MsgPushDeltas payload
     stamped with the SENDER's per-sender monotone batch sequence. The
     receiver tracks the highest contiguous seq per sender and answers
@@ -186,7 +198,7 @@ class MsgSyncRequest:
 
 
 @dataclass(frozen=True)
-class MsgRelayPush:
+class MsgRelayPush(_Batched):
     """Schema v10 origin-preserving relay: a MsgSeqPush whose content
     ORIGINATED at another replica, re-exported by a region bridge
     between WAN meshes. ``seq`` is the RELAYING sender's transport seq —

@@ -316,7 +316,7 @@ class Journal:
             try:
                 data = None
                 try:
-                    payload = codec.encode(MsgPushDeltas(name, tuple(batch)))
+                    payload = codec.encode(MsgPushDeltas(name, batch))
                     data = frame(
                         struct.pack(">I", zlib.crc32(payload)) + payload
                     )

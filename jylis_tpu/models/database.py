@@ -605,6 +605,8 @@ def warmup() -> None:
         # the f32 payload (1.0f LE) is space-free, so the split survives
         b"TENSOR SET k MAX 1 \x00\x00\x80?",
         b"TENSOR GET k",
+        b"MAP TREG SET k f v 1",
+        b"MAP TREG GETALL k",
     ):
         db.apply(resp, line.split(b" "))
     # counter GETs after purely-local INCs serve from the host cache and
@@ -617,3 +619,6 @@ def warmup() -> None:
     # TENSOR GETs never touch the device; the threshold/converge drain
     # kernel compiles here at its default bucket shape, not mid-serving
     db.manager("TENSOR").repo.drain()
+    # MAP TREG reads never touch the device; the field table's sparse
+    # drain compiles here at its default bucket shape
+    db.manager("MAP").repo.drain()

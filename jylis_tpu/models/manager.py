@@ -544,8 +544,10 @@ class RepoManager:
             # Lazy import: database.py imports this module at load.
             from .database import sync_bucket
 
+            from ..cluster.codec import keys_of
+
             note = self.registry.note_write_heat
-            for key, _delta in batch:
+            for key in keys_of(batch):
                 note(
                     self.name,
                     sync_bucket(

@@ -77,6 +77,18 @@ def _patch_vids(state, ki, vids):
     return state._replace(vid=state.vid.at[ki].set(vids, mode="drop"))
 
 
+def patch_tie_vids(state, rows, vids):
+    """The mirror's ids at ``rows`` set to ``vids`` (what a table's
+    `settle_ties` decided), padded to a bucket so the patch compiles
+    once a size; distinct out-of-range pads drop."""
+    pb = bucket(len(rows))
+    pk = pad_rows(pb)
+    pv = np.full(pb, -1, np.int32)
+    pk[: len(rows)] = rows
+    pv[: len(rows)] = vids
+    return _patch_vids(state, pk, pv)
+
+
 # a batch covering >= 1/DENSE_FRACTION of the keyspace drains through the
 # elementwise dense join (each plane streamed once, no random access)
 DENSE_FRACTION = 4
@@ -268,12 +280,7 @@ class RepoTREG:
             # (dense outputs are in key order: the slot IS the row)
             rows, vids = self._tbl.settle_ties(hit if dense else ki[hit])
             if len(rows):
-                pb = bucket(len(rows))
-                pk = pad_rows(pb)  # distinct out-of-range pads drop
-                pv = np.full(pb, -1, np.int32)
-                pk[: len(rows)] = rows
-                pv[: len(rows)] = vids
-                self._state = _patch_vids(self._state, pk, pv)
+                self._state = patch_tie_vids(self._state, rows, vids)
         return int(hit.size)
 
     def _drain_sharded(self, n: int) -> int:

@@ -16,6 +16,7 @@ from __future__ import annotations
 import ctypes
 import struct
 import weakref
+from itertools import chain
 
 import numpy as np
 
@@ -33,7 +34,7 @@ _OUT_CAP = 1 << 16
 _OUT_CEIL = 1 << 24
 _MAX_ARGS = 1024
 # `scan_apply`'s ``held`` with every engine type in it
-ALL_TYPES = 0b11111
+ALL_TYPES = 0b111111
 
 # jy_tlog_export_merged's "view unavailable" sentinel (serve_engine.cpp)
 _TLOG_UNAVAILABLE = -1 - (1 << 40)
@@ -139,6 +140,37 @@ def _declare(c: ctypes.CDLL) -> None:
         "jy_tlog_delta_raise_cutoff": (None, [vp, i64, u64]),
         "jy_tlog_clear_deltas": (None, [vp]),
         "jy_eng_served": (None, [vp, vp]),
+        # MAP field table
+        "jy_map_rows": (i64, [vp]),
+        "jy_map_rid_count": (i64, [vp]),
+        "jy_map_rids": (None, [vp, vp]),
+        "jy_map_reserve": (None, [vp, i64, i64]),
+        "jy_map_set_rid": (None, [vp, u64]),
+        "jy_map_find": (i64, [vp, u8p, i64, u8p, i64]),
+        "jy_map_set": (i64, [vp, u8p, i64, u8p, i64, u64, u64, u8p, i64]),
+        "jy_map_del": (i32, [vp, i64]),
+        "jy_map_note_edit": (None, [vp, i64]),
+        "jy_map_get": (i32, [vp, i64, pu64, pvp, pi64]),
+        "jy_map_field_name": (None, [vp, i64, pvp, pi64]),
+        "jy_map_mark_mixed": (None, [vp, u8p, i64]),
+        "jy_map_is_mixed": (i32, [vp, u8p, i64]),
+        "jy_map_record": (i64, [vp, u8p, i64, vp, i64, i32]),
+        "jy_map_join_unit": (
+            i64, [vp, u8p, i64, vp, i64, vp, i64, u64, u8p, i64],
+        ),
+        "jy_map_check_wire": (i32, [vp, i64, i64]),
+        "jy_map_load_wire": (None, [vp, vp, i64, i64]),
+        "jy_map_wire_build": (i64, [vp, vp, i64]),
+        "jy_map_wire_take": (None, [vp, vp, vp]),
+        "jy_map_pend_count": (i64, [vp]),
+        "jy_map_dirty_count": (i64, [vp]),
+        "jy_map_export_planes": (
+            i64, [vp, vp, vp, i64, vp, vp, vp, vp, vp, i64, i32],
+        ),
+        "jy_map_settle_ties": (i64, [vp, vp, i64, vp]),
+        "jy_map_clear_pend": (None, [vp]),
+        "jy_map_take": (i64, [vp, i32, vp, i64]),
+        "jy_map_tallies": (None, [vp, vp]),
         # UJSON queue + render memo
         "jy_uq_count": (i64, [vp]),
         "jy_uq_bytes": (i64, [vp]),
@@ -191,25 +223,42 @@ _declared = False
 _gil_lib: ctypes.PyDLL | None = None
 
 
+def _ensure_declared(cdll) -> None:
+    global _declared, _gil_lib
+    if not _declared:
+        _declare(cdll)
+        _gil_lib = ctypes.PyDLL(cdll._name)
+        _apply_sigs(_gil_lib, _sender_sigs())
+        _declared = True
+
+
+def map_wire_ok(payload, count: int) -> bool:
+    """Can the native MAP field table read these ``count`` units (a MAP
+    batch's wire bytes, any buffer) in place: well-formed, every inner
+    type TREG. False without the library."""
+    cdll = lib()
+    if cdll is None:
+        return False
+    _ensure_declared(cdll)
+    buf = np.frombuffer(payload, np.uint8)
+    return bool(cdll.jy_map_check_wire(buf.ctypes.data, len(buf), count))
+
+
 class ServeEngine:
-    """One native engine instance = all five data-type tables of one node."""
+    """One native engine instance = all six data-type tables of one node."""
 
     def __init__(self, cdll):
-        global _declared, _gil_lib
-        if not _declared:
-            _declare(cdll)
-            _gil_lib = ctypes.PyDLL(cdll._name)
-            _apply_sigs(_gil_lib, _sender_sigs())
-            _declared = True
+        _ensure_declared(cdll)
         self._lib = cdll
         self._gil = _gil_lib
         self._h = cdll.jy_eng_new()
         self._out = (ctypes.c_uint8 * _OUT_CAP)()
         self._offs = (ctypes.c_int64 * _MAX_ARGS)()
         self._lens = (ctypes.c_int64 * _MAX_ARGS)()
-        self._changed = (ctypes.c_int32 * 5)()
+        self._changed = (ctypes.c_int32 * len(self.TYPE_ORDER))()
         self._tlog_vals: list[bytes] = []  # native vid -> bytes mirror
         self._sender_seen = [0] * 8  # what sender_tally last counted
+        self._map_seen = [0] * 3  # what map_tally last counted
 
     def __del__(self):
         if getattr(self, "_h", None):
@@ -706,12 +755,192 @@ class ServeEngine:
         out.sort()
         return out
 
+
+    # ---- MAP field table ops (native/engine.h MapTable) --------------------
+
+    def map_rows(self) -> int:
+        return self._lib.jy_map_rows(self._h)
+
+    def map_rid_count(self) -> int:
+        return self._lib.jy_map_rid_count(self._h)
+
+    def map_rids(self) -> list[int]:
+        """The replica ids the table knows: a device row's columns."""
+        out = np.zeros(self.map_rid_count(), np.uint64)
+        self._lib.jy_map_rids(self._h, out.ctypes.data)
+        return out.tolist()
+
+    def map_reserve(self, keys: int, fields: int) -> None:
+        self._lib.jy_map_reserve(self._h, keys, fields)
+
+    def map_set_rid(self, rid: int) -> None:
+        """The replica id a natively settled ``MAP TREG SET`` edits as."""
+        self._lib.jy_map_set_rid(self._h, rid)
+
+    def map_find(self, key: bytes, field: bytes) -> int:
+        return self._lib.jy_map_find(self._h, key, len(key), field, len(field))
+
+    def map_set(self, key: bytes, field: bytes, rid: int, ts: int,
+                value: bytes) -> int:
+        return self._lib.jy_map_set(
+            self._h, key, len(key), field, len(field), rid, ts, value,
+            len(value),
+        )
+
+    def map_del(self, row: int) -> bool:
+        return bool(self._lib.jy_map_del(self._h, row))
+
+    def map_note_edit(self, row: int) -> None:
+        self._lib.jy_map_note_edit(self._h, row)
+
+    def map_get(self, row: int):
+        """(value, ts) of a LIVE field row, else None."""
+        ts = ctypes.c_uint64()
+        ptr = ctypes.c_void_p()
+        n = ctypes.c_int64()
+        if not self._lib.jy_map_get(
+            self._h, row, ctypes.byref(ts), ctypes.byref(ptr), ctypes.byref(n)
+        ):
+            return None
+        return ctypes.string_at(ptr, n.value), ts.value
+
+    def map_field_name(self, row: int) -> bytes:
+        ptr = ctypes.c_void_p()
+        n = ctypes.c_int64()
+        self._lib.jy_map_field_name(
+            self._h, row, ctypes.byref(ptr), ctypes.byref(n)
+        )
+        return ctypes.string_at(ptr, n.value)
+
+    def map_mark_mixed(self, key: bytes) -> None:
+        self._lib.jy_map_mark_mixed(self._h, key, len(key))
+
+    def map_is_mixed(self, key: bytes) -> bool:
+        return bool(self._lib.jy_map_is_mixed(self._h, key, len(key)))
+
+    def map_record(self, key: bytes, count: bool = False):
+        """A record's live field rows, in name order, as an int64 array
+        (``count``: tallied as one GETALL)."""
+        cap = 16
+        while True:
+            rows = np.empty(cap, np.int64)
+            n = self._lib.jy_map_record(
+                self._h, key, len(key), rows.ctypes.data, cap, int(count)
+            )
+            if n >= 0:
+                return rows[:n]
+            cap = -n
+
+    def map_join_unit(self, packed: bytes, ver: dict, tomb: dict, ts: int,
+                      value: bytes) -> int:
+        """Join one foreign TREG unit under its packed key; -1 when the
+        key names no (key, field)."""
+        pairs = [
+            np.fromiter(chain.from_iterable(d.items()), np.uint64, 2 * len(d))
+            for d in (ver, tomb)
+        ]
+        return self._lib.jy_map_join_unit(
+            self._h, packed, len(packed), pairs[0].ctypes.data, len(ver),
+            pairs[1].ctypes.data, len(tomb), ts, value, len(value),
+        )
+
+    def map_load_wire(self, payload, count: int) -> None:
+        buf = np.frombuffer(payload, np.uint8)
+        self._lib.jy_map_load_wire(self._h, buf.ctypes.data, len(buf), count)
+
+    def map_wire(self, rows=None):
+        """``rows`` (or, with None, every row sorted by packed key) as
+        their wire units: (the units' count, their bytes concatenated,
+        where each unit starts in them)."""
+        if rows is None:
+            n = self.map_rows()
+            need = self._lib.jy_map_wire_build(self._h, None, 0)
+        else:
+            rows = np.ascontiguousarray(rows, np.int64)
+            n = len(rows)
+            need = self._lib.jy_map_wire_build(self._h, rows.ctypes.data, n)
+        out = np.empty(need, np.uint8)
+        starts = np.empty(n, np.int64)
+        self._lib.jy_map_wire_take(self._h, out.ctypes.data, starts.ctypes.data)
+        return n, out.tobytes(), starts.tolist()
+
+    def map_pend_count(self) -> int:
+        return self._lib.jy_map_pend_count(self._h)
+
+    def map_dirty_count(self) -> int:
+        return self._lib.jy_map_dirty_count(self._h)
+
+    def map_export_planes(
+        self, ki, cells, ts_hi, ts_lo, rank_hi, rank_lo, vid, dense: bool
+    ) -> int:
+        """The rows changed since the last drain as the drain's batch
+        planes (`treg_export_planes`' contract, plus ``cells``: the
+        (batch, 4 * replicas) u32 plane of ver | tomb columns)."""
+        cap = len(vid)
+        arrs = (ts_hi, ts_lo, rank_hi, rank_lo)
+        if (
+            len(ki) < self.map_pend_count()
+            or ki.dtype != np.int32
+            or vid.dtype != np.int32
+            or cells.dtype != np.uint32
+            or cells.ndim != 2
+            or cells.shape[0] != cap
+            or cells.shape[1] % 4
+            or any(len(p) != cap or p.dtype != np.uint32 for p in arrs)
+            or not all(a.flags.c_contiguous for a in (ki, vid, cells, *arrs))
+        ):
+            raise ValueError("map_export_planes: batch arrays do not fit")
+        n = self._lib.jy_map_export_planes(
+            self._h, ki.ctypes.data, cells.ctypes.data, cells.shape[1] // 4,
+            ts_hi.ctypes.data, ts_lo.ctypes.data, rank_hi.ctypes.data,
+            rank_lo.ctypes.data, vid.ctypes.data, cap, int(dense),
+        )
+        if n < 0:
+            raise ValueError("map_export_planes: a slot outside the batch")
+        return n
+
+    def map_settle_ties(self, rows):
+        rows = np.array(rows, np.int32)  # a copy: compacted in place
+        vids = np.empty(len(rows), np.int32)
+        m = self._lib.jy_map_settle_ties(
+            self._h, rows.ctypes.data, len(rows), vids.ctypes.data
+        )
+        return rows[:m], vids[:m]
+
+    def map_clear_pend(self) -> None:
+        self._lib.jy_map_clear_pend(self._h)
+
+    def map_take_dirty(self):
+        """Field rows edited locally since the last flush; clears."""
+        return self._export_sync_dirty(self._lib.jy_map_take, 0)
+
+    def map_take_sync(self):
+        """Field rows changed since the last digest pass; clears."""
+        return self._export_sync_dirty(self._lib.jy_map_take, 1)
+
+    def map_tally(self) -> None:
+        """Bring the registry's `drain.MAP.*` command tallies up to the
+        table's counters (`pull_tallies`)."""
+        now = (ctypes.c_uint64 * 3)()
+        self._lib.jy_map_tallies(self._h, now)
+        seen, self._map_seen = self._map_seen, list(now)
+        reg = resolve_registry(self)
+        reg.tally("drain.MAP.sets", now[0] - seen[0])
+        reg.tally("drain.MAP.getalls", now[1] - seen[1])
+        reg.tally("drain.MAP.getall_fields", now[2] - seen[2])
+
+    def pull_tallies(self) -> None:
+        """What the registry calls before it reports its tallies: the
+        counters that live in the library are read now."""
+        self.sender_tally()
+        self.map_tally()
+
     # the engine's changed/served-counter type order (serve_engine.cpp)
-    TYPE_ORDER = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON")
+    TYPE_ORDER = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON", "MAP")
 
     def served_counts(self) -> dict[str, int]:
         """Commands settled natively since startup, per data type."""
-        out = np.zeros(5, np.uint64)
+        out = np.zeros(len(self.TYPE_ORDER), np.uint64)
         self._lib.jy_eng_served(self._h, out.ctypes.data)
         return dict(zip(self.TYPE_ORDER, out.tolist()))
 
@@ -798,7 +1027,7 @@ class ServeEngine:
         self.metrics = registry
         registry.tally("serving.ENGINE.reply_buffer_bytes", len(self._out))
         me = weakref.ref(self)
-        registry.sender_fn = lambda: (e := me()) and e.sender_tally()
+        registry.sender_fn = lambda: (e := me()) and e.pull_tallies()
 
     def _grow_out(self, need: int) -> None:
         """Replace the reply buffer by one of the next power of two that
@@ -818,12 +1047,12 @@ class ServeEngine:
 
     def scan_apply(self, buf, held: int = ALL_TYPES):
         """Apply a pipelined burst under the repo locks of the types in
-        ``held`` (bit i: type i of the order G, PN, TREG, TLOG, UJSON;
+        ``held`` (bit i: type i of `TYPE_ORDER`;
         the default is a caller that owns the engine alone): the run of
-        commands ahead up to the first that names another of the five
+        commands ahead up to the first that names another of the six
         types, which is left where it is (rc 5). Returns
         (rc, consumed, n: the replies' length, unhandled: list[bytes] |
-        None, changed: tuple of 5 per-type counts in that order); the
+        None, changed: tuple of 6 per-type counts in that order); the
         replies are the first ``n`` bytes of the reply
         array, which the next burst reuses: `reply_bytes` copies them
         out, `sender_send` hands them to the sender. rc as
@@ -831,7 +1060,7 @@ class ServeEngine:
         the reply buffer grows to the reply and the burst runs again)
         and its 4 (counted here, handed on as 1)."""
         if not buf:
-            return 0, 0, 0, None, (0, 0, 0, 0, 0)
+            return 0, 0, 0, None, (0,) * len(self.TYPE_ORDER)
         base = ctypes.addressof(ctypes.c_char.from_buffer(buf))
         out_len = ctypes.c_int64()
         consumed = ctypes.c_int64()
@@ -945,12 +1174,12 @@ class ServeEngine:
 
     def types_ahead(self, buf) -> int:
         """What the run of commands at the head of ``buf`` names, as
-        `scan_apply` would see it: the low five bits are the set of
+        `scan_apply` would see it: the low six bits are the set of
         engine types (the ``held`` order) named by the complete commands
         up to the first that names none of them or cannot be read (64
         commands ahead at most);
-        ``>> 8`` is the FIRST command's type, 5 for another first word
-        (SYSTEM, MAP, ...), 7 where no complete command names one.
+        ``>> 8`` is the FIRST command's type, 6 for another first word
+        (SYSTEM, TENSOR, ...), 7 where no complete command names one.
         Reads only."""
         if not buf:
             return 7 << 8

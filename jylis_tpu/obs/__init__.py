@@ -204,7 +204,12 @@ SERVING = (
 # view because a delta was too wide for the store's pinned grid, rows
 # gathered and decoded for a document with no decoded view, and renders
 # that had to sort a path's tokens because the view kept no order for
-# it yet (a view's first render of the path). ENGINE:
+# it yet (a view's first render of the path). MAP (models/repo_map.py):
+# SETs acknowledged, GETALLs answered and the fields those rendered (the
+# native field table's own counters for `MAP TREG`, read when a surface
+# reports, plus the Python path's), and field rows whose register's
+# 8-byte prefix tied on the device and were settled by the host table.
+# ENGINE:
 # times the reply buffer was replaced by a larger one (a reply alone
 # outgrew it), the bytes it holds now (it only grows, so the sum of its
 # steps), and commands whose reply passed the buffer's ceiling and went
@@ -243,6 +248,10 @@ TALLIES = (
     "drain.UJSON.row_rewrites",
     "drain.UJSON.row_reads",
     "drain.UJSON.render_sorts",
+    "drain.MAP.sets",
+    "drain.MAP.getalls",
+    "drain.MAP.getall_fields",
+    "drain.MAP.tie_rows",
     "serving.ENGINE.reply_grows",
     "serving.ENGINE.reply_buffer_bytes",
     "serving.ENGINE.oversize_defers",

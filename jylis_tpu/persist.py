@@ -80,7 +80,7 @@ def write_snapshot(batches, path: str) -> None:
             # load validation refuses the file and moves it aside
             data = faults.point(
                 "snapshot.write",
-                frame(codec.encode(MsgPushDeltas(name, tuple(batch)))),
+                frame(codec.encode(MsgPushDeltas(name, batch))),
             )
             if data is not None:
                 f.write(data)
@@ -123,7 +123,9 @@ def load_snapshot(database, path: str) -> int:
     msgs = []
     try:
         for body in frames:
-            msg = codec.decode(body)
+            # lazy: a MAP batch the native field table can read stays
+            # its (checked) wire bytes, loaded in one call below
+            msg = codec.decode(body, lazy=True)
             if not isinstance(msg, MsgPushDeltas):
                 raise SnapshotError("unexpected message in snapshot")
             msgs.append(msg)
@@ -156,7 +158,7 @@ def load_snapshot(database, path: str) -> int:
             )
     # fully validated: only now touch the database
     for msg in msgs:
-        database.manager(msg.name).repo.load_state(list(msg.batch))
+        database.manager(msg.name).repo.load_state(msg.batch)
     # restored state lands on the device NOW: converge only buffers, and
     # leaving a whole snapshot in host pending buffers would bypass the
     # drain thresholds and tax every read with the merge path

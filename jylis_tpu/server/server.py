@@ -513,16 +513,16 @@ class Server:
         self._s_lock_wait = self._reg.seam("lock.wait_serve")
         # what a native round takes, by the set of types it names (the
         # engine's `held` bits): (locks, their managers each with its
-        # type's bit number, every OTHER engine lock). Locks in DATABASE
-        # MAP order (TREG, TLOG, G, PN, UJSON), the order
-        # database.all_locks takes them in
+        # type's bit number, every OTHER engine lock). Locks in the
+        # DATABASE's map's order (TREG, TLOG, G, PN, UJSON, MAP), the
+        # order database.all_locks takes them in
         self._mgrs = self._rounds = ()
         self._engine = database.native_engine
         if self._engine is not None:
             self._mgrs = mgrs = tuple(
                 database.manager(n) for n in self._ENGINE_TYPES
             )
-            order = (2, 3, 0, 1, 4)
+            order = (2, 3, 0, 1, 4, 5)
             self._rounds = tuple(
                 (
                     tuple(mgrs[i]._lock for i in order if held >> i & 1),
@@ -708,7 +708,7 @@ class Server:
 
     # the engine's type order (serve_engine.cpp scan_apply2: `held`'s
     # bits, `changed`'s cells)
-    _ENGINE_TYPES = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON")
+    _ENGINE_TYPES = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON", "MAP")
 
     async def _write_wait(self, door, t_stage: float) -> float:
         """``await door.drain()`` with bytes the consumer has not taken:
@@ -806,7 +806,7 @@ class Server:
         complement (negative), nothing taken: the round sleeps in that
         lock's line, where the Python path would sleep. Never yields."""
         ahead = self._engine.types_ahead(view)
-        held = ahead & 31
+        held = ahead & 63
         if RepoLock.take_all(self._rounds[held][0]):
             return held
         held = 1 << (ahead >> 8)

@@ -767,6 +767,469 @@ struct TlogTable {
     }
 };
 
+// ---- MAP field table -------------------------------------------------------
+//
+// `MAP TREG`: a record is a key whose fields are last-writer-wins
+// registers (models/repo_map.py, ops/compose.py). One row a FIELD, holding
+// the field's whole product state: per-replica edit counters `ver`, the
+// removal tombstone `tomb` (both pointwise max, by replica column) and the
+// inner register (ts, value) under TregTable's (ts, value-bytes) rule. The
+// host row IS the state (every write and every foreign unit joins it at
+// once, so a read never waits for a drain); the device table mirrors it:
+// rows changed since the last drain (`pend_rows`) leave as the drain's
+// batch planes, `ver`/`tomb` as the counters' cells and the register as
+// TREG's five planes with a per-row generation in the vid plane (see
+// "TREG table": the kernel compares ids only at one row).
+//
+// A record's fields are reachable from its key: `kfields[key row]` holds
+// the key's field rows in ascending byte order of their names, so GETALL
+// renders them with no scan and no sort, and a field is found by a binary
+// search of ten names. Fields of any OTHER inner type stay in the Python
+// oracle's table (models/map_table.py PyMapTable); a key that has one is
+// `kmixed`, and every command on it is the oracle path's.
+
+constexpr uint8_t M_DIRTY = 1;  // edited locally since the last flush
+constexpr uint8_t M_PEND = 2;   // changed since the last device drain
+constexpr uint8_t M_SYNC = 4;   // changed since the last digest pass
+
+// LEB128 (cluster/codec.py, utils/wire.py): false on a truncated varint
+// or one that does not fit 64 bits
+inline bool rd_varint(const uint8_t* p, int64_t n, int64_t* pos,
+                      uint64_t* out) {
+    uint64_t v = 0;
+    for (int shift = 0; shift < 64; shift += 7) {
+        if (*pos >= n) return false;
+        uint8_t b = p[(*pos)++];
+        if (shift == 63 && (b & 0x7E)) return false;  // past 64 bits
+        v |= static_cast<uint64_t>(b & 0x7F) << shift;
+        if (!(b & 0x80)) {
+            *out = v;
+            return true;
+        }
+    }
+    return false;
+}
+
+inline void wr_varint(std::vector<uint8_t>& out, uint64_t v) {
+    while (v >= 0x80) {
+        out.push_back(static_cast<uint8_t>(v) | 0x80);
+        v >>= 7;
+    }
+    out.push_back(static_cast<uint8_t>(v));
+}
+
+struct MapTable {
+    KeyIndex kidx;                              // record keys
+    std::vector<std::vector<int32_t>> kfields;  // field rows, by name
+    std::vector<uint8_t> kmixed;  // the oracle's table holds fields of it
+    // per field row
+    std::vector<int32_t> fkey;
+    std::vector<uint8_t> fnames;  // field-name arena, append-only
+    std::vector<int64_t> fname_off;
+    std::vector<int32_t> fname_len;
+    std::vector<uint64_t> ver, tomb;  // rows x hcap, by replica column
+    std::vector<uint64_t> reg_ts;
+    std::vector<std::string> reg_val;
+    std::vector<int32_t> reg_gen;  // the mirror's vid; -1 while bottom
+    std::vector<uint8_t> flags;
+    std::vector<int64_t> dirty_rows, pend_rows, sync_rows;
+    // replica id <-> column, in order of first sight
+    std::vector<uint64_t> rids;
+    std::unordered_map<uint64_t, int32_t> rid_col;
+    int64_t hcap = 1;  // columns a row holds (doubles)
+    // acknowledged SETs, GETALLs served, fields those rendered
+    uint64_t n_sets = 0, n_getalls = 0, n_getall_fields = 0;
+    std::vector<uint8_t> wire_buf;  // rows as wire units, built then taken
+    std::vector<int64_t> wire_starts;  // where each unit begins in it
+
+    int64_t rows() const { return static_cast<int64_t>(fkey.size()); }
+
+    const uint8_t* fname(int64_t row) const {
+        return fnames.data() + fname_off[row];
+    }
+
+    static int cmp_bytes(const uint8_t* a, int64_t an, const uint8_t* b,
+                         int64_t bn) {
+        int c = memcmp(a, b, static_cast<size_t>(an < bn ? an : bn));
+        if (c != 0) return c;
+        return an < bn ? -1 : (an > bn ? 1 : 0);
+    }
+
+    int32_t col_for(uint64_t rid) {
+        auto it = rid_col.find(rid);
+        if (it != rid_col.end()) return it->second;
+        int32_t col = static_cast<int32_t>(rids.size());
+        rids.push_back(rid);
+        rid_col.emplace(rid, col);
+        if (col >= hcap) {  // re-stride both planes to twice the columns
+            int64_t wide = hcap * 2;
+            for (std::vector<uint64_t>* plane : {&ver, &tomb}) {
+                std::vector<uint64_t> fresh(
+                    static_cast<size_t>(rows() * wide), 0);
+                for (int64_t r = 0; r < rows(); r++)
+                    memcpy(&fresh[r * wide], &(*plane)[r * hcap],
+                           static_cast<size_t>(hcap) * 8);
+                plane->swap(fresh);
+            }
+            hcap = wide;
+        }
+        return col;
+    }
+
+    int64_t upsert_key(const uint8_t* k, int64_t n) {
+        auto [row, fresh] = kidx.upsert(k, n);
+        if (fresh) {
+            kfields.emplace_back();
+            kmixed.push_back(0);
+        }
+        return row;
+    }
+
+    // position of `name` among the key's fields: (index, found)
+    std::pair<size_t, bool> locate(int64_t krow, const uint8_t* f,
+                                   int64_t fn) const {
+        const std::vector<int32_t>& v = kfields[krow];
+        size_t lo = 0, hi = v.size();
+        while (lo < hi) {
+            size_t mid = (lo + hi) / 2;
+            int c = cmp_bytes(fname(v[mid]), fname_len[v[mid]], f, fn);
+            if (c == 0) return {mid, true};
+            if (c < 0)
+                lo = mid + 1;
+            else
+                hi = mid;
+        }
+        return {lo, false};
+    }
+
+    int64_t find_field(const uint8_t* k, int64_t kn, const uint8_t* f,
+                       int64_t fn) const {
+        int64_t krow = kidx.find(k, kn);
+        if (krow < 0) return -1;
+        auto [at, found] = locate(krow, f, fn);
+        return found ? kfields[krow][at] : -1;
+    }
+
+    int64_t upsert_field(int64_t krow, const uint8_t* f, int64_t fn) {
+        auto [at, found] = locate(krow, f, fn);
+        if (found) return kfields[krow][at];
+        int64_t row = rows();
+        fkey.push_back(static_cast<int32_t>(krow));
+        fname_off.push_back(static_cast<int64_t>(fnames.size()));
+        fname_len.push_back(static_cast<int32_t>(fn));
+        fnames.insert(fnames.end(), f, f + fn);
+        ver.resize(ver.size() + static_cast<size_t>(hcap), 0);
+        tomb.resize(tomb.size() + static_cast<size_t>(hcap), 0);
+        reg_ts.push_back(0);
+        reg_val.emplace_back();
+        reg_gen.push_back(-1);
+        flags.push_back(0);
+        std::vector<int32_t>& v = kfields[krow];
+        v.insert(v.begin() + static_cast<int64_t>(at),
+                 static_cast<int32_t>(row));
+        return row;
+    }
+
+    // capacity a restore is about to need (no regrowth while it loads)
+    void reserve(int64_t keys, int64_t fields) {
+        fkey.reserve(fields);
+        fname_off.reserve(fields);
+        fname_len.reserve(fields);
+        ver.reserve(static_cast<size_t>(fields * hcap));
+        tomb.reserve(static_cast<size_t>(fields * hcap));
+        reg_ts.reserve(fields);
+        reg_val.reserve(fields);
+        reg_gen.reserve(fields);
+        flags.reserve(fields);
+        kfields.reserve(keys);
+        kmixed.reserve(keys);
+    }
+
+    bool live(int64_t row) const {
+        const uint64_t* v = &ver[row * hcap];
+        const uint64_t* t = &tomb[row * hcap];
+        for (int64_t c = 0; c < hcap; c++)
+            if (v[c] > t[c]) return true;
+        return false;
+    }
+
+    void mark(int64_t row, uint8_t bits) {
+        uint8_t fresh = bits & ~flags[row];
+        flags[row] |= bits;
+        if (fresh & M_DIRTY) dirty_rows.push_back(row);
+        if (fresh & M_PEND) pend_rows.push_back(row);
+        if (fresh & M_SYNC) sync_rows.push_back(row);
+    }
+
+    void reg_join(int64_t row, uint64_t ts, const uint8_t* v, int64_t n) {
+        if (!TregTable::wins(ts, v, n, reg_ts[row], reg_val[row])) return;
+        reg_ts[row] = ts;
+        reg_val[row].assign(reinterpret_cast<const char*>(v), n);
+        reg_gen[row] = static_cast<int32_t>(
+            (static_cast<uint32_t>(reg_gen[row]) + 1u) & 0x7fffffffu);
+    }
+
+    // local SET (MapCRDT.set_field): the editor's counter advances by
+    // one, the write joins the register
+    int64_t set(const uint8_t* k, int64_t kn, const uint8_t* f, int64_t fn,
+                uint64_t rid, uint64_t ts, const uint8_t* v, int64_t n) {
+        int32_t col = col_for(rid);
+        int64_t row = upsert_field(upsert_key(k, kn), f, fn);
+        ver[row * hcap + col]++;
+        reg_join(row, ts, v, n);
+        mark(row, M_DIRTY | M_PEND | M_SYNC);
+        n_sets++;
+        return row;
+    }
+
+    // local DEL (MapCRDT.del_field): the tombstone covers every edit
+    // this replica has seen; false when there is nothing live to remove
+    bool del(int64_t row) {
+        if (!live(row)) return false;
+        uint64_t* v = &ver[row * hcap];
+        uint64_t* t = &tomb[row * hcap];
+        for (int64_t c = 0; c < hcap; c++)
+            if (v[c] > t[c]) t[c] = v[c];
+        mark(row, M_DIRTY | M_PEND | M_SYNC);
+        return true;
+    }
+
+    // split a packed (key, field) wire key (ops/compose.pack_field)
+    static bool unpack(const uint8_t* p, int64_t n, int64_t* koff,
+                       int64_t* klen) {
+        int64_t pos = 0;
+        uint64_t kn = 0;
+        if (!rd_varint(p, n, &pos, &kn)) return false;
+        if (kn > static_cast<uint64_t>(n - pos)) return false;
+        *koff = pos;
+        *klen = static_cast<int64_t>(kn);
+        return true;
+    }
+
+    // one unit of a MAP batch's wire bytes (cluster/codec.py delta/MAP
+    // under its packed key), parsed in place. false: malformed, a varint
+    // past u64, or an inner type other than TREG (the oracle's to read)
+    struct WireUnit {
+        const uint8_t *key, *field, *val;
+        int64_t kn, fn, vn;
+        uint64_t ts;
+        int64_t ver_at, nver, tomb_at, ntomb;  // (rid, seq) varint pairs
+    };
+
+    static bool read_unit(const uint8_t* p, int64_t n, int64_t* pos,
+                          WireUnit* u) {
+        uint64_t len = 0;
+        if (!rd_varint(p, n, pos, &len) ||
+            len > static_cast<uint64_t>(n - *pos))
+            return false;
+        int64_t koff = 0, kn = 0;
+        if (!unpack(p + *pos, static_cast<int64_t>(len), &koff, &kn))
+            return false;
+        u->key = p + *pos + koff;
+        u->kn = kn;
+        u->field = u->key + kn;
+        u->fn = static_cast<int64_t>(len) - koff - kn;
+        *pos += static_cast<int64_t>(len);
+        if (!rd_varint(p, n, pos, &len) || len != 4 || n - *pos < 4 ||
+            memcmp(p + *pos, "TREG", 4) != 0)
+            return false;
+        *pos += 4;
+        uint64_t x = 0;
+        for (int pass = 0; pass < 2; pass++) {
+            uint64_t cnt = 0;
+            if (!rd_varint(p, n, pos, &cnt) ||
+                cnt > static_cast<uint64_t>(n - *pos))
+                return false;
+            (pass ? u->tomb_at : u->ver_at) = *pos;
+            (pass ? u->ntomb : u->nver) = static_cast<int64_t>(cnt);
+            for (uint64_t i = 0; i < 2 * cnt; i++)
+                if (!rd_varint(p, n, pos, &x)) return false;
+        }
+        if (!rd_varint(p, n, pos, &len) ||
+            len > static_cast<uint64_t>(n - *pos))
+            return false;
+        u->val = p + *pos;
+        u->vn = static_cast<int64_t>(len);
+        *pos += u->vn;
+        return rd_varint(p, n, pos, &u->ts);
+    }
+
+    // a whole batch payload (`count` units): can `load_wire` take it
+    static bool check_wire(const uint8_t* p, int64_t n, int64_t count) {
+        int64_t pos = 0;
+        WireUnit u;
+        for (int64_t i = 0; i < count; i++)
+            if (!read_unit(p, n, &pos, &u)) return false;
+        return pos == n;
+    }
+
+    // join a checked batch payload in, unit by unit, with no object a
+    // unit made on the way (boot recovery's 10^7 fields)
+    void load_wire(const uint8_t* p, int64_t n, int64_t count) {
+        int64_t pos = 0;
+        WireUnit u;
+        std::vector<uint64_t> pairs;
+        for (int64_t i = 0; i < count; i++) {
+            if (!read_unit(p, n, &pos, &u)) return;  // checked: cannot be
+            pairs.clear();
+            uint64_t x = 0;
+            for (int64_t at : {u.ver_at, u.tomb_at}) {
+                int64_t cnt = at == u.ver_at ? u.nver : u.ntomb;
+                for (int64_t j = 0; j < 2 * cnt; j++) {
+                    rd_varint(p, n, &at, &x);
+                    pairs.push_back(x);
+                }
+            }
+            join_unit(u, pairs.data(), pairs.data() + 2 * u.nver);
+        }
+    }
+
+    // one foreign unit (a peer's, a restore's, a journal's): pointwise
+    // max of the counters and the tombstone, given as interleaved
+    // (rid, seq) pairs, and the register's join
+    int64_t join_unit(const WireUnit& u, const uint64_t* vp,
+                      const uint64_t* tp) {
+        // columns first: a new replica id may re-stride the planes
+        for (int64_t i = 0; i < u.nver; i++) col_for(vp[2 * i]);
+        for (int64_t i = 0; i < u.ntomb; i++) col_for(tp[2 * i]);
+        int64_t row = upsert_field(upsert_key(u.key, u.kn), u.field, u.fn);
+        for (int64_t i = 0; i < u.nver; i++) {
+            uint64_t& c = ver[row * hcap + rid_col[vp[2 * i]]];
+            if (vp[2 * i + 1] > c) c = vp[2 * i + 1];
+        }
+        for (int64_t i = 0; i < u.ntomb; i++) {
+            uint64_t& c = tomb[row * hcap + rid_col[tp[2 * i]]];
+            if (tp[2 * i + 1] > c) c = tp[2 * i + 1];
+        }
+        reg_join(row, u.ts, u.val, u.vn);
+        mark(row, M_PEND | M_SYNC);
+        return row;
+    }
+
+    // one row as its wire unit under its packed key (delta/MAP): a
+    // {rid: n} span is written in ascending rid order, zero cells left
+    // out, as the oracle's encoder writes a normalised dict
+    void write_unit(std::vector<uint8_t>& out, int64_t row,
+                    std::vector<std::pair<uint64_t, uint64_t>>& tmp) const {
+        int64_t krow = fkey[row];
+        int64_t kn = kidx.key_len[krow], fn = fname_len[row];
+        std::vector<uint8_t> head;
+        wr_varint(head, static_cast<uint64_t>(kn));
+        wr_varint(out, head.size() + static_cast<uint64_t>(kn + fn));
+        out.insert(out.end(), head.begin(), head.end());
+        out.insert(out.end(), kidx.key_ptr(krow), kidx.key_ptr(krow) + kn);
+        out.insert(out.end(), fname(row), fname(row) + fn);
+        out.push_back(4);
+        out.insert(out.end(), {'T', 'R', 'E', 'G'});
+        for (const std::vector<uint64_t>* plane : {&ver, &tomb}) {
+            tmp.clear();
+            for (int64_t c = 0; c < static_cast<int64_t>(rids.size()); c++)
+                if ((*plane)[row * hcap + c])
+                    tmp.emplace_back(rids[c], (*plane)[row * hcap + c]);
+            std::sort(tmp.begin(), tmp.end());
+            wr_varint(out, tmp.size());
+            for (auto& [rid, v] : tmp) {
+                wr_varint(out, rid);
+                wr_varint(out, v);
+            }
+        }
+        wr_varint(out, reg_val[row].size());
+        out.insert(out.end(), reg_val[row].begin(), reg_val[row].end());
+        wr_varint(out, reg_ts[row]);
+    }
+
+    // the packed wire keys of two rows, compared as bytes (dump order:
+    // the oracle sorts its packed keys). Equal key lengths put the
+    // varint heads equal; else the heads differ in their first bytes.
+    bool packed_less(int64_t a, int64_t b) const {
+        int64_t ka = fkey[a], kb = fkey[b];
+        int64_t an = kidx.key_len[ka], bn = kidx.key_len[kb];
+        if (an != bn) {
+            std::vector<uint8_t> ha, hb;
+            wr_varint(ha, static_cast<uint64_t>(an));
+            wr_varint(hb, static_cast<uint64_t>(bn));
+            ha.insert(ha.end(), kidx.key_ptr(ka), kidx.key_ptr(ka) + an);
+            ha.insert(ha.end(), fname(a), fname(a) + fname_len[a]);
+            hb.insert(hb.end(), kidx.key_ptr(kb), kidx.key_ptr(kb) + bn);
+            hb.insert(hb.end(), fname(b), fname(b) + fname_len[b]);
+            return cmp_bytes(ha.data(), static_cast<int64_t>(ha.size()),
+                             hb.data(), static_cast<int64_t>(hb.size())) < 0;
+        }
+        int c = memcmp(kidx.key_ptr(ka), kidx.key_ptr(kb),
+                       static_cast<size_t>(an));
+        if (c != 0) return c < 0;
+        return cmp_bytes(fname(a), fname_len[a], fname(b), fname_len[b]) < 0;
+    }
+
+    // drain prologue: the rows changed since the last drain as the
+    // drain's batch planes, in pend_rows order (TregTable::export_planes'
+    // contract for the register's five; `cells` is the counters' plane of
+    // `rep` columns a polarity: ver hi | tomb hi | ver lo | tomb lo).
+    // Returns the rows written, -1 when one would fall outside `cap` or
+    // the table knows more replicas than `rep`.
+    int64_t export_planes(int32_t* ki, uint32_t* cells, int64_t rep,
+                          uint32_t* ts_hi, uint32_t* ts_lo,
+                          uint32_t* rank_hi, uint32_t* rank_lo, int32_t* vid,
+                          int64_t cap, bool dense) const {
+        int64_t n = static_cast<int64_t>(pend_rows.size());
+        int64_t known = static_cast<int64_t>(rids.size());
+        if (known > rep || (!dense && n > cap)) return -1;
+        for (int64_t i = 0; i < n; i++) {
+            int64_t row = pend_rows[i];
+            int64_t slot = dense ? row : i;
+            if (slot >= cap) return -1;
+            ki[i] = static_cast<int32_t>(row);
+            uint32_t* c = cells + slot * 4 * rep;
+            for (int64_t j = 0; j < known; j++) {
+                uint64_t v = ver[row * hcap + j], t = tomb[row * hcap + j];
+                c[j] = static_cast<uint32_t>(v >> 32);
+                c[rep + j] = static_cast<uint32_t>(t >> 32);
+                c[2 * rep + j] = static_cast<uint32_t>(v);
+                c[3 * rep + j] = static_cast<uint32_t>(t);
+            }
+            uint64_t rank = TregTable::prefix_rank(reg_val[row]);
+            ts_hi[slot] = static_cast<uint32_t>(reg_ts[row] >> 32);
+            ts_lo[slot] = static_cast<uint32_t>(reg_ts[row]);
+            rank_hi[slot] = static_cast<uint32_t>(rank >> 32);
+            rank_lo[slot] = static_cast<uint32_t>(rank);
+            vid[slot] = reg_gen[row];
+        }
+        return n;
+    }
+
+    // rows the device flagged (ts and 8-byte rank equal, ids differ): the
+    // host row is the join of everything the mirror has seen, so the row
+    // it sent is the winner; the mirror's id is patched to its generation
+    int64_t settle_ties(int32_t* rws, int64_t n, int32_t* vids) const {
+        int64_t m = 0;
+        for (int64_t i = 0; i < n; i++) {
+            int64_t row = rws[i];
+            if (row < 0 || row >= rows()) continue;
+            rws[m] = static_cast<int32_t>(row);
+            vids[m++] = reg_gen[row];
+        }
+        return m;
+    }
+
+    void clear_pend() {
+        for (int64_t row : pend_rows) flags[row] &= ~M_PEND;
+        pend_rows.clear();
+    }
+
+    // rows of one list (M_DIRTY or M_SYNC), handed over and cleared
+    int64_t take(uint8_t bit, int64_t* out, int64_t cap) {
+        std::vector<int64_t>& list = bit == M_DIRTY ? dirty_rows : sync_rows;
+        int64_t n = static_cast<int64_t>(list.size());
+        if (n > cap) return -n;
+        for (int64_t i = 0; i < n; i++) {
+            out[i] = list[i];
+            flags[list[i]] &= ~bit;
+        }
+        list.clear();
+        return n;
+    }
+};
+
 // ---- UJSON serving memo ----------------------------------------------------
 //
 // The ORSWOT document lattice stays in Python (host docs) or on the
@@ -1109,11 +1572,13 @@ struct Engine {
     TlogTable tlog;
     UjsonQueue uq;
     UjsonTable uj;
-    // commands settled natively, per type (G, PN, TREG, TLOG, UJSON) —
+    MapTable map;
+    uint64_t map_rid = 0;  // the node's own replica id (RepoMAP sets it)
+    // commands settled natively, per type (G, PN, TREG, TLOG, UJSON, MAP) —
     // reads included; deferred commands count on the Python side instead
     // (models/manager.py _apply_core's per-Database tally). SYSTEM
     // METRICS reports the sum.
-    uint64_t served[5] = {0, 0, 0, 0, 0};
+    uint64_t served[6] = {0, 0, 0, 0, 0, 0};
     // atomic: a counters' read from another thread may meet its making
     std::atomic<Sender*> sender{nullptr};
 

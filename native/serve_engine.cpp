@@ -23,15 +23,37 @@ namespace {
 // tests/test_serve_tables.py)
 constexpr int64_t TREG_PENDING_DRAIN = 4096;
 
-// Which of the engine's five types a command's first word names, in the
-// changed[] order (G, PN, TREG, TLOG, UJSON); 5: any other word (SYSTEM,
-// MAP, ...: no table of the engine's).
+// MAP TREG's threshold is TREG's (repo_map.py PENDING_DRAIN_THRESHOLD)
+constexpr int64_t MAP_PENDING_DRAIN = 4096;
+
+// Which of the engine's six types a command's first word names, in the
+// changed[] order (G, PN, TREG, TLOG, UJSON, MAP); N_TYPES: any other
+// word (SYSTEM, TENSOR, ...: no table of the engine's).
+constexpr int32_t N_TYPES = 6;
+
 inline int32_t type_of(const uint8_t* buf, int64_t off, int64_t len) {
-    static const char* const names[5] = {"GCOUNT", "PNCOUNT", "TREG", "TLOG",
-                                         "UJSON"};
-    for (int32_t i = 0; i < 5; i++)
+    static const char* const names[N_TYPES] = {"GCOUNT", "PNCOUNT", "TREG",
+                                               "TLOG",   "UJSON",   "MAP"};
+    for (int32_t i = 0; i < N_TYPES; i++)
         if (word_is(buf, off, len, names[i])) return i;
-    return 5;
+    return N_TYPES;
+}
+
+// `*2\r\n$<n>\r\n<value>\r\n:<ts>\r\n`: what TREG GET and MAP TREG GET
+// answer for a set register; `o` has room for the value + 64 bytes
+inline int64_t fmt_pair(uint8_t* o, const std::string& val, uint64_t ts) {
+    int64_t n = 0;
+    memcpy(o + n, "*2\r\n$", 5);
+    n += 5;
+    n += fmt_u64(o + n, val.size());
+    o[n++] = '\r';
+    o[n++] = '\n';
+    memcpy(o + n, val.data(), val.size());
+    n += static_cast<int64_t>(val.size());
+    o[n++] = '\r';
+    o[n++] = '\n';
+    n += fmt_int_reply(o + n, ts, false);
+    return n;
 }
 
 }  // namespace
@@ -541,10 +563,15 @@ void jy_tlog_clear_deltas(void* e) {
 }
 
 // commands settled natively since startup, per type (G, PN, TREG, TLOG,
-// UJSON) — the SYSTEM METRICS "cmds" surface's native half
+// UJSON, MAP) — the SYSTEM METRICS "cmds" surface's native half
+// the replica id a natively settled MAP SET edits as (the node's own)
+void jy_map_set_rid(void* e, uint64_t rid) {
+    static_cast<Engine*>(e)->map_rid = rid;
+}
+
 void jy_eng_served(void* e, uint64_t* out) {
     Engine* eng = static_cast<Engine*>(e);
-    for (int i = 0; i < 5; i++) out[i] = eng->served[i];
+    for (int i = 0; i < N_TYPES; i++) out[i] = eng->served[i];
 }
 
 // ---- UJSON queue -----------------------------------------------------------
@@ -597,6 +624,195 @@ int64_t jy_uj_memo_len(void* e, const uint8_t* k, int64_t n) {
     return row < 0 ? 0 : static_cast<int64_t>(u.memo[row].size());
 }
 
+// ---- MAP field table (engine.h MapTable) -----------------------------------
+
+int64_t jy_map_rows(void* e) { return static_cast<Engine*>(e)->map.rows(); }
+
+int64_t jy_map_rid_count(void* e) {
+    return static_cast<int64_t>(static_cast<Engine*>(e)->map.rids.size());
+}
+
+// the replica ids the table knows, in column order
+void jy_map_rids(void* e, uint64_t* out) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    for (size_t i = 0; i < t.rids.size(); i++) out[i] = t.rids[i];
+}
+
+void jy_map_reserve(void* e, int64_t keys, int64_t fields) {
+    static_cast<Engine*>(e)->map.reserve(keys, fields);
+}
+
+int64_t jy_map_find(void* e, const uint8_t* k, int64_t kn, const uint8_t* f,
+                    int64_t fn) {
+    return static_cast<Engine*>(e)->map.find_field(k, kn, f, fn);
+}
+
+int64_t jy_map_set(void* e, const uint8_t* k, int64_t kn, const uint8_t* f,
+                   int64_t fn, uint64_t rid, uint64_t ts, const uint8_t* v,
+                   int64_t vn) {
+    return static_cast<Engine*>(e)->map.set(k, kn, f, fn, rid, ts, v, vn);
+}
+
+int32_t jy_map_del(void* e, int64_t row) {
+    return static_cast<Engine*>(e)->map.del(row) ? 1 : 0;
+}
+
+void jy_map_note_edit(void* e, int64_t row) {
+    static_cast<Engine*>(e)->map.mark(row, M_DIRTY | M_SYNC);
+}
+
+// a live field's register; 0 when the row is dead (GET -> null)
+int32_t jy_map_get(void* e, int64_t row, uint64_t* ts, const uint8_t** ptr,
+                   int64_t* len) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    if (!t.live(row)) return 0;
+    *ts = t.reg_ts[row];
+    *ptr = reinterpret_cast<const uint8_t*>(t.reg_val[row].data());
+    *len = static_cast<int64_t>(t.reg_val[row].size());
+    return 1;
+}
+
+void jy_map_field_name(void* e, int64_t row, const uint8_t** ptr,
+                       int64_t* len) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    *ptr = t.fname(row);
+    *len = t.fname_len[row];
+}
+
+void jy_map_mark_mixed(void* e, const uint8_t* k, int64_t kn) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    t.kmixed[t.upsert_key(k, kn)] = 1;
+}
+
+int32_t jy_map_is_mixed(void* e, const uint8_t* k, int64_t kn) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    int64_t krow = t.kidx.find(k, kn);
+    return krow >= 0 && t.kmixed[krow] ? 1 : 0;
+}
+
+// a record's LIVE field rows in name order (KEYS / GETALL on the Python
+// path); -n when `cap` is short of n. GETALL counts itself (`count`).
+int64_t jy_map_record(void* e, const uint8_t* k, int64_t kn, int64_t* out,
+                      int64_t cap, int32_t count) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    int64_t krow = t.kidx.find(k, kn);
+    int64_t n = 0;
+    if (krow >= 0) {
+        if (static_cast<int64_t>(t.kfields[krow].size()) > cap)
+            return -static_cast<int64_t>(t.kfields[krow].size());
+        for (int32_t row : t.kfields[krow])
+            if (t.live(row)) out[n++] = row;
+    }
+    if (count) {
+        t.n_getalls++;
+        t.n_getall_fields += static_cast<uint64_t>(n);
+    }
+    return n;
+}
+
+// one foreign unit under its packed key, its counters and tombstone as
+// interleaved (rid, seq) pairs; -1: the key names no (key, field)
+int64_t jy_map_join_unit(void* e, const uint8_t* packed, int64_t pn,
+                         const uint64_t* ver, int64_t nv,
+                         const uint64_t* tomb, int64_t nt, uint64_t ts,
+                         const uint8_t* v, int64_t vn) {
+    int64_t koff = 0, kn = 0;
+    if (!MapTable::unpack(packed, pn, &koff, &kn)) return -1;
+    MapTable::WireUnit u{};
+    u.key = packed + koff;
+    u.kn = kn;
+    u.field = u.key + kn;
+    u.fn = pn - koff - kn;
+    u.val = v;
+    u.vn = vn;
+    u.ts = ts;
+    u.nver = nv;
+    u.ntomb = nt;
+    return static_cast<Engine*>(e)->map.join_unit(u, ver, tomb);
+}
+
+int32_t jy_map_check_wire(const uint8_t* p, int64_t n, int64_t count) {
+    return MapTable::check_wire(p, n, count) ? 1 : 0;
+}
+
+void jy_map_load_wire(void* e, const uint8_t* p, int64_t n, int64_t count) {
+    static_cast<Engine*>(e)->map.load_wire(p, n, count);
+}
+
+// rows as their wire units (delta/MAP under the packed key), in the
+// order given; `rows` null: every row of the table, sorted by packed key
+// (a state dump). Built into the table's buffer (the bytes' length
+// comes back), then taken out of it by `jy_map_wire_take`.
+int64_t jy_map_wire_build(void* e, const int64_t* rows, int64_t n) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    std::vector<int64_t> all;
+    if (rows == nullptr) {
+        all.resize(static_cast<size_t>(t.rows()));
+        for (int64_t i = 0; i < t.rows(); i++) all[i] = i;
+        std::sort(all.begin(), all.end(), [&t](int64_t a, int64_t b) {
+            return t.packed_less(a, b);
+        });
+        rows = all.data();
+        n = t.rows();
+    }
+    t.wire_buf.clear();
+    t.wire_starts.clear();
+    std::vector<std::pair<uint64_t, uint64_t>> tmp;
+    for (int64_t i = 0; i < n; i++) {
+        t.wire_starts.push_back(static_cast<int64_t>(t.wire_buf.size()));
+        t.write_unit(t.wire_buf, rows[i], tmp);
+    }
+    return static_cast<int64_t>(t.wire_buf.size());
+}
+
+// the built bytes and (n of them) where each unit starts
+void jy_map_wire_take(void* e, uint8_t* out, int64_t* starts) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    if (!t.wire_buf.empty()) memcpy(out, t.wire_buf.data(), t.wire_buf.size());
+    if (!t.wire_starts.empty())
+        memcpy(starts, t.wire_starts.data(), t.wire_starts.size() * 8);
+    std::vector<uint8_t>().swap(t.wire_buf);
+    std::vector<int64_t>().swap(t.wire_starts);
+}
+
+int64_t jy_map_pend_count(void* e) {
+    return static_cast<int64_t>(
+        static_cast<Engine*>(e)->map.pend_rows.size());
+}
+
+int64_t jy_map_dirty_count(void* e) {
+    return static_cast<int64_t>(
+        static_cast<Engine*>(e)->map.dirty_rows.size());
+}
+
+int64_t jy_map_export_planes(void* e, int32_t* ki, uint32_t* cells,
+                             int64_t rep, uint32_t* ts_hi, uint32_t* ts_lo,
+                             uint32_t* rank_hi, uint32_t* rank_lo,
+                             int32_t* vid, int64_t cap, int32_t dense) {
+    return static_cast<Engine*>(e)->map.export_planes(
+        ki, cells, rep, ts_hi, ts_lo, rank_hi, rank_lo, vid, cap, dense != 0);
+}
+
+int64_t jy_map_settle_ties(void* e, int32_t* rows, int64_t n, int32_t* vids) {
+    return static_cast<Engine*>(e)->map.settle_ties(rows, n, vids);
+}
+
+void jy_map_clear_pend(void* e) { static_cast<Engine*>(e)->map.clear_pend(); }
+
+// which: 0 = rows edited since the last flush, 1 = since the last digest
+int64_t jy_map_take(void* e, int32_t which, int64_t* out, int64_t cap) {
+    return static_cast<Engine*>(e)->map.take(which ? M_SYNC : M_DIRTY, out,
+                                             cap);
+}
+
+// acknowledged SETs, GETALLs served, fields those rendered
+void jy_map_tallies(void* e, uint64_t* out) {
+    MapTable& t = static_cast<Engine*>(e)->map;
+    out[0] = t.n_sets;
+    out[1] = t.n_getalls;
+    out[2] = t.n_getall_fields;
+}
+
 // ---- the batch applier -----------------------------------------------------
 //
 // Returns:
@@ -611,20 +827,21 @@ int64_t jy_uj_memo_len(void* e, const uint8_t* k, int64_t n) {
 //   4  as 1, and the reason is a reply of more than out_ceil bytes
 //      (engine.py counts it and hands the caller a 1)
 //   5  stopped BEFORE a command of a type that is not in `held`: nothing
-//      of it is consumed and *n_args is its type (0..4)
+//      of it is consumed and *n_args is its type (0..5)
 //  -1  protocol error at the stop point (serve replies, drop connection)
 //  -2  a command has more than max_args arguments (grow and retry)
-// changed[5] counts state-changing applies per type
-// (G, PN, TREG, TLOG, UJSON) for the caller's on-change notifications.
+// changed[6] counts state-changing applies per type
+// (G, PN, TREG, TLOG, UJSON, MAP) for the caller's on-change notifications.
 //
 // `held` is the set of types (bit i: type i of that order) whose repo
 // lock the caller holds: a run of commands is applied under it, and a
-// command of any other of the five ends the run untouched (code 5). The
+// command of any other of the six ends the run untouched (code 5). The
 // boundary is per TYPE because the state is: a command of type X reads
 // and writes X's own table and nothing of another type's — the counters
 // `t[which]`, TREG `treg`, TLOG `tlog` (its value interner `vals` is a
 // member of the table), UJSON `uq` and `uj` (the write queue and the
-// render memo) — plus its own cells of `changed[]` and `served[]`. What
+// render memo), MAP `map` — plus its own cells of `changed[]` and
+// `served[]`. What
 // every command shares is the caller's: `out`, `offs`, `lens`, one burst
 // at a time on the loop thread. A command of no engine type is handed
 // back (code 1) whatever is held: it touches no table here.
@@ -638,7 +855,7 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
     *out_len = 0;
     *consumed = 0;
     *n_args = 0;
-    for (int i = 0; i < 5; i++) changed[i] = 0;
+    for (int i = 0; i < N_TYPES; i++) changed[i] = 0;
     while (true) {
         if (out_cap - *out_len < 64) return 2;
         int64_t sub_consumed = 0;
@@ -657,8 +874,8 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
             *consumed += sub_consumed;
             continue;
         }
-        int32_t ty = argc >= 1 ? type_of(buf, offs[0], lens[0]) : 5;
-        if (ty < 5 && !((held >> ty) & 1)) {
+        int32_t ty = argc >= 1 ? type_of(buf, offs[0], lens[0]) : N_TYPES;
+        if (ty < N_TYPES && !((held >> ty) & 1)) {
             *n_args = ty;  // the caller takes that lock and comes again
             return 5;
         }
@@ -738,19 +955,7 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
                 int64_t need =
                     static_cast<int64_t>(val->size()) + 64;  // headers + ts
                 if (out_cap - *out_len < need) return no_room(need);
-                uint8_t* o = out + *out_len;
-                int64_t n = 0;
-                memcpy(o + n, "*2\r\n$", 5);
-                n += 5;
-                n += fmt_u64(o + n, val->size());
-                o[n++] = '\r';
-                o[n++] = '\n';
-                memcpy(o + n, val->data(), val->size());
-                n += static_cast<int64_t>(val->size());
-                o[n++] = '\r';
-                o[n++] = '\n';
-                n += fmt_int_reply(o + n, ts, false);
-                *out_len += n;
+                *out_len += fmt_pair(out + *out_len, *val, ts);
                 eng->served[2]++;
                 *consumed += sub_consumed;
                 continue;
@@ -946,16 +1151,109 @@ int32_t jy_eng_scan_apply2(void* ev, const uint8_t* buf, int64_t len,
             return defer();
         }
 
+        // ---- MAP ----------------------------------------------------------
+        // MAP TREG SET / GET / GETALL on a key whose fields are all this
+        // table's; every other inner type, DEL, KEYS and a key the
+        // oracle's table shares are the Python path's
+        if (argc >= 4 && word_is(buf, offs[0], lens[0], "MAP") &&
+            word_is(buf, offs[1], lens[1], "TREG")) {
+            MapTable& t = eng->map;
+            int64_t krow = t.kidx.find(buf + offs[3], lens[3]);
+            if (krow >= 0 && t.kmixed[krow]) return defer();
+            if (argc >= 5 && word_is(buf, offs[2], lens[2], "GET")) {
+                int64_t row = -1;
+                if (krow >= 0) {
+                    auto [at, found] =
+                        t.locate(krow, buf + offs[4], lens[4]);
+                    if (found) row = t.kfields[krow][at];
+                }
+                if (row < 0 || !t.live(row)) {
+                    memcpy(out + *out_len, "$-1\r\n", 5);
+                    *out_len += 5;
+                } else {
+                    int64_t need =
+                        static_cast<int64_t>(t.reg_val[row].size()) + 64;
+                    if (out_cap - *out_len < need) return no_room(need);
+                    *out_len += fmt_pair(out + *out_len, t.reg_val[row],
+                                         t.reg_ts[row]);
+                }
+                eng->served[5]++;
+                *consumed += sub_consumed;
+                continue;
+            }
+            if (argc >= 4 && word_is(buf, offs[2], lens[2], "GETALL")) {
+                int64_t need = 32, n = 0;
+                if (krow >= 0)
+                    for (int32_t row : t.kfields[krow])
+                        if (t.live(row)) {
+                            n++;
+                            need += t.fname_len[row] + 32 +
+                                    static_cast<int64_t>(
+                                        t.reg_val[row].size()) +
+                                    64;
+                        }
+                if (out_cap - *out_len < need) return no_room(need);
+                uint8_t* o = out + *out_len;
+                int64_t m = 0;
+                o[m++] = '*';
+                m += fmt_u64(o + m, static_cast<uint64_t>(2 * n));
+                o[m++] = '\r';
+                o[m++] = '\n';
+                if (krow >= 0)
+                    for (int32_t row : t.kfields[krow]) {
+                        if (!t.live(row)) continue;
+                        o[m++] = '$';
+                        m += fmt_u64(o + m,
+                                     static_cast<uint64_t>(t.fname_len[row]));
+                        o[m++] = '\r';
+                        o[m++] = '\n';
+                        memcpy(o + m, t.fname(row),
+                               static_cast<size_t>(t.fname_len[row]));
+                        m += t.fname_len[row];
+                        o[m++] = '\r';
+                        o[m++] = '\n';
+                        m += fmt_pair(o + m, t.reg_val[row], t.reg_ts[row]);
+                    }
+                *out_len += m;
+                t.n_getalls++;
+                t.n_getall_fields += static_cast<uint64_t>(n);
+                eng->served[5]++;
+                *consumed += sub_consumed;
+                continue;
+            }
+            // SET key field value ts, exactly (the inner write's arity is
+            // the oracle's to refuse)
+            if (argc == 7 && word_is(buf, offs[2], lens[2], "SET")) {
+                uint64_t ts = 0;
+                if (!parse_amount(buf + offs[6], lens[6], &ts))
+                    return defer();  // ParseError -> help
+                // the write about to land would tip the drain threshold:
+                // Python's may_drain path must run it (threaded drain)
+                if (static_cast<int64_t>(t.pend_rows.size()) + 1 >=
+                    MAP_PENDING_DRAIN)
+                    return defer();
+                t.set(buf + offs[3], lens[3], buf + offs[4], lens[4],
+                      eng->map_rid, ts, buf + offs[5], lens[5]);
+                changed[5]++;
+                eng->served[5]++;
+                memcpy(out + *out_len, "+OK\r\n", 5);
+                *out_len += 5;
+                *consumed += sub_consumed;
+                continue;
+            }
+            return defer();
+        }
+
         return defer();  // any other first word: datatype help / SYSTEM
     }
 }
 
 // The types the run of commands AHEAD names, as scan_apply would see
-// them: bits 0..4 are the set of the engine's types (changed[] order)
+// them: bits 0..5 are the set of the engine's types (changed[] order)
 // that the complete commands of `buf` name, up to the first that names
 // no engine type, is incomplete or malformed or has more than max_args
 // arguments (blank inline lines skipped, as there); bits 8.. are the
-// type of the FIRST of them (0..4), 5 for another first word, 7 when
+// type of the FIRST of them (0..5), 6 for another first word, 7 when
 // there is no complete command to name one. Reads only, changes nothing:
 // the server asks it before a round, takes the locks of the set when all
 // of them are free, and else the first command's alone. It looks
@@ -975,11 +1273,12 @@ int32_t jy_eng_types_ahead(const uint8_t* buf, int64_t len, int64_t* offs,
                       &argc) != 1)
             break;
         bool inline_blank = argc == 0 && buf[at] != '*';
-        int32_t ty = argc >= 1 ? type_of(buf + at, offs[0], lens[0]) : 5;
+        int32_t ty =
+            argc >= 1 ? type_of(buf + at, offs[0], lens[0]) : N_TYPES;
         at += consumed;
         if (inline_blank) continue;
         if (first == 7) first = ty;
-        if (ty == 5) break;
+        if (ty == N_TYPES) break;
         mask |= 1 << ty;
     }
     return mask | (first << 8);

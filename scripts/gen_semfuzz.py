@@ -66,7 +66,11 @@ def _value_pool(g: dict) -> list[bytes] | None:
 def _gen_args(rng: random.Random, key: str, g: dict) -> list[bytes]:
     """One client command (list of RESP array args) for grammar entry
     ``g`` — mostly valid, sometimes boundary, sometimes mutated."""
-    tword, sub = key.split(" ")
+    # "TYPE SUB", or a composed type's "MAP TREG SUB": the command's
+    # fixed words, its arguments behind them
+    words = key.split(" ")
+    tword, sub = words[0], words[-1]
+    first = len(words)  # the key argument's index
     roll = rng.random()
     min_argc = g["min_argc"]
     u64_at = set(g["u64_args"])
@@ -78,11 +82,11 @@ def _gen_args(rng: random.Random, key: str, g: dict) -> list[bytes]:
         argc = max(argc, max(opt_at) + 1)
     if pathy and rng.random() < 0.6:
         argc += rng.randrange(1, 3)  # deeper paths stay valid-by-grammar
-    args = [tword.encode(), sub.encode()]
-    for i in range(2, argc):
+    args = [w.encode() for w in words]
+    for i in range(first, argc):
         if i in u64_at or i in opt_at:
             args.append(rng.choice(U64_VALID))
-        elif i == 2:
+        elif i == first:
             args.append(rng.choice(KEYS))
         elif values is not None and i == argc - 1:
             args.append(rng.choice(values))
@@ -93,33 +97,35 @@ def _gen_args(rng: random.Random, key: str, g: dict) -> list[bytes]:
     if roll < 0.70:
         return args
     if roll < 0.85:  # boundary: extremes in place of the friendly pools
-        for i in range(2, len(args)):
+        for i in range(first, len(args)):
             if i in u64_at or i in opt_at:
                 args[i] = rng.choice(
                     [b"0", b"18446744073709551615", b"007"]
                 )
-            elif i == 2:
+            elif i == first:
                 args[i] = rng.choice([b"", b"x" * 300, b"\x00\xff\r\n"])
         return args
     # mutated-invalid: both paths must converge on the same help text
     mutation = rng.randrange(5)
-    if mutation == 0 and len(args) > 2:
+    if mutation == 0 and len(args) > first:
         args.pop()  # arity short of the grammar
     elif mutation == 1:
         args.append(b"junk")  # extra arg (legal only for path commands)
     elif mutation == 2:
-        args[1] = rng.choice([sub.lower().encode(), sub.encode() + b"X"])
+        args[first - 1] = rng.choice(
+            [sub.lower().encode(), sub.encode() + b"X"]
+        )
     elif mutation == 3 and (u64_at or opt_at):
         idx = rng.choice(sorted(u64_at | opt_at))
         if idx < len(args):
             args[idx] = rng.choice(U64_INVALID)
     elif mutation == 4:
-        if values is not None and len(args) > 2:
+        if values is not None and len(args) > first:
             args[-1] = rng.choice(BAD_JSON)
         elif pathy:
             args.append(BAD_PATH)
         else:
-            args[1] = b"NOPE"
+            args[first - 1] = b"NOPE"
     return args
 
 

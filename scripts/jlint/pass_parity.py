@@ -46,6 +46,10 @@ REPO_GLOB_DIR = os.path.join(ROOT, "jylis_tpu", "models")
 
 _TYPE_RE = re.compile(r'word_is\(buf,\s*offs\[0\],\s*lens\[0\],\s*"(\w+)"\)')
 _SUB_RE = re.compile(r'word_is\(buf,\s*offs\[1\],\s*lens\[1\],\s*"(\w+)"\)')
+# a COMPOSED type (MAP) names its inner type at offs[1] and its
+# subcommand at offs[2]: a type whose block has offs[2] guards takes its
+# subcommands from those, and its offs[1] words are inner types
+_SUB2_RE = re.compile(r'word_is\(buf,\s*offs\[2\],\s*lens\[2\],\s*"(\w+)"\)')
 
 
 def extract_native(path: str = SERVE_ENGINE) -> dict[str, list[str]]:
@@ -57,8 +61,11 @@ def extract_native(path: str = SERVE_ENGINE) -> dict[str, list[str]]:
         events.append((m.start(), "type", m.group(1)))
     for m in _SUB_RE.finditer(text):
         events.append((m.start(), "sub", m.group(1)))
+    for m in _SUB2_RE.finditer(text):
+        events.append((m.start(), "sub2", m.group(1)))
     events.sort()
     surface: dict[str, set[str]] = {}
+    composed: dict[str, set[str]] = {}
     active: list[str] = []
     last_kind = None
     for pos, kind, word in events:
@@ -68,6 +75,10 @@ def extract_native(path: str = SERVE_ENGINE) -> dict[str, list[str]]:
             else:
                 active = [word]
             surface.setdefault(word, set())
+        elif kind == "sub2":
+            for t in active:
+                composed.setdefault(t, set()).add(word)
+            continue  # adjacency of type guards is not its business
         else:
             # a `which == 1 && … word_is(…)` qualifier in the shared
             # counter block restricts the subcommand to PNCOUNT
@@ -79,6 +90,7 @@ def extract_native(path: str = SERVE_ENGINE) -> dict[str, list[str]]:
             for t in targets:
                 surface[t].add(word)
         last_kind = kind
+    surface.update(composed)
     return {t: sorted(subs) for t, subs in sorted(surface.items())}
 
 

@@ -68,6 +68,8 @@ _THRESHOLDS = [
      os.path.join(MODELS_DIR, "tlog_table.py"), "ROW_DRAIN_THRESHOLD"),
     ("PENDING_DRAIN_THRESHOLD", ENGINE_H,
      os.path.join(MODELS_DIR, "tlog_table.py"), "PENDING_DRAIN_THRESHOLD"),
+    ("MAP_PENDING_DRAIN", SERVE_ENGINE,
+     os.path.join(MODELS_DIR, "repo_map.py"), "PENDING_DRAIN_THRESHOLD"),
 ]
 
 
@@ -85,6 +87,15 @@ _GUARD0 = re.compile(
 _GUARD1 = re.compile(
     r'argc >= (\d+) && word_is \( buf , offs \[ 1 \] , lens \[ 1 \] , '
     r'"(\w+)" \)'
+)
+# a composed type's block (MAP): the inner type at offs[1] beside the
+# type guard, the subcommand at offs[2] (`argc == N`: an exact arity)
+_INNER = re.compile(
+    r'word_is \( buf , offs \[ 1 \] , lens \[ 1 \] , "(\w+)" \)'
+)
+_GUARD2 = re.compile(
+    r'argc (?:>=|==) (\d+) && word_is \( buf , offs \[ 2 \] , '
+    r'lens \[ 2 \] , "(\w+)" \)'
 )
 _BOOL_GUARD = re.compile(
     r'bool is_(\w+) = argc >= (\d+) && word_is \( buf , offs \[ 1 \] , '
@@ -152,6 +163,10 @@ def _native_replies(blocks, which_value=None) -> list[str]:
         elif text == _LIT_ARR0:
             reps.add("*0")
     comp = [d for text, d in lits if text == _LIT_ARR2]
+    # `fmt_pair(...)` writes the same pair (serve_engine.cpp)
+    for block in blocks:
+        for items, depth in _iter_item_lists(block):
+            comp += [depth for _g in cpp_ast.find_calls(items, "fmt_pair")]
     if comp:
         # the pair-array composite swallows its own $bulk/:u64 parts
         if any(d > 0 for d in comp):
@@ -342,6 +357,17 @@ def extract_native(path: str = SERVE_ENGINE) -> dict[str, dict]:
                 which_types[int(wm.group(1))] = m.group(2)
             elif m.group(2) == "UJSON":
                 _extract_ujson_block(m.group(2), st.then, out)
+            elif _INNER.search(cond):
+                # "MAP TREG GET": the command's words up to its verb
+                head = f"{m.group(2)} {_INNER.search(cond).group(1)}"
+                for sst in st.then.stmts:
+                    if not isinstance(sst, cpp_ast.If):
+                        continue
+                    sm = _GUARD2.search(cpp_ast.render(sst.cond))
+                    if sm:
+                        out[f"{head} {sm.group(2)}"] = _native_grammar(
+                            int(sm.group(1)), [sst.then]
+                        )
             else:
                 inner: dict[str, dict] = {}
                 for sst in st.then.stmts:
@@ -857,7 +883,10 @@ def build_manifest(old: dict | None = None) -> dict:
     commands: dict[str, dict] = {}
     for key in sorted(native):
         nat = native[key]
-        py = python.get(key)
+        # the oracle dispatches a composed type's command ("MAP TREG
+        # GET") on its type and verb, whatever the inner type
+        words = key.split(" ")
+        py = python.get(f"{words[0]} {words[-1]}")
         divergences = (
             _diff(nat, py)
             if py is not None
@@ -1029,9 +1058,10 @@ def check(
             )
 
     # coverage: every pass-3 native command must have a manifest entry
+    covered = {(k.split(" ")[0], k.split(" ")[-1]) for k in cur_cmds}
     for t, subs in pass_parity.extract_native().items():
         for sub in subs:
-            if f"{t} {sub}" not in cur_cmds:
+            if (t, sub) not in covered:
                 out.append(
                     Finding(
                         "JL1103", rel, 1,

@@ -21,6 +21,7 @@ gen = load("harness/gen.py")
 resp = load("harness/resp.py")
 TLOG = load("reference/TLOG.py")
 UJSON = load("reference/UJSON.py")
+MAP = load("reference/MAP.py")
 
 TLOG_RECIPE = {"keys": 24, "entries": 20, "value_bytes": 48, "key_format": "thread%07d",
                "ts_epoch_ms": gen.TS_EPOCH_MS, "ts_shift": gen.TS_SHIFT, "base_days": 30}
@@ -39,6 +40,16 @@ UJSON_RECIPE = {"keys": 12, "members": 40, "path": "members", "key_format": "doc
 def ujson_reference(seed: int, **sizes):
     recipe = dict(UJSON_RECIPE, **sizes)
     return UJSON.Reference(recipe, seed, 1, [], gen.hottest(recipe["keys"], recipe["keys"]))
+
+
+MAP_RECIPE = {"keys": 2000, "fields": 10, "value_bytes": 100, "key_format": "user%07d",
+              "ts_ceiling": gen.TS_EPOCH_MS << gen.TS_SHIFT}
+
+
+def map_reference(seed: int, own_rid: int = 1, **sizes):
+    recipe = dict(MAP_RECIPE, **sizes)
+    return MAP.Reference(recipe, seed, own_rid, [], gen.hottest(recipe["keys"], recipe["keys"]),
+                         gen.Values(seed))
 
 
 class Replies:

@@ -767,7 +767,9 @@ def test_real_native_surface_is_python_subset():
         "TLOG": ["CLR", "TRIM", "TRIMAT"],
         # the composed types (schema v9) are host-only like TENSOR: the
         # native engine defers their first words to the oracle
-        "MAP": ["DEL", "GET", "KEYS", "SET"],
+        # (MAP's three TREG forms went native in PR 46: DEL and KEYS,
+        # and every other inner type, stay the oracle's)
+        "MAP": ["DEL", "KEYS"],
         "BCOUNT": ["DEC", "GET", "GRANT", "INC", "TRANSFER"],
     }
 
@@ -1562,10 +1564,23 @@ def test_semantics_missing_manifest_fires_jl1103(tmp_path):
     assert "missing" in findings[0].msg
 
 
-def test_semantics_drift_fires_jl1103_both_directions(tmp_path):
+def _pinned_manifest() -> dict:
+    """A fresh extraction with every note written and the divergences
+    the committed manifest justifies by design (MAP TREG's inner-lattice
+    hooks, which the extractor does not follow) justified here too."""
     manifest = pass_semantics.build_manifest(old={})
-    for rec in manifest["commands"].values():
+    committed = pass_semantics._load_committed()["commands"]
+    for key, rec in manifest["commands"].items():
         rec["note"] = "pinned"
+        rec["justified"] = [
+            d for d in committed.get(key, {}).get("justified", [])
+            if d in rec["divergences"]
+        ]
+    return manifest
+
+
+def test_semantics_drift_fires_jl1103_both_directions(tmp_path):
+    manifest = _pinned_manifest()
     # forward drift: a committed fact no longer matches the extraction
     tampered = copy.deepcopy(manifest)
     tampered["commands"]["GCOUNT INC"]["native"]["min_argc"] = 99
@@ -1590,9 +1605,7 @@ def test_semantics_drift_fires_jl1103_both_directions(tmp_path):
 
 
 def test_semantics_placeholder_and_stale_justification_fire_jl1103(tmp_path):
-    manifest = pass_semantics.build_manifest(old={})
-    for rec in manifest["commands"].values():
-        rec["note"] = "pinned"
+    manifest = _pinned_manifest()
     manifest["commands"]["GCOUNT GET"]["note"] = pass_semantics.PLACEHOLDER
     manifest["commands"]["TLOG INS"]["justified"] = ["bogus divergence"]
     mpath, hpath = _write_sem(tmp_path, manifest)
@@ -1613,9 +1626,7 @@ def test_semantics_divergence_fires_jl1101_and_jl1102(tmp_path, monkeypatch):
     mutated["GCOUNT INC"]["min_argc"] = 5  # arity gap -> JL1101
     mutated["GCOUNT GET"]["replies"] = ["$bulk"]  # shape gap -> JL1102
     monkeypatch.setattr(pass_semantics, "extract_python", lambda: mutated)
-    manifest = pass_semantics.build_manifest(old={})
-    for rec in manifest["commands"].values():
-        rec["note"] = "pinned"
+    manifest = _pinned_manifest()
     mpath, hpath = _write_sem(tmp_path, manifest)
     findings = pass_semantics.check(mpath, hpath)
     assert _sem_rules(findings) == ["JL1101", "JL1102"]
@@ -1649,9 +1660,7 @@ def test_semantics_transport_divergence_fires_jl1101(tmp_path, monkeypatch):
 
 
 def test_semantics_stale_harness_fires_jl1103(tmp_path):
-    manifest = pass_semantics.build_manifest(old={})
-    for rec in manifest["commands"].values():
-        rec["note"] = "pinned"
+    manifest = _pinned_manifest()
     mpath, hpath = _write_sem(tmp_path, manifest)
     assert pass_semantics.check(mpath, hpath) == []  # fresh render: clean
     with open(hpath, "a", encoding="utf-8") as f:
@@ -1693,13 +1702,17 @@ def test_semantics_inventory_matches_pass3_dispatch():
     """The symbolic extractor and pass 3's word_is dispatch scan must
     agree on WHICH commands the native front-end serves — a gap either
     way means one of the two extractions went blind."""
-    sem = set(pass_semantics.extract_native())
+    # a composed type's command ("MAP TREG GET") is pass 3's "MAP GET"
+    sem = {
+        (k.split(" ")[0], k.split(" ")[-1])
+        for k in pass_semantics.extract_native()
+    }
     parity = {
-        f"{t} {sub}"
+        (t, sub)
         for t, subs in pass_parity.extract_native().items()
         for sub in subs
     }
-    assert sem == parity
+    assert sem == parity and ("MAP", "GETALL") in sem
 
 
 def test_real_semantics_manifest_clean_and_committed():
@@ -1710,9 +1723,25 @@ def test_real_semantics_manifest_clean_and_committed():
     assert pass_semantics.check() == []
     manifest = pass_semantics._load_committed()
     cmds = manifest["commands"]
-    assert len(cmds) == 16
+    assert len(cmds) == 19
+    # the only divergences, string for string: MAP TREG's inner-lattice
+    # hooks (InnerTREG.write / .render), which the extractor does not
+    # follow on the oracle's side. A fourth command, or another string
+    # on one of these, cannot be justified without this list changing.
+    by_design = {
+        "MAP TREG GET": [
+            "replies: native ['$-1', '*2[$bulk,:u64]'] != oracle ['$-1']",
+        ],
+        "MAP TREG GETALL": [
+            "replies: native ['*n[*2[$bulk,:u64]]'] != oracle ['*n[$bulk]']",
+        ],
+        "MAP TREG SET": [
+            "arity: native min_argc 7 != oracle 5",
+            "u64-args: native [6] != oracle []",
+        ],
+    }
     for key, rec in cmds.items():
-        assert rec["divergences"] == rec["justified"] == [], key
+        assert rec["divergences"] == rec["justified"] == by_design.get(key, []), key
         assert rec["note"] and rec["note"] != pass_semantics.PLACEHOLDER
     assert manifest["transport"]["divergences"] == []
     for rec in manifest["thresholds"].values():

@@ -21,7 +21,7 @@ from jylis_tpu.native.engine import ALL_TYPES, make_engine
 from test_async_serving import make_server
 
 USERS = 12
-TYPES = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON")  # the engine's order
+TYPES = ("GCOUNT", "PNCOUNT", "TREG", "TLOG", "UJSON", "MAP")  # the engine's order
 # the mix's shares (TAPIR's Table 2 mapped onto three types)
 MIX = (
     (0.5446, lambda r, u, n: b"TLOG GET list%d 10" % u),
@@ -142,7 +142,7 @@ def test_the_mix_on_one_connection_is_the_python_paths_byte_for_byte(seed, depth
 
 
 def observed(eng) -> dict:
-    """What each of the five types holds, read through the engine's own
+    """What each of the six types holds, read through the engine's own
     lookups (no apply): rows, pending writes, deltas, served counts."""
     return {
         "GCOUNT": (eng.rows(0), eng.pend_count(0), eng.dirty_count(0)),
@@ -150,6 +150,7 @@ def observed(eng) -> dict:
         "TREG": (eng.treg_rows(), eng.treg_pend_count(), eng.treg_delta_count()),
         "TLOG": (eng.tlog_rows(), eng.tlog_pend_total(), eng.tlog_deltas_size()),
         "UJSON": (eng.uq_count(), eng.uj_memo_len(b"k0")),
+        "MAP": (eng.map_rows(), eng.map_pend_count(), eng.map_dirty_count()),
     }
 
 
@@ -159,6 +160,7 @@ WRITES = {
     "TREG": b"TREG SET k%d v 7\r\n",
     "TLOG": b"TLOG INS k%d v 7\r\n",
     "UJSON": b"UJSON INS k%d members 5\r\n",
+    "MAP": b"MAP TREG SET k%d field3 v 7\r\n",
 }
 
 
@@ -171,7 +173,7 @@ def write(name: str, n: int = 0) -> bytes:
 def test_a_run_stops_before_the_first_command_of_a_type_that_is_not_held(held):
     """Under ONE held type the engine applies that type's commands and
     stops, nothing consumed of it, at the first command of any other of
-    the five; `changed`, `served` and every other type's table stay as
+    the six; `changed`, `served` and every other type's table stay as
     they were. A command of no engine type is handed back whatever is
     held."""
     eng = make_engine()
@@ -185,7 +187,7 @@ def test_a_run_stops_before_the_first_command_of_a_type_that_is_not_held(held):
         buf = bytearray(mine + write(held, 2 * k + 1) + write(other, k) + mine)
         before, served = observed(eng), eng.served_counts()
         ahead = eng.types_ahead(buf)
-        assert ahead & 31 == 1 << bit | 1 << TYPES.index(other) and ahead >> 8 == bit
+        assert ahead & 63 == 1 << bit | 1 << TYPES.index(other) and ahead >> 8 == bit
         rc, consumed, n, unhandled, changed = eng.scan_apply(buf, 1 << bit)
         assert rc == 5 and consumed == len(buf) - len(write(other, k) + mine)
         assert unhandled is None
@@ -215,7 +217,11 @@ def test_types_ahead_reads_what_scan_apply_would_see():
     assert eng.types_ahead(bytearray(b"TLOG GE")) == none  # unfinished
     assert eng.types_ahead(bytearray(b"*x\r\n")) == none  # malformed
     assert eng.types_ahead(bytearray(b"\r\n\r\n")) == none  # blank lines
-    assert eng.types_ahead(bytearray(b"MAP GET k f\r\nTREG GET k\r\n")) == 5 << 8
+    # a first word that is no engine type ends the run before it
+    assert eng.types_ahead(bytearray(b"TENSOR GET k\r\nTREG GET k\r\n")) == 6 << 8
+    # MAP is the engine's sixth type, whatever inner type the command names
+    buf = bytearray(b"MAP GCOUNT GET k f\r\nTREG GET k\r\n")
+    assert eng.types_ahead(buf) == (1 << 5 | 1 << 2) | 5 << 8
     # blank inline lines are skipped, RESP arrays read like inline commands
     buf = bytearray(b"\r\n*3\r\n$4\r\nTREG\r\n$3\r\nGET\r\n$1\r\nk\r\nUJSON GET d\r\nTLOG GE")
     assert eng.types_ahead(buf) == (1 << 2 | 1 << 4) | 2 << 8

@@ -6,7 +6,7 @@ scanner differentials in test_native_resp.py) with the sanitizer runtime
 LD_PRELOADed; jax cannot be imported there (jaxlib's pybind11 C++
 exceptions abort under the ASAN interceptor), so everything here drives
 ``ServeEngine`` via ctypes only: full pipelined bursts through
-``scan_apply`` over all five types, the reply-buffer flush (rc 2) and
+``scan_apply`` over all six types, the reply-buffer flush (rc 2) and
 defer (rc 1) boundaries, protocol errors, the UJSON render memo and
 write queue, TLOG interner compaction, and the bulk delta exports. In
 the regular suite it doubles as an engine integration test.
@@ -481,3 +481,88 @@ def test_bulk_delta_exports(eng):
     # cleared: a second flush exports nothing
     assert eng.treg_flush_deltas() == []
     assert eng.tlog_flush_deltas() == []
+
+
+def test_map_field_table_burst_wire_and_drain_exports(eng):
+    """The sixth type's table, end to end without jax: `MAP TREG` SET /
+    GET / GETALL through `scan_apply` (and what it hands back), foreign
+    units, a replica id that re-strides the planes, the wire forms a
+    flush, a dump and a restore carry, the drain's batch planes (sparse
+    and dense), ties, and the taken lists."""
+    eng.map_set_rid(7)
+    burst = b"".join(
+        resp(b"MAP", b"TREG", b"SET", b"user%d" % (i % 5), b"field%d" % (i % 12),
+             b"v%03d" % i + b"x" * (i % 40), b"%d" % (i + 1))
+        for i in range(300)
+    )
+    burst += resp(b"MAP", b"TREG", b"GETALL", b"user3")
+    burst += resp(b"MAP", b"TREG", b"GET", b"user3", b"field3")
+    burst += resp(b"MAP", b"TREG", b"GET", b"user3", b"nope")
+    burst += resp(b"MAP", b"TREG", b"GETALL", b"nobody")
+    rc, replies, deferred, rest = drain_native(eng, burst)
+    assert (rc, rest) == (0, b"") and deferred == []
+    assert replies.startswith(b"+OK\r\n" * 300 + b"*24\r\n$6\r\nfield0\r\n*2\r\n$")
+    assert replies.endswith(b"$-1\r\n*0\r\n")
+    assert eng.served_counts()["MAP"] == 304 and eng.map_rows() == 60
+    # field names come back in byte order: field0, field1, field10, field11, field2 ...
+    rows = eng.map_record(b"user3")
+    assert [eng.map_field_name(int(r)) for r in rows][:5] == [
+        b"field0", b"field1", b"field10", b"field11", b"field2"]
+    # what the engine hands back: DEL, KEYS, another inner type, another
+    # arity, a bad timestamp, a key that holds a field of another type
+    eng.map_mark_mixed(b"user4")
+    handed = [
+        (b"MAP", b"TREG", b"DEL", b"user1", b"field1"),
+        (b"MAP", b"TREG", b"KEYS", b"user1"),
+        (b"MAP", b"GCOUNT", b"GET", b"user1", b"hits"),
+        (b"MAP", b"TREG", b"SET", b"user1", b"field1", b"v"),
+        (b"MAP", b"TREG", b"SET", b"user1", b"field1", b"v", b"1", b"extra"),
+        (b"MAP", b"TREG", b"SET", b"user1", b"field1", b"v", b"-1"),
+        (b"MAP", b"TREG", b"GETALL", b"user4"),
+        (b"MAP", b"TREG"),
+    ]
+    rc, replies, deferred, rest = drain_native(eng, b"".join(resp(*c) for c in handed))
+    assert (rc, rest, replies) == (0, b"", b"") and deferred == [list(c) for c in handed]
+    # a foreign unit from a new replica (re-strides ver/tomb), a DEL, a tie
+    row = eng.map_find(b"user1", b"field1")
+    before = eng.map_get(row)
+    assert eng.map_join_unit(b"\x05user1field1", {9: 4, 11: 1}, {7: 1}, before[1], before[0] + b"!") == row
+    assert eng.map_get(row) == (before[0] + b"!", before[1])  # equal ts: the greater value
+    assert eng.map_join_unit(b"\x80", {}, {}, 1, b"v") == -1  # no (key, field) in it
+    assert sorted(eng.map_rids()) == [7, 9, 11]
+    assert eng.map_del(row) and eng.map_get(row) is None and not eng.map_del(row)
+    # flush / dump / restore as wire bytes
+    n, payload, starts = eng.map_wire(eng.map_take_dirty())
+    assert n == 60 and len(starts) == 60 and starts[0] == 0 and eng.map_dirty_count() == 0
+    n_all, dump, _ = eng.map_wire()
+    assert n_all == 60 and len(dump) > len(b"v000") * 60
+    from jylis_tpu.native.engine import map_wire_ok
+
+    assert map_wire_ok(dump, 60) and not map_wire_ok(dump, 59) and not map_wire_ok(dump[:-1], 60)
+    assert not map_wire_ok(dump.replace(b"\x04TREG", b"\x04TLOG", 1), 60)
+    other = ServeEngine(lib())
+    other.map_reserve(8, 64)
+    other.map_load_wire(dump, 60)
+    assert other.map_wire()[1] == dump and other.map_pend_count() == 60
+    # the drain's planes: sparse (slot i) and dense (slot row), 4 x 8 replica columns
+    for dense in (False, True):
+        cap = 64
+        ki = np.empty(cap, np.int32)
+        cells = np.zeros((cap, 32), np.uint32)
+        planes = [np.zeros(cap, np.uint32) for _ in range(4)]
+        vid = np.full(cap, -1, np.int32)
+        assert other.map_export_planes(ki, cells, *planes, vid, dense) == 60
+        assert sorted(ki[:60].tolist()) == list(range(60)) and (vid[:60] >= 0).all()
+        assert cells[:60, 16:24].sum() >= 60  # ver's low words: an edit a row
+    with pytest.raises(ValueError):
+        other.map_export_planes(np.empty(8, np.int32), np.zeros((8, 32), np.uint32),
+                                *[np.zeros(8, np.uint32) for _ in range(4)],
+                                np.full(8, -1, np.int32), False)
+    rows, vids = other.map_settle_ties(np.array([3, 999, -1, 5], np.int32))
+    assert rows.tolist() == [3, 5] and (vids >= 0).all()
+    other.map_clear_pend()
+    assert other.map_pend_count() == 0 and len(other.map_take_sync()) == 60
+    eng.map_tally()
+    assert eng.metrics.tallies["drain.MAP.sets"] == 300
+    assert eng.metrics.tallies["drain.MAP.getalls"] == 2
+    assert eng.metrics.tallies["drain.MAP.getall_fields"] == 12

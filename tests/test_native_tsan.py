@@ -270,8 +270,9 @@ def test_a_round_of_one_type_runs_beside_the_holders_of_the_others(cdll):
     """No mutex in common: the "loop" thread serves rounds that hold ONE
     type (`scan_apply(buf, held)`: the only user of the engine's reply
     and argument scratch) while a "TLOG drain" thread and a "UJSON fold"
-    thread, each the one holder of ITS type's lock, work on their own
-    tables through the calls a drain makes. A round stops before the
+    thread and a "MAP drain" thread, each the one holder of ITS type's
+    lock, work on their own tables through the calls a drain makes. A
+    round stops before the
     first command of a type it does not hold (rc 5), so nothing of the
     loop's ever reaches the tables the other two are in."""
     eng = ServeEngine(cdll)
@@ -287,7 +288,7 @@ def test_a_round_of_one_type_runs_beside_the_holders_of_the_others(cdll):
                 + resp(b"TLOG", b"INS", b"log-0", b"never", b"1")
             )
             rc, consumed, n, _u, changed = eng.scan_apply(buf, treg)
-            assert rc == 5 and changed[2] == 1 and changed[3] == changed[4] == 0
+            assert rc == 5 and changed[2] == 1 and not any(changed[3:])
             assert eng.reply_bytes(n).startswith(b"+OK\r\n*2\r\n")
             del buf[:consumed]
             stopped.append(bytes(buf[:4]))
@@ -312,7 +313,28 @@ def test_a_round_of_one_type_runs_beside_the_holders_of_the_others(cdll):
             eng.uj_invalidate(key, [b"members"], False)
             eng.uq_drain()
 
-    _run_threads([loop, tlog_drain, ujson_fold])
+    def map_drain():
+        import numpy as np
+
+        eng.map_set_rid(7)
+        for i in range(N_ROUNDS * 4):
+            key, field = b"rec-%d" % (i % 4), b"field%d" % (i % 3)
+            eng.map_set(key, field, 7, i + 1, b"local-%d" % i)
+            eng.map_join_unit(
+                b"\x05" + key + field, {9: i + 1}, {}, 10_000 + i, b"foreign-%d" % i
+            )
+            n = eng.map_pend_count()
+            assert n >= 1 and eng.map_get(eng.map_find(key, field))[1] >= 10_000
+            planes = [np.zeros(16, np.uint32) for _ in range(4)]
+            eng.map_export_planes(
+                np.empty(16, np.int32), np.zeros((16, 32), np.uint32), *planes,
+                np.full(16, -1, np.int32), False,
+            )
+            eng.map_clear_pend()
+            eng.map_wire(eng.map_take_dirty())
+
+    _run_threads([loop, tlog_drain, ujson_fold, map_drain])
+    assert eng.map_rows() == 12 and eng.served_counts()["MAP"] == 0
     assert set(stopped) == {b"*5\r\n"}  # the TLOG INS, untouched every time
     assert eng.tlog_find(b"log-0") >= 0 and eng.treg_rows() == 8
     pend = eng.tlog_export_pend(eng.tlog_find(b"log-0"))

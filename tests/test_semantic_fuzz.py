@@ -31,6 +31,9 @@ from scripts.jlint import pass_semantics  # noqa: E402
 GRAMMAR = {
     'GCOUNT GET': {'min_argc': 3, 'u64_args': [], 'opt_u64_args': [], 'validators': []},
     'GCOUNT INC': {'min_argc': 4, 'u64_args': [3], 'opt_u64_args': [], 'validators': []},
+    'MAP TREG GET': {'min_argc': 5, 'u64_args': [], 'opt_u64_args': [], 'validators': []},
+    'MAP TREG GETALL': {'min_argc': 4, 'u64_args': [], 'opt_u64_args': [], 'validators': []},
+    'MAP TREG SET': {'min_argc': 7, 'u64_args': [6], 'opt_u64_args': [], 'validators': []},
     'PNCOUNT DEC': {'min_argc': 4, 'u64_args': [3], 'opt_u64_args': [], 'validators': []},
     'PNCOUNT GET': {'min_argc': 3, 'u64_args': [], 'opt_u64_args': [], 'validators': []},
     'PNCOUNT INC': {'min_argc': 4, 'u64_args': [3], 'opt_u64_args': [], 'validators': []},

@@ -415,7 +415,7 @@ def test_scan_apply_tlog_get_and_cutoff_byte_match_oracle():
     rc, consumed, replies, unhandled, changed = scan_bytes(native.engine, bytearray(burst)
     )
     assert rc == 0 and consumed == len(burst) and unhandled is None
-    assert changed == (0, 0, 0, 0, 0)  # reads change nothing
+    assert changed == (0, 0, 0, 0, 0, 0)  # reads change nothing
     assert replies == b"".join(_oracle_reply(oracle, a) for a in gets)
     # non-quiescent reads served that: pend was never drained. Now drain
     # (memo is current after the GETs, so the base carries) and re-check
@@ -503,7 +503,7 @@ def test_ujson_queue_flush_order_and_replies():
     rc, consumed, replies, unhandled, changed = scan_bytes(eng, wire)
     assert rc == 0 and consumed == len(wire)
     assert replies == b"+OK\r\n" * 9
-    assert changed == (0, 0, 0, 0, 9)
+    assert changed == (0, 0, 0, 0, 9, 0)
     assert eng.uq_count() == 9
     for args in (
         [b"INS", b"u", b"roles", b'"admin"'],
