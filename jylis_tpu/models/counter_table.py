@@ -14,9 +14,22 @@ small table interface with two implementations:
 Values are stored as u64 bit patterns; PNCOUNT decodes them as the
 wrapped two's-complement i64 the reference's (p-n).i64() defines.
 Polarity 0 is GCOUNT's only / PNCOUNT's P plane; polarity 1 is N.
+
+The foreign window lives here too, once: every column a peer converged
+into a row, cumulative, by COLUMN (the repo owns replica id -> column).
+`fold_foreign` joins a slice of deltas into it in one call, the sync
+digest reads it (`sync_cols`), and `export_drain` hands the drain its
+batch ready: rows and the u64 matrix `[P | N]`, pending own values
+joined with the foreign rows' columns, a row of the batch a row of the
+matrix (padded with zero rows to ``pad_to``), or with ``by_row`` each at
+its own row number (a dense drain's whole plane). Nothing clears before
+`finish_drain`, so a device failure mid-drain leaves every contribution
+for the retry.
 """
 
 from __future__ import annotations
+
+import numpy as np
 
 U64_MASK = (1 << 64) - 1
 
@@ -24,7 +37,7 @@ U64_MASK = (1 << 64) - 1
 class PyTable:
     __slots__ = (
         "_keys", "_rkeys", "_value", "_own", "_ownset", "_pend", "_pendset",
-        "_pend_rows", "_dirty", "_foreign", "_sync_dirty",
+        "_pend_rows", "_dirty", "_foreign", "_sync_dirty", "_fcols",
     )
 
     def __init__(self):
@@ -37,8 +50,10 @@ class PyTable:
         self._pendset = ([], [])
         self._pend_rows: dict[int, None] = {}
         self._dirty: dict[int, None] = {}
-        self._foreign: set[int] = set()
+        self._foreign: dict[int, None] = {}  # rows a drain owes a join
         self._sync_dirty: dict[int, None] = {}  # since last digest pass
+        # per polarity: row -> {col: max value a peer converged}
+        self._fcols: tuple[dict[int, dict[int, int]], ...] = ({}, {})
 
     def rows(self) -> int:
         return len(self._rkeys)
@@ -80,43 +95,96 @@ class PyTable:
     def is_foreign(self, row: int) -> bool:
         return row in self._foreign
 
-    def set_foreign(self, row: int) -> None:
-        self._foreign.add(row)
-
     def value(self, row: int) -> int:
         return self._value[row]
-
-    def own(self, row: int, polarity: int) -> int:
-        return self._own[polarity][row]
-
-    def own_max(self, row: int, polarity: int, v: int) -> None:
-        if v > self._own[polarity][row]:
-            self._own[polarity][row] = v
-        self._ownset[polarity][row] = True
 
     def own_set(self, row: int) -> int:
         return (1 if self._ownset[0][row] else 0) | (
             2 if self._ownset[1][row] else 0
         )
 
-    def apply_drain(self, rows, values) -> None:
-        for row, v in zip(rows, values):
-            self._value[row] = int(v) & U64_MASK
-            self._foreign.discard(row)
+    def upsert_many(self, keys: list[bytes]) -> np.ndarray:
+        return np.fromiter(map(self.upsert, keys), np.int64, len(keys))
+
+    def fold_foreign(
+        self, key_rows, npol: int, counts, cols, vals, adopt_col: int = -1
+    ) -> int:
+        """The oracle of `jy_eng_fold_foreign` (counter_engine.cpp)."""
+        counts, cols, vals = counts.tolist(), cols.tolist(), vals.tolist()
+        at = 0
+        for k, row in enumerate(key_rows.tolist()):
+            self._foreign[row] = None
+            self._sync_dirty[row] = None
+            for pol in range(npol):
+                if not counts[k * npol + pol]:
+                    continue
+                cells = self._fcols[pol].setdefault(row, {})
+                for _ in range(counts[k * npol + pol]):
+                    col, v = cols[at], vals[at]
+                    at += 1
+                    if v > cells.get(col, 0):
+                        cells[col] = v
+                    if col == adopt_col:
+                        if v > self._own[pol][row]:
+                            self._own[pol][row] = v
+                        self._ownset[pol][row] = True
+        return at
 
     def pend_count(self) -> int:
         return len(self._pend_rows)
 
-    def export_pending(self, clear: bool = True):
-        rows = list(self._pend_rows)
-        vp = [self._pend[0][r] if self._pendset[0][r] else 0 for r in rows]
-        vn = [self._pend[1][r] if self._pendset[1][r] else 0 for r in rows]
-        if clear:
-            for r in rows:
-                self._pend[0][r] = self._pend[1][r] = 0
-                self._pendset[0][r] = self._pendset[1][r] = False
-            self._pend_rows.clear()
-        return rows, vp, vn
+    def _drain_rows(self) -> list[int]:
+        return list(dict.fromkeys([*self._pend_rows, *self._foreign]))
+
+    def drain_count(self) -> int:
+        return len(self._drain_rows())
+
+    def export_drain(
+        self, own_col: int, rep_cap: int, npol: int, pad_to: int, by_row: bool
+    ):
+        rows = self._drain_rows()
+        mat = np.zeros((pad_to, npol * rep_cap), np.uint64)
+        for i, row in enumerate(rows):
+            if by_row:
+                i = row
+            for pol in range(npol):
+                cells = {}
+                if row in self._foreign:
+                    cells.update(self._fcols[pol].get(row, ()))
+                if self._pendset[pol][row]:
+                    cells[own_col] = max(
+                        self._pend[pol][row], cells.get(own_col, 0)
+                    )
+                for col, v in cells.items():
+                    if col >= rep_cap:
+                        raise RuntimeError("a column beyond the drain's width")
+                    mat[i, pol * rep_cap + col] = v
+        return np.asarray(rows, np.int64), mat
+
+    def finish_drain(self, rows, values) -> None:
+        for row, v in zip(rows, values):
+            self._value[row] = int(v) & U64_MASK
+        self._foreign.clear()
+        for r in self._pend_rows:
+            self._pend[0][r] = self._pend[1][r] = 0
+            self._pendset[0][r] = self._pendset[1][r] = False
+        self._pend_rows.clear()
+
+    def sync_cols(self, row: int, own_col: int):
+        """(cols, P values, N values): the row's foreign columns joined
+        with its own contribution at ``own_col``."""
+        per_pol = []
+        for pol in (0, 1):
+            d = dict(self._fcols[pol].get(row, ()))
+            if self._ownset[pol][row] and self._own[pol][row] > d.get(own_col, 0):
+                d[own_col] = self._own[pol][row]
+            per_pol.append(d)
+        cols = list(dict.fromkeys([*per_pol[0], *per_pol[1]]))
+        return (
+            cols,
+            [per_pol[0].get(c, 0) for c in cols],
+            [per_pol[1].get(c, 0) for c in cols],
+        )
 
     def dirty_count(self) -> int:
         return len(self._dirty)
@@ -135,14 +203,23 @@ class PyTable:
         return rows
 
 
+def _sync_buffers(cap: int):
+    """`NativeTable.sync_cols`' out-buffers and their addresses (taking
+    an array's address costs more than the call that fills it): kept by
+    the table, whose repo lock makes the digest pass their one user."""
+    cols, vals = np.empty(cap, np.int32), np.empty((2, cap), np.uint64)
+    return cols, vals, cols.ctypes.data, vals[0].ctypes.data, vals[1].ctypes.data, cap
+
+
 class NativeTable:
     """One counter type's view over a shared native engine."""
 
-    __slots__ = ("_eng", "_which")
+    __slots__ = ("_eng", "_which", "_sync")
 
     def __init__(self, engine, which: int):
         self._eng = engine
         self._which = which
+        self._sync = _sync_buffers(64)
 
     def rows(self) -> int:
         return self._eng.rows(self._which)
@@ -162,30 +239,43 @@ class NativeTable:
     def is_foreign(self, row: int) -> bool:
         return self._eng.is_foreign(self._which, row)
 
-    def set_foreign(self, row: int) -> None:
-        self._eng.set_foreign(self._which, row)
-
     def value(self, row: int) -> int:
         return self._eng.value(self._which, row)
 
-    def own(self, row: int, polarity: int) -> int:
-        return self._eng.own(self._which, row, polarity)
+    def upsert_many(self, keys: list[bytes]) -> np.ndarray:
+        return self._eng.upsert_many(self._which, keys)
 
-    def own_max(self, row: int, polarity: int, v: int) -> None:
-        self._eng.own_max(self._which, row, polarity, v)
-
-    def own_set(self, row: int) -> int:
-        return self._eng.own_set(self._which, row)
-
-    def apply_drain(self, rows, values) -> None:
-        self._eng.apply_drain(self._which, rows, values)
+    def fold_foreign(
+        self, key_rows, npol: int, counts, cols, vals, adopt_col: int = -1
+    ) -> int:
+        return self._eng.fold_foreign(
+            self._which, key_rows, npol, counts, cols, vals, adopt_col
+        )
 
     def pend_count(self) -> int:
         return self._eng.pend_count(self._which)
 
-    def export_pending(self, clear: bool = True):
-        rows, vp, vn = self._eng.export_pending(self._which, clear=clear)
-        return rows.tolist(), vp.tolist(), vn.tolist()
+    def drain_count(self) -> int:
+        return self._eng.drain_count(self._which)
+
+    def export_drain(
+        self, own_col: int, rep_cap: int, npol: int, pad_to: int, by_row: bool
+    ):
+        return self._eng.export_drain(
+            self._which, own_col, rep_cap, npol, pad_to, by_row
+        )
+
+    def finish_drain(self, rows, values) -> None:
+        self._eng.finish_drain(self._which, rows, values)
+
+    def sync_cols(self, row: int, own_col: int):
+        while True:
+            cols, vals, *where = self._sync
+            n = self._eng.sync_cols(self._which, row, own_col, *where)
+            if n >= 0:
+                vp, vn = vals[:, :n].tolist()
+                return cols[:n].tolist(), vp, vn
+            self._sync = _sync_buffers(-n)
 
     def dirty_count(self) -> int:
         return self._eng.dirty_count(self._which)

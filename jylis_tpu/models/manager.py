@@ -284,6 +284,9 @@ class RepoManager:
     ):
         self.name = name
         self.repo = repo
+        # a repo that folds a whole slice of foreign deltas in one call
+        # (the counters) is handed the slice; the others take it a key
+        self._converge_batch = getattr(repo, "converge_batch", None)
         self.help = help_obj
         self._clock = clock
         # per-Database commands-served tally (SYSTEM METRICS "cmds");
@@ -557,6 +560,9 @@ class RepoManager:
         self._deltas_fn((self.name, batch))
 
     def converge_deltas(self, batch) -> None:
+        if self._converge_batch is not None:
+            self._converge_batch(batch)
+            return
         for key, delta in batch:
             self.repo.converge(key, delta)
 

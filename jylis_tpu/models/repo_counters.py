@@ -15,9 +15,14 @@ foreign flags) lives behind the table backends in counter_table.py:
 pure-Python dicts as the oracle, or the native C++ engine — the SAME
 state the server's native batch applier (native/counter_engine.cpp)
 mutates, so commands applied natively and Python-side drains/flushes
-share one source of truth. Foreign delta columns (sparse per-replica
-maps from the cluster) stay in Python dicts; they merge with the
-exported pending-own values at drain time.
+share one source of truth. The foreign window lives there too, held
+ONCE: the columns peers converged into a row, cumulative, by column
+(this module owns replica id -> column). A slice of foreign deltas is
+flattened into arrays in one pass and folded in by one table call
+(`converge_batch`; `converge` is its one-key form, `load_state` its
+boot form); the sync digest reads the same columns; and a drain takes
+its batch from one table call, ready: the rows and the u64 matrix
+`[P | N]`, pending own values joined with the foreign rows' columns.
 
 Delta wire shape: GCOUNT -> dict {replica_id: u64}; PNCOUNT -> a
 (p_dict, n_dict) pair. Outbound deltas carry only this node's own column
@@ -28,10 +33,13 @@ so flushes never need a device read.
 from __future__ import annotations
 
 from functools import partial
+from itertools import chain, islice
+from operator import methodcaller
 
 import jax
 import numpy as np
 
+from ..native.codec import flatten_lazy
 from ..native.engine import G as ENG_G, PN as ENG_PN, resolve_engine
 from ..ops import gcount, planes, pncount
 from ..parallel import (
@@ -43,7 +51,13 @@ from ..parallel import (
 )
 from .base import ParseError, bucket, need, pad_rows, parse_u64, U64_MAX
 from .counter_table import NativeTable, PyTable
-from ..utils.metrics import DEVICE, FINISH, drain_phase, timed_drain
+from ..utils.metrics import (
+    DEVICE,
+    FINISH,
+    drain_phase,
+    resolve_registry,
+    timed_drain,
+)
 from .help import RepoHelp
 
 GCOUNT_HELP = RepoHelp("GCOUNT", {"GET": "key", "INC": "key value"})
@@ -84,6 +98,11 @@ def _drain_pn_dense(state, d):
 # sparse composite's random accesses cost far more per row than streaming
 DENSE_FRACTION = 4
 
+# keys a restore folds per table call: bounds the flattened arrays
+LOAD_SLICE = 1 << 16
+
+_VALUES = methodcaller("values")  # of a dict or of the codec's lazy map
+
 
 def _wrap_i64(v: int) -> int:
     """Wrap into signed-64 range (the reference's modular (p-n).i64())."""
@@ -91,9 +110,11 @@ def _wrap_i64(v: int) -> int:
 
 
 class _CounterRepo:
-    """Shared machinery; subclasses bind the ops module and command set."""
+    """Shared machinery; subclasses bind the ops module, the drain
+    programs and the command set."""
 
     _which: int  # native engine table id
+    _npol: int  # polarities: the batch matrix is (rows, _npol * rep_cap)
 
     def __init__(
         self,
@@ -105,6 +126,7 @@ class _CounterRepo:
     ):
         self._identity = identity
         self._rids: dict[int, int] = {}  # replica id -> column
+        self._rid_of: list[int] = []  # column -> replica id
         # mesh mode (SURVEY.md §5.8): with >1 visible device the keyspace
         # planes live keys-sharded over the serving mesh and drains route
         # through parallel/sharded — the per-type actor keyspace of
@@ -119,15 +141,7 @@ class _CounterRepo:
         self._tbl = (
             NativeTable(engine, self._which) if engine is not None else PyTable()
         )
-        # foreign delta columns buffered per row per polarity (sparse
-        # {col: max-value} maps from cluster converges)
-        self._pending_f: tuple[dict[int, dict[int, int]], ...] = ({}, {})
-        # sync-digest bookkeeping (cluster/syncdigest): a CUMULATIVE join
-        # of every foreign column ever converged, keyed by replica id —
-        # unlike _pending_f it never clears, so the per-key canonical
-        # state (own ⊔ foreign) reads host-side with no device pull
-        self._sync_f: tuple[dict[int, dict[int, int]], ...] = ({}, {})
-        self._sync_dirty_extra: set[int] = set()  # converge-path rows
+        self._state = self._place(self._ops.init(self._key_cap, self._rep_cap))
 
     def _get_raw(self, key: bytes) -> int:
         """Serving value bits for a key (drains first when foreign deltas
@@ -142,8 +156,8 @@ class _CounterRepo:
     def _col_for(self, rid: int) -> int:
         col = self._rids.get(rid)
         if col is None:
-            col = len(self._rids)
-            self._rids[rid] = col
+            col = self._rids[rid] = len(self._rid_of)
+            self._rid_of.append(rid)
         return col
 
     def _round_cap(self, k: int) -> int:
@@ -178,74 +192,116 @@ class _CounterRepo:
 
     def _pend_size(self) -> int:
         """Exact drain batch size: own-pending rows unioned with the
-        buffered foreign rows (metrics, read before the drain runs)."""
-        own_rows, _vp, _vn = self._tbl.export_pending(clear=False)
-        rows = set(own_rows)
-        rows.update(self._pending_f[0])
-        rows.update(self._pending_f[1])
-        return len(rows)
+        foreign rows (metrics, read before the drain runs)."""
+        return self._tbl.drain_count()
 
-    def converge_polarity(self, key: bytes, polarity: int, delta: dict) -> None:
-        row = self._tbl.upsert(key)
-        p = self._pending_f[polarity].setdefault(row, {})
-        sf = self._sync_f[polarity].setdefault(row, {})
-        for rid, v in delta.items():
-            col = self._col_for(rid)
-            if v > p.get(col, 0):
-                p[col] = v
-            if v > sf.get(rid, 0):
-                sf[rid] = v
-        self._sync_dirty_extra.add(row)
-        self._tbl.set_foreign(row)
+    # -- lattice plumbing ---------------------------------------------------
 
-    def _collect_rows(self):
-        """The drain batch: pending-own values merged with the buffered
-        foreign columns -> (rows, per-row {col: val} per polarity).
-        Reads WITHOUT clearing: the window clears in `_finish_drain`, so
-        a device failure mid-drain keeps every contribution for the
-        retry (the old dict path's exception-safety contract)."""
-        own_rows, vp, vn = self._tbl.export_pending(clear=False)
+    def _fold(self, batch, batched: bool, adopt: bool = False) -> None:
+        """Join [(key, delta), ...] into the table's foreign window: the
+        deltas flattened into arrays (cells per (key, polarity), and the
+        cells' columns and values in that order) with no interpreted
+        step a key, one column lookup per distinct replica id, one table
+        call. ``adopt`` (a restore) also takes this node's own column as
+        its own contribution: it is private monotonic state, and losing
+        it would make future INCs vanish under the max."""
+        keys, deltas = zip(*batch)
+        flat = flatten_lazy(deltas)  # the native decode's: no dict a key
+        if flat is None:
+            # one {rid: value} per (key, polarity): PNCOUNT's delta is (P, N)
+            dicts = deltas if self._npol == 1 else list(chain.from_iterable(deltas))
+            flat = (
+                list(map(len, dicts)),
+                list(chain.from_iterable(dicts)),
+                list(chain.from_iterable(map(_VALUES, dicts))),
+            )
+        counts, rids, vals = flat
+        for rid in dict.fromkeys(rids):
+            self._col_for(rid)
+        cells = self._tbl.fold_foreign(
+            self._tbl.upsert_many(keys),
+            self._npol,
+            np.array(counts, np.int32),
+            np.fromiter(map(self._rids.__getitem__, rids), np.int32, len(rids)),
+            np.array(vals, np.uint64),
+            self._col_for(self._identity) if adopt else -1,
+        )
+        self._tally(len(keys), len(keys) if batched else 0, cells)
+
+    def converge_batch(self, batch) -> None:
+        """A slice of a peer's push, [(key, delta), ...], in one fold."""
+        if batch:
+            self._fold(batch, batched=True)
+
+    def converge(self, key: bytes, delta) -> None:
+        self._fold(((key, delta),), batched=False)
+
+    def load_state(self, batch) -> None:
+        batch = iter(batch)
+        while chunk := list(islice(batch, LOAD_SLICE)):
+            self._fold(chunk, batched=True, adopt=True)
+
+    def _drain(self) -> None:
+        """THE drain: the table's ready batch, placed as the mesh, the
+        dense or the sparse program takes it, and the joined rows' sums
+        back into the table. The window clears in `finish_drain` alone,
+        so a device failure mid-drain keeps every contribution for the
+        retry."""
+        n = self._tbl.drain_count()
+        if not n:
+            return
         own_col = self._col_for(self._identity)
-        per_pol: tuple[dict[int, dict[int, int]], ...] = ({}, {})
-        for pol, own_vals in ((0, vp), (1, vn)):
-            fdict = self._pending_f[pol]
-            for row, v in zip(own_rows, own_vals):
-                if v:
-                    per_pol[pol][row] = {own_col: v}
-            for row, cols in fdict.items():
-                d = per_pol[pol].setdefault(row, {})
-                for col, v in cols.items():
-                    if v > d.get(col, 0):
-                        d[col] = v
-        rows = list(dict.fromkeys(list(per_pol[0]) + list(per_pol[1])))
-        return rows, per_pol
-
-    def _finish_drain(self, rows, values_bits) -> None:
-        self._tbl.apply_drain(rows, values_bits)
-        self._tbl.export_pending(clear=True)  # drain succeeded: clear window
-        self._pending_f[0].clear()
-        self._pending_f[1].clear()
+        self._grow_to_fit()
+        mesh = self._mesh
+        dense = mesh is None and n * DENSE_FRACTION >= self._key_cap
+        # every batch is the (rows, npol * R) u64 matrix [P | N], as the
+        # plane is: a row of the batch each (the sparse program's padded
+        # with zero rows), or the dense program's whole plane
+        b = n if mesh is not None else self._key_cap if dense else bucket(n)
+        rows, mat = self._tbl.export_drain(
+            own_col, self._rep_cap, self._npol, b, by_row=dense
+        )
+        if mesh is not None:
+            ki, mat, slots = route_drain64(
+                rows, mat, self._n_shards, self._key_cap // self._n_shards
+            )
+        elif not dense:
+            ki = pad_rows(b)
+            ki[:n] = rows
+        d = planes.pack64_np(mat)
+        drain_phase(self, DEVICE)
+        if mesh is not None:
+            self._state, sums = self._drain_mesh(mesh, self._state, ki, d)
+        elif dense:
+            self._state, sums = self._drain_dense(self._state, d)
+        else:
+            self._state, sums = self._drain_sparse(self._state, ki, d)
+        sums = np.asarray(sums).view(np.uint64)
+        drain_phase(self, FINISH)
+        if mesh is not None:
+            live = slots >= 0
+            rows, sums = slots[live], sums[live]
+        else:
+            sums = sums[rows] if dense else sums[:n]
+        self._tbl.finish_drain(rows, sums)
 
     # -- sync digest (cluster/syncdigest.py) ---------------------------------
 
     def sync_dirty_keys(self) -> list[bytes]:
         """Keys whose canonical state may have changed since the last
         digest pass (native INC/DEC fast path ∪ converge/load); clears."""
-        rows = set(self._tbl.export_sync_dirty())
-        rows.update(self._sync_dirty_extra)
-        self._sync_dirty_extra.clear()
-        return [self._tbl.key_of(r) for r in rows]
+        return [self._tbl.key_of(r) for r in self._tbl.export_sync_dirty()]
 
-    def _sync_cols(self, row: int, polarity: int) -> list[tuple[int, int]]:
-        """{rid: max} for one polarity: own contribution ⊔ the cumulative
-        foreign mirror — exactly the column state the device converges
-        to, with no device read."""
-        d = dict(self._sync_f[polarity].get(row, ()))
-        if self._tbl.own_set(row) & (1 << polarity):
-            own = self._tbl.own(row, polarity)
-            if own > d.get(self._identity, 0):
-                d[self._identity] = own
-        return sorted((rid, v) for rid, v in d.items() if v)
+    def _sync_cols(self, row: int):
+        """Per polarity, the sorted [(rid, max)] of a row: own
+        contribution ⊔ the foreign columns — exactly the column state
+        the device converges to, with no device read."""
+        cols, vp, vn = self._tbl.sync_cols(row, self._col_for(self._identity))
+        rids = [self._rid_of[c] for c in cols]
+        return (
+            sorted((rid, v) for rid, v in zip(rids, vp) if v),
+            sorted((rid, v) for rid, v in zip(rids, vn) if v),
+        )
 
     # -- snapshot plumbing shared by both types ------------------------------
 
@@ -260,10 +316,10 @@ class RepoGCOUNT(_CounterRepo):
     help = GCOUNT_HELP
     _ops = gcount
     _which = ENG_G
-
-    def __init__(self, identity: int, **kw):
-        super().__init__(identity, **kw)
-        self._state = self._place(gcount.init(self._key_cap, self._rep_cap))
+    _npol = 1
+    _drain_sparse = staticmethod(_drain_g)
+    _drain_dense = staticmethod(_drain_g_dense)
+    _drain_mesh = staticmethod(drain_sharded_g)
 
     def _get_value(self, key: bytes) -> int:
         return self._get_raw(key)
@@ -272,7 +328,7 @@ class RepoGCOUNT(_CounterRepo):
         row = self._tbl.find(key)
         if row < 0:
             return None
-        cols = self._sync_cols(row, 0)
+        cols, _n = self._sync_cols(row)
         return repr(cols).encode() if cols else None
 
     # -- commands (repo_gcount.pony:25-60) ---------------------------------
@@ -292,59 +348,15 @@ class RepoGCOUNT(_CounterRepo):
 
     # -- lattice plumbing ---------------------------------------------------
 
-    def converge(self, key: bytes, delta: dict) -> None:
-        self.converge_polarity(key, 0, delta)
+    def _tally(self, keys: int, batched: int, cells: int) -> None:
+        reg = resolve_registry(self)
+        reg.tally("drain.GCOUNT.converged_keys", keys)
+        reg.tally("drain.GCOUNT.batched_keys", batched)
+        reg.tally("drain.GCOUNT.foreign_cells", cells)
 
     @timed_drain("GCOUNT", _CounterRepo._pend_size)
     def drain(self) -> None:
-        rows, per_pol = self._collect_rows()
-        if not rows:
-            return
-        self._grow_to_fit()
-        pending = per_pol[0]
-        if self._mesh is not None:
-            deltas = np.zeros((len(rows), self._rep_cap), np.uint64)
-            for i, row in enumerate(rows):
-                for col, v in pending.get(row, {}).items():
-                    deltas[i, col] = v
-            lr, payload, slots = route_drain64(
-                np.asarray(rows, np.int64),
-                deltas,
-                self._n_shards,
-                self._key_cap // self._n_shards,
-            )
-            d = planes.pack64_np(payload)
-            drain_phase(self, DEVICE)
-            self._state, sums = drain_sharded_g(self._mesh, self._state, lr, d)
-            sums = np.asarray(sums)
-            drain_phase(self, FINISH)
-            live = [(int(g), sums[j]) for j, g in enumerate(slots) if g >= 0]
-            self._finish_drain([r for r, _ in live], [v for _, v in live])
-        elif len(rows) * DENSE_FRACTION >= self._key_cap:
-            dense = np.zeros((self._key_cap, self._rep_cap), np.uint64)
-            for row in rows:
-                for col, v in pending.get(row, {}).items():
-                    dense[row, col] = v
-            d = planes.pack64_np(dense)
-            drain_phase(self, DEVICE)
-            self._state, sums = _drain_g_dense(self._state, d)
-            sums = np.asarray(sums)
-            drain_phase(self, FINISH)
-            self._finish_drain(rows, [sums[row] for row in rows])
-        else:
-            b = bucket(len(rows))
-            ki = pad_rows(b)
-            ki[: len(rows)] = rows
-            deltas = np.zeros((b, self._rep_cap), np.uint64)
-            for i, row in enumerate(rows):
-                for col, v in pending.get(row, {}).items():
-                    deltas[i, col] = v
-            d = planes.pack64_np(deltas)
-            drain_phase(self, DEVICE)
-            self._state, sums = _drain_g(self._state, ki, d)
-            sums = np.asarray(sums)
-            drain_phase(self, FINISH)
-            self._finish_drain(rows, [sums[i] for i in range(len(rows))])
+        self._drain()
 
     def flush_deltas(self):
         rows, op, _on, _sb = self._tbl.export_dirty()
@@ -359,31 +371,17 @@ class RepoGCOUNT(_CounterRepo):
     def dump_state(self):
         self.drain()
         counts = gcount.to_counts(self._state)
-        # jlint: order-ok — builds a col->rid LOOKUP map (order unused);
-        # the wire encoder sorts every span by rid before any byte ships
-        cols = {col: rid for rid, col in self._rids.items()}
+        rid_of = self._rid_of
         out = []
         for key, row in self._sorted_keys():
             d = {
-                cols[c]: int(v)
-                for c, v in enumerate(counts[row, : len(cols)])
+                rid_of[c]: int(v)
+                for c, v in enumerate(counts[row, : len(rid_of)])
                 if v
             }
             if d:
                 out.append((key, d))
         return out
-
-    def load_state(self, batch) -> None:
-        for key, delta in batch:
-            self.converge(key, delta)
-            # my own column is my private monotonic state: losing it would
-            # make future INCs disappear under the pending max
-            # jlint: ridbranch-ok — boot-only own-column repair; the
-            # lattice value converged above is identity-independent
-            if self._identity in delta:
-                self._tbl.own_max(
-                    self._tbl.upsert(key), 0, delta[self._identity]
-                )
 
 
 class RepoPNCOUNT(_CounterRepo):
@@ -391,10 +389,10 @@ class RepoPNCOUNT(_CounterRepo):
     help = PNCOUNT_HELP
     _ops = pncount
     _which = ENG_PN
-
-    def __init__(self, identity: int, **kw):
-        super().__init__(identity, **kw)
-        self._state = self._place(pncount.init(self._key_cap, self._rep_cap))
+    _npol = 2
+    _drain_sparse = staticmethod(_drain_pn)
+    _drain_dense = staticmethod(_drain_pn_dense)
+    _drain_mesh = staticmethod(drain_sharded_pn)
 
     def _get_value(self, key: bytes) -> int:
         return _wrap_i64(self._get_raw(key))
@@ -403,8 +401,7 @@ class RepoPNCOUNT(_CounterRepo):
         row = self._tbl.find(key)
         if row < 0:
             return None
-        p = self._sync_cols(row, 0)
-        n = self._sync_cols(row, 1)
+        p, n = self._sync_cols(row)
         return repr((p, n)).encode() if p or n else None
 
     # -- commands (repo_pncount.pony:26-67) --------------------------------
@@ -424,69 +421,17 @@ class RepoPNCOUNT(_CounterRepo):
             return True
         raise ParseError()
 
-    def converge(self, key: bytes, delta: tuple) -> None:
-        dp, dn = delta
-        self.converge_polarity(key, 0, dp)
-        self.converge_polarity(key, 1, dn)
+    # -- lattice plumbing ---------------------------------------------------
+
+    def _tally(self, keys: int, batched: int, cells: int) -> None:
+        reg = resolve_registry(self)
+        reg.tally("drain.PNCOUNT.converged_keys", keys)
+        reg.tally("drain.PNCOUNT.batched_keys", batched)
+        reg.tally("drain.PNCOUNT.foreign_cells", cells)
 
     @timed_drain("PNCOUNT", _CounterRepo._pend_size)
     def drain(self) -> None:
-        rows, per_pol = self._collect_rows()
-        if not rows:
-            return
-        self._grow_to_fit()
-        pend_p, pend_n = per_pol
-        # every batch is the (rows, 2R) u64 matrix [P | N], as the plane is
-        r = self._rep_cap
-        if self._mesh is not None:
-            deltas = np.zeros((len(rows), 2 * r), np.uint64)
-            for i, row in enumerate(rows):
-                for col, v in pend_p.get(row, {}).items():
-                    deltas[i, col] = v
-                for col, v in pend_n.get(row, {}).items():
-                    deltas[i, r + col] = v
-            lr, payload, slots = route_drain64(
-                np.asarray(rows, np.int64),
-                deltas,
-                self._n_shards,
-                self._key_cap // self._n_shards,
-            )
-            d = planes.pack64_np(payload)
-            drain_phase(self, DEVICE)
-            self._state, sums = drain_sharded_pn(self._mesh, self._state, lr, d)
-            sums = np.asarray(sums).view(np.uint64)
-            drain_phase(self, FINISH)
-            live = [(int(g), sums[j]) for j, g in enumerate(slots) if g >= 0]
-            self._finish_drain([r for r, _ in live], [v for _, v in live])
-        elif len(rows) * DENSE_FRACTION >= self._key_cap:
-            dense = np.zeros((self._key_cap, 2 * r), np.uint64)
-            for row in rows:
-                for col, v in pend_p.get(row, {}).items():
-                    dense[row, col] = v
-                for col, v in pend_n.get(row, {}).items():
-                    dense[row, r + col] = v
-            d = planes.pack64_np(dense)
-            drain_phase(self, DEVICE)
-            self._state, sums = _drain_pn_dense(self._state, d)
-            sums = np.asarray(sums).view(np.uint64)
-            drain_phase(self, FINISH)
-            self._finish_drain(rows, [sums[row] for row in rows])
-        else:
-            b = bucket(len(rows))
-            ki = pad_rows(b)
-            ki[: len(rows)] = rows
-            deltas = np.zeros((b, 2 * r), np.uint64)
-            for i, row in enumerate(rows):
-                for col, v in pend_p.get(row, {}).items():
-                    deltas[i, col] = v
-                for col, v in pend_n.get(row, {}).items():
-                    deltas[i, r + col] = v
-            d = planes.pack64_np(deltas)
-            drain_phase(self, DEVICE)
-            self._state, sums = _drain_pn(self._state, ki, d)
-            sums = np.asarray(sums).view(np.uint64)
-            drain_phase(self, FINISH)
-            self._finish_drain(rows, [sums[i] for i in range(len(rows))])
+        self._drain()
 
     def flush_deltas(self):
         rows, op, on, sb = self._tbl.export_dirty()
@@ -502,26 +447,13 @@ class RepoPNCOUNT(_CounterRepo):
 
     def dump_state(self):
         self.drain()
-        # jlint: order-ok — builds a col->rid LOOKUP map (order unused);
-        # the wire encoder sorts every span by rid before any byte ships
-        cols = {col: rid for rid, col in self._rids.items()}
+        rid_of = self._rid_of
         counts = planes.unpack64_np(self._state)  # [P | N]
         p, n = counts[:, : self._rep_cap], counts[:, self._rep_cap :]
         out = []
         for key, row in self._sorted_keys():
-            dp = {cols[c]: int(v) for c, v in enumerate(p[row, : len(cols)]) if v}
-            dn = {cols[c]: int(v) for c, v in enumerate(n[row, : len(cols)]) if v}
+            dp = {rid_of[c]: int(v) for c, v in enumerate(p[row, : len(rid_of)]) if v}
+            dn = {rid_of[c]: int(v) for c, v in enumerate(n[row, : len(rid_of)]) if v}
             if dp or dn:
                 out.append((key, (dp, dn)))
         return out
-
-    def load_state(self, batch) -> None:
-        for key, (dp, dn) in batch:
-            self.converge(key, (dp, dn))
-            row = self._tbl.upsert(key)
-            # jlint: ridbranch-ok — boot-only own-column repair (above)
-            if self._identity in dp:
-                self._tbl.own_max(row, 0, dp[self._identity])
-            # jlint: ridbranch-ok — boot-only own-column repair (above)
-            if self._identity in dn:
-                self._tbl.own_max(row, 1, dn[self._identity])

@@ -449,6 +449,31 @@ class LazyPNPair:
         return repr(self._mat())
 
 
+def flatten_lazy(deltas):
+    """(counts, rids, vals) of a run of lazily decoded counter deltas:
+    the cells per (key, polarity), and the cells' replica ids and values
+    in wire order, read from the arrays the deltas bank: no dict is
+    built. None unless every delta is one of this module's lazy ones,
+    consecutive over the same arrays (what `_decode_counters` made and a
+    slice of it keeps)."""
+    first = deltas[0]
+    kind = type(first)
+    if kind is not LazyPNPair and kind is not LazyU64Map:
+        return None
+    rids, lo = first._rids, first._lo
+    counts, at = [], lo
+    for d in deltas:
+        if type(d) is not kind or d._rids is not rids or d._lo != at:
+            return None
+        if kind is LazyPNPair:
+            counts += (d._np, d._nn)
+            at += d._np + d._nn
+        else:
+            counts.append(d._n)
+            at += d._n
+    return counts, rids[lo:at], first._vals[lo:at]
+
+
 def _decode_counters(cdll, name, rest, ndicts) -> Msg | None:
     n_keys = ctypes.c_int64()
     total = ctypes.c_int64()

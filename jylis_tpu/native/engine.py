@@ -58,13 +58,14 @@ def _declare(c: ctypes.CDLL) -> None:
         "jy_eng_key": (None, [vp, i32, i64, pvp, pi64]),
         "jy_eng_inc": (None, [vp, i32, i64, i32, u64]),
         "jy_eng_is_foreign": (i32, [vp, i32, i64]),
-        "jy_eng_set_foreign": (None, [vp, i32, i64]),
         "jy_eng_value": (u64, [vp, i32, i64]),
         "jy_eng_own": (u64, [vp, i32, i64, i32]),
-        "jy_eng_own_max": (None, [vp, i32, i64, i32, u64]),
-        "jy_eng_own_set": (i32, [vp, i32, i64]),
-        "jy_eng_apply_drain": (None, [vp, i32, vp, vp, i64]),
-        "jy_eng_export_pending": (i64, [vp, i32, vp, vp, vp, i64, i32]),
+        "jy_eng_upsert_many": (None, [vp, i32, u8p, vp, i64, vp]),
+        "jy_eng_fold_foreign": (i64, [vp, i32, vp, i64, i32, vp, vp, vp, i32]),
+        "jy_eng_drain_count": (i64, [vp, i32]),
+        "jy_eng_export_drain": (i64, [vp, i32, i32, i32, i32, i32, vp, vp, i64]),
+        "jy_eng_finish_drain": (None, [vp, i32, vp, vp, i64]),
+        "jy_eng_sync_cols": (i64, [vp, i32, i64, i32, vp, vp, vp, i64]),
         "jy_eng_dirty_count": (i64, [vp, i32]),
         "jy_eng_pend_count": (i64, [vp, i32]),
         "jy_eng_export_dirty": (i64, [vp, i32, vp, vp, vp, vp, i64]),
@@ -288,40 +289,73 @@ class ServeEngine:
     def is_foreign(self, which: int, row: int) -> bool:
         return bool(self._lib.jy_eng_is_foreign(self._h, which, row))
 
-    def set_foreign(self, which: int, row: int) -> None:
-        self._lib.jy_eng_set_foreign(self._h, which, row)
-
     def value(self, which: int, row: int) -> int:
         return self._lib.jy_eng_value(self._h, which, row)
 
     def own(self, which: int, row: int, polarity: int) -> int:
         return self._lib.jy_eng_own(self._h, which, row, polarity)
 
-    def own_max(self, which: int, row: int, polarity: int, v: int) -> None:
-        self._lib.jy_eng_own_max(self._h, which, row, polarity, v)
+    def upsert_many(self, which: int, keys: list[bytes]) -> np.ndarray:
+        """Rows of ``keys`` (made where new), one call for all of them."""
+        lens = np.fromiter(map(len, keys), np.int64, len(keys))
+        rows = np.empty(len(keys), np.int64)
+        self._lib.jy_eng_upsert_many(
+            self._h, which, b"".join(keys), lens.ctypes.data, len(keys),
+            rows.ctypes.data,
+        )
+        return rows
 
-    def apply_drain(self, which: int, rows, values) -> None:
+    def fold_foreign(
+        self, which: int, key_rows, npol: int, counts, cols, vals,
+        adopt_col: int = -1,
+    ) -> int:
+        """A slice of foreign deltas joins the table's foreign window
+        (`jy_eng_fold_foreign`): int64 ``key_rows``, int32 ``counts`` per
+        (key, polarity), and the cells' int32 ``cols`` and u64 ``vals``
+        in that order. Returns the cells folded."""
+        return self._lib.jy_eng_fold_foreign(
+            self._h, which, key_rows.ctypes.data, len(key_rows), npol,
+            counts.ctypes.data, cols.ctypes.data, vals.ctypes.data, adopt_col,
+        )
+
+    def drain_count(self, which: int) -> int:
+        return self._lib.jy_eng_drain_count(self._h, which)
+
+    def export_drain(
+        self, which: int, own_col: int, rep_cap: int, npol: int, pad_to: int,
+        by_row: bool,
+    ):
+        """The drain batch: (n,) int64 rows and the (pad_to, npol *
+        rep_cap) u64 matrix whose first n rows are theirs, or with
+        ``by_row`` those at the rows' own numbers; the rest 0."""
+        rows = np.empty(pad_to, np.int64)
+        mat = np.zeros((pad_to, npol * rep_cap), np.uint64)
+        n = self._lib.jy_eng_export_drain(
+            self._h, which, own_col, rep_cap, npol, by_row,
+            rows.ctypes.data, mat.ctypes.data, pad_to,
+        )
+        if n < 0:
+            raise RuntimeError(
+                f"counter drain batch does not fit ({pad_to} rows x "
+                f"{rep_cap} columns)"
+            )
+        return rows[:n], mat
+
+    def finish_drain(self, which: int, rows, values) -> None:
         rows = np.ascontiguousarray(rows, np.int64)
         values = np.ascontiguousarray(values, np.uint64)
-        self._lib.jy_eng_apply_drain(
+        self._lib.jy_eng_finish_drain(
             self._h, which,
             rows.ctypes.data, values.ctypes.data, len(rows),
         )
 
-    def export_pending(self, which: int, clear: bool = True):
-        cap = 256
-        while True:
-            rows = np.empty(cap, np.int64)
-            vp = np.empty(cap, np.uint64)
-            vn = np.empty(cap, np.uint64)
-            n = self._lib.jy_eng_export_pending(
-                self._h, which,
-                rows.ctypes.data, vp.ctypes.data, vn.ctypes.data, cap,
-                1 if clear else 0,
-            )
-            if n >= 0:
-                return rows[:n], vp[:n], vn[:n]
-            cap = -n
+    def sync_cols(self, which: int, row: int, own_col: int, cols, vp, vn, cap):
+        """One row's canonical columns (`jy_eng_sync_cols`) into the
+        caller's buffers, given by address: the cells written, or -n
+        when the row's n do not fit ``cap``."""
+        return self._lib.jy_eng_sync_cols(
+            self._h, which, row, own_col, cols, vp, vn, cap
+        )
 
     def dirty_count(self, which: int) -> int:
         return self._lib.jy_eng_dirty_count(self._h, which)
@@ -344,10 +378,6 @@ class ServeEngine:
             if n >= 0:
                 return rows[:n], op[:n], on[:n], sb[:n]
             cap = -n
-
-    def own_set(self, which: int, row: int) -> int:
-        """bit0 = P own ever written, bit1 = N own ever written."""
-        return self._lib.jy_eng_own_set(self._h, which, row)
 
     def _export_sync_dirty(self, fn, *head) -> list[int]:
         cap = 256

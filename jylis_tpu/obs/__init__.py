@@ -176,7 +176,13 @@ SERVING = (
 # (`registry.tally`): on /metrics they are further `kind`s of
 # jylis_drain_total, in SYSTEM METRICS `<TYPE> <kind> <n>` lines; and, on
 # the same surfaces under the type ENGINE, the native engine's reply
-# buffer (`serving.ENGINE.<kind>`). TREG:
+# buffer (`serving.ENGINE.<kind>`). GCOUNT and PNCOUNT
+# (models/repo_counters.py), added a slice, never a key: keys whose
+# foreign deltas were folded into the table's foreign window (a peer's
+# push, a restore, a journal replay), of those the ones that came as a
+# slice through `converge_batch` (all of them, unless a caller still
+# converges key by key), and the (polarity, replica id) cells those
+# folds carried. TREG:
 # rows the engine's bulk call assembled (0 on a node that serves from
 # the Python tables: no compiler on the host), and rows whose 8-byte
 # prefix tied on the device and were settled by the full strings. TLOG:
@@ -221,6 +227,12 @@ SERVING = (
 # `send` and the zero-timeout `poll` that looks for POLLOUT (not its idle
 # polling, not its sleep).
 TALLIES = (
+    "drain.GCOUNT.converged_keys",
+    "drain.GCOUNT.batched_keys",
+    "drain.GCOUNT.foreign_cells",
+    "drain.PNCOUNT.converged_keys",
+    "drain.PNCOUNT.batched_keys",
+    "drain.PNCOUNT.foreign_cells",
     "drain.TREG.bulk_rows",
     "drain.TREG.tie_rows",
     "drain.TLOG.entries",

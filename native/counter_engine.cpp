@@ -10,10 +10,14 @@
 // applier in serve_engine.cpp.
 //
 // Split of responsibilities (single source of truth):
-//   * native: per-key own/value/dirty/pending-own/foreign + INC/DEC/GET
-//   * Python: device drains, foreign-delta pending (dict of sparse
-//     cols), flush/snapshot orchestration, cluster converge — all via
-//     the bulk export/apply calls below.
+//   * native: per-key own/value/dirty/pending-own + INC/DEC/GET, and the
+//     foreign window: the columns peers converged into a row, held once
+//     (cumulative, by column), folded in by one call a slice
+//     (`jy_eng_fold_foreign`) and read by the sync digest
+//     (`jy_eng_sync_cols`) and by the drain, which leaves as one ready
+//     u64 matrix (`jy_eng_export_drain`)
+//   * Python: the replica id -> column map, device drains,
+//     flush/snapshot orchestration — all via the bulk calls below.
 //
 // All values are u64 bit patterns; PNCOUNT's serving value is the
 // two's-complement wrapped i64 the reference's (p-n).i64() defines.
@@ -55,10 +59,6 @@ int32_t jy_eng_is_foreign(void* e, int32_t which, int64_t row) {
     return (static_cast<Engine*>(e)->t[which].flags[row] & F_FOREIGN) ? 1 : 0;
 }
 
-void jy_eng_set_foreign(void* e, int32_t which, int64_t row) {
-    static_cast<Engine*>(e)->t[which].flags[row] |= F_FOREIGN;
-}
-
 uint64_t jy_eng_value(void* e, int32_t which, int64_t row) {
     return static_cast<Engine*>(e)->t[which].value[row];
 }
@@ -68,51 +68,146 @@ uint64_t jy_eng_own(void* e, int32_t which, int64_t row, int32_t polarity) {
     return polarity ? t.own_n[row] : t.own_p[row];
 }
 
-void jy_eng_own_max(void* e, int32_t which, int64_t row, int32_t polarity,
-                    uint64_t v) {
-    Table& t = static_cast<Engine*>(e)->t[which];
-    uint64_t& own = polarity ? t.own_n[row] : t.own_p[row];
-    if (v > own) own = v;
-    t.flags[row] |= polarity ? F_OWNSET_N : F_OWNSET_P;
-}
-
-int32_t jy_eng_own_set(void* e, int32_t which, int64_t row) {
-    uint8_t f = static_cast<Engine*>(e)->t[which].flags[row];
-    return ((f & F_OWNSET_P) ? 1 : 0) | ((f & F_OWNSET_N) ? 2 : 0);
-}
-
-// drain writeback: authoritative post-join values for these rows; the
-// foreign mark clears (the pending batch that made them stale is gone)
-void jy_eng_apply_drain(void* e, int32_t which, const int64_t* rows,
-                        const uint64_t* values, int64_t n) {
+// every key of a slice in one call: `blob` is the keys end to end
+void jy_eng_upsert_many(void* e, int32_t which, const uint8_t* blob,
+                        const int64_t* lens, int64_t n, int64_t* rows) {
     Table& t = static_cast<Engine*>(e)->t[which];
     for (int64_t i = 0; i < n; i++) {
-        t.value[rows[i]] = values[i];
-        t.flags[rows[i]] &= static_cast<uint8_t>(~F_FOREIGN);
+        rows[i] = t.upsert(blob, lens[i]);
+        blob += lens[i];
     }
 }
 
-// pending-own export for the drain batch; `clear` zeroes the window
-// (callers peek first, drain on device, then clear — so a device failure
-// mid-drain leaves the window intact for the retry)
-int64_t jy_eng_export_pending(void* e, int32_t which, int64_t* rows,
-                              uint64_t* vp, uint64_t* vn, int64_t cap,
-                              int32_t clear) {
+// a slice of foreign deltas joins the window: every key's row is marked
+// foreign and sync-dirty, and its cells fold in by max. The cells lie
+// key-major, then by polarity: `counts[k * npol + pol]` of them each.
+// A cell at `adopt_col` (>= 0: a restore, whose batch carries this
+// node's own column) is also adopted as the row's own contribution, or
+// a later INC would vanish under it. Returns the cells folded.
+int64_t jy_eng_fold_foreign(void* e, int32_t which, const int64_t* key_rows,
+                            int64_t n_keys, int32_t npol,
+                            const int32_t* counts, const int32_t* cols,
+                            const uint64_t* vals, int32_t adopt_col) {
     Table& t = static_cast<Engine*>(e)->t[which];
-    int64_t n = static_cast<int64_t>(t.pend_rows.size());
-    if (n > cap) return -n;  // caller regrows buffers
-    for (int64_t i = 0; i < n; i++) {
-        int64_t r = t.pend_rows[i];
-        rows[i] = r;
-        vp[i] = (t.flags[r] & F_PEND_P) ? t.pend_p[r] : 0;
-        vn[i] = (t.flags[r] & F_PEND_N) ? t.pend_n[r] : 0;
-        if (clear) {
-            t.flags[r] &= static_cast<uint8_t>(~(F_PEND_P | F_PEND_N));
-            t.pend_p[r] = 0;
-            t.pend_n[r] = 0;
+    int64_t at = 0;
+    for (int64_t k = 0; k < n_keys; k++) {
+        int64_t row = key_rows[k];
+        if (!(t.flags[row] & F_FOREIGN)) {
+            t.flags[row] |= F_FOREIGN;
+            t.foreign_rows.push_back(row);
+        }
+        t.mark_sync(row);
+        for (int32_t pol = 0; pol < npol; pol++) {
+            for (int32_t j = counts[k * npol + pol]; j > 0; j--, at++) {
+                uint64_t v = vals[at];
+                Table::FCell& c = t.fcell(row, static_cast<uint32_t>(cols[at]));
+                uint64_t& cur = pol ? c.n : c.p;
+                if (v > cur) cur = v;
+                if (cols[at] == adopt_col) {
+                    uint64_t& own = pol ? t.own_n[row] : t.own_p[row];
+                    if (v > own) own = v;
+                    t.flags[row] |= pol ? F_OWNSET_N : F_OWNSET_P;
+                }
+            }
         }
     }
-    if (clear) t.pend_rows.clear();
+    return at;
+}
+
+// rows the next drain carries: own-pending rows and foreign rows
+int64_t jy_eng_drain_count(void* e, int32_t which) {
+    Table& t = static_cast<Engine*>(e)->t[which];
+    int64_t n = static_cast<int64_t>(t.pend_rows.size());
+    for (int64_t r : t.foreign_rows)
+        if (!(t.flags[r] & (F_PEND_P | F_PEND_N))) n++;
+    return n;
+}
+
+// the drain batch, ready: `rows` and the zeroed (cap, npol * rep_cap) u64
+// matrix [P | N] get the pending own values at `own_col` joined with a
+// foreign row's columns; the i-th row of the batch is the matrix's i-th,
+// or with `by_row` (a dense drain, cap = the plane's rows) the one at
+// its own row number. Nothing clears: the window goes in
+// `jy_eng_finish_drain`, so a device failure mid-drain leaves every
+// contribution for the retry. Returns the rows written, or -1 when the
+// batch does not fit `cap` rows or a column lies beyond `rep_cap`.
+int64_t jy_eng_export_drain(void* e, int32_t which, int32_t own_col,
+                            int32_t rep_cap, int32_t npol, int32_t by_row,
+                            int64_t* rows, uint64_t* mat, int64_t cap) {
+    Table& t = static_cast<Engine*>(e)->t[which];
+    if (own_col >= rep_cap) return -1;
+    const int64_t width = static_cast<int64_t>(npol) * rep_cap;
+    int64_t n = 0;
+    auto emit = [&](int64_t r) -> bool {
+        if (n >= cap || (by_row && r >= cap)) return false;
+        uint64_t* out = mat + (by_row ? r : n) * width;
+        rows[n++] = r;
+        if (t.flags[r] & F_PEND_P) out[own_col] = t.pend_p[r];
+        if (npol > 1 && (t.flags[r] & F_PEND_N))
+            out[rep_cap + own_col] = t.pend_n[r];
+        if (!(t.flags[r] & F_FOREIGN)) return true;
+        for (const Table::FCell& c : t.fcells[r]) {
+            if (c.col >= static_cast<uint32_t>(rep_cap)) return false;
+            if (c.p > out[c.col]) out[c.col] = c.p;
+            if (npol > 1 && c.n > out[rep_cap + c.col])
+                out[rep_cap + c.col] = c.n;
+        }
+        return true;
+    };
+    for (int64_t r : t.pend_rows)
+        if (!emit(r)) return -1;
+    for (int64_t r : t.foreign_rows)
+        if (!(t.flags[r] & (F_PEND_P | F_PEND_N)) && !emit(r)) return -1;
+    return n;
+}
+
+// drain writeback: authoritative post-join values for the drained rows;
+// the window the batch was read from clears (pending own values, and
+// the foreign marks: the columns themselves stay, they are the digest's)
+void jy_eng_finish_drain(void* e, int32_t which, const int64_t* rows,
+                         const uint64_t* values, int64_t n) {
+    Table& t = static_cast<Engine*>(e)->t[which];
+    for (int64_t i = 0; i < n; i++) t.value[rows[i]] = values[i];
+    for (int64_t r : t.foreign_rows)
+        t.flags[r] &= static_cast<uint8_t>(~F_FOREIGN);
+    t.foreign_rows.clear();
+    for (int64_t r : t.pend_rows) {
+        t.flags[r] &= static_cast<uint8_t>(~(F_PEND_P | F_PEND_N));
+        t.pend_p[r] = 0;
+        t.pend_n[r] = 0;
+    }
+    t.pend_rows.clear();
+}
+
+// one row's canonical columns for the sync digest: the foreign columns
+// joined with the own contribution at `own_col` — what the device
+// converges to, with no device read. Returns the cells written, or -n
+// when `cap` is too small for the row's n.
+int64_t jy_eng_sync_cols(void* e, int32_t which, int64_t row, int32_t own_col,
+                         int32_t* cols, uint64_t* vp, uint64_t* vn,
+                         int64_t cap) {
+    Table& t = static_cast<Engine*>(e)->t[which];
+    const std::vector<Table::FCell>& cells = t.fcells[row];
+    int64_t need = static_cast<int64_t>(cells.size()) + 1;
+    if (need > cap) return -need;
+    uint64_t own_p = (t.flags[row] & F_OWNSET_P) ? t.own_p[row] : 0;
+    uint64_t own_n = (t.flags[row] & F_OWNSET_N) ? t.own_n[row] : 0;
+    bool own_seen = false;
+    int64_t n = 0;
+    for (const Table::FCell& c : cells) {
+        bool own = static_cast<int32_t>(c.col) == own_col;
+        own_seen |= own;
+        cols[n] = static_cast<int32_t>(c.col);
+        vp[n] = own && own_p > c.p ? own_p : c.p;
+        vn[n] = own && own_n > c.n ? own_n : c.n;
+        n++;
+    }
+    if (!own_seen && (own_p || own_n)) {
+        cols[n] = own_col;
+        vp[n] = own_p;
+        vn[n] = own_n;
+        n++;
+    }
     return n;
 }
 

@@ -135,6 +135,16 @@ struct Table {
     std::vector<int64_t> dirty_rows;  // insertion order; F_DIRTY dedups
     std::vector<int64_t> pend_rows;   // rows with any F_PEND_*
     std::vector<int64_t> sync_dirty;  // rows changed since last digest
+    // the foreign window: every column a peer ever converged into a row,
+    // cumulative and by COLUMN (the repo maps replica id -> column). Held
+    // once: the sync digest reads it, and a drain sends a foreign row's
+    // columns whole (the device's join is idempotent)
+    struct FCell {
+        uint32_t col;
+        uint64_t p, n;
+    };
+    std::vector<std::vector<FCell>> fcells;
+    std::vector<int64_t> foreign_rows;  // rows with F_FOREIGN set
 
     int64_t find(const uint8_t* k, int64_t n) const { return idx.find(k, n); }
 
@@ -147,8 +157,23 @@ struct Table {
             pend_p.push_back(0);
             pend_n.push_back(0);
             flags.push_back(0);
+            fcells.emplace_back();
         }
         return row;
+    }
+
+    FCell& fcell(int64_t row, uint32_t col) {
+        for (FCell& c : fcells[row])
+            if (c.col == col) return c;
+        fcells[row].push_back(FCell{col, 0, 0});
+        return fcells[row].back();
+    }
+
+    void mark_sync(int64_t row) {
+        if (!(flags[row] & F_SYNCD)) {
+            flags[row] |= F_SYNCD;
+            sync_dirty.push_back(row);
+        }
     }
 
     void mark_dirty(int64_t row) {
@@ -170,10 +195,7 @@ struct Table {
         if (!(flags[row] & (F_PEND_P | F_PEND_N))) pend_rows.push_back(row);
         flags[row] |= bit;
         mark_dirty(row);
-        if (!(flags[row] & F_SYNCD)) {
-            flags[row] |= F_SYNCD;
-            sync_dirty.push_back(row);
-        }
+        mark_sync(row);
         value[row] += polarity ? static_cast<uint64_t>(-amount) : amount;
     }
 };
